@@ -106,11 +106,12 @@ def _mktestdata():
     return mod
 
 
-def gen_to_file(n, path, mindate_ms=None, maxdate_ms=None):
+def gen_to_file(n, path, mindate_ms=None, maxdate_ms=None, seed=12345):
     """Write n generated records to path; native generator
     (native/dngen.cc, same shape/distributions as tools/mktestdata)
     when available, Python otherwise.  Timestamps increase linearly
-    over [mindate_ms, maxdate_ms) (default: mktestdata's window)."""
+    over [mindate_ms, maxdate_ms) (default: mktestdata's window);
+    `seed` feeds the native generator's RNG."""
     mod = _mktestdata()
     if mindate_ms is None:
         mindate_ms = int(mod.MINDATE.timestamp() * 1000)
@@ -142,7 +143,7 @@ def gen_to_file(n, path, mindate_ms=None, maxdate_ms=None):
             for start in range(0, n, chunk):
                 cnt = min(chunk, n - start)
                 nb = lib.dn_gen(buf, len(buf), start, cnt, n,
-                                mindate_ms, maxdate_ms, 12345)
+                                mindate_ms, maxdate_ms, seed)
                 if nb <= 0:
                     raise RuntimeError('dn_gen failed (rv=%d)' % nb)
                 f.write(ctypes.string_at(buf, nb))

@@ -1536,7 +1536,27 @@ class DeviceScan(VectorScan):
         from .ops import pallas_kernels as pk
         use_pallas = progs.run_pallas is not None and \
             pk.should_use(ns, total_w)
+        self._log_kernel(pkey, use_pallas, profile[-1], ns)
         return progs, use_pallas
+
+    def _log_kernel(self, pkey, use_pallas, sparse_cap, ns):
+        """One debug record per (program, kernel) of this scan naming
+        the aggregation kernel the device runs and the mesh it runs
+        over — how a forced run proves WHICH device program answered
+        (chip_smoke.py reads it under LOG_LEVEL=debug)."""
+        seen = self.__dict__.setdefault('_kernels_logged', set())
+        if (pkey, use_pallas) in seen:
+            return
+        seen.add((pkey, use_pallas))
+        from .ops import pallas_kernels as pk
+        mesh = self._device_mesh()
+        LOG.debug('device aggregate kernel',
+                  kernel='pallas-onehot' if use_pallas else
+                  ('sparse-sort-merge' if sparse_cap else 'segment-sum'),
+                  interpret=bool(use_pallas and pk.needs_interpret()),
+                  segments=ns,
+                  mesh_devices=int(mesh[0].devices.size) if mesh else 0,
+                  merge='psum+pmin' if mesh else None)
 
     def _run_staged(self, staged, inputs):
         pn, profile, caps, ns, total_w = staged

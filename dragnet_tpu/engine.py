@@ -33,12 +33,15 @@ import numpy as np
 
 from . import jsvalues as jsv
 from . import batch as mod_batch
+from . import log as mod_log
 from . import query as mod_query
 from .aggr import Aggregator
 from .ops.kernels import FALSE, TRUE, ERROR
 
 BATCH_SIZE = 65536
 MAX_DENSE_SEGMENTS = 1 << 24
+
+LOG = mod_log.get('engine')
 
 # Deferred columnar merge: when a batch yields at least this many unique
 # key tuples, batch results are buffered as (global-code columns, weight
@@ -753,9 +756,13 @@ class VectorScan(object):
                 # (4x the scatter path's throughput on TPU)
                 from .ops import pallas_kernels as pk
                 if pk.should_use(num_segments, total):
+                    interpret = pk.needs_interpret()
+                    LOG.debug('device aggregate kernel',
+                              kernel='pallas-onehot',
+                              interpret=interpret,
+                              segments=num_segments)
                     agg = pk.make_pallas_aggregate(
-                        tuple(radices), n,
-                        interpret=pk.needs_interpret())
+                        tuple(radices), n, interpret=interpret)
                     w = weights.astype(np.float32)
                     return np.asarray(agg(codes, w, alive)).astype(
                         np.float64)
