@@ -1985,12 +1985,10 @@ class DeviceScan(VectorScan):
                 else:
                     specs[k] = SP()   # lookup tables: replicated
             sargs = {k: args[k] for k in specs}
-            from .ops import shard_map_compat
-            shard_map, vma_kwarg = shard_map_compat()
-            return shard_map(
+            return jax.shard_map(
                 lambda a: body(a, use_pallas), mesh=mesh,
                 in_specs=(specs,), out_specs=(SP(), SP(), SP()),
-                **{vma_kwarg: not use_pallas})(sargs)
+                check_vma=not use_pallas)(sargs)
 
         def fold(args, acc, use_pallas):
             """One batch folded into the device-resident accumulator:

@@ -73,19 +73,6 @@ def get_jax():
     return _jax if _jax else None
 
 
-def shard_map_compat():
-    """(shard_map, variance-check kwarg name) across jax versions: the
-    stable jax.shard_map (kwarg check_vma) when present, else the
-    experimental API (kwarg check_rep, jax <= 0.4.x).  Both take the
-    same (f, mesh=, in_specs=, out_specs=) signature."""
-    jax, _ = get_jax()
-    sm = getattr(jax, 'shard_map', None)
-    if sm is not None:
-        return sm, 'check_vma'
-    from jax.experimental.shard_map import shard_map
-    return shard_map, 'check_rep'
-
-
 _backend_ready = None
 
 

@@ -36,16 +36,15 @@ def make_mesh(devices=None, axis='d'):
     return Mesh(np.array(devices), (axis,))
 
 
-@functools.lru_cache(maxsize=None)
-def _sharded_aggregate_cached(radices, per_device, ndev, scatter,
-                              integer_weights, use_pallas=False):
+def sharded_step(mesh, radices, per_device, scatter, integer_weights,
+                 use_pallas=False, interpret=False):
+    """The shard_map'd (codes[ncols, n], weights[n], alive[n]) -> dense
+    aggregate over `mesh` (record axis sharded over 'd'): a local
+    segment-sum (or the one-hot kernel) per device, merged by psum, or
+    by psum_scatter when `scatter` leaves each device a disjoint slice
+    of the buckets.  Unjitted, so callers choose the shardings."""
     jax, jnp = get_jax()
-    from jax.sharding import Mesh, PartitionSpec as P
-    from ..ops import shard_map_compat
-    shard_map, vma_kwarg = shard_map_compat()
-
-    mesh = make_mesh()
-    assert len(mesh.devices.flat) == ndev
+    from jax.sharding import PartitionSpec as P
 
     num_segments = 1
     for r in radices:
@@ -54,13 +53,12 @@ def _sharded_aggregate_cached(radices, per_device, ndev, scatter,
 
     if use_pallas:
         from ..ops import pallas_kernels as pk
-        interp = pk.needs_interpret()
 
         def local_step(codes, weights, alive):
             # fused one-hot matmul per shard (f32; caller guarantees
             # the total weight is f32-exact)
             return pk.onehot_dense(radices, per_device, codes,
-                                   weights, alive, interpret=interp)
+                                   weights, alive, interpret=interpret)
     else:
         def local_step(codes, weights, alive):
             # codes: [ncols, per_device] i32; weights/alive: [per_device]
@@ -88,11 +86,24 @@ def _sharded_aggregate_cached(radices, per_device, ndev, scatter,
 
     # pallas_call does not annotate its outputs with mesh-axis
     # variance, so the vma check must be off for that path only
-    sharded = shard_map(step, mesh=mesh,
-                        in_specs=(P(None, 'd'), P('d'), P('d')),
-                        out_specs=out_spec,
-                        **{vma_kwarg: not use_pallas})
-    return jax.jit(sharded), mesh
+    return jax.shard_map(step, mesh=mesh,
+                         in_specs=(P(None, 'd'), P('d'), P('d')),
+                         out_specs=out_spec, check_vma=not use_pallas)
+
+
+@functools.lru_cache(maxsize=None)
+def _sharded_aggregate_cached(radices, per_device, ndev, scatter,
+                              integer_weights, use_pallas=False):
+    jax, _ = get_jax()
+    mesh = make_mesh()
+    assert len(mesh.devices.flat) == ndev
+    interpret = False
+    if use_pallas:
+        from ..ops import pallas_kernels as pk
+        interpret = pk.needs_interpret()
+    return jax.jit(sharded_step(mesh, radices, per_device, scatter,
+                                integer_weights, use_pallas,
+                                interpret)), mesh
 
 
 def sharded_aggregate(key_codes, radices, weights, alive, scatter=False):

@@ -1,0 +1,245 @@
+"""Ask the TPU v5e compiler, without the chip.
+
+The kernels and jitted programs of the scan / build / query device path
+are compiled here for a DESCRIBED v5e (no device attached), at the real
+batch widths and with x64 on as the program has it.  What Mosaic or the
+TPU backend would refuse on the chip — a 64-bit vector layout in the
+one-hot kernel, a misaligned tile, a program over the memory budget, a
+shard_map that cannot be partitioned — is refused here, at no chip
+time.  Nothing runs: results and times come only from a chip run
+(chip_smoke.py).
+
+The topology is described inside a module-scoped fixture, never at
+import: only one process may load libtpu, and xdist workers import
+every test file.  All of these tests stay in this one file for the
+same reason, and none of them starts a child process.
+
+Not covered: DeviceScan asks `jax.default_backend()` for buffer
+donation (device_scan._donate_kw) and sees the CPU here, so the
+programs compile WITHOUT `donate_argnums`; and DeviceScanStack's
+combined multi-metric jit is only compiled through its member folds.
+"""
+
+import os
+import sys
+
+import numpy as np
+import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+import bench                                        # noqa: E402
+from dragnet_tpu import device_index                # noqa: E402
+from dragnet_tpu import devbench                    # noqa: E402
+from dragnet_tpu import engine                      # noqa: E402
+from dragnet_tpu import native as mod_native        # noqa: E402
+from dragnet_tpu import query as mod_query          # noqa: E402
+from dragnet_tpu.ops import get_jax                 # noqa: E402
+from dragnet_tpu.ops import byteparse_kernels       # noqa: E402
+from dragnet_tpu.ops import kernels                 # noqa: E402
+from dragnet_tpu.ops import pallas_kernels as pk    # noqa: E402
+from dragnet_tpu.parallel import mesh as mod_mesh   # noqa: E402
+from dragnet_tpu.vpipe import Pipeline              # noqa: E402
+
+BATCH = engine.BATCH_SIZE
+# forces the device-resident sparse sort-merge program at any corpus
+# size (chip_smoke.py's scan-sparse phase)
+SPARSE_QUERY = {'breakdowns': [{'name': 'req.url'}, {'name': 'latency'},
+                               {'name': 'dataLatency'}],
+                'filter': {'eq': ['req.method', 'GET']}}
+
+
+@pytest.fixture(scope='module')
+def topo():
+    os.environ.setdefault('TPU_LOG_DIR', 'disabled')
+    jax, _ = get_jax()
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        t = topologies.get_topology_desc(platform='tpu',
+                                         topology_name='v5e:2x2')
+    except Exception as e:
+        pytest.skip('no v5e:2x2 topology can be described here: %s' % e)
+    # a compile for a described chip is written to the persistent
+    # cache but cannot be read back without the chip
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update('jax_enable_compilation_cache', False)
+    compilation_cache.reset_cache()
+    yield t
+    jax.config.update('jax_enable_compilation_cache', was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope='module')
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope='module')
+def mesh4(topo):
+    from jax.sharding import Mesh
+    assert len(topo.devices) == 4
+    return Mesh(np.array(topo.devices), ('d',))
+
+
+@pytest.fixture(scope='module')
+def corpus(tmp_path_factory):
+    """One full batch of generated muskie-style records."""
+    if mod_native.get_lib() is None:
+        pytest.skip('native parser unavailable')
+    path = str(tmp_path_factory.mktemp('tpu_compile') / 'batch.log')
+    bench.gen_to_file(BATCH, path)
+    return path
+
+
+def _sds(shape, dtype, sharding):
+    jax, _ = get_jax()
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _like(tree, sharding):
+    """ShapeDtypeStructs placed on `sharding` for every array leaf."""
+    jax, _ = get_jax()
+    return jax.tree_util.tree_map(
+        lambda x: _sds(np.shape(x), x.dtype, sharding), tree)
+
+
+def _compile(fn, *args):
+    jax, _ = get_jax()
+    if not hasattr(fn, 'lower'):
+        fn = jax.jit(fn)
+    return fn.lower(*args).compile()
+
+
+# -- aggregation kernels ------------------------------------------------------
+
+@pytest.mark.parametrize('radices', [(16, 32), (64, 64)])
+def test_onehot_kernel_compiles_with_mosaic(one_chip, radices):
+    """The Pallas one-hot kernel at the engine's batch size, under x64,
+    NOT in interpret mode; (64, 64) is MAX_PALLAS_SEGMENTS."""
+    assert radices[0] * radices[1] <= pk.MAX_PALLAS_SEGMENTS
+
+    def agg(codes, weights, alive):
+        return pk.onehot_dense(radices, BATCH, codes, weights, alive,
+                               interpret=False)
+    compiled = _compile(agg,
+                        _sds((2, BATCH), np.int32, one_chip),
+                        _sds((BATCH,), np.float32, one_chip),
+                        _sds((BATCH,), np.bool_, one_chip))
+    assert 'tpu_custom_call' in compiled.as_text()
+
+
+@pytest.mark.parametrize('radices', [(16, 32), (64, 64)])
+def test_segment_sum_aggregate_compiles(one_chip, radices):
+    agg = kernels.make_aggregate(radices, BATCH, True)
+    _compile(agg,
+             _sds((2, BATCH), np.int32, one_chip),
+             _sds((BATCH,), np.int32, one_chip),
+             _sds((BATCH,), np.bool_, one_chip))
+
+
+# -- the DeviceScan programs --------------------------------------------------
+
+def _staged_program(query_conf, datafile):
+    """(jitted fold, example inputs, accumulator shapes, use_pallas) of
+    the program DeviceScan builds for one real batch of `datafile`:
+    staged on the CPU backend exactly as a scan stages it, but not
+    run."""
+    jax, _ = get_jax()
+    from dragnet_tpu.device_scan import DeviceScan
+    scan = DeviceScan(mod_query.query_load(dict(query_conf)), None,
+                      Pipeline())
+    parser = devbench._one_batch_parser(datafile, scan, BATCH)
+    n = parser.batch_size()
+    assert n == BATCH
+    assert scan._probe_backend()
+    inputs = {}
+    staged = scan._stage_device(engine.NativeColumns(parser),
+                                np.ones(n, dtype=np.float64), None,
+                                inputs)
+    assert staged is not None, 'batch was not eligible for the device'
+    progs, use_pallas = scan._staged_programs(staged)
+    inputs[scan._pfx + 'base'] = np.int64(0)
+    run = progs.run_pallas if use_pallas else progs.run_scatter
+    return run, inputs, jax.eval_shape(progs.acc_init), use_pallas
+
+
+@pytest.mark.parametrize('name,query_conf,sparse', [
+    ('dense', bench.QUERY, False),
+    ('wide-dense', bench.HC_QUERY, False),
+    ('sparse', SPARSE_QUERY, True),
+])
+def test_device_scan_program_compiles(one_chip, corpus, name,
+                                      query_conf, sparse):
+    run, inputs, acc, use_pallas = _staged_program(query_conf, corpus)
+    assert not use_pallas
+    assert (len(acc) == 5) == sparse     # the sparse set's five leaves
+    _compile(run, _like(inputs, one_chip), _like(acc, one_chip))
+
+
+def test_device_scan_pallas_program_compiles(one_chip, corpus,
+                                             monkeypatch):
+    """PALLAS_QUERY's program with the Mosaic kernel inside the fold.
+    The routing gate and the interpret switch both ask for the live
+    backend, which is the CPU here: steer them as the chip would."""
+    monkeypatch.setenv('DN_PALLAS', 'force')
+    monkeypatch.setattr(pk, 'needs_interpret', lambda: False)
+    run, inputs, acc, use_pallas = _staged_program(bench.PALLAS_QUERY,
+                                                   corpus)
+    assert use_pallas
+    compiled = _compile(run, _like(inputs, one_chip),
+                        _like(acc, one_chip))
+    assert 'tpu_custom_call' in compiled.as_text()
+
+
+# -- index query and parse lanes ---------------------------------------------
+
+def test_index_fold_compiles(one_chip):
+    """The slot-packed fold at the default dispatch budget: 64 slots of
+    16384 rows (DN_INDEX_DEVICE_BATCH_ROWS = 1 << 20)."""
+    prow, ptab, pu = 16384, 4096, 65536
+    nslots = device_index.batch_rows() // prow
+    assert nslots == device_index._MAX_SLOTS
+    prog = device_index._fold_program(nslots, prow, ptab, pu)
+    rows = tuple(_sds((prow,), np.int64, one_chip)
+                 for _ in range(nslots))
+    _compile(prog, rows, rows, _sds((nslots, ptab), np.int64, one_chip),
+             _sds((pu,), np.int64, one_chip))
+
+
+def test_byteparse_parity_compiles(one_chip):
+    fn = byteparse_kernels._jax_fn()
+    _compile(fn, _sds((byteparse_kernels.PAD_QUANTUM,), np.uint8,
+                      one_chip))
+
+
+# -- the mesh path ------------------------------------------------------------
+
+@pytest.mark.parametrize('scatter,use_pallas,collective', [
+    (False, False, ('all-reduce',)),
+    # at this accumulator size the v5e compiler lowers psum_scatter
+    # to an all-reduce plus a per-device dynamic-slice
+    (True, False, ('reduce-scatter', 'all-reduce')),
+    (False, True, ('all-reduce',)),
+])
+def test_sharded_aggregate_compiles_on_mesh(mesh4, scatter, use_pallas,
+                                            collective):
+    """parallel/mesh.py's sharded aggregate over the four chips of a
+    v5e 2x2: the merge must be a collective, not a gather to one
+    device."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    radices = (16, 32)
+    per_device = BATCH // 4
+    step = mod_mesh.sharded_step(mesh4, radices, per_device, scatter,
+                                 True, use_pallas, interpret=False)
+    wdtype = np.float32 if use_pallas else np.int32
+    compiled = _compile(
+        step,
+        _sds((2, BATCH), np.int32, NamedSharding(mesh4, P(None, 'd'))),
+        _sds((BATCH,), wdtype, NamedSharding(mesh4, P('d'))),
+        _sds((BATCH,), np.bool_, NamedSharding(mesh4, P('d'))))
+    text = compiled.as_text()
+    assert any(op in text for op in collective)
+    assert ('tpu_custom_call' in text) == use_pallas
