@@ -4,7 +4,7 @@
 
 PYTHON ?= python3
 
-.PHONY: all native test check bench bench-iq bench-iq-device \
+.PHONY: all native test check bench chip-smoke bench-iq bench-iq-device \
     bench-build bench-parse \
     bench-serve bench-cluster bench-follow bench-subscribe \
     bench-fanin bench-verify \
@@ -23,14 +23,21 @@ test: native
 
 check:
 	$(PYTHON) -m compileall -q dragnet_tpu bin/dn.py bench.py \
-	    __graft_entry__.py tests
+	    chip_smoke.py __graft_entry__.py tests
 	$(PYTHON) tools/checkstyle dragnet_tpu bin tests \
 	    tools/checkstyle tools/json_streamer tools/pathenum \
 	    tools/validate-schema tools/profile_device tools/mktestdata \
-	    tools/soak_faults.py bench.py __graft_entry__.py
+	    tools/soak_faults.py bench.py chip_smoke.py __graft_entry__.py
 
 bench: native
 	$(PYTHON) bench.py
+
+# the quickest proof that scan, build and query still run on the chip:
+# forced device lanes through bin/dn at 2M records, byte-compared with
+# DN_ENGINE=vector; the last stdout line is the JSON verdict (exit 0
+# only on a TPU).  It builds native/ itself.
+chip-smoke:
+	$(PYTHON) chip_smoke.py
 
 # the serving-path legs only: 365-shard index-query execution
 # (stacked DN_IQ_STACK batch vs DN_IQ_THREADS per-shard pool vs

@@ -10,21 +10,6 @@ import sys  # noqa: E402
 _root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, _root)
 
-# bin/dn may have prepended tools/fast_start (DN_FAST_START=1) so OUR
-# interpreter skipped a heavyweight site hook; strip it from the
-# inherited PYTHONPATH so child processes get normal startup.
-_shim = os.path.join(_root, 'tools', 'fast_start')
-if os.environ.get('PYTHONPATH'):
-    _parts = os.environ['PYTHONPATH'].split(os.pathsep)
-    _kept = [p for p in _parts
-             if not (p and os.path.abspath(p) == _shim)]
-    if len(_kept) != len(_parts):
-        # empty entries mean cwd — preserve them; only the shim goes
-        if _kept:
-            os.environ['PYTHONPATH'] = os.pathsep.join(_kept)
-        else:
-            del os.environ['PYTHONPATH']
-
 from dragnet_tpu.cli import main  # noqa: E402
 _REQUIRE_S = _time.time() - _T0   # module-load cost (reference
                                   # bin/dn:80-83 tracked the same span)

@@ -732,13 +732,15 @@ class VectorScan(object):
 
     def _dense_aggregate(self, key_codes, radices, weights, alive, n):
         # 'auto' favors the numpy bincount for single-device CLI runs
-        # (dispatch latency dwarfs these kernel sizes, especially over a
-        # tunneled accelerator); DN_ENGINE=jax forces the device kernel,
+        # (dispatch latency dwarfs these kernel sizes); DN_ENGINE=jax
+        # forces the device kernel,
         # and the mesh/cluster path always runs on devices.
-        use_jax = False
-        if engine_mode() == 'jax':
+        use_jax = engine_mode() == 'jax'
+        if use_jax:
             from .ops import get_jax
-            use_jax = get_jax() is not None
+            if get_jax() is None:
+                from .errors import DNError
+                raise DNError('DN_ENGINE=jax: jax is not installed')
 
         num_segments = 1
         for r in radices:

@@ -41,31 +41,33 @@ def _build_target(so_path, src):
     if os.path.exists(so_path) and \
             os.path.getmtime(so_path) >= os.path.getmtime(src):
         return True
-    try:
-        # serialize concurrent builds (multi-process cluster launches)
-        import fcntl
-        os.makedirs(os.path.join(_NATIVE_DIR, 'build'), exist_ok=True)
-        lockpath = os.path.join(_NATIVE_DIR, 'build', '.lock')
-        with open(lockpath, 'w') as lock:
-            fcntl.flock(lock, fcntl.LOCK_EX)
-            if not (os.path.exists(so_path) and os.path.getmtime(
-                    so_path) >= os.path.getmtime(src)):
-                # build the specific target so a compile failure in one
-                # library cannot fail the other's build
-                target = os.path.relpath(so_path, _NATIVE_DIR)
-                subprocess.run(['make', '-C', _NATIVE_DIR, target],
-                               check=True, stdout=subprocess.DEVNULL,
-                               stderr=subprocess.DEVNULL)
-    except Exception:
-        # a stale-but-loadable library beats the 9x-slower fallback,
-        # but its semantics may lag the source — say so
-        if os.path.exists(so_path):
-            import sys
-            sys.stderr.write(
-                'dn: warning: native rebuild failed; using stale %s '
-                '(set DN_NATIVE=0 to force the Python path)\n'
-                % so_path)
+    # serialize concurrent builds (multi-process cluster launches)
+    import fcntl
+    os.makedirs(os.path.join(_NATIVE_DIR, 'build'), exist_ok=True)
+    lockpath = os.path.join(_NATIVE_DIR, 'build', '.lock')
+    with open(lockpath, 'w') as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        if os.path.exists(so_path) and os.path.getmtime(so_path) >= \
+                os.path.getmtime(src):
             return True
+        # build the specific target so a compile failure in one
+        # library cannot fail the other's build
+        target = os.path.relpath(so_path, _NATIVE_DIR)
+        try:
+            proc = subprocess.run(['make', '-C', _NATIVE_DIR, target],
+                                  stdout=subprocess.PIPE,
+                                  stderr=subprocess.STDOUT)
+            failed = proc.returncode != 0
+            output = proc.stdout.decode('utf-8', 'replace')
+        except OSError as e:
+            failed, output = True, str(e)
+    if failed:
+        # a library older than its source is NOT loaded: its
+        # semantics may lag the code that calls it
+        import sys
+        sys.stderr.write('dn: warning: native build of %s failed; the '
+                         'Python path takes over:\n%s\n'
+                         % (target, output.rstrip()))
         return False
     return os.path.exists(so_path)
 

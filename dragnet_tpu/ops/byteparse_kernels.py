@@ -23,13 +23,12 @@ Two implementations, bit-identical (differential-tested):
   the fast H2D direction and only the packed n/8 parity mask comes
   back down the slow D2H one.
 
-The first device call runs under the wedge-armor deadline
-(``DN_DEVICE_PROBE_TIMEOUT``, device_scan.run_with_deadline): a hung
-device plugin costs one bounded probe and the parser degrades to the
-numpy kernel with a warning, never a hung ``dn scan``.
+The first device call runs under the probe deadline
+(``DN_DEVICE_PROBE_TIMEOUT``, device_scan.run_with_deadline): a device
+backend that never answers costs one bounded probe and fails the scan
+with the reason (``DN_PARSE=device`` is a forced lane), never a hung
+``dn scan``.
 """
-
-import sys
 
 import numpy as np
 
@@ -129,14 +128,13 @@ def device_parity_available():
 
 
 def parity_device(arr):
-    """The jax parity scan with first-contact wedge armor: the first
-    call runs under DN_DEVICE_PROBE_TIMEOUT on a daemon thread; a
-    timeout or error warns once and pins the numpy kernel for the rest
-    of the process (identical arrays either way)."""
+    """The jax parity scan (DN_PARSE=device, a forced lane) with
+    first-contact wedge armor: the first call runs under
+    DN_DEVICE_PROBE_TIMEOUT on a daemon thread, and a timeout or error
+    fails the scan with the reason rather than answering from the
+    numpy kernel."""
     if _DEVICE_STATE['ok'] is True:
         return _parity_jax_call(arr)
-    if _DEVICE_STATE['ok'] is False:
-        return parity_numpy(arr)
     from ..device_scan import probe_deadline_s, run_with_deadline
     status, result = run_with_deadline(
         lambda: _parity_jax_call(arr), probe_deadline_s(),
@@ -145,8 +143,7 @@ def parity_device(arr):
         _DEVICE_STATE['ok'] = True
         return result
     _DEVICE_STATE['ok'] = False
-    sys.stderr.write(
-        'dn: warning: device parse kernel %s; using host vector '
-        'kernel\n' % ('probe timed out' if status == 'timeout'
-                      else 'failed (%s)' % (result,)))
-    return parity_numpy(arr)
+    from ..errors import DNError
+    raise DNError('DN_PARSE=device: device parse kernel %s'
+                  % ('probe timed out' if status == 'timeout'
+                     else 'failed (%r)' % (result,)))

@@ -119,7 +119,7 @@ def expected(datafile):
 @pytest.fixture
 def cachedir(tmp_path, monkeypatch):
     """An isolated audition cache per test."""
-    monkeypatch.setenv('DN_XLA_CACHE_DIR', str(tmp_path))
+    monkeypatch.setenv('JAX_COMPILATION_CACHE_DIR', str(tmp_path))
     monkeypatch.delenv('DN_AUDITION_CACHE', raising=False)
     monkeypatch.delenv('DN_AUDITION_TTL_S', raising=False)
     return str(tmp_path)

@@ -502,10 +502,12 @@ def test_structural_kernels_identical():
     assert np.array_equal(a, np.asarray(b))
 
 
-def test_device_kernel_wedge_falls_back(monkeypatch):
-    """A hung jax parity kernel degrades to the numpy kernel under the
-    probe deadline instead of hanging the scan."""
+def test_device_kernel_wedge_is_an_error(monkeypatch):
+    """A hung jax parity kernel fails the scan under the probe
+    deadline: DN_PARSE=device is a forced lane, so it neither hangs
+    nor answers from the numpy kernel."""
     import time as mod_time
+    from dragnet_tpu.errors import DNError
 
     def hang(arr):
         mod_time.sleep(60)
@@ -514,9 +516,10 @@ def test_device_kernel_wedge_falls_back(monkeypatch):
     monkeypatch.setenv('DN_DEVICE_PROBE_TIMEOUT', '1')
     arr = np.frombuffer(b'{"a":1}\n', dtype=np.uint8)
     t0 = mod_time.monotonic()
-    out = bk.parity_device(arr)
+    with pytest.raises(DNError) as ei:
+        bk.parity_device(arr)
     assert mod_time.monotonic() - t0 < 30
-    assert np.array_equal(out, bk.parity_numpy(arr))
+    assert 'probe timed out' in ei.value.message
     assert bk._DEVICE_STATE['ok'] is False
 
 

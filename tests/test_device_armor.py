@@ -11,6 +11,8 @@ import os
 import sys
 import time
 
+import pytest
+
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
@@ -21,7 +23,7 @@ from dragnet_tpu.vpipe import Pipeline                 # noqa: E402
 
 def _enable_cache(monkeypatch, tmp_path):
     monkeypatch.setenv('DN_AUDITION_CACHE', '1')
-    monkeypatch.setenv('DN_XLA_CACHE_DIR', str(tmp_path / 'xla'))
+    monkeypatch.setenv('JAX_COMPILATION_CACHE_DIR', str(tmp_path / 'xla'))
 
 
 # -- cache mechanics -------------------------------------------------------
@@ -54,7 +56,7 @@ def test_audition_cache_ttl(tmp_path, monkeypatch):
 
 def test_audition_cache_disabled(tmp_path, monkeypatch):
     monkeypatch.setenv('DN_AUDITION_CACHE', '0')
-    monkeypatch.setenv('DN_XLA_CACHE_DIR', str(tmp_path / 'xla'))
+    monkeypatch.setenv('JAX_COMPILATION_CACHE_DIR', str(tmp_path / 'xla'))
     device_scan.audition_cache_put('k', True)
     assert device_scan.audition_cache_get('k') is None
     assert not os.path.exists(str(tmp_path / 'xla'))
@@ -156,17 +158,63 @@ def test_probe_deadline_env(monkeypatch):
     assert device_scan.probe_deadline_s() == 420.0
 
 
-def test_forced_probe_timeout_falls_back(monkeypatch, capsys):
-    """DN_ENGINE=jax with a wedged backend: the synchronous probe —
-    previously an indefinite hang — times out, warns, and permanently
-    routes the scan to the host engine."""
+def test_forced_probe_timeout_is_an_error(monkeypatch):
+    """DN_ENGINE=jax with a backend that never answers: the
+    synchronous probe times out and the scan FAILS with the reason —
+    a forced device lane never finishes on the host."""
+    from dragnet_tpu.errors import DNError
     q = mod_query.query_load({'breakdowns': [{'name': 'host'}]})
     s = device_scan.DeviceScan(q, None, Pipeline())
+    monkeypatch.setenv('DN_ENGINE', 'jax')
+    monkeypatch.setenv('DN_DEVICE_PROBE_TIMEOUT', '0.1')
+    monkeypatch.setattr(s, '_probe_ok', lambda: time.sleep(30))
+    with pytest.raises(DNError) as ei:
+        s._probe_backend()
+    assert 'DN_ENGINE=jax' in ei.value.message
+    assert 'device backend unresponsive' in ei.value.message
+
+
+def test_unforced_probe_timeout_falls_back(monkeypatch, capsys):
+    """The same timeout without DN_ENGINE=jax (the cluster backend's
+    synchronous probe in auto mode): warns, and permanently routes the
+    scan to the host engine."""
+    q = mod_query.query_load({'breakdowns': [{'name': 'host'}]})
+    s = device_scan.DeviceScan(q, None, Pipeline())
+    monkeypatch.delenv('DN_ENGINE', raising=False)
     monkeypatch.setenv('DN_DEVICE_PROBE_TIMEOUT', '0.1')
     monkeypatch.setattr(s, '_probe_ok', lambda: time.sleep(30))
     assert s._probe_backend() is False
     assert s._disabled
+    assert s.probe_status == 'timeout'
     assert 'device backend unresponsive' in capsys.readouterr().err
+
+
+def test_forced_probe_error_is_an_error(monkeypatch):
+    from dragnet_tpu.errors import DNError
+    q = mod_query.query_load({'breakdowns': [{'name': 'host'}]})
+    s = device_scan.DeviceScan(q, None, Pipeline())
+    monkeypatch.setenv('DN_ENGINE', 'jax')
+
+    def boom():
+        raise RuntimeError('no chip')
+    monkeypatch.setattr(s, '_probe_ok', boom)
+    with pytest.raises(DNError) as ei:
+        s._probe_backend()
+    assert 'no chip' in ei.value.message
+
+
+def test_forced_scan_without_native_columns_is_an_error(monkeypatch):
+    """DN_ENGINE=jax and a batch from the Python record path (no
+    native library): an error, not a silent host run."""
+    from dragnet_tpu.errors import DNError
+    q = mod_query.query_load({'breakdowns': [{'name': 'host'}]})
+    s = device_scan.DeviceScan(q, None, Pipeline())
+    monkeypatch.setenv('DN_ENGINE', 'jax')
+    with pytest.raises(DNError) as ei:
+        s._try_device(object(), [1], None)
+    assert 'native column parser' in ei.value.message
+    monkeypatch.setenv('DN_ENGINE', 'auto')
+    assert s._try_device(object(), [1], None) is False
 
 
 def test_auto_probe_deadline_disables(monkeypatch):

@@ -232,7 +232,7 @@ def test_auto_audition_persists_iq_verdict(tmp_path, monkeypatch):
     on."""
     _need_jax()
     cache_dir = str(tmp_path / 'xla')
-    monkeypatch.setenv('DN_XLA_CACHE_DIR', cache_dir)
+    monkeypatch.setenv('JAX_COMPILATION_CACHE_DIR', cache_dir)
     monkeypatch.setenv('DN_AUDITION_CACHE', '1')
     ds, _, _ = _built(tmp_path, n=1500)
     conf = FUZZ_QUERIES[0]

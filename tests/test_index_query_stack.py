@@ -376,44 +376,55 @@ def test_device_lane_differential(tmp_path, monkeypatch):
     assert dev == host
 
 
-def test_device_lane_clean_fallback(tmp_path, monkeypatch, capsys):
-    """No usable chip (jax unavailable): the device lane warns once
-    and the host path answers identically — dn query never fails for
-    lack of a device."""
+def test_forced_device_lane_without_jax_is_an_error(tmp_path,
+                                                    monkeypatch):
+    """DN_ENGINE=jax and no jax: the forced device lane fails the
+    query with the reason instead of answering from the host."""
+    from dragnet_tpu.errors import DNError
     datafile = str(tmp_path / 'data.log')
     idx = str(tmp_path / 'idx')
     _make_data(datafile, n=900)
     ds = _ds(datafile, idx)
     ds.build([_metric()], 'day')
     monkeypatch.setenv('DN_IQ_STACK', '1')
-    host = ds.query(_query(QUERIES[0]), 'day').points
 
     from dragnet_tpu import ops
     mod_iqs._reset_device_state()
     monkeypatch.setenv('DN_ENGINE', 'jax')
     monkeypatch.setattr(ops, 'get_jax', lambda: None)
-    pts = ds.query(_query(QUERIES[0]), 'day').points
-    assert pts == host
-    assert mod_iqs._DEVICE_STATE['ready'] is False
+    with pytest.raises(DNError) as ei:
+        ds.query(_query(QUERIES[0]), 'day')
+    assert 'device index-query lane unavailable' in ei.value.message
+    assert 'jax unavailable' in ei.value.message
+
+
+def test_auto_device_lane_clean_fallback(monkeypatch, capsys):
+    """A lane auto mode chose (not forced) still warns once and leaves
+    the answer to the host path."""
+    from dragnet_tpu import device_index as mod_di
+    mod_iqs._reset_device_state()
+    monkeypatch.delenv('DN_ENGINE', raising=False)
+    monkeypatch.delenv('DN_INDEX_DEVICE', raising=False)
+    mod_di._warn_device('backend failed to initialize')
+    mod_di._warn_device('backend failed to initialize')
     err = capsys.readouterr().err
-    assert 'device index-query lane unavailable' in err
-    # warned once; later queries stay quiet
-    ds.query(_query(QUERIES[0]), 'day')
-    assert 'unavailable' not in capsys.readouterr().err
+    assert err.count('device index-query lane unavailable') == 1
+    mod_iqs._reset_device_state()
 
 
-def test_device_lane_deadline_armor(tmp_path, monkeypatch, capsys):
-    """A wedged backend (first device op never returns) trips the
-    probe deadline: warning + host fallback instead of a hung query."""
+def test_forced_device_lane_deadline_is_an_error(tmp_path, monkeypatch):
+    """A backend whose first device op never returns trips the probe
+    deadline: a forced lane fails with the reason instead of hanging
+    the query (or answering from the host)."""
     pytest.importorskip('jax')
     import time as mod_time
+    from dragnet_tpu.errors import DNError
     datafile = str(tmp_path / 'data.log')
     idx = str(tmp_path / 'idx')
     _make_data(datafile, n=900)
     ds = _ds(datafile, idx)
     ds.build([_metric()], 'day')
     monkeypatch.setenv('DN_IQ_STACK', '1')
-    host = ds.query(_query(QUERIES[0]), 'day').points
 
     mod_iqs._reset_device_state()
     monkeypatch.setenv('DN_ENGINE', 'jax')
@@ -423,10 +434,11 @@ def test_device_lane_deadline_armor(tmp_path, monkeypatch, capsys):
         mod_di, '_fold_program',
         lambda s, r, t, pu:
         (lambda locs, ws, ttabs, acc: mod_time.sleep(60)))
-    pts = ds.query(_query(QUERIES[0]), 'day').points
-    assert pts == host
+    with pytest.raises(DNError) as ei:
+        ds.query(_query(QUERIES[0]), 'day')
+    assert 'unresponsive' in ei.value.message
     assert mod_iqs._DEVICE_STATE['ready'] is False
-    assert 'unresponsive' in capsys.readouterr().err
+    mod_iqs._reset_device_state()
 
 
 # -- CLI + cluster plan ----------------------------------------------------
