@@ -102,8 +102,9 @@ _SCRUB = ('DN_ENGINE', 'DN_INDEX_DEVICE', 'DN_PARSE', 'DN_PALLAS',
           'DN_COUNTERS_ALL', 'LOG_LEVEL', 'DN_TRACE', 'DRAGNET_CONFIG')
 
 
-# lines the XLA runtime writes itself (glog: "E0927 13:52:05.07 ...")
-_RUNTIME_LOG = re.compile(r'^[IWEF]\d{4} \d\d:\d\d:\d\d\.')
+# a line of a --counters dump (vpipe.Stage.dump: '%-18s %-13s%8d'), or
+# the build's one status line
+_COUNTER_LINE = re.compile(r'^(\S.*:\s*\d+|indexes for ".*" built)$')
 
 
 class PhaseFailed(Exception):
@@ -121,7 +122,7 @@ def child_env(extra):
     return env
 
 
-def run_child(argv, env, what):
+def run_child(argv, env):
     """One child, run to its end; (rc, stdout, stderr, seconds)."""
     t0 = time.monotonic()
     p = subprocess.run(argv, env=env, cwd=ROOT, stdout=subprocess.PIPE,
@@ -130,7 +131,7 @@ def run_child(argv, env, what):
 
 
 def must_run(argv, env, what):
-    rc, out, err, secs = run_child(argv, env, what)
+    rc, out, err, secs = run_child(argv, env)
     if rc != 0:
         show_failure(what, argv, rc, out, err)
         raise PhaseFailed('%s exited %d' % (what, rc))
@@ -147,8 +148,10 @@ def show_failure(what, argv, rc, out, err):
 
 
 def split_stderr(err):
-    """(counter lines, warning lines, debug-log records) of a dn
-    child's stderr."""
+    """(counter lines, `dn:` lines, debug-log records) of a dn child's
+    stderr.  Anything else — the XLA runtime's own log lines, Python
+    warnings of the installed jax — belongs to neither the program's
+    answer nor its warnings and is dropped."""
     counters, warnings, logs = [], [], []
     for line in err.decode('utf-8', 'replace').splitlines():
         if line.startswith('{'):
@@ -159,7 +162,7 @@ def split_stderr(err):
                 pass
         if line.startswith('dn: '):
             warnings.append(line)
-        elif not _RUNTIME_LOG.match(line):
+        elif _COUNTER_LINE.match(line):
             counters.append(line)
     return counters, warnings, logs
 
@@ -205,7 +208,9 @@ class Smoke(object):
     def dn(self, args, extra_env, what):
         env = dict(self.base_env)
         env.update(extra_env)
-        return run_child([DN] + args, child_env(env), what)
+        got = run_child([DN] + args, child_env(env))
+        say('  %s: exit %d in %.1fs' % (what, got[0], got[3]))
+        return got
 
     def setup(self):
         o = self.opts
