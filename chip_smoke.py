@@ -193,7 +193,6 @@ def digest(data):
 class Smoke(object):
     def __init__(self, opts, device):
         self.opts = opts
-        self.device = device
         self.on_tpu = device['platform'] == 'tpu'
         self.failed = []
         self.scratch = tempfile.mkdtemp(prefix='dn_chip_smoke_')
@@ -206,11 +205,16 @@ class Smoke(object):
     # -- set-up --------------------------------------------------------------
 
     def dn(self, args, extra_env, what):
+        """One `bin/dn` child run to its end: (stdout, stderr,
+        seconds); a non-zero exit fails the phase with its output."""
         env = dict(self.base_env)
         env.update(extra_env)
-        got = run_child([DN] + args, child_env(env))
-        say('  %s: exit %d in %.1fs' % (what, got[0], got[3]))
-        return got
+        rc, out, err, secs = run_child([DN] + args, child_env(env))
+        say('  %s: exit %d in %.1fs' % (what, rc, secs))
+        if rc != 0:
+            show_failure(what, args, rc, out, err)
+            raise PhaseFailed('%s exited %d' % (what, rc))
+        return out, err, secs
 
     def setup(self):
         o = self.opts
@@ -234,17 +238,11 @@ class Smoke(object):
             args.append('--index-path=' + indexdir)
         if backend is not None:
             args.append('--backend=' + backend)
-        rc, out, err, _ = self.dn(args, {}, 'datasource-add')
-        if rc != 0:
-            show_failure('datasource-add', args, rc, out, err)
-            raise PhaseFailed('datasource-add %s' % name)
+        self.dn(args, {}, 'datasource-add')
         if indexdir is not None:
             for mname, margs in METRIC_ARGS:
-                args = ['metric-add'] + margs + [name, mname]
-                rc, out, err, _ = self.dn(args, {}, 'metric-add')
-                if rc != 0:
-                    show_failure('metric-add', args, rc, out, err)
-                    raise PhaseFailed('metric-add %s' % mname)
+                self.dn(['metric-add'] + margs + [name, mname], {},
+                        'metric-add')
 
     # -- one phase -----------------------------------------------------------
 
@@ -252,18 +250,12 @@ class Smoke(object):
                             ref_env, need, forced=True):
         """Run the device command and its reference; fail unless the
         bytes agree, the lane counters in `need` are > 0 and (forced
-        phases) no warning was written.  Returns the device run's
-        (lane counters, debug-log records, seconds, stdout)."""
-        rc, out, err, secs = self.dn(dev_args, dev_env, name)
-        if rc != 0:
-            show_failure(name, dev_args, rc, out, err)
-            raise PhaseFailed('device run exited %d' % rc)
-        rrc, rout, rerr, rsecs = self.dn(ref_args, ref_env,
-                                         name + ' (reference)')
-        if rrc != 0:
-            show_failure(name + ' (reference)', ref_args, rrc, rout,
-                         rerr)
-            raise PhaseFailed('reference run exited %d' % rrc)
+        phases) no warning was written.  Returns the device run's lane
+        counters, debug-log records and stdout, and both runs'
+        seconds."""
+        out, err, secs = self.dn(dev_args, dev_env, name)
+        rout, rerr, rsecs = self.dn(ref_args, ref_env,
+                                    name + ' (reference)')
         counters, warnings, logs = split_stderr(err)
         # the reference build names its own datasource
         rcounters, _, _ = split_stderr(
@@ -289,9 +281,8 @@ class Smoke(object):
         if rlanes['ndevicebatches'] or rlanes['nstackedbatches'] or \
                 rlanes['index device sums']:
             raise PhaseFailed('the reference run used the device')
-        self.last = {'lanes': lanes, 'logs': logs, 'secs': secs,
-                     'ref_secs': rsecs, 'out': out}
-        return self.last
+        return {'lanes': lanes, 'logs': logs, 'secs': secs,
+                'ref_secs': rsecs, 'out': out}
 
     def phase(self, name, fn):
         try:
@@ -404,20 +395,12 @@ class Smoke(object):
         def run():
             want = self.opts.chips
             env = {'DN_ENGINE': 'jax', 'LOG_LEVEL': 'debug'}
-            rc, out, err, secs = self.dn(
+            out, err, secs = self.dn(
                 ['scan', '--counters'] + QUERY_ARGS + ['smoke_mesh'],
                 env, 'mesh scan')
-            if rc != 0:
-                show_failure('mesh scan', ['scan', 'smoke_mesh'], rc,
-                             out, err)
-                raise PhaseFailed('mesh scan exited %d' % rc)
-            rrc, rout, rerr, rsecs = self.dn(
+            rout, rerr, rsecs = self.dn(
                 ['scan', '--counters'] + QUERY_ARGS + ['smoke'],
                 env, 'one-chip scan')
-            if rrc != 0:
-                show_failure('one-chip scan', ['scan', 'smoke'], rrc,
-                             rout, rerr)
-                raise PhaseFailed('one-chip scan exited %d' % rrc)
             counters, warnings, logs = split_stderr(err)
             rcounters, rwarnings, rlogs = split_stderr(rerr)
             if warnings or rwarnings:

@@ -544,6 +544,7 @@ class DeviceScan(VectorScan):
         self._plans = None            # built lazily from the query
         self._epoch_sig = None
         self._programs = None
+        self._kernels_logged = set()  # (program key, pallas) debug-logged
         self._acc = None              # device-resident (dense, first, cvec)
         self._acc_meta = None         # epoch ('caps', 'cols', 'ns')
         self._acc_batch = 0           # batches folded into the acc
@@ -1541,10 +1542,9 @@ class DeviceScan(VectorScan):
         the aggregation kernel the device runs and the mesh it runs
         over — how a forced run proves WHICH device program answered
         (chip_smoke.py reads it under LOG_LEVEL=debug)."""
-        seen = self.__dict__.setdefault('_kernels_logged', set())
-        if (pkey, use_pallas) in seen:
+        if (pkey, use_pallas) in self._kernels_logged:
             return
-        seen.add((pkey, use_pallas))
+        self._kernels_logged.add((pkey, use_pallas))
         from .ops import pallas_kernels as pk
         mesh = self._device_mesh()
         LOG.debug('device aggregate kernel',

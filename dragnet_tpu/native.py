@@ -38,8 +38,12 @@ def _build_target(so_path, src):
     present afterward."""
     if not os.path.exists(src):
         return os.path.exists(so_path)
-    if os.path.exists(so_path) and \
-            os.path.getmtime(so_path) >= os.path.getmtime(src):
+
+    def fresh():
+        return os.path.exists(so_path) and \
+            os.path.getmtime(so_path) >= os.path.getmtime(src)
+
+    if fresh():
         return True
     # serialize concurrent builds (multi-process cluster launches)
     import fcntl
@@ -47,8 +51,7 @@ def _build_target(so_path, src):
     lockpath = os.path.join(_NATIVE_DIR, 'build', '.lock')
     with open(lockpath, 'w') as lock:
         fcntl.flock(lock, fcntl.LOCK_EX)
-        if os.path.exists(so_path) and os.path.getmtime(so_path) >= \
-                os.path.getmtime(src):
+        if fresh():
             return True
         # build the specific target so a compile failure in one
         # library cannot fail the other's build
