@@ -50,18 +50,17 @@ DN = os.path.join(ROOT, 'bin', 'dn')
 MINDATE_MS = 1388534400000
 DAYS = 30
 
-# bench.py's QUERY / PALLAS_QUERY / HC_QUERY as dn arguments
+# bench.py's QUERY and PALLAS_QUERY as dn arguments
 QUERY_ARGS = ['-b', 'host,req.method,operation,latency[aggr=quantize]',
               '-f', '{"ne":["res.statusCode",599]}']
 PALLAS_ARGS = ['-b', 'host,latency[aggr=quantize]']
-HC_ARGS = ['-b', 'req.url,latency']
-# HC_QUERY's accumulator (1024 urls x 2048 latencies at 2M records) fits
-# the dense budget, so it runs the dense program with a compacted
-# flush; a third wide column pushes the key space past
-# MAX_DENSE_SEGMENTS at every corpus size, which is what routes a scan
-# to the device-resident sparse sort-merge program (the filter keeps
-# the unique tuples, 341k at 2M records, inside the set's first
-# capacity, so the scan compiles that program once per batch shape)
+# bench.py's HC_QUERY (`req.url,latency`) does not reach the sparse
+# program on this corpus: its accumulator (1024 urls x 2048 latencies at
+# 2M records) fits the dense budget.  A third wide column pushes the key
+# space past MAX_DENSE_SEGMENTS at every corpus size, which is what
+# routes a scan to the device-resident sparse sort-merge program (the
+# filter keeps the unique tuples, 341k at 2M records, inside the set's
+# first capacity)
 SPARSE_ARGS = ['-b', 'req.url,latency,dataLatency',
                '-f', '{"eq":["req.method","GET"]}']
 
@@ -99,7 +98,8 @@ LANE_COUNTERS = ('ndevicebatches', 'nstackedbatches', 'ncompactflush',
 
 # variables that would re-route a child behind this script's back
 _SCRUB = ('DN_ENGINE', 'DN_INDEX_DEVICE', 'DN_PARSE', 'DN_PALLAS',
-          'DN_COUNTERS_ALL', 'LOG_LEVEL', 'DN_TRACE', 'DRAGNET_CONFIG')
+          'DN_COUNTERS_ALL', 'LOG_LEVEL', 'DN_TRACE', 'DRAGNET_CONFIG',
+          'JAX_LOG_COMPILES')
 
 
 # a line of a --counters dump (vpipe.Stage.dump: '%-18s %-13s%8d'), or
@@ -167,6 +167,19 @@ def split_stderr(err):
     return counters, warnings, logs
 
 
+_COMPILED = re.compile(
+    r'Finished XLA compilation of (\S+) in ([0-9.]+) sec')
+
+
+def compile_summary(err):
+    """'compiled N programs in S s' from a child's JAX_LOG_COMPILES
+    lines (programs the persistent cache served are not compiled and
+    not counted)."""
+    secs = [float(m.group(2))
+            for m in _COMPILED.finditer(err.decode('utf-8', 'replace'))]
+    return 'compiled %d programs in %.1fs' % (len(secs), sum(secs))
+
+
 def lane_counts(counter_lines):
     """{lane counter: summed value} from a --counters dump."""
     got = dict.fromkeys(LANE_COUNTERS, 0)
@@ -200,6 +213,10 @@ class Smoke(object):
         self.base_env = {
             'DRAGNET_CONFIG': os.path.join(self.scratch, 'dragnetrc'),
             'DN_COUNTERS_ALL': '1',
+            # every XLA compilation reports its seconds on stderr, so
+            # that a child's wall-clock can be split into compile and
+            # the rest
+            'JAX_LOG_COMPILES': '1',
         }
 
     # -- set-up --------------------------------------------------------------
@@ -210,7 +227,8 @@ class Smoke(object):
         env = dict(self.base_env)
         env.update(extra_env)
         rc, out, err, secs = run_child([DN] + args, child_env(env))
-        say('  %s: exit %d in %.1fs' % (what, rc, secs))
+        say('  %s: exit %d in %.1fs (%s)'
+            % (what, rc, secs, compile_summary(err)))
         if rc != 0:
             show_failure(what, args, rc, out, err)
             raise PhaseFailed('%s exited %d' % (what, rc))
@@ -448,7 +466,6 @@ class Smoke(object):
         self.scan_phase('scan-dense', QUERY_ARGS, 'segment-sum')
         self.scan_phase('scan-pallas', PALLAS_ARGS, 'pallas-onehot')
         self.scan_phase('scan-sparse', SPARSE_ARGS, 'sparse-sort-merge')
-        self.scan_phase('scan-hc', HC_ARGS)
         if self.build_phase():
             self.query_phase()
         else:

@@ -1411,6 +1411,12 @@ class DeviceScan(VectorScan):
         pn = self._pad_floor()
         while pn < n:
             pn <<= 1
+        # the capacity only grows within a scan: a later, smaller batch
+        # (the tail of a file) reuses the program already compiled
+        # instead of compiling a second variant of everything — on the
+        # chip a variant of the sparse program costs over a minute of
+        # compilation, its dead padding rows next to nothing
+        self._sticky['pn_floor'] = pn
         mesh_info = self._device_mesh()
         if mesh_info is not None:
             nsh = int(mesh_info[0].devices.size)

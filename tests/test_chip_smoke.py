@@ -10,8 +10,8 @@ import subprocess
 import sys
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-PHASES = ('scan-dense', 'scan-pallas', 'scan-sparse', 'scan-hc', 'build',
-          'query', 'auto')
+PHASES = ('scan-dense', 'scan-pallas', 'scan-sparse', 'build', 'query',
+          'auto')
 
 
 def _run(extra_env):
@@ -55,7 +55,7 @@ def test_forced_scans_fail_without_the_native_parser():
     phases = _phase_lines(lines)
     # (scan-pallas projects top-level fields only, so the byte-parse
     # lane still feeds the device columns without the native library)
-    for name in ('scan-dense', 'scan-sparse', 'scan-hc', 'build'):
+    for name in ('scan-dense', 'scan-sparse', 'build'):
         assert phases[name].startswith('FAILED'), '\n'.join(lines)
     assert any('native column parser' in ln for ln in lines)
     assert rc != 0
