@@ -142,15 +142,16 @@ def test_segment_sum_aggregate_compiles(one_chip, radices):
 
 # -- the DeviceScan programs --------------------------------------------------
 
-def _staged_program(query_conf, datafile):
+def _staged_program(query_conf, datafile, scan_cls=None):
     """(jitted fold, example inputs, accumulator shapes, use_pallas) of
     the program DeviceScan builds for one real batch of `datafile`:
     staged on the CPU backend exactly as a scan stages it, but not
     run."""
     jax, _ = get_jax()
-    from dragnet_tpu.device_scan import DeviceScan
-    scan = DeviceScan(mod_query.query_load(dict(query_conf)), None,
-                      Pipeline())
+    if scan_cls is None:
+        from dragnet_tpu.device_scan import DeviceScan as scan_cls
+    scan = scan_cls(mod_query.query_load(dict(query_conf)), None,
+                    Pipeline())
     parser = devbench._one_batch_parser(datafile, scan, BATCH)
     n = parser.batch_size()
     assert n == BATCH
@@ -216,6 +217,25 @@ def test_byteparse_parity_compiles(one_chip):
 
 
 # -- the mesh path ------------------------------------------------------------
+
+def test_mesh_device_scan_program_compiles(mesh4, corpus, monkeypatch):
+    """The cluster backend's whole-pipeline program (`dn scan` on a
+    `--backend=cluster` datasource, chip_smoke.py --chips 4): the
+    DeviceScan body under shard_map over the four chips, dense weights
+    and counters merged by psum, first occurrences by pmin."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from dragnet_tpu.parallel import cluster
+    monkeypatch.setattr(cluster.MeshDeviceScan, '_mesh_cache',
+                        (mesh4, 'd'))
+    run, inputs, acc, use_pallas = _staged_program(
+        bench.QUERY, corpus, scan_cls=cluster.MeshDeviceScan)
+    assert not use_pallas
+    replicated = NamedSharding(mesh4, P())
+    compiled = _compile(run, _like(inputs, replicated),
+                        _like(acc, replicated))
+    text = compiled.as_text()
+    assert 'all-reduce' in text
+
 
 @pytest.mark.parametrize('scatter,use_pallas,collective', [
     (False, False, ('all-reduce',)),
