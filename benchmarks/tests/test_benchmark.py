@@ -1,0 +1,427 @@
+"""The benchmark's own tests (CPU): `python -m pytest benchmarks/tests -q`.
+
+* the plain reference against dragnet_tpu/scan.py's StreamScan, once per
+  query shape;
+* the control: the reference with bfloat16 accumulation must NOT agree;
+* the trace reduction on a small recorded trace;
+* the generator's determinism per seed;
+* BENCHMARK.json against the data files;
+* one 20,000-record rehearsal of each cell end to end (final line's
+  keys, non-zero exit off the chip), with a throw-away cell and metric
+  added as files, and one with the timed path broken underneath.
+"""
+
+import glob
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+BENCH = os.path.dirname(HERE)
+ROOT = os.path.dirname(BENCH)
+sys.path.insert(0, BENCH)
+sys.path.insert(0, ROOT)
+
+from gen import corpus                                    # noqa: E402
+from reference.groupby import Reference, compare         # noqa: E402
+from loader import load_module                            # noqa: E402
+import traffic                                            # noqa: E402
+
+trace_reduce = load_module('trace', 'reduce')
+
+MINDATE = 1388534400000
+DAY = 86400000
+
+
+def _load(kind, name):
+    with open(os.path.join(BENCH, kind, name + '.json')) as f:
+        return json.load(f)
+
+
+def _cells():
+    with open(os.path.join(ROOT, 'BENCHMARK.json')) as f:
+        return [w['name'] for w in json.load(f)['workloads']]
+
+
+@pytest.fixture(scope='module')
+def small(tmp_path_factory):
+    """6,000 records over 30 days: (path, columns, reference)."""
+    d = tmp_path_factory.mktemp('corpus')
+    lib = corpus.build_library(str(d))
+    path = str(d / 'muskie.log')
+    cols, _ = corpus.generate(lib, path, 6000, MINDATE,
+                              MINDATE + 30 * DAY, 2147483999)
+    ref = Reference(cols, {'host': corpus.HOSTS, 'method': corpus.METHODS,
+                           'op': corpus.OPERATIONS})
+    return path, cols, ref
+
+
+def _shapes():
+    """Every query shape a committed workload sends or verifies with."""
+    seen, out = set(), []
+    for path in sorted(glob.glob(os.path.join(BENCH, 'workloads', '*.json'))):
+        with open(path) as f:
+            wl = json.load(f)
+        for t in (wl.get('templates') or []) + (wl.get('verify') or []):
+            if 'query' not in t:
+                continue
+            key = json.dumps(t['query'], sort_keys=True)
+            if key not in seen:
+                seen.add(key)
+                out.append(pytest.param(t['query'], id=t['name']))
+    return out
+
+
+def _stream_scan_points(path, query):
+    """The program's per-record host path over the file, as sorted
+    `--points` lines."""
+    from dragnet_tpu import query as mod_query
+    from dragnet_tpu.scan import StreamScan
+    from dragnet_tpu.vpipe import Pipeline
+    qc = mod_query.query_load({
+        'breakdowns': [dict(b, field=b.get('field') or b['name'])
+                       for b in query['breakdowns']],
+        **({'filter': query['filter']} if query.get('filter') else {})})
+    from dragnet_tpu import output as mod_output
+    import io
+    scan = StreamScan(qc, 'time', Pipeline())
+    with open(path) as f:
+        for line in f:
+            scan.write(json.loads(line), 1)
+    out = io.StringIO()
+    mod_output.print_points(scan.aggr.points(), out)
+    return sorted(out.getvalue().encode().splitlines())
+
+
+@pytest.mark.parametrize('query', _shapes())
+def test_reference_equals_stream_scan(small, query):
+    path, _, ref = small
+    got = _stream_scan_points(path, query)
+    assert ref.expected_lines(query, part='batch') == got
+    assert ref.expected_lines(query, part='day') == got
+
+
+@pytest.mark.parametrize('query', _shapes())
+def test_control_bfloat16_fails(query):
+    """At a size where counts pass 256 the bfloat16 control must differ
+    from the exact reference: the comparison can fail."""
+    lib = corpus.build_library(os.path.join(ROOT, '.cache', 'bench', 'gen'))
+    path = os.path.join(ROOT, '.cache', 'bench', 'gen', 'control.log')
+    cols, _ = corpus.generate(lib, path, 400000, MINDATE,
+                              MINDATE + 30 * DAY, 5)
+    os.unlink(path)
+    ref = Reference(cols, {'host': corpus.HOSTS, 'method': corpus.METHODS,
+                           'op': corpus.OPERATIONS})
+    for part in ('batch', 'day'):
+        exact = ref.expected_lines(query, part=part)
+        low = ref.expected_lines(query, part=part, accumulate='bfloat16')
+        ntuples, delta = compare(b'\n'.join(low), exact)
+        assert ntuples > 0 and delta > 0
+        assert compare(b'\n'.join(exact), exact) == (0, 0)
+
+
+def test_compare_counts_differences():
+    exp = sorted([b'{"fields":{"a":"x"},"value":3}',
+                  b'{"fields":{"a":"y"},"value":5}'])
+    assert compare(b'\n'.join(exp) + b'\n', exp) == (0, 0)
+    assert compare(exp[0] + b'\n', exp) == (1, 5)
+    assert compare(b'{"fields":{"a":"x"},"value":4}\n' + exp[1], exp) \
+        == (1, 1)
+
+
+def test_generator_is_deterministic_per_seed(tmp_path):
+    lib = corpus.build_library(str(tmp_path))
+    outs = []
+    for seed in (2147483999, 2147483999, 7):
+        p = str(tmp_path / ('c%d.log' % len(outs)))
+        cols, n = corpus.generate(lib, p, 3000, MINDATE, MINDATE + 30 * DAY,
+                                  seed)
+        with open(p, 'rb') as f:
+            outs.append((f.read(), cols))
+    assert outs[0][0] == outs[1][0]
+    assert outs[0][0] != outs[2][0]
+    # the columns are the file's records
+    rec = json.loads(outs[0][0].splitlines()[17])
+    cols = outs[0][1]
+    assert rec['host'] == corpus.HOSTS[cols['host'][17]]
+    assert rec['operation'] == corpus.OPERATIONS[cols['op'][17]]
+    assert rec['req']['method'] == corpus.METHODS[cols['method'][17]]
+    assert rec['latency'] == cols['latency'][17]
+    assert rec['res']['statusCode'] == cols['status'][17]
+
+
+def test_generator_equals_the_programs(tmp_path):
+    """benchgen.cc is a copy: same bytes as native/dngen.cc."""
+    import bench
+    theirs = str(tmp_path / 'theirs.log')
+    bench.gen_to_file(2000, theirs, mindate_ms=MINDATE,
+                      maxdate_ms=MINDATE + 30 * DAY, seed=99)
+    lib = corpus.build_library(str(tmp_path))
+    mine = str(tmp_path / 'mine.log')
+    corpus.generate(lib, mine, 2000, MINDATE, MINDATE + 30 * DAY, 99)
+    with open(theirs, 'rb') as a, open(mine, 'rb') as b:
+        assert a.read() == b.read()
+
+
+def test_traffic_same_work_every_seed():
+    """The seed draws the start days; the classes, their order and the
+    arrivals are the workload's own."""
+    wl = dict(_load('workloads', 'muskie-365d-index.query-windows'),
+              loop='open', rate_per_s=12, mix_seed=1)
+    a = traffic.open_loop(wl, 1, 20.0, 365)
+    b = traffic.open_loop(wl, 2147483999, 20.0, 365)
+    assert len(a) == len(b) == 240
+    arrivals = lambda rs: [(r.due_s, r.template['name'], r.days)
+                           for r in rs]
+    assert arrivals(a) == arrivals(b)
+    assert [r.start_day for r in a] != [r.start_day for r in b]
+    assert all(0 <= r.start_day <= 365 - r.days for r in a)
+    assert a == traffic.open_loop(wl, 1, 20.0, 365)
+    assert traffic.apportion([40, 35, 15, 10], 7) == [3, 2, 1, 1]
+    import itertools
+    wl = _load('workloads', 'muskie-365d-index.query-windows')
+    c = list(itertools.islice(traffic.closed_loop(wl, 1, 365), 800))
+    d = list(itertools.islice(traffic.closed_loop(wl, 2, 365), 800))
+    kinds = lambda rs: [(r.template['name'], r.days) for r in rs]
+    assert kinds(c) == kinds(d) and kinds(c[:400]) == kinds(c[400:])
+    assert sum(1 for r in c[:400] if r.days == 7) == 200
+    assert sum(1 for r in c[:400] if r.days == 365) == 40
+    assert [r.start_day for r in c] != [r.start_day for r in d]
+
+
+def test_trace_reduction_on_recorded_trace():
+    """A trace recorded on the chip (TPU v5 lite, PR 25), cut down to a
+    few hundred events: the reduction's numbers are pinned."""
+    with open(os.path.join(HERE, 'data', 'recorded_events.json')) as f:
+        doc = json.load(f)
+    with open(os.path.join(HERE, 'data', 'recorded_expected.json')) as f:
+        want = json.load(f)
+    got = trace_reduce.reduce_events(doc)
+    assert got['window_s'] == pytest.approx(want['window_s'])
+    assert got['busy_s'] == pytest.approx(want['busy_s'])
+    assert 0 < got['busy_s'] < got['window_s']
+    assert len(got['chips']) == want['nchips']
+    for c, w in zip(got['chips'], want['chips']):
+        assert c['idle_share'] == pytest.approx(w['idle_share'])
+        assert c['collective_s'] == pytest.approx(w['collective_s'])
+    assert [n for n, _ in got['breakdown']['device_ops']] == \
+        want['top_ops']
+
+
+def test_trace_reduction_arithmetic():
+    ev = lambda n, s, d: [n, s, d]
+    doc = {'planes': [
+        {'name': '/device:TPU:0', 'lines': [
+            {'name': 'XLA Ops', 'events': [
+                ev('fusion.1', 0, 100), ev('all-reduce.2', 50, 100),
+                ev('fusion.1', 400, 100)]},
+            {'name': 'XLA Modules', 'events': [
+                ev('jit_run(123)', 0, 150), ev('jit_run(123)', 400, 100)]}]},
+        {'name': '/host:CPU', 'lines': [
+            {'name': 'worker', 'events': [ev('parse', 140, 270)]}]}]}
+    got = trace_reduce.reduce_events(doc)
+    assert got['window_s'] == pytest.approx(500e-9)
+    chip = got['chips'][0]
+    assert chip['busy_s'] == pytest.approx(250e-9)
+    assert chip['idle_share'] == pytest.approx(0.5)
+    assert chip['collective_s'] == pytest.approx(100e-9)
+    assert chip['modules']['jit_run'] == [2, pytest.approx(250e-9)]
+    assert got['breakdown']['device_ops'][0] == ['fusion.1',
+                                                 pytest.approx(200e-9)]
+    assert got['breakdown']['idle_gaps'][0] == ['parse (worker)',
+                                                pytest.approx(250e-9)]
+
+
+def test_benchmark_json_matches_the_files():
+    with open(os.path.join(ROOT, 'BENCHMARK.json')) as f:
+        doc = json.load(f)
+    assert doc['paths'] == ['benchmarks']
+    e2e = {m['name']: m for m in doc['end_to_end']}
+    layers = {m['name']: m for m in doc['per_layer']}
+    for cell in doc['workloads']:
+        wl = _load('workloads', cell['name'])
+        cfg = _load('configs', wl['config'])
+        assert (wl['config'], wl['traffic'], wl['why'], cfg['chips']) == \
+            (cell['config'], cell['traffic'], cell['why'], cell['chips'])
+        for name, spec in wl['end_to_end'].items():
+            assert e2e[name]['unit'] == spec['unit']
+            assert cell['name'] in e2e[name].get('workloads',
+                                                 [cell['name']])
+        for name in wl['per_layer']:
+            assert cell['name'] in layers[name]['workloads']
+    for c in doc['configs']:
+        cfg = _load('configs', c['name'])
+        assert (cfg['source'], cfg['reduced']) == (c['source'], c['reduced'])
+    for name, m in layers.items():
+        meta = load_module('metrics', name).META
+        assert {k: m[k] for k in meta} == meta
+        for cell in m['workloads']:
+            assert name in _load('workloads', cell)['per_layer']
+            assert m['moves'] in _load('workloads', cell)['end_to_end']
+
+
+# -- rehearsals -------------------------------------------------------------
+
+THROWAWAY_METRIC = '''"""A throw-away per-layer metric, added by the test as a file."""
+META = {'layer': 'test', 'source': 'program_counter', 'unit': 'count',
+        'better': 'higher', 'moves': 'setup_s'}
+
+
+def read(r):
+    return float(len(r.outcomes))
+'''
+
+BROKEN_LAUNCHER = '''"""The normal launcher with the timed path broken underneath: every
+answer's first count is one too high where it is produced."""
+import os, sys
+sys.path.insert(0, %(root)r)
+from dragnet_tpu import cli
+_real = cli.dn_output
+
+
+def _broken(query, opts, result, dsname):
+    import io
+    out, sys.stdout = sys.stdout, io.StringIO()
+    try:
+        _real(query, opts, result, dsname)
+        text = sys.stdout.getvalue()
+    finally:
+        sys.stdout = out
+    head, sep, tail = text.partition('"value":')
+    if sep:
+        digits = ''
+        while tail and tail[0].isdigit():
+            digits, tail = digits + tail[0], tail[1:]
+        text = head + sep + str(int(digits) + 1) + tail
+    sys.stdout.write(text)
+
+
+cli.dn_output = _broken
+sys.argv[0] = %(launcher)r
+exec(compile(open(%(launcher)r).read(), %(launcher)r, 'exec'))
+'''
+
+
+@pytest.fixture
+def throwaway():
+    """Files a test adds under benchmarks/ and takes away again."""
+    made = []
+
+    def add(kind, name, text):
+        path = os.path.join(BENCH, kind, name)
+        with open(path, 'w') as f:
+            f.write(text)
+        made.append(path)
+        return path
+    yield add
+    for p in made:
+        os.unlink(p)
+
+
+def _rehearse(cell, extra_env=None, trace=0, seconds=2):
+    env = dict(os.environ, JAX_PLATFORMS='cpu')
+    env.pop('XLA_FLAGS', None)
+    env.update(extra_env or {})
+    p = subprocess.run(
+        [sys.executable, os.path.join(BENCH, 'run.py'), '--workload', cell,
+         '--seed', '2147483999', '--seconds', str(seconds), '--trace',
+         str(trace)], env=env, cwd=ROOT, stdout=subprocess.PIPE,
+        stderr=subprocess.PIPE, timeout=600)
+    lines = p.stdout.decode().splitlines()
+    assert lines, p.stderr.decode()[-3000:]
+    return p.returncode, lines
+
+
+def _small_copy(add, cell, launcher=None, per_layer=None):
+    """The cell with its configuration cut to 20,000 records, as
+    throw-away files; returns the copy's name."""
+    wl = _load('workloads', cell)
+    cfg = _load('configs', wl['config'])
+    cfg['name'] = 't-' + cfg['name']
+    cfg['corpus']['records'] = 20000
+    wl.update(name='t-' + cell, config=cfg['name'])
+    if launcher:
+        wl['launcher'] = launcher
+    if per_layer:
+        wl['per_layer'] = wl['per_layer'] + per_layer
+    add('configs', cfg['name'] + '.json', json.dumps(cfg))
+    add('workloads', wl['name'] + '.json', json.dumps(wl))
+    return wl['name'], cfg
+
+
+@pytest.mark.parametrize('cell', _cells())
+def test_rehearsal_end_to_end(cell, throwaway):
+    """Each cell at 20,000 records on the CPU: runs to its end, every
+    answer equal to the reference, the result's keys in place, no result
+    line and a non-zero exit because this is not the chip."""
+    name, cfg = _small_copy(throwaway, cell)
+    env = {'XLA_FLAGS': '--xla_force_host_platform_device_count=4'} \
+        if cfg['chips'] == 4 else {}
+    rc, lines = _rehearse(name, env)
+    assert rc != 0
+    assert lines[-1].startswith('rehearsal ')
+    with pytest.raises(ValueError):
+        json.loads(lines[-1])            # not a result line
+    doc = json.loads(lines[-1][len('rehearsal '):])
+    assert set(doc) == {'correct', 'attempted', 'failed', 'metrics',
+                        'device'}
+    assert doc['device']['platform'] == 'cpu'
+    assert doc['device']['count'] == cfg['chips']
+    assert doc['correct'] is True, lines
+    assert doc['failed'] == 0 and doc['attempted'] > 0
+    wl = _load('workloads', cell)
+    assert set(doc['metrics']) == set(wl['end_to_end'])
+    for m in doc['metrics'].values():
+        assert m['value'] > 0 and m['unit']
+
+
+def test_rehearsal_takes_a_new_cell_and_metric_as_files(throwaway):
+    """A cell, a configuration and a per-layer metric that run.py has
+    never heard of, added as files only."""
+    throwaway('metrics', 't_requests_seen.py', THROWAWAY_METRIC)
+    name, _ = _small_copy(throwaway, 'muskie-30d.scan-dense',
+                          per_layer=['t_requests_seen'])
+    rc, lines = _rehearse(name, trace=1)
+    assert rc != 0
+    doc = json.loads(lines[-1][len('rehearsal '):])
+    assert doc['metrics']['t_requests_seen']['value'] == doc['attempted']
+    assert 'window_compiles.scan' in doc['metrics']
+    assert {'busy_s', 'window_s'} <= set(doc['device'])
+    # a CPU has no device plane: nothing is written under a device
+    # metric's name, and the run says so
+    assert 'device_idle_share.scan' not in doc['metrics']
+    assert doc['correct'] is False
+
+
+def test_broken_timed_path_is_not_correct(throwaway):
+    """The rest of a run with an answer altered where it is produced:
+    `correct` comes out false, by the comparison and nothing else."""
+    launcher = throwaway('tests', 't_broken_launcher.py', BROKEN_LAUNCHER % {
+        'root': ROOT,
+        'launcher': os.path.join(BENCH, 'drivers', 'launch_serve.py')})
+    name, _ = _small_copy(throwaway, 'muskie-30d.scan-dense',
+                          launcher=launcher)
+    rc, lines = _rehearse(name)
+    assert rc != 0
+    doc = json.loads(lines[-1][len('rehearsal '):])
+    assert doc['correct'] is False
+    assert any('mismatched_tuples' in ln and 'over its limit' in ln
+               for ln in lines), lines
+
+
+def test_no_program_no_result(tmp_path):
+    """In a directory that holds only BENCHMARK.json and benchmarks/."""
+    import shutil
+    shutil.copy(os.path.join(ROOT, 'BENCHMARK.json'), str(tmp_path))
+    shutil.copytree(BENCH, str(tmp_path / 'benchmarks'),
+                    ignore=shutil.ignore_patterns('__pycache__', 't-*'))
+    p = subprocess.run(
+        [sys.executable, 'benchmarks/run.py', '--workload', _cells()[0],
+         '--seed', '1', '--seconds', '1', '--trace', '0'],
+        cwd=str(tmp_path), stdout=subprocess.PIPE, stderr=subprocess.PIPE,
+        env=dict(os.environ, JAX_PLATFORMS='cpu'))
+    assert p.returncode != 0
+    assert not p.stdout.strip()
