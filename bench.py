@@ -1340,7 +1340,6 @@ def serve_bench(tmpdir):
         'serve_cache_hits': caches['hits'],
         'serve_cache_misses': caches['misses'],
         'device_path_engaged': st['device']['engaged'],
-        'device_mfu_pct': gauges.get('device_mfu_pct'),
         'device_residency_pct': gauges.get('device_residency_pct'),
         'device_engaged_gauge': gauges.get('device_engaged'),
         'serve_query_latency_p50_ms': qlat.get('p50'),

@@ -25,6 +25,7 @@ from . import find as mod_find
 from .aggr import Aggregator
 from .scan import StreamScan
 from .vpipe import Pipeline
+from .obs import metrics as obs_metrics
 
 LOG = mod_log.get('datasource-file')
 
@@ -860,16 +861,36 @@ class DatasourceFile(object):
         state = {'done': 0}
 
         def counted_chunks():
-            for chunk in _read_ahead(files, readsz):
-                state['done'] += len(chunk)
-                yield chunk
+            # scan.read: the wait of this thread for the read-ahead
+            # thread's next chunk
+            ahead = _read_ahead(files, readsz)
+            try:
+                while True:
+                    with obs_metrics.leaf_stage('scan.read'):
+                        chunk = next(ahead, None)
+                    if chunk is None:
+                        return
+                    state['done'] += len(chunk)
+                    yield chunk
+            finally:
+                ahead.close()
+
+        def parse(buf, length=None):
+            # scan.parse: the parser (all its threads) as this thread
+            # sees it, over bytes or over (address, length)
+            with obs_metrics.leaf_stage('scan.parse'):
+                nrecords = parser.parse(buf) if length is None \
+                    else parse_at(buf, length)
+            obs_metrics.inc('scan_parse_bytes',
+                            len(buf) if length is None else length)
+            obs_metrics.inc('scan_parse_records', nrecords)
 
         if parse_at is None:
             # byte-lane / plain parsers: complete-line buffers from
             # the shared chunk-boundary joiner (ingest.py — the same
             # carry discipline as iter_lines/iter_stream_lines)
             for lbuf in mod_ingest.iter_line_buffers(counted_chunks()):
-                parser.parse(lbuf)
+                parse(lbuf)
                 if parser.batch_size() >= batch_size:
                     if progress is not None:
                         progress(state['done'], total)
@@ -888,19 +909,18 @@ class DatasourceFile(object):
             start = 0
             if carry:
                 first = chunk.index(b'\n', 0, nl + 1)
-                parser.parse(carry + chunk[:first + 1])
+                parse(carry + chunk[:first + 1])
                 start = first + 1
             arr = np.frombuffer(chunk, dtype=np.uint8)
             if nl + 1 > start:
-                parse_at(arr[start:].ctypes.data,
-                         nl + 1 - start)
+                parse(arr[start:].ctypes.data, nl + 1 - start)
             carry = chunk[nl + 1:]
             if parser.batch_size() >= batch_size:
                 if progress is not None:
                     progress(state['done'], total)
                 flush()
         if carry:
-            parser.parse(carry)
+            parse(carry)
         if progress is not None:
             progress(state['done'], total)
         flush()
@@ -1208,11 +1228,14 @@ def _bump_parse_counters(parser_stage, adapter_stage, nlines, nbad, n):
 
 def _batch_weights(skinner, src, n):
     """Per-record weights for one batch: 1 for raw json, the coerced
-    point value for json-skinner (src is a parser or snapshot)."""
-    if skinner:
-        tags, nums, strcodes = src.columns('value')
-        return _skinner_weights(tags, nums, strcodes, src)
-    return np.ones(n, dtype=np.float64)
+    point value for json-skinner (src is a parser or snapshot).  The
+    first part of a batch's scan.stage; DeviceScan._stage_device is
+    the rest."""
+    with obs_metrics.leaf_stage('scan.stage'):
+        if skinner:
+            tags, nums, strcodes = src.columns('value')
+            return _skinner_weights(tags, nums, strcodes, src)
+        return np.ones(n, dtype=np.float64)
 
 
 def _skinner_weights(tags, nums, strcodes, parser):

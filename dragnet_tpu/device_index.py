@@ -65,6 +65,8 @@ import os
 
 import numpy as np
 
+from .obs import metrics as obs_metrics
+
 # sticky per-process device availability — SHARED with the legacy
 # single-dispatch lane in index_query_stack (one verdict per process,
 # whichever lane trips it first)
@@ -298,7 +300,6 @@ def _residency():
 
 def _note_engagement(ndispatch, nshards, nrows, pinned_hits,
                      h2d_bytes, h2d_saved):
-    from .obs import metrics as obs_metrics
     _ENGAGE['dispatches'] += ndispatch
     _ENGAGE['shards'] += nshards
     _ENGAGE['rows'] += nrows
@@ -356,37 +357,40 @@ def _device_fold(inv, w64, nuniq, shard_ctx):
 
     # stage every non-empty shard: pinned device tensors where
     # residency has them, fresh host arrays (uploaded per dispatch,
-    # then pinned) otherwise
+    # then pinned) otherwise.  One stage for the whole loop: a year's
+    # query stages 365 shards
     staged = []                  # (prow, ttable, dev_local, dev_w)
     pinned_hits = 0
     h2d_bytes = 0
     h2d_saved = 0
-    for s in range(nshards_total):
-        lo, hi = int(bounds[s]), int(bounds[s + 1])
-        if lo == hi:
-            continue
-        local, ttable, nlocal = _stage_shard(inv[lo:hi])
-        prow = _pow2(hi - lo)
-        key = None
-        if plan is not None and s < len(pairs):
-            ident = _shard_identity(*pairs[s]) \
-                if pairs[s][0] is not None else None
-            if ident is not None:
-                key = ('iq-shard', plan, ident, prow)
-            dev = res.get_device(key, repoch)
-            if dev is not None:
-                staged.append((prow, ttable, nlocal, dev[0], dev[1]))
-                pinned_hits += 1
-                h2d_saved += prow * 16          # two i64 lanes
+    with obs_metrics.leaf_stage('index_fold.stage'):
+        for s in range(nshards_total):
+            lo, hi = int(bounds[s]), int(bounds[s + 1])
+            if lo == hi:
                 continue
-        pl, pw = _pad_slot(local, w64[lo:hi], nlocal, prow)
-        dl = jax.device_put(pl)
-        dw = jax.device_put(pw)
-        h2d_bytes += pl.nbytes + pw.nbytes
-        if key is not None:
-            res.put_device(key, repoch, (dl, dw),
-                           nbytes=pl.nbytes + pw.nbytes)
-        staged.append((prow, ttable, nlocal, dl, dw))
+            local, ttable, nlocal = _stage_shard(inv[lo:hi])
+            prow = _pow2(hi - lo)
+            key = None
+            if plan is not None and s < len(pairs):
+                ident = _shard_identity(*pairs[s]) \
+                    if pairs[s][0] is not None else None
+                if ident is not None:
+                    key = ('iq-shard', plan, ident, prow)
+                dev = res.get_device(key, repoch)
+                if dev is not None:
+                    staged.append((prow, ttable, nlocal, dev[0], dev[1]))
+                    pinned_hits += 1
+                    h2d_saved += prow * 16          # two i64 lanes
+                    continue
+            pl, pw = _pad_slot(local, w64[lo:hi], nlocal, prow)
+            dl = jax.device_put(pl)
+            dw = jax.device_put(pw)
+            h2d_bytes += pl.nbytes + pw.nbytes
+            if key is not None:
+                res.put_device(key, repoch, (dl, dw),
+                               nbytes=pl.nbytes + pw.nbytes)
+            staged.append((prow, ttable, nlocal, dl, dw))
+    obs_metrics.inc('index_fold_shards_staged', len(staged))
 
     if not staged:
         return np.zeros(nuniq, dtype=np.int64), None, 0, 0, 0, 0
@@ -394,37 +398,40 @@ def _device_fold(inv, w64, nuniq, shard_ctx):
     # pack by padded row count: pow2 slot ladder bounded by the
     # batch-rows budget, so a year of daily shards folds in a handful
     # of launches and the program cache stays O(log^2)
-    groups = {}
-    for st in staged:
-        groups.setdefault(st[0], []).append(st)
-    budget = batch_rows()
-    acc = jax.device_put(np.zeros(pu, dtype=np.int64))
     ndispatch = 0
-    for prow in sorted(groups):
-        todo = groups[prow]
-        smax = max(1, min(_MAX_SLOTS, budget // prow))
-        i = 0
-        while i < len(todo):
-            s = 1
-            while s * 2 <= min(smax, len(todo) - i):
-                s <<= 1
-            chunk = todo[i:i + s]
-            i += s
-            ptab = _pow2(max(c[2] + 1 for c in chunk))
-            ttabs = np.full((s, ptab), pu - 1, dtype=np.int64)
-            for j, (_pr, tt, nl, _dl, _dw) in enumerate(chunk):
-                ttabs[j, :nl] = tt
-            h2d_bytes += ttabs.nbytes
-            prog = _fold_program(s, prow, ptab, pu)
-            acc = prog(tuple(c[3] for c in chunk),
-                       tuple(c[4] for c in chunk), ttabs, acc)
-            ndispatch += 1
-    try:
-        acc.block_until_ready()
-    except AttributeError:
-        pass
+    with obs_metrics.leaf_stage('index_fold.dispatch'):
+        groups = {}
+        for st in staged:
+            groups.setdefault(st[0], []).append(st)
+        budget = batch_rows()
+        acc = jax.device_put(np.zeros(pu, dtype=np.int64))
+        for prow in sorted(groups):
+            todo = groups[prow]
+            smax = max(1, min(_MAX_SLOTS, budget // prow))
+            i = 0
+            while i < len(todo):
+                s = 1
+                while s * 2 <= min(smax, len(todo) - i):
+                    s <<= 1
+                chunk = todo[i:i + s]
+                i += s
+                ptab = _pow2(max(c[2] + 1 for c in chunk))
+                ttabs = np.full((s, ptab), pu - 1, dtype=np.int64)
+                for j, (_pr, tt, nl, _dl, _dw) in enumerate(chunk):
+                    ttabs[j, :nl] = tt
+                h2d_bytes += ttabs.nbytes
+                prog = _fold_program(s, prow, ptab, pu)
+                acc = prog(tuple(c[3] for c in chunk),
+                           tuple(c[4] for c in chunk), ttabs, acc)
+                ndispatch += 1
+    with obs_metrics.leaf_stage('index_fold.device_wait'):
+        try:
+            acc.block_until_ready()
+        except AttributeError:
+            pass
     # ONE fetch: everything upstream stayed on the device
-    out = np.asarray(acc)[:nuniq]
+    with obs_metrics.leaf_stage('index_fold.fetch'):
+        out = np.asarray(acc)[:nuniq]
     return out, acc, ndispatch, pinned_hits, h2d_bytes, h2d_saved
 
 
@@ -440,7 +447,6 @@ def batched_sums(inv, weights, nuniq, shard_ctx=None, stage=None,
     the timed verdict persists to the audition cache for the next
     auto-mode query."""
     from .engine import MAX_DENSE_SEGMENTS
-    from .obs import metrics as obs_metrics
     if nuniq > MAX_DENSE_SEGMENTS or len(inv) == 0:
         return None
     st = _DEVICE_STATE

@@ -46,12 +46,14 @@ import numpy as np
 from . import jsvalues as jsv
 from . import log as mod_log
 from . import query as mod_query
+from . import vpipe as mod_vpipe
 from . import watchdog
 from .errors import DNError
 from .engine import (VectorScan, NativeColumns, MAX_DENSE_SEGMENTS,
                      BATCH_SIZE, engine_mode)
 from .ops.kernels import FALSE, TRUE, ERROR
 from .ops import get_jax, backend_ready, accelerator_likely
+from .obs import metrics as obs_metrics
 
 I32MIN = -(2 ** 31)
 I32MAX = 2 ** 31 - 1
@@ -172,10 +174,13 @@ def run_with_deadline(fn, seconds, what):
     device op, and the process-exit path does not join daemons."""
     box = []
     done = threading.Event()
+    # the thread's stages and counters belong to the caller's request
+    scope = mod_vpipe.current_scope()
 
     def _go():
         try:
-            box.append(('ok', fn()))
+            with mod_vpipe.adopt_scope(scope):
+                box.append(('ok', fn()))
         except BaseException as e:
             box.append(('error', e))
         finally:
@@ -517,7 +522,7 @@ class DeviceScan(VectorScan):
         # synth columns, base) coexist in one merged inputs dict while
         # parser-derived columns stay shared across metrics
         self._pfx = ''
-        # when True, _run_staged records (run, inputs, staged) on
+        # when True, _staged_run records (run, inputs, staged) on
         # self.captured — the kernel-resident benchmark replays the
         # exact production program over device-resident inputs
         self.capture_next = False
@@ -689,18 +694,19 @@ class DeviceScan(VectorScan):
         if acc is None:
             return
         try:
-            cap = meta.get('sparse_cap')
-            if cap:
-                k = min(cap, _pow2(max(self._sparse_ub, 1)))
-                out = _sparse_program(cap, k,
-                                      tuple(meta['caps']))(acc)
-            elif meta['cols'] and \
-                    meta['ns'] >= self.COMPACT_MIN_SEGMENTS:
-                k = min(int(acc[0].shape[0]), self.COMPACT_K)
-                out = _compact_program(int(acc[0].shape[0]), k)(acc)
-            else:
-                return    # small fetch: nothing worth overlapping
-            _issue_async(out)
+            with obs_metrics.leaf_stage('scan.dispatch'):
+                cap = meta.get('sparse_cap')
+                if cap:
+                    k = min(cap, _pow2(max(self._sparse_ub, 1)))
+                    out = _sparse_program(cap, k,
+                                          tuple(meta['caps']))(acc)
+                elif meta['cols'] and \
+                        meta['ns'] >= self.COMPACT_MIN_SEGMENTS:
+                    k = min(int(acc[0].shape[0]), self.COMPACT_K)
+                    out = _compact_program(int(acc[0].shape[0]), k)(acc)
+                else:
+                    return    # small fetch: nothing worth overlapping
+                _issue_async(out)
         except Exception:
             LOG.debug('flush prefetch failed; staying synchronous')
             return
@@ -722,51 +728,56 @@ class DeviceScan(VectorScan):
             cap = meta.get('sparse_cap')
             if cap:
                 cols, w32, wof, cvec, stats = out
-                st = np.asarray(stats)
-                n = int(st[0])
-                k = int(cols[0].shape[0])
                 compacted = True
-                if n > k or bool(np.asarray(wof)):
-                    # ub bound failed or i32 weight overflow: refetch
-                    fetched = _sparse_fetch(acc, _pow2(max(n, 1)),
-                                            meta['caps'])
-                    if fetched is None:   # device fetch error: full
-                        fetched = _sparse_full_result(acc,
-                                                      meta['caps'])
-                        compacted = False
-                    cols_np, wsumf, cvec_np, st = fetched
-                else:
-                    cols_np = [c[:n].astype(np.int64)
-                               for c in _fetch_arrays(cols)]
-                    wsumf = np.asarray(w32)[:n].astype(np.float64)
-                    cvec_np = np.asarray(cvec)
+                with obs_metrics.leaf_stage('scan.fetch'):
+                    st = np.asarray(stats)
+                    n = int(st[0])
+                    k = int(cols[0].shape[0])
+                    if n > k or bool(np.asarray(wof)):
+                        # ub bound failed or i32 weight overflow:
+                        # refetch
+                        fetched = _sparse_fetch(acc, _pow2(max(n, 1)),
+                                                meta['caps'])
+                        if fetched is None:  # device fetch error: full
+                            fetched = _sparse_full_result(
+                                acc, meta['caps'])
+                            compacted = False
+                        cols_np, wsumf, cvec_np, st = fetched
+                    else:
+                        cols_np = [c[:n].astype(np.int64)
+                                   for c in _fetch_arrays(cols)]
+                        wsumf = np.asarray(w32)[:n].astype(np.float64)
+                        cvec_np = np.asarray(cvec)
                 if int(st[1]):
                     raise RuntimeError(
                         'device sparse aggregation overflowed its '
                         'resident set (cap=%d)' % cap)
                 if compacted:
                     self.aggr.stage.bump_hidden('ncompactflush', 1)
-                self._emit_counters(cvec_np)
-                self._emit_cols(meta, cols_np, wsumf)
+                with obs_metrics.leaf_stage('scan.emit'):
+                    self._emit_counters(cvec_np)
+                    self._emit_cols(meta, cols_np, wsumf)
             else:
                 cnt, segs, dense, cvec = out
-                n = int(np.asarray(cnt))
-                k = int(segs.shape[0])
                 compacted = True
-                if n > k:
-                    fetched = _compact_fetch(acc, _pow2(n))
-                    if fetched is None:   # device fetch error: full
-                        fetched = _dense_full_result(acc)
-                        compacted = False
-                    segs_np, wsumf, cvec_np = fetched
-                else:
-                    segs_np = np.asarray(segs)[:n].astype(np.int64)
-                    wsumf = np.asarray(dense)[:n].astype(np.float64)
-                    cvec_np = np.asarray(cvec)
+                with obs_metrics.leaf_stage('scan.fetch'):
+                    n = int(np.asarray(cnt))
+                    k = int(segs.shape[0])
+                    if n > k:
+                        fetched = _compact_fetch(acc, _pow2(n))
+                        if fetched is None:  # device fetch error: full
+                            fetched = _dense_full_result(acc)
+                            compacted = False
+                        segs_np, wsumf, cvec_np = fetched
+                    else:
+                        segs_np = np.asarray(segs)[:n].astype(np.int64)
+                        wsumf = np.asarray(dense)[:n].astype(np.float64)
+                        cvec_np = np.asarray(cvec)
                 if compacted:
                     self.aggr.stage.bump_hidden('ncompactflush', 1)
-                self._emit_counters(cvec_np)
-                self._decode_emit(meta, segs_np, wsumf)
+                with obs_metrics.leaf_stage('scan.emit'):
+                    self._emit_counters(cvec_np)
+                    self._decode_emit(meta, segs_np, wsumf)
 
     def _emit_counters(self, cvec):
         for (stage, name, always), v in zip(self._counter_spec, cvec):
@@ -848,7 +859,6 @@ class DeviceScan(VectorScan):
         the other modes the scan finishes on the host engine, which
         computes identical results, and the reason survives in
         `probe_status` (and the probe-stage span)."""
-        from .obs import metrics as obs_metrics
         with obs_metrics.timed_stage('device_scan.probe') as sp:
             status, ok = run_with_deadline(self._probe_ok,
                                            probe_deadline_s(),
@@ -888,7 +898,8 @@ class DeviceScan(VectorScan):
         probation measurements."""
         if self._acc is not None:
             jax, _ = get_jax()
-            jax.block_until_ready(self._acc)
+            with obs_metrics.leaf_stage('scan.device_wait'):
+                jax.block_until_ready(self._acc)
 
     def _after_device_batch(self, n):
         """Crossover probation: time a window of device batches against
@@ -917,13 +928,6 @@ class DeviceScan(VectorScan):
         self._sync_device()
         elapsed = time.monotonic() - start
         rate = seen / elapsed if elapsed > 0 else float('inf')
-        if rate > 0 and elapsed > 0:
-            # the measured device rate feeds the device_mfu_pct /
-            # engagement gauges (obs/metrics.refresh_device_gauges)
-            import math
-            if math.isfinite(rate):
-                from .obs import metrics as obs_metrics
-                obs_metrics.set_gauge('device_records_per_sec', rate)
         if self._host_rate is not None and rate < self._host_rate:
             self._disabled = True
             LOG.info('device de-escalated (lost probation)',
@@ -971,10 +975,12 @@ class DeviceScan(VectorScan):
         if self._backend_ok is None and not self._probe_backend():
             return False
         inputs = {}
-        staged = self._stage_device(provider, weights, alive, inputs)
-        if staged is None:
-            return False
-        self._run_staged(staged, inputs)
+        with obs_metrics.leaf_stage('scan.stage'):
+            staged = self._stage_device(provider, weights, alive, inputs)
+            if staged is None:
+                return False
+            run = self._staged_run(staged, inputs)
+        self._dispatch_staged(run, inputs)
         return True
 
     def _stage_device(self, provider, weights, alive, inputs):
@@ -1484,7 +1490,6 @@ class DeviceScan(VectorScan):
             p <<= 1
         fl = min(p, hi)
         sk['pn_floor'] = fl
-        from .obs import metrics as obs_metrics
         obs_metrics.set_gauge('device_batch_floor', fl)
         return fl
 
@@ -1502,7 +1507,8 @@ class DeviceScan(VectorScan):
                 self._sparse_ub += n
                 return True
             if self._acc is not None and len(self._acc) == 5:
-                nuniq = int(np.asarray(self._acc[4])[0])
+                with obs_metrics.leaf_stage('scan.fetch'):
+                    nuniq = int(np.asarray(self._acc[4])[0])
                 if nuniq + n <= cap:
                     self._sparse_ub = nuniq + n
                     return True
@@ -1561,7 +1567,10 @@ class DeviceScan(VectorScan):
                   mesh_devices=int(mesh[0].devices.size) if mesh else 0,
                   merge='psum+pmin' if mesh else None)
 
-    def _run_staged(self, staged, inputs):
+    def _staged_run(self, staged, inputs):
+        """The last of a batch's staging: its jitted program, with the
+        accumulator made ready and the batch base written into
+        `inputs`."""
         pn, profile, caps, ns, total_w = staged
         progs, use_pallas = self._staged_programs(staged)
         run = progs.run_pallas if use_pallas else progs.run_scatter
@@ -1574,15 +1583,12 @@ class DeviceScan(VectorScan):
             # by type, so it needs the np view of the inputs
             self.capture_next = False
             self.captured = (run, dict(inputs), staged, use_pallas)
-        if self._device_mesh() is None:
-            nbytes = _upload_inputs(inputs)
-        else:
-            # mesh shardings are the jit's to decide; keep host arrays
-            nbytes = sum(int(getattr(v, 'nbytes', 0) or 0)
-                         for v in inputs.values()
-                         if isinstance(v, np.ndarray))
-        _note_h2d(nbytes)
-        self._acc, token = run(inputs, self._acc)
+        return run
+
+    def _dispatch_staged(self, run, inputs):
+        nbytes = _upload_batch(inputs, self._device_mesh())
+        with obs_metrics.leaf_stage('scan.dispatch'):
+            self._acc, token = run(inputs, self._acc)
         self._acc_batch += 1
         self._note_dispatch(token, nbytes)
         if self._acc_batch % SYNC_EVERY_BATCHES == 0:
@@ -1598,7 +1604,6 @@ class DeviceScan(VectorScan):
         device was busy while this batch staged + uploaded), then
         bound the in-flight window by blocking on the token from
         `depth` dispatches back."""
-        from .obs import metrics as obs_metrics
         depth = pipeline_depth()
         q = self._pipe
         obs_metrics.inc('device_pipe_dispatches')
@@ -1607,11 +1612,11 @@ class DeviceScan(VectorScan):
             obs_metrics.inc('device_pipe_overlapped')
             obs_metrics.inc('device_h2d_overlapped_bytes', int(nbytes))
         q.append(token)
-        jax = None
-        while len(q) > depth:
-            if jax is None:
-                jax, _ = get_jax()
-            jax.block_until_ready(q.popleft())
+        if len(q) > depth:
+            jax, _ = get_jax()
+            with obs_metrics.leaf_stage('scan.device_wait'):
+                while len(q) > depth:
+                    jax.block_until_ready(q.popleft())
 
     # -- the device program -------------------------------------------------
 
@@ -2149,25 +2154,30 @@ class DeviceScan(VectorScan):
             return
 
         if not meta['cols']:
-            _issue_async(acc)
-            self._emit_counters(np.asarray(acc[2]))
-            self.aggr.write_key(
-                (), self._weight(float(np.asarray(acc[0])[0])))
+            with obs_metrics.leaf_stage('scan.fetch'):
+                _issue_async(acc)
+                cvec = np.asarray(acc[2])
+                total = float(np.asarray(acc[0])[0])
+            with obs_metrics.leaf_stage('scan.emit'):
+                self._emit_counters(cvec)
+                self.aggr.write_key((), self._weight(total))
             return
 
         segs = wsum = cvec = None
-        if meta['ns'] >= self.COMPACT_MIN_SEGMENTS:
-            fetched = _compact_fetch(acc, self.COMPACT_K)
-            if fetched is not None:
-                segs, wsum, cvec = fetched
-                self.aggr.stage.bump_hidden('ncompactflush', 1)
-        if segs is None:
-            segs, wsum, cvec = _dense_full_result(acc)
-        self._emit_counters(cvec)
-        # global codes for the shared emit path: device string codes
-        # are already engine-dictionary codes; bucket codes offset
-        # by the window origin give raw ordinals
-        self._decode_emit(meta, segs, wsum)
+        with obs_metrics.leaf_stage('scan.fetch'):
+            if meta['ns'] >= self.COMPACT_MIN_SEGMENTS:
+                fetched = _compact_fetch(acc, self.COMPACT_K)
+                if fetched is not None:
+                    segs, wsum, cvec = fetched
+                    self.aggr.stage.bump_hidden('ncompactflush', 1)
+            if segs is None:
+                segs, wsum, cvec = _dense_full_result(acc)
+        with obs_metrics.leaf_stage('scan.emit'):
+            self._emit_counters(cvec)
+            # global codes for the shared emit path: device string
+            # codes are already engine-dictionary codes; bucket codes
+            # offset by the window origin give raw ordinals
+            self._decode_emit(meta, segs, wsum)
 
     def _flush_sparse(self, acc, meta, sparse_ub):
         """Flush the sparse (high-cardinality) accumulator: the set is
@@ -2176,13 +2186,14 @@ class DeviceScan(VectorScan):
         epoch's unique-count upper bound."""
         k0 = _pow2(max(min(sparse_ub, meta['sparse_cap']), 1)) \
             if sparse_ub else self.COMPACT_K
-        fetched = _sparse_fetch(acc, k0, meta['caps'])
-        if fetched is None:
-            cols, wsum, cvec, stats = _sparse_full_result(
-                acc, meta['caps'])
-        else:
-            cols, wsum, cvec, stats = fetched
-            self.aggr.stage.bump_hidden('ncompactflush', 1)
+        with obs_metrics.leaf_stage('scan.fetch'):
+            fetched = _sparse_fetch(acc, k0, meta['caps'])
+            if fetched is None:
+                cols, wsum, cvec, stats = _sparse_full_result(
+                    acc, meta['caps'])
+            else:
+                cols, wsum, cvec, stats = fetched
+                self.aggr.stage.bump_hidden('ncompactflush', 1)
         if int(stats[1]):
             # the host pressure guard exists to make this unreachable;
             # if it ever trips, results are incomplete — fail loudly
@@ -2190,8 +2201,9 @@ class DeviceScan(VectorScan):
                 'device sparse aggregation overflowed its resident set'
                 ' (cap=%d); results would be incomplete'
                 % meta['sparse_cap'])
-        self._emit_counters(cvec)
-        self._emit_cols(meta, cols, wsum)
+        with obs_metrics.leaf_stage('scan.emit'):
+            self._emit_counters(cvec)
+            self._emit_cols(meta, cols, wsum)
 
 
 # jitted flush-compaction programs, keyed by (acc_len, K)
@@ -2289,11 +2301,25 @@ def _sparse_program_full(cap, k):
 
 
 def _note_h2d(nbytes):
-    """Host->device transfer accounting (always-on counter; traces
-    see the totals as span attrs on device_scan.fetch/probe)."""
+    """Host->device transfer accounting (always-on counter)."""
     if nbytes:
-        from .obs import metrics as obs_metrics
         obs_metrics.inc('device_h2d_bytes', int(nbytes))
+
+
+def _upload_batch(inputs, mesh):
+    """The scan.upload stage of one batch: its host arrays to the
+    device (in place), counted in `device_h2d_bytes`; returns the
+    byte count.  Under a mesh the shardings are the jit's to decide,
+    so the arrays stay on the host and are only counted."""
+    with obs_metrics.leaf_stage('scan.upload'):
+        if mesh is None:
+            nbytes = _upload_inputs(inputs)
+        else:
+            nbytes = sum(int(getattr(v, 'nbytes', 0) or 0)
+                         for v in inputs.values()
+                         if isinstance(v, np.ndarray))
+    _note_h2d(nbytes)
+    return nbytes
 
 
 def _upload_inputs(inputs):
@@ -2379,7 +2405,6 @@ def parallel_fetch_enabled():
             _PARALLEL_FETCH.update(
                 enabled=False, source='probe', probe_ms=ms,
                 reason=reason)
-    from .obs import metrics as obs_metrics
     obs_metrics.set_gauge(
         'device_parallel_fetch',
         1 if _PARALLEL_FETCH['enabled'] else 0)
@@ -2398,20 +2423,16 @@ def _fetch_arrays(arrays):
     =1) allows it — concurrent transfers are not assumed safe on
     every backend, so the capability is probed once (gain on the
     current chip: not measured)."""
-    from .obs import metrics as obs_metrics
-    from .obs import trace as obs_trace
     arrays = list(arrays)
-    with obs_trace.span('device_scan.d2h', narrays=len(arrays)) as sp:
-        if len(arrays) <= 1 or not parallel_fetch_enabled():
-            out = [np.asarray(a) for a in arrays]
-        else:
-            import concurrent.futures as cf
-            with cf.ThreadPoolExecutor(min(4, len(arrays))) as ex:
-                out = list(ex.map(np.asarray, arrays))
-        nbytes = sum(int(a.nbytes) for a in out)
-        if nbytes:
-            obs_metrics.inc('device_d2h_bytes', nbytes)
-            sp.set(bytes=nbytes)
+    if len(arrays) <= 1 or not parallel_fetch_enabled():
+        out = [np.asarray(a) for a in arrays]
+    else:
+        import concurrent.futures as cf
+        with cf.ThreadPoolExecutor(min(4, len(arrays))) as ex:
+            out = list(ex.map(np.asarray, arrays))
+    nbytes = sum(int(a.nbytes) for a in out)
+    if nbytes:
+        obs_metrics.inc('device_d2h_bytes', nbytes)
     return out
 
 
@@ -2601,12 +2622,34 @@ class DeviceScanStack(object):
     def _process_device(self, provider, weights, alive):
         scans = self.scans
         inputs = {}
-        staged = []
-        for s in scans:
-            st = s._stage_device(provider, weights, alive, inputs)
-            if st is None:
-                return False
-            staged.append(st)
+        with obs_metrics.leaf_stage('scan.stage'):
+            staged = []
+            for s in scans:
+                st = s._stage_device(provider, weights, alive, inputs)
+                if st is None:
+                    return False
+                staged.append(st)
+            run = self._stacked_program(staged, inputs)
+        nbytes = _upload_batch(inputs, scans[0]._device_mesh())
+        with obs_metrics.leaf_stage('scan.dispatch'):
+            accs, token = run(inputs, tuple(s._acc for s in scans))
+        for s, acc in zip(scans, accs):
+            s._acc = acc
+            s._acc_batch += 1
+            # telemetry: this batch went through the combined program
+            # (kept out of --counters for golden byte parity)
+            s.aggr.stage.bump_hidden('nstackedbatches', 1)
+        self._nbatch += 1
+        scans[0]._note_dispatch(token, nbytes)
+        if self._nbatch % SYNC_EVERY_BATCHES == 0:
+            scans[0]._sync_device()
+        return True
+
+    def _stacked_program(self, staged, inputs):
+        """The combined jitted program of one staged batch (every
+        scan's accumulator made ready and its batch base written into
+        `inputs` on the way)."""
+        scans = self.scans
         pns = set(st[0] for st in staged)
         assert len(pns) == 1, pns    # same batch, same mesh => same pad
 
@@ -2651,26 +2694,7 @@ class DeviceScanStack(object):
             if len(_STACK_CACHE) >= 32:
                 _STACK_CACHE.pop(next(iter(_STACK_CACHE)))
             _STACK_CACHE[ckey] = run
-
-        if scans[0]._device_mesh() is None:
-            nbytes = _upload_inputs(inputs)
-        else:
-            nbytes = sum(int(getattr(v, 'nbytes', 0) or 0)
-                         for v in inputs.values()
-                         if isinstance(v, np.ndarray))
-        _note_h2d(nbytes)
-        accs, token = run(inputs, tuple(s._acc for s in scans))
-        for s, acc in zip(scans, accs):
-            s._acc = acc
-            s._acc_batch += 1
-            # telemetry: this batch went through the combined program
-            # (kept out of --counters for golden byte parity)
-            s.aggr.stage.bump_hidden('nstackedbatches', 1)
-        self._nbatch += 1
-        scans[0]._note_dispatch(token, nbytes)
-        if self._nbatch % SYNC_EVERY_BATCHES == 0:
-            scans[0]._sync_device()
-        return True
+        return run
 
 
 def make_stack(scanners):

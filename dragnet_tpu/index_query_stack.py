@@ -614,8 +614,8 @@ def run_stacked(paths, query, aggr, index_list):
 
     from .obs import metrics as obs_metrics
     try:
-        with obs_metrics.timed_stage('index_query_stack.load',
-                                     nshards=len(paths)):
+        with obs_metrics.leaf_stage('index_query_stack.load',
+                                    nshards=len(paths)):
             mod_iqmt.run_shard_loads(paths, query, on_blocks)
     except _GateFailed:
         return False
@@ -682,7 +682,7 @@ def run_stacked(paths, query, aggr, index_list):
     # exactly the order the sequential loop scans groups; the first
     # occurrence of each aggregate tuple in this order IS its flat-map
     # insertion position
-    with obs_metrics.timed_stage('index_query_stack.sort', nrows=n):
+    with obs_metrics.leaf_stage('index_query_stack.sort', nrows=n):
         perm = _order_rows(shard_ids, sort_cols)
         acols = [c[perm] for c in agg_cols]
         first_idx, inv, order = _unique_rows(acols)

@@ -26,8 +26,10 @@ lookup and a few adds under a registry lock that is only ever
 contended by /stats snapshots.
 """
 
+import bisect
 import contextlib
 import os
+import sys
 import threading
 import time
 
@@ -123,10 +125,8 @@ class Histogram(object):
         self.counts[self._slot(v)] += 1
 
     def _slot(self, v):
-        for i, b in enumerate(self.bounds):
-            if v <= b:
-                return i
-        return len(self.bounds)
+        # the first bound >= v; past the last one, the +Inf slot
+        return bisect.bisect_left(self.bounds, v)
 
     def merge(self, other):
         if other.bounds == self.bounds:
@@ -306,6 +306,144 @@ def timed_stage(name, metric='stage_ms', labels=None, **span_attrs):
         observe(metric, (time.perf_counter() - t0) * 1000.0, **labels)
 
 
+_LEAF = threading.local()       # .top: this thread's open leaf stage
+_TRACE_ANNOTATION = None        # jax.profiler.TraceAnnotation, once seen
+
+
+def _annotation(name, tctx):
+    """An entered `jax.profiler.TraceAnnotation(name)`, or None in a
+    process that has not imported jax (a host-engine process never
+    imports it for this).  A TraceMe: a flag test while no profiler
+    session runs, an event on the trace's /host:CPU plane while one
+    does — the device planes' clock.  A traced request's id (`tctx`,
+    its TraceContext) rides as the event's `trace` stat, so the event
+    joins its DN_TRACE line."""
+    global _TRACE_ANNOTATION
+    cls = _TRACE_ANNOTATION
+    if cls is None:
+        profiler = getattr(sys.modules.get('jax'), 'profiler', None)
+        if profiler is None:
+            return None
+        cls = _TRACE_ANNOTATION = profiler.TraceAnnotation
+    ann = cls(name) if tctx is None else cls(name, trace=tctx.trace_id)
+    ann.__enter__()
+    return ann
+
+
+class leaf_stage(object):
+    """timed_stage for a LEAF of the request: a stage that encloses
+    no other.  The same span `name` and the same always-on
+    `stage_ms{stage=name}` observation, plus a third leg, the
+    profiler annotation (_annotation).  Only leaves go to the
+    profiler: the trace reducer names a device-idle gap by the host
+    event that covers most of it, so an annotation around other
+    stages would take every gap inside it — serve.execute and the
+    other enclosing spans stay timed_stage / span.
+
+    A leaf opened inside another on the same thread (an epoch flush
+    in the middle of staging) suspends the outer one: its annotation
+    ends and is opened again afterwards, and its `stage_ms` is its
+    self time.  So leaves never overlap, and their sum is at most the
+    request's own time.
+
+    A scan meets some 200 of these, each after native code has had
+    the caches, so the off path is kept short: the request's scope is
+    read once, and no span object exists unless tracing is on
+    (``as sp: sp.set(...)`` works either way)."""
+
+    __slots__ = ('name', 'attrs', 'outer', 'registry', 'tctx', 'span',
+                 'ann', 't0', 'inner_ms')
+
+    def __init__(self, name, **span_attrs):
+        self.name = name
+        self.attrs = span_attrs
+        self.inner_ms = 0.0
+
+    def __enter__(self):
+        self.outer = outer = getattr(_LEAF, 'top', None)
+        if outer is not None and outer.ann is not None:
+            outer.ann.__exit__(None, None, None)
+        _LEAF.top = self
+        obs = getattr(mod_vpipe.current_scope(), 'obs', None)
+        reg = getattr(obs, 'registry', None)
+        self.registry = reg if reg is not None else _GLOBAL
+        self.tctx = tctx = getattr(obs, 'trace', None)
+        self.span = None
+        if tctx is not None:
+            from . import trace as mod_trace
+            self.span = mod_trace.span(self.name, **self.attrs)
+        self.ann = _annotation(self.name, tctx)
+        self.t0 = time.perf_counter()
+        return self
+
+    def set(self, **attrs):
+        if self.span is not None:
+            self.span.set(**attrs)
+        return self
+
+    def __exit__(self, *exc):
+        ms = (time.perf_counter() - self.t0) * 1000.0
+        if self.ann is not None:
+            self.ann.__exit__(*exc)
+        if self.span is not None:
+            self.span.__exit__(*exc)
+        self.registry.observe('stage_ms', ms - self.inner_ms,
+                              stage=self.name)
+        _LEAF.top = outer = self.outer
+        if outer is not None:
+            outer.inner_ms += ms
+            if outer.ann is not None:
+                outer.ann = _annotation(outer.name, outer.tctx)
+        return False
+
+
+# -- compilations (jax.monitoring) ------------------------------------------
+
+# .hit: the persistent cache answered this thread's compile request
+_COMPILE_TLS = threading.local()
+
+
+def watch_compiles(jax):
+    """Count XLA compilations in the GLOBAL registry (a compile
+    belongs to the process; a listener has no request scope).  Called
+    once per process, by ops.get_jax when it imports jax — so only in
+    a process where the program imported jax itself.
+
+    On the installed jax (0.9.0, looked at in PR 26) every compile
+    request ends in one `/jax/core/compile/backend_compile_duration`
+    duration event — whether XLA compiled or the persistent cache
+    answered (it is also when `Finished XLA compilation` is logged) —
+    and a cache answer is preceded, on the same thread, by one
+    `/jax/compilation_cache/cache_hits` event.  `cache_misses` fires
+    only when an entry is WRITTEN (subject to the size and time
+    thresholds), so it does not count compiles.  Hence:
+
+    * ``xla_compiles_total`` / ``xla_compile_ms`` — real compiles:
+      duration events with no cache hit before them.
+    * ``xla_cache_loads_total`` — programs loaded from the cache."""
+    def on_event(event, **_kw):
+        if event == '/jax/compilation_cache/cache_hits':
+            _COMPILE_TLS.hit = True
+
+    def on_duration(event, secs, **_kw):
+        if event != '/jax/core/compile/backend_compile_duration':
+            return
+        reg = _GLOBAL
+        if getattr(_COMPILE_TLS, 'hit', False):
+            _COMPILE_TLS.hit = False
+            reg.inc('xla_cache_loads_total')
+        else:
+            reg.inc('xla_compiles_total')
+            reg.observe('xla_compile_ms', secs * 1000.0)
+
+    jax.monitoring.register_event_listener(on_event)
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    # present from the start, so a scrape reads 0 and not nothing
+    reg = _GLOBAL
+    reg.inc('xla_compiles_total', 0)
+    reg.inc('xla_cache_loads_total', 0)
+
+
 # -- device gauges (ROADMAP open item 4: the reporting half) ---------------
 
 _DEVICE_COUNTER_GAUGES = (
@@ -338,12 +476,6 @@ def refresh_device_gauges(counters, registry=None):
       ``device_index_sums``      — the raw engagement counters.
     * ``device_residency_pct``   — share of engine batches that ran on
       the device lane (device / (device + host)); 0 when nothing ran.
-    * ``device_mfu_pct``         — measured device records/s against
-      the rig's calibrated peak (DN_DEVICE_PEAK_RECORDS_PER_SEC).
-      HONEST ZEROS: without a measured device rate (CPU rigs, host
-      lane) and a calibrated peak, this reports 0.0 rather than a
-      guess.  device_scan sets `device_records_per_sec` when the
-      device lane actually measures a window.
     * ``device_residency_hit_rate`` / ``device_pinned_bytes`` /
       ``device_h2d_saved_bytes`` / ``device_d2h_saved_bytes`` — HBM
       residency (serve/residency.py), present only when a serve
@@ -362,19 +494,6 @@ def refresh_device_gauges(counters, registry=None):
     denom = host_batches + dev_batches
     reg.set_gauge('device_residency_pct',
                   100.0 * dev_batches / denom if denom else 0.0)
-    rate = 0.0
-    with reg._lock:
-        for (n, _lb), m in reg._metrics.items():
-            if n == 'device_records_per_sec' and m.kind == GAUGE:
-                rate = max(rate, float(m.value))
-    peak = 0.0
-    try:
-        peak = float(os.environ.get(
-            'DN_DEVICE_PEAK_RECORDS_PER_SEC', '0') or 0)
-    except ValueError:
-        peak = 0.0
-    mfu = 100.0 * rate / peak if (rate > 0 and peak > 0) else 0.0
-    reg.set_gauge('device_mfu_pct', mfu)
     src = _RESIDENCY_SOURCE
     if src is not None:
         try:

@@ -71,6 +71,8 @@ def get_jax():
             jax.config.update(
                 'jax_persistent_cache_min_entry_size_bytes', -1)
         import jax.numpy as jnp
+        from ..obs import metrics as obs_metrics
+        obs_metrics.watch_compiles(jax)
         _jax = (jax, jnp)
     return _jax if _jax else None
 

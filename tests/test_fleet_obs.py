@@ -297,7 +297,7 @@ def test_prometheus_exposition_completeness():
                      'integrity_corrupt_shards_total',
                      'router_failovers_total', 'serve_shed_total',
                      'handoff_shards_streamed_total',
-                     'follow_ingest_lag_ms', 'device_mfu_pct'):
+                     'follow_ingest_lag_ms', 'device_residency_pct'):
         assert expected in names, expected
     assert len(names) > 25
     reg = obs_metrics.Registry()

@@ -123,7 +123,7 @@ def test_device_gauges_honest_zeros():
     g = {n: m.value for n, _, m in reg.snapshot()
          if m.kind == obs_metrics.GAUGE}
     assert g['device_engaged'] == 0.0
-    assert g['device_mfu_pct'] == 0.0
+    assert 'device_mfu_pct' not in g
     assert g['device_residency_pct'] == 0.0
 
 
@@ -372,8 +372,7 @@ def test_stats_schema_golden_shape(server, corpus):
     assert isinstance(lat['p99'], float)
     qw = m['histograms'].get('serve_queue_wait_ms')
     assert qw is not None and qw['count'] >= 1
-    for g in ('device_engaged', 'device_mfu_pct',
-              'device_residency_pct'):
+    for g in ('device_engaged', 'device_residency_pct'):
         assert g in m['gauges']
     assert st['device']['engaged'] in (False, True)
 
@@ -395,7 +394,7 @@ def test_metrics_op_prometheus(server, corpus):
     for line in text.splitlines():
         if not line.startswith('#'):
             assert _PROM_LINE.match(line), line
-    assert 'dn_device_mfu_pct' in text
+    assert 'dn_device_residency_pct' in text
 
 
 def test_trace_id_propagates_and_joins(server, corpus, tmp_path,

@@ -1,0 +1,438 @@
+"""The leaf stages of the scan, build and index-fold host path
+(obs/metrics.leaf_stage): always-on `stage_ms{stage}`, spans in the
+request's tree, `jax.profiler` annotations on the device trace's
+clock; the counters at the same boundaries; the compile counters of
+`jax.monitoring`.
+
+One forced-device scan, build and index query over a small corpus run
+once per module (`runs`), in small batches so that every stage of the
+table occurs; the tests read what they left behind.
+"""
+
+import hashlib
+import importlib.util
+import json
+import os
+import subprocess
+import sys
+import time
+
+import pytest
+
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO_ROOT)
+
+from dragnet_tpu import cli                                # noqa: E402
+from dragnet_tpu.obs import metrics as obs_metrics         # noqa: E402
+from dragnet_tpu.serve import server as mod_server         # noqa: E402
+
+SCAN_STAGES = ('scan.read', 'scan.parse', 'scan.stage', 'scan.upload',
+               'scan.dispatch', 'scan.device_wait', 'scan.fetch',
+               'scan.emit')
+FOLD_STAGES = ('index_fold.stage', 'index_fold.dispatch',
+               'index_fold.device_wait', 'index_fold.fetch')
+QUERY_STAGES = ('index_query_stack.load',
+                'index_query_stack.sort') + FOLD_STAGES
+# which request must have met which leaves
+LEAVES = {'scan': SCAN_STAGES, 'build': SCAN_STAGES,
+          'query': QUERY_STAGES}
+ALL_LEAVES = set(SCAN_STAGES + QUERY_STAGES)
+
+NRECORDS = 3000
+SMALL_BATCH = 512
+SCAN_ARGS = ['scan', '-b', 'host,req.method,latency[aggr=quantize]',
+             '-f', '{"ne":["latency",7]}']
+
+
+def gen_corpus(path, n=NRECORDS):
+    """`n` records by formula over three days: the same bytes every
+    time, so that outputs can be pinned."""
+    t0 = 1388534400
+    with open(path, 'w') as f:
+        for i in range(n):
+            ts = time.strftime('%Y-%m-%dT%H:%M:%S.000Z',
+                               time.gmtime(t0 + i * 80))
+            f.write(json.dumps({
+                'time': ts, 'host': 'host%d' % (i % 5),
+                'req': {'method': ('GET', 'PUT', 'HEAD')[i % 3]},
+                'latency': (i * 7) % 230,
+            }, separators=(',', ':')) + '\n')
+
+
+def run_cli(args):
+    with mod_server.thread_stdio() as cap:
+        rc = cli.main(list(args))
+    out, err = cap.finish()
+    return rc, out, err
+
+
+def stage_table():
+    """{stage: (count, summed ms)} of `stage_ms`, and {counter: value},
+    of the global registry."""
+    stages, counters = {}, {}
+    for name, labels, m in obs_metrics.global_registry().snapshot():
+        if name == 'stage_ms':
+            stages[dict(labels)['stage']] = (m.total, m.sum)
+        elif m.kind == obs_metrics.COUNTER:
+            counters[name] = m.value
+    return stages, counters
+
+
+def add_datasource(root, name='stageds'):
+    datafile = os.path.join(root, 'data.log')
+    gen_corpus(datafile)
+    os.environ['DRAGNET_CONFIG'] = os.path.join(root, 'dragnetrc.json')
+    for args in (
+            ['datasource-add', '--path', datafile, '--index-path',
+             os.path.join(root, 'idx'), '--time-field', 'time', name],
+            ['metric-add', '-b',
+             'timestamp[field=time,date,aggr=lquantize,step=86400]',
+             '-b', 'host', '-b', 'latency[aggr=quantize]', name, 'm1'],
+            ['metric-add', '-b',
+             'timestamp[field=time,date,aggr=lquantize,step=86400]',
+             '-b', 'req.method', name, 'm2']):
+        rc, out, err = run_cli(args)
+        assert rc == 0, err
+    return datafile
+
+
+@pytest.fixture(scope='module')
+def runs(tmp_path_factory):
+    """{op: {'stages', 'counters', 'doc' (the DN_TRACE line), 'out',
+    'err'}} of one forced-device scan, build and (second, so that the
+    first device contact's deadline thread is behind it) index query."""
+    from dragnet_tpu import device_scan as mod_ds
+    from dragnet_tpu import engine as mod_engine
+    root = str(tmp_path_factory.mktemp('stage_spans'))
+    sink = os.path.join(root, 'trace.jsonl')
+    got = {}
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(mod_engine, 'BATCH_SIZE', SMALL_BATCH)
+        mp.setattr(mod_ds, 'BATCH_SIZE', SMALL_BATCH)
+        for k, v in (('DN_READ_SIZE', '16384'), ('DN_ENGINE', 'jax'),
+                     ('DN_INDEX_DEVICE', '1'), ('DN_PARSE_THREADS', '1'),
+                     ('DN_DEVICE_PIPELINE_DEPTH', '1'),
+                     ('DN_TRACE', sink),
+                     ('DRAGNET_CONFIG', '')):
+            mp.setenv(k, v)
+        mp.delenv('DN_SLOW_MS', raising=False)
+        datafile = add_datasource(root)
+        got['corpus_bytes'] = os.path.getsize(datafile)
+        for op, args in (('scan', SCAN_ARGS + ['--counters', 'stageds']),
+                         ('build', ['build', 'stageds']),
+                         ('warm', ['query', '-b', 'host', 'stageds']),
+                         ('query', ['query', '-b', 'host', '--counters',
+                                    'stageds'])):
+            obs_metrics.reset_global_registry()
+            open(sink, 'w').close()
+            rc, out, err = run_cli(args)
+            assert rc == 0, err
+            stages, counters = stage_table()
+            with open(sink) as f:
+                docs = [json.loads(ln) for ln in f]
+            assert len(docs) == 1
+            got[op] = {'stages': stages, 'counters': counters,
+                       'doc': docs[0], 'out': out, 'err': err}
+    return got
+
+
+# -- (1) always-on stage_ms and the counters beside it ----------------------
+
+@pytest.mark.parametrize('op,stage', [
+    (op, s) for op in ('scan', 'build', 'query') for s in LEAVES[op]])
+def test_every_stage_is_observed(runs, op, stage):
+    count, ms = runs[op]['stages'].get(stage, (0, 0.0))
+    assert count > 0 and ms >= 0.0
+
+
+@pytest.mark.parametrize('op', ['scan', 'build'])
+def test_parse_counters_equal_the_corpus(runs, op):
+    c = runs[op]['counters']
+    assert c['scan_parse_bytes'] == runs['corpus_bytes']
+    assert c['scan_parse_records'] == NRECORDS
+
+
+def test_index_fold_counts_its_staged_shards(runs):
+    # three days of records, one shard a day
+    assert runs['query']['counters']['index_fold_shards_staged'] == 3
+
+
+@pytest.mark.parametrize('op', ['scan', 'build', 'query'])
+def test_leaf_stages_sum_within_the_request(runs, op):
+    """No double counting: no leaf's `stage_ms` holds another leaf's
+    time, so together they fit into the request's root span."""
+    r = runs[op]
+    leaves = sum(ms for s, (_n, ms) in r['stages'].items()
+                 if s in ALL_LEAVES)
+    assert 0 < leaves <= r['doc']['dur_ms']
+
+
+def test_nested_leaf_suspends_the_outer_one():
+    """An epoch flush in the middle of staging: the outer leaf's
+    stage_ms is its self time, and the two sum to the wall time."""
+    obs_metrics.reset_global_registry()
+    t0 = time.perf_counter()
+    with obs_metrics.leaf_stage('t.outer'):
+        time.sleep(0.02)
+        with obs_metrics.leaf_stage('t.inner'):
+            time.sleep(0.03)
+        time.sleep(0.01)
+    wall_ms = (time.perf_counter() - t0) * 1000.0
+    stages, _ = stage_table()
+    (n_out, outer), (n_in, inner) = stages['t.outer'], stages['t.inner']
+    assert (n_out, n_in) == (1, 1)
+    assert inner >= 30.0 and 30.0 <= outer < wall_ms - inner + 1.0
+    assert outer + inner <= wall_ms
+    assert getattr(obs_metrics._LEAF, 'top', None) is None
+
+
+# -- (2) the same stages in the request's span tree -------------------------
+
+def _walk(span, fn, parent=None):
+    fn(span, parent)
+    for c in span.get('children') or []:
+        _walk(c, fn, span)
+
+
+@pytest.mark.parametrize('op', ['scan', 'build', 'query'])
+def test_stages_in_the_span_tree(runs, op):
+    doc = runs[op]['doc']
+    assert doc['op'] == op and len(doc['trace']) == 32
+    names = set()
+
+    def check(span, parent):
+        names.add(span['name'])
+        if parent is not None and span.get('thread') is None:
+            # children lie inside their parents' intervals (0.01 ms
+            # of rounding in the document)
+            assert span['t0_ms'] >= parent['t0_ms'] - 0.01
+            assert span['t0_ms'] + span['dur_ms'] <= \
+                parent['t0_ms'] + parent['dur_ms'] + 0.01
+    _walk(doc['spans'], check)
+    assert set(LEAVES[op]) <= names
+    assert 'device_scan.d2h' not in names
+
+
+# -- (3) the profiler leg ----------------------------------------------------
+
+PROFILED = r'''
+import glob, json, os, sys
+sys.path.insert(0, %(root)r)
+sys.path.insert(0, os.path.join(%(root)r, 'tests'))
+import test_stage_spans as t
+from dragnet_tpu.serve import client as mod_client
+from dragnet_tpu.serve import server as mod_server
+work = sys.argv[1]
+os.environ['DN_TRACE'] = os.path.join(work, 'trace.jsonl')
+os.environ['DN_ENGINE'] = 'jax'
+t.add_datasource(work)
+srv = mod_server.DnServer(
+    socket_path=os.path.join(work, 's.sock'),
+    conf={'max_inflight': 2, 'queue_depth': 4, 'deadline_ms': 0,
+          'coalesce': True, 'drain_s': 10}).start()
+req = {'op': 'scan', 'ds': 'stageds',
+       'config': os.environ['DRAGNET_CONFIG'],
+       'queryconfig': {'breakdowns': [{'name': 'host', 'field': 'host'}]},
+       'opts': {'points': True}}
+try:
+    rc, _, out, err = mod_client.request_bytes(srv.socket_path, req)
+    assert rc == 0, err                   # jax imported, backend up
+    import jax
+    from jax.profiler import ProfileData
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(os.path.join(work, 'prof'),
+                             profiler_options=opts)
+    open(os.environ['DN_TRACE'], 'w').close()
+    rc, _, out, err = mod_client.request_bytes(srv.socket_path, req)
+    jax.profiler.stop_trace()
+    assert rc == 0, err
+finally:
+    srv.stop()
+path = glob.glob(os.path.join(work, 'prof', 'plugins', 'profile', '*',
+                              '*.xplane.pb'))[0]
+events = {}
+for plane in ProfileData.from_file(path).planes:
+    if plane.name != '/host:CPU':
+        continue
+    for line in plane.lines:
+        for e in line.events:
+            events.setdefault(e.name, set()).update(
+                str(v) for k, v in e.stats if k == 'trace')
+with open(os.environ['DN_TRACE']) as f:
+    ids = sorted(set(json.loads(ln)['trace'] for ln in f))
+print(json.dumps({'events': {k: sorted(v) for k, v in events.items()},
+                  'trace_ids': ids}))
+'''
+
+
+def _run_script(text, *argv, env=None, timeout=180):
+    """A child process with a time limit of its own; its last stdout
+    line is a JSON document."""
+    e = dict(os.environ, JAX_PLATFORMS='cpu')
+    for k in list(e):
+        if k.startswith('DN_') or k == 'DRAGNET_CONFIG':
+            del e[k]
+    e['DN_AUDITION_CACHE'] = '0'
+    e.update(env or {})
+    p = subprocess.run([sys.executable, '-c', text] + list(argv), env=e,
+                       cwd=REPO_ROOT, stdout=subprocess.PIPE,
+                       stderr=subprocess.PIPE, timeout=timeout)
+    assert p.returncode == 0, p.stderr.decode()[-3000:]
+    return json.loads(p.stdout.decode().splitlines()[-1])
+
+
+def test_profiler_host_plane_holds_the_leaves_only(tmp_path):
+    """Under a jax.profiler trace the leaves are events of the host
+    plane, on the device planes' clock; the enclosing spans are not;
+    and an event carries its request's trace id."""
+    doc = _run_script(PROFILED % {'root': REPO_ROOT}, str(tmp_path))
+    events = doc['events']
+    for stage in ('scan.parse', 'scan.stage', 'scan.fetch'):
+        assert stage in events, sorted(events)
+    assert 'serve.execute' not in events
+    assert not [n for n in events if n.startswith('serve.')]
+    assert len(doc['trace_ids']) == 1
+    assert events['scan.parse'] == doc['trace_ids']
+
+
+# -- (4) what the benchmark's reducer makes of an annotation ----------------
+
+def test_reducer_names_a_gap_by_the_enclosing_annotation():
+    # by path: `benchmarks/` is no package, and nothing of it belongs
+    # on this process's sys.path
+    spec = importlib.util.spec_from_file_location(
+        'bench_trace_reduce',
+        os.path.join(REPO_ROOT, 'benchmarks', 'trace', 'reduce.py'))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    reduce_events = mod.reduce_events
+    ms = 1000000
+    doc = {'planes': [
+        {'name': '/device:TPU:0', 'lines': [
+            {'name': 'XLA Ops', 'events': [
+                ['fusion.1', 0, 2 * ms], ['fusion.1', 80 * ms, 2 * ms]]}]},
+        {'name': '/host:CPU', 'lines': [
+            {'name': 'dn-serve-job', 'events': [
+                ['scan.dispatch', 0, 1 * ms],
+                ['scan.fetch', 3 * ms, 76 * ms],
+                ['np.asarray(jax.Array)', 4 * ms, 74 * ms]]}]}]}
+    gaps = reduce_events(doc)['breakdown']['idle_gaps']
+    assert gaps[0][0] == 'scan.fetch (dn-serve-job)'
+    assert gaps[0][1] == pytest.approx(0.078)
+
+
+# -- (5) the compile counters -------------------------------------------------
+
+def _xla_compiles():
+    return stage_table()[1].get('xla_compiles_total', 0)
+
+
+def test_xla_compiles_total_counts_real_compiles_once():
+    from dragnet_tpu.ops import get_jax
+    jax, jnp = get_jax()                   # registers the listeners
+
+    @jax.jit
+    def fresh(x):
+        return (x * 3 + 1).sum()
+    x = jnp.arange(1237, dtype=jnp.int32)  # its own input: made before
+    x.block_until_ready()
+    c0 = _xla_compiles()
+    fresh(x).block_until_ready()
+    c1 = _xla_compiles()
+    fresh(x).block_until_ready()
+    c2 = _xla_compiles()
+    assert (c1 - c0, c2 - c1) == (1, 0)
+    stages = {n: m for n, _lb, m in
+              obs_metrics.global_registry().snapshot()}
+    assert stages['xla_compile_ms'].total >= 1
+
+
+CACHED = r'''
+import json, sys
+sys.path.insert(0, %(root)r)
+from dragnet_tpu.ops import get_jax
+from dragnet_tpu.obs import metrics as obs_metrics
+jax, jnp = get_jax()
+# write every program, however quick its compile
+jax.config.update('jax_persistent_cache_min_compile_time_secs', 0)
+x = jnp.arange(1237, dtype=jnp.int32)
+x.block_until_ready()
+def count():
+    c = {n: m.value for n, _lb, m in
+         obs_metrics.global_registry().snapshot()
+         if m.kind == obs_metrics.COUNTER}
+    return c['xla_compiles_total'], c['xla_cache_loads_total']
+c0 = count()
+jax.jit(lambda v: (v * 5 + 2).sum())(x).block_until_ready()
+c1 = count()
+print(json.dumps({'compiles': c1[0] - c0[0], 'loads': c1[1] - c0[1]}))
+'''
+
+
+def test_xla_cache_loads_total_counts_the_persistent_cache(tmp_path):
+    """The same program in two processes that share a cache directory:
+    the first compiles it, the second loads it and compiles nothing."""
+    env = {'JAX_COMPILATION_CACHE_DIR': str(tmp_path / 'xla')}
+    text = CACHED % {'root': REPO_ROOT}
+    assert _run_script(text, env=env) == {'compiles': 1, 'loads': 0}
+    assert _run_script(text, env=env) == {'compiles': 0, 'loads': 1}
+
+
+# -- (6) a host-engine process stays off jax --------------------------------
+
+HOST_SCAN = r'''
+import json, os, sys
+sys.path.insert(0, %(root)r)
+sys.path.insert(0, os.path.join(%(root)r, 'tests'))
+import test_stage_spans as t
+t.add_datasource(sys.argv[1])
+rc, out, err = t.run_cli(t.SCAN_ARGS + ['stageds'])
+assert rc == 0, err
+stages, counters = t.stage_table()
+print(json.dumps({'jax': 'jax' in sys.modules, 'stages': sorted(stages),
+                  'records': counters['scan_parse_records']}))
+'''
+
+
+def test_host_engine_scan_never_imports_jax(tmp_path):
+    doc = _run_script(HOST_SCAN % {'root': REPO_ROOT}, str(tmp_path),
+                      env={'DN_ENGINE': 'vector'})
+    assert doc['jax'] is False
+    assert {'scan.read', 'scan.parse', 'scan.stage'} <= set(doc['stages'])
+    assert doc['records'] == NRECORDS
+
+
+# -- (7) outputs, byte for byte ------------------------------------------------
+
+# sha256 of stdout + stderr at the parent commit (d23e433, PR 25), same
+# corpus, same commands, DN_ENGINE=jax and DN_INDEX_DEVICE=1
+GOLDEN = {
+    'scan': '64082b5a79301d496a6e08a94e015917a06340538e67d88a'
+            '6451279b88d94335',
+    'query': 'e4c67f078f75fb2d76da1eeba74702098681f5c3fb9e728e'
+             'f96e4ef5b2c2348d',
+}
+
+
+def _digest(run):
+    return hashlib.sha256(run['out'] + run['err']).hexdigest()
+
+
+@pytest.mark.parametrize('op', sorted(GOLDEN))
+def test_outputs_are_byte_for_byte_the_parents(runs, op):
+    """`--counters` dumps and answers with every stage, span and
+    annotation live: what the parent commit wrote."""
+    assert _digest(runs[op]) == GOLDEN[op]
+
+
+def test_points_equal_the_host_engines(runs, tmp_path, monkeypatch):
+    monkeypatch.setenv('DRAGNET_CONFIG', '')
+    add_datasource(str(tmp_path))
+    outs = {}
+    for engine in ('jax', 'vector'):
+        monkeypatch.setenv('DN_ENGINE', engine)
+        rc, out, err = run_cli(SCAN_ARGS + ['--points', 'stageds'])
+        assert rc == 0, err
+        outs[engine] = out
+    assert outs['jax'] == outs['vector'] and outs['jax']
