@@ -51,7 +51,7 @@ from . import watchdog
 from .errors import DNError
 from .engine import (VectorScan, NativeColumns, MAX_DENSE_SEGMENTS,
                      BATCH_SIZE, engine_mode)
-from .ops.kernels import FALSE, TRUE, ERROR
+from .ops.kernels import FALSE, TRUE, ERROR, I64MAX, sparse_fold
 from .ops import get_jax, backend_ready, accelerator_likely
 from .obs import metrics as obs_metrics
 
@@ -61,7 +61,6 @@ I32MAX = 2 ** 31 - 1
 # numeric-row plans: outcome of <leaf op const> for an exact-int32 row
 NUM_FALSE, NUM_TRUE, NUM_EQ, NUM_NE, NUM_LE, NUM_GE = range(6)
 
-I64MAX = 2 ** 63 - 1
 I16MIN = -(2 ** 15)
 I16MAX = 2 ** 15 - 1
 
@@ -1590,6 +1589,8 @@ class DeviceScan(VectorScan):
         with obs_metrics.leaf_stage('scan.dispatch'):
             self._acc, token = run(inputs, self._acc)
         self._acc_batch += 1
+        if self._acc_meta['sparse_cap']:
+            obs_metrics.inc('device_sparse_fold_batches')
         self._note_dispatch(token, nbytes)
         if self._acc_batch % SYNC_EVERY_BATCHES == 0:
             # periodic dispatch barrier (no fetch): hard backstop on
@@ -2016,43 +2017,20 @@ class DeviceScan(VectorScan):
 
         def fold_sparse(args, acc):
             """Sparse fold: sort-merge the batch's fused i64 keys into
-            the device-resident compacted set.  keys/first take the
-            per-key min (first-occurrence order preserved exactly),
-            weights sum, and the unique count rides along so the host
-            pressure guard can read it without a full fetch."""
+            the device-resident compacted set (kernels.sparse_fold).
+            keys/first take the per-key min (first-occurrence order
+            preserved exactly), weights sum, and the unique count rides
+            along so the host pressure guard can read it without a full
+            fetch.  Runs past the capacity are dropped; the sticky
+            overflow flag makes that loud at flush (the host guard
+            prevents it from ever tripping)."""
             assert mesh is None
-            keys0, wsum0, first0, cvec0, stats0 = acc
             cvec_b, fused, wb, gidx = body(args, False)
             i64 = jnp.int64
             first_b = jnp.where(fused != i64(I64MAX),
                                 args[pfx + 'base'] + gidx.astype(i64),
                                 i64(I64MAX))
-            k = jnp.concatenate([keys0, fused])
-            w = jnp.concatenate([wsum0, wb])
-            f = jnp.concatenate([first0, first_b])
-            order = jnp.argsort(k)
-            ks = k[order]
-            ws = w[order]
-            fs = f[order]
-            newrun = jnp.concatenate(
-                [jnp.ones((1,), dtype=bool), ks[1:] != ks[:-1]])
-            seg = jnp.cumsum(newrun.astype(jnp.int32)) - jnp.int32(1)
-            valid = ks != i64(I64MAX)
-            nuniq = jnp.sum(newrun & valid).astype(i64)
-            # run ids past the capacity are dropped by the segment ops;
-            # the sticky overflow flag makes that loud at flush (the
-            # host guard prevents it from ever tripping)
-            keys1 = jax.ops.segment_min(ks, seg,
-                                        num_segments=sparse_cap)
-            wsum1 = jax.ops.segment_sum(ws, seg,
-                                        num_segments=sparse_cap)
-            first1 = jax.ops.segment_min(fs, seg,
-                                         num_segments=sparse_cap)
-            over = jnp.maximum(
-                stats0[1], (nuniq > sparse_cap).astype(i64))
-            return (keys1, wsum1, first1,
-                    cvec0 + cvec_b.astype(i64),
-                    jnp.stack([nuniq, over]))
+            return sparse_fold(jax, jnp, acc, cvec_b, fused, wb, first_b)
 
         if sparse_cap:
             def run_sparse(args, acc):
@@ -2636,6 +2614,8 @@ class DeviceScanStack(object):
         for s, acc in zip(scans, accs):
             s._acc = acc
             s._acc_batch += 1
+            if s._acc_meta['sparse_cap']:
+                obs_metrics.inc('device_sparse_fold_batches')
             # telemetry: this batch went through the combined program
             # (kept out of --counters for golden byte parity)
             s.aggr.stage.bump_hidden('nstackedbatches', 1)

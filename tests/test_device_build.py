@@ -192,3 +192,52 @@ def test_stack_disable_env(tmp_path, monkeypatch):
     assert t1.keys() == t2.keys()
     for rel in t1:
         assert t1[rel] == t2[rel], rel
+
+
+def test_sparse_fold_batches_counter(tmp_path, monkeypatch):
+    """`device_sparse_fold_batches` counts one per metric and batch
+    that went through the sparse sort-merge fold: with the dense budget
+    forced small, a stacked build grows it by the sparse metrics'
+    batches (the dense metric beside them adds nothing), and a dense
+    build leaves it alone."""
+    from dragnet_tpu import engine as mod_engine
+    from dragnet_tpu import device_scan as mod_ds
+    from dragnet_tpu.obs import metrics as obs_metrics
+    datafile = tmp_path / 'data.log'
+    _write_data(datafile, 1500)
+
+    def folded():
+        return obs_metrics.global_registry().counter(
+            'device_sparse_fold_batches').value
+
+    # (sparse, dense) metric-batches by the programs the stack staged
+    seen = [0, 0]
+    orig = mod_ds.DeviceScanStack._stacked_program
+
+    def spy(self, staged, inputs):
+        for st in staged:
+            seen[0 if st[1][-1] else 1] += 1
+        return orig(self, staged, inputs)
+    monkeypatch.setattr(mod_ds.DeviceScanStack, '_stacked_program', spy)
+
+    before = folded()
+    _, stacked = _build(monkeypatch, datafile, tmp_path / 'i1', 'jax',
+                        batch=256)
+    assert stacked > 0 and seen[0] == 0
+    assert folded() == before
+
+    # byhost (the day axis twice, 32 x 32, x 32 hosts) stays dense
+    # under 2^16; the two metrics with a latency column pass it
+    monkeypatch.setattr(mod_engine, 'MAX_DENSE_SEGMENTS', 1 << 16)
+    monkeypatch.setattr(mod_ds, 'MAX_DENSE_SEGMENTS', 1 << 16)
+    seen[:] = [0, 0]
+    _, stacked = _build(monkeypatch, datafile, tmp_path / 'i2', 'jax',
+                        batch=256)
+    assert stacked > 0 and seen[0] > 0 and seen[1] > 0
+    assert folded() == before + seen[0]
+
+    t1 = _tree_bytes(tmp_path / 'i1')
+    t2 = _tree_bytes(tmp_path / 'i2')
+    assert t1.keys() == t2.keys()
+    for rel in t1:
+        assert t1[rel] == t2[rel], rel

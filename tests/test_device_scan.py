@@ -383,10 +383,22 @@ def test_sparse_device_engages(tmp_path, monkeypatch):
     })
     q = mod_query.query_load(
         {'breakdowns': [{'name': 'host'}, {'name': 'latency'}]})
+    from dragnet_tpu.obs import metrics as obs_metrics
+    folded = obs_metrics.global_registry().counter(
+        'device_sparse_fold_batches')
+    before = folded.value
     r = ds.scan(q)
     ndev = sum(s.counters.get('ndevicebatches', 0)
                for s in r.pipeline.stages)
     assert ndev > 0, 'sparse device path never ran'
+    assert folded.value == before + ndev
+    # a dense scan runs device batches and folds none of them sparsely
+    monkeypatch.setattr(mod_engine, 'MAX_DENSE_SEGMENTS', 1 << 24)
+    monkeypatch.setattr(mod_ds, 'MAX_DENSE_SEGMENTS', 1 << 24)
+    r = ds.scan(q)
+    assert sum(s.counters.get('ndevicebatches', 0)
+               for s in r.pipeline.stages) > 0
+    assert folded.value == before + ndev
 
 
 def test_prefetch_flush_differential(tmp_path, monkeypatch):
@@ -495,3 +507,183 @@ def test_sparse_cap_overflow_falls_back(tmp_path, monkeypatch):
                                      engine='jax', batch=64)
     assert host_points == dev_points
     assert host_counters == dev_counters
+
+
+# -- the sparse fold program alone ------------------------------------------
+
+I64MAX = (1 << 63) - 1
+
+
+def _np_sparse_fold(acc, cvec_b, kb, wb, fb):
+    """The fold's contract as a plain numpy sort-merge, nothing of the
+    program: per live key the exact i64 weight sum and the smallest
+    first occurrence, keys ascending, the first `cap` kept."""
+    import numpy as np
+    keys0, wsum0, first0, cvec0, stats0 = acc
+    cap = len(keys0)
+    k = np.concatenate([keys0, kb])
+    w = np.concatenate([wsum0, wb])
+    f = np.concatenate([first0, fb])
+    live = k != I64MAX
+    uniq, inv = np.unique(k[live], return_inverse=True)
+    sums = np.zeros(len(uniq), dtype=np.int64)
+    np.add.at(sums, inv, w[live])
+    firsts = np.full(len(uniq), I64MAX, dtype=np.int64)
+    np.minimum.at(firsts, inv, f[live])
+    keys1 = np.full(cap, I64MAX, dtype=np.int64)
+    wsum1 = np.zeros(cap, dtype=np.int64)
+    first1 = np.full(cap, I64MAX, dtype=np.int64)
+    m = min(cap, len(uniq))
+    keys1[:m], wsum1[:m], first1[:m] = uniq[:m], sums[:m], firsts[:m]
+    over = max(int(stats0[1]), int(len(uniq) > cap))
+    return (keys1, wsum1, first1, cvec0 + cvec_b.astype(np.int64),
+            np.array([len(uniq), over], dtype=np.int64))
+
+
+def _sparse_batches(case, rng, cap, bn):
+    """At least four batches of (keys, weights, row index) for one
+    case; a dead row has key I64MAX and weight 0."""
+    import numpy as np
+
+    def batch(keys, weights=None, dead=0.1, rows=None):
+        keys = np.asarray(keys, dtype=np.int64).copy()
+        w = np.ones(bn, dtype=np.int64) if weights is None \
+            else np.asarray(weights, dtype=np.int64).copy()
+        kill = rng.random(bn) < dead
+        keys[kill] = I64MAX
+        w[kill] = 0
+        rows = np.arange(bn) if rows is None else rows
+        return keys, w, rows
+
+    def draw(hi):
+        return rng.integers(0, hi, bn)
+
+    if case == 'unit-weights':
+        return [batch(draw(300)) for _ in range(5)]
+    if case == 'signed-weights':
+        # sums that cancel to 0 stay in the set with weight 0
+        return [batch(draw(200), rng.integers(-(1 << 40), 1 << 40, bn))
+                for _ in range(5)]
+    if case == 'dead-batch':
+        return [batch(draw(300)), batch(draw(300), dead=1.1),
+                batch(draw(300)), batch(draw(300), dead=1.1),
+                batch(draw(300))]
+    if case == 'all-resident':
+        first = batch(draw(1 << 40), dead=0.0)
+        return [first] + [batch(rng.choice(first[0], bn))
+                          for _ in range(4)]
+    if case == 'past-capacity':
+        # 3 x bn distinct keys into cap = 2 x bn; then batches whose
+        # keys are all resident, so only the sticky flag says `over`
+        wide = [batch(rng.permutation(1 << 20)[:bn] + (i << 20), dead=0.0)
+                for i in range(3)]
+        return wide + [batch(rng.choice(wide[0][0], bn))
+                       for _ in range(2)]
+    if case == 'later-batch-smaller-row':
+        # the same keys every batch, met at ever smaller rows: the
+        # first occurrence stays the first batch's
+        keys = draw(100)
+        return [batch(np.roll(keys, -i * 7), dead=0.0,
+                      rows=np.arange(bn) if i == 0
+                      else rng.permutation(bn) // (i + 1))
+                for i in range(4)]
+    raise AssertionError(case)
+
+
+SPARSE_CASES = ('unit-weights', 'signed-weights', 'dead-batch',
+                'all-resident', 'past-capacity',
+                'later-batch-smaller-row')
+
+
+@pytest.mark.parametrize('case', SPARSE_CASES)
+def test_sparse_fold_program_matches_numpy_sort_merge(case):
+    """kernels.sparse_fold, jitted alone, against a numpy sort-merge
+    written here: several batches folded into one set, all five leaves
+    compared after every batch."""
+    import numpy as np
+    from dragnet_tpu.ops import kernels
+    jax, jnp = get_jax()
+    bn = 256
+    cap = 2 * bn if case == 'past-capacity' else 8 * bn
+    ncnt = 3
+    rng = np.random.default_rng(SPARSE_CASES.index(case))
+    fold = jax.jit(lambda acc, cvec_b, kb, wb, fb: kernels.sparse_fold(
+        jax, jnp, acc, cvec_b, kb, wb, fb))
+    want = (np.full(cap, I64MAX, dtype=np.int64),
+            np.zeros(cap, dtype=np.int64),
+            np.full(cap, I64MAX, dtype=np.int64),
+            np.zeros(ncnt, dtype=np.int64),
+            np.zeros(2, dtype=np.int64))
+    got = want
+    batches = _sparse_batches(case, rng, cap, bn)
+    assert len(batches) >= 4
+    for i, (kb, wb, rows) in enumerate(batches):
+        fb = np.where(kb != I64MAX, (np.int64(i) << 32) + rows,
+                      I64MAX).astype(np.int64)
+        cvec_b = rng.integers(0, bn, ncnt).astype(np.int32)
+        want = _np_sparse_fold(want, cvec_b, kb, wb, fb)
+        got = fold(got, cvec_b, kb, wb, fb)
+        for name, g, w in zip(('keys', 'wsum', 'first', 'cvec', 'stats'),
+                              got, want):
+            assert np.array_equal(np.asarray(g), w), (case, i, name)
+    nuniq, over = want[4]
+    if case == 'past-capacity':
+        assert over == 1 and nuniq == cap       # sticky: the last
+        # batches add no key, and the set holds the first `cap` runs
+    else:
+        assert over == 0 and 0 < nuniq < cap
+    if case == 'later-batch-smaller-row':
+        assert (want[2][:nuniq] >> 32 == 0).all()
+    if case == 'signed-weights':
+        assert (want[1][:nuniq] < 0).any()
+
+
+def test_sparse_program_has_no_scatter_or_long_gather(tmp_path,
+                                                      monkeypatch):
+    """The sparse program of a real staged batch, lowered on the CPU at
+    sparse_cap 4096 and a 512-row batch: no scatter anywhere, and no
+    gather over the capacity + batch axis (on the TPU both run one
+    element at a time, which made the fold 221 ms a batch)."""
+    import re
+    import numpy as np
+    from dragnet_tpu import devbench
+    from dragnet_tpu import engine as mod_engine
+    from dragnet_tpu import device_scan as mod_ds
+    from dragnet_tpu.vpipe import Pipeline
+    jax, _ = get_jax()
+    cap, bn = 1 << 12, 512
+    monkeypatch.setattr(mod_engine, 'MAX_DENSE_SEGMENTS', 64)
+    monkeypatch.setattr(mod_ds, 'MAX_DENSE_SEGMENTS', 64)
+    monkeypatch.setattr(mod_ds, 'SPARSE_CAP0', cap)
+    monkeypatch.setattr(mod_engine, 'BATCH_SIZE', bn)
+    monkeypatch.setattr(mod_ds, 'BATCH_SIZE', bn)
+
+    rng = random.Random(79)
+    lines = [ln for ln in _mklines(rng, bn)
+             if '[1,"two"]' not in ln and '{"x":1}' not in ln]
+    datafile = str(tmp_path / 'data.log')
+    with open(datafile, 'w') as f:
+        f.write('\n'.join(lines) + '\n')
+    scan = mod_ds.DeviceScan(mod_query.query_load(
+        {'breakdowns': [{'name': 'host'}, {'name': 'latency'}]}),
+        None, Pipeline())
+    parser = devbench._one_batch_parser(datafile, scan, bn)
+    assert scan._probe_backend()
+    inputs = {}
+    staged = scan._stage_device(
+        mod_engine.NativeColumns(parser),
+        np.ones(parser.batch_size(), dtype=np.float64), None, inputs)
+    assert staged is not None and staged[0] == bn
+    assert staged[1][-1] == cap             # the sparse lane, at cap
+    progs, _ = scan._staged_programs(staged)
+    inputs[scan._pfx + 'base'] = np.int64(0)
+    text = progs.run_scatter.lower(
+        inputs, jax.eval_shape(progs.acc_init)).as_text()
+
+    long_axis = 'tensor<%dx' % (cap + bn)
+    assert long_axis + 'i64>' in text       # the concatenation is there
+    # one sort: a second costs the cold build on the chip 20 s and more
+    assert len(re.findall(r'stablehlo\.sort', text)) == 1
+    assert 'scatter' not in text
+    assert not [ln for ln in text.splitlines()
+                if 'stablehlo.gather' in ln and long_axis in ln]
