@@ -12,7 +12,9 @@ the query shape allows, with scan.py as the exact-semantics fallback.
 """
 
 import os
+import queue as mod_queue
 import sys
+import threading
 
 import numpy as np
 
@@ -297,9 +299,7 @@ class DatasourceFile(object):
                 wscan.aggr = rec
 
                 def process(snap):
-                    src = _RemappedParser(snap, remap) if skinner \
-                        else snap
-                    provider = NativeColumns(src)
+                    provider = NativeColumns(_remapped(snap, remap))
                     wscan._process(provider,
                                    _batch_weights(skinner, snap,
                                                   snap.batch_size()))
@@ -317,22 +317,21 @@ class DatasourceFile(object):
                                               pipeline, stage_offset,
                                               finish_fn=radix.finalize)
 
-            def device_batch(src, n):
-                nlines, nbad = parser.counters()
+            def device_batch(batch, n):
+                nlines, nbad = batch.counters()
                 _bump_parse_counters(parser_stage, adapter_stage,
                                      nlines, nbad, n)
-                weights = _batch_weights(skinner, parser, n)
-                scanner.write_native_batch(src, weights)
-                parser.reset_batch()
+                weights = _batch_weights(skinner, batch, n)
+                scanner.write_native_batch(_remapped(batch, remap),
+                                           weights)
                 if scanner._disabled:
                     scanner._flush()
                     return False     # hand back to the MT executor
                 return True
 
-            def submit_batch(ex, n):
-                snap = scan_mt.ParserSnapshot(parser, paths, hints,
+            def submit_batch(ex, batch, n):
+                snap = scan_mt.ParserSnapshot(batch, paths, hints,
                                               dicts)
-                parser.reset_batch()
                 _bump_parse_counters(parser_stage, adapter_stage,
                                      snap.nlines, snap.nbad, n)
                 if auto_mt:
@@ -347,33 +346,25 @@ class DatasourceFile(object):
                     lambda: [DeviceScan(query, self.ds_timefield,
                                         _Pipeline(),
                                         ds_filter=self.ds_filter)],
-                    lambda snap: NativeColumns(
-                        _RemappedParser(snap, remap) if skinner
-                        else snap),
+                    lambda snap: NativeColumns(_remapped(snap, remap)),
                     lambda snap, n: _batch_weights(skinner, snap, n))
 
             self._takeover_stream(
                 files, parser, BATCH_SIZE, progress_fn, new_executor,
                 submit_batch,
                 scanner.take_over_now if auto_mt else None,
-                lambda: _RemappedParser(parser, remap) if skinner
-                else parser,
                 device_batch)
         else:
-            # one provider for the whole scan so per-column caches
-            # (decoded array values etc.) persist across batches
-            src = _RemappedParser(parser, remap) if skinner else parser
-
-            def flush():
-                n = parser.batch_size()
+            def flush(batch):
+                n = batch.batch_size()
                 if n == 0:
                     return
-                nlines, nbad = parser.counters()
+                nlines, nbad = batch.counters()
                 _bump_parse_counters(parser_stage, adapter_stage,
                                      nlines, nbad, n)
-                weights = _batch_weights(skinner, parser, n)
-                scanner.write_native_batch(src, weights)
-                parser.reset_batch()
+                weights = _batch_weights(skinner, batch, n)
+                scanner.write_native_batch(_remapped(batch, remap),
+                                           weights)
 
             self._stream_native(files, parser, flush, BATCH_SIZE,
                                 progress=progress_fn)
@@ -628,6 +619,23 @@ class DatasourceFile(object):
         stack = mod_device_scan.make_stack(scanners) \
             if scan_cls is not VectorScan else None
 
+        def process_batch(batch, n):
+            """One batch through every metric's scan on this thread
+            (the forced-device build and the takeover's device side)."""
+            nlines, nbad = batch.counters()
+            _bump_parse_counters(parser_stage, adapter_stage,
+                                 nlines, nbad, n)
+            provider = NativeColumns(_remapped(batch, remap))
+            weights = _batch_weights(skinner, batch, n)
+            alive0 = None
+            if ds_pred is not None:
+                alive0 = eval_ds_filter(ds_pred, ds_stage, provider, n)
+            if stack is not None:
+                stack.process(provider, weights, alive0)
+            else:
+                for s in scanners:
+                    s._process(provider, weights, alive=alive0)
+
         nworkers = scan_mt.scan_threads()
         use_mt = nworkers > 0 and scan_cls is VectorScan
         # auto-device builds mirror the scan path: MT host workers by
@@ -655,9 +663,7 @@ class DatasourceFile(object):
 
                 def process(snap):
                     n = snap.batch_size()
-                    src = _RemappedParser(snap, remap) if skinner \
-                        else snap
-                    provider = NativeColumns(src)
+                    provider = NativeColumns(_remapped(snap, remap))
                     weights = _batch_weights(skinner, snap, n)
                     alive0 = None
                     if wpred is not None:
@@ -695,22 +701,8 @@ class DatasourceFile(object):
                     s._backend_ok = scanners[0]._backend_ok
                 return True
 
-            def device_batch(src, n):
-                nlines, nbad = parser.counters()
-                _bump_parse_counters(parser_stage, adapter_stage,
-                                     nlines, nbad, n)
-                provider = NativeColumns(src)
-                weights = _batch_weights(skinner, parser, n)
-                alive0 = None
-                if ds_pred is not None:
-                    alive0 = eval_ds_filter(ds_pred, ds_stage,
-                                            provider, n)
-                if stack is not None:
-                    stack.process(provider, weights, alive0)
-                else:
-                    for s in scanners:
-                        s._process(provider, weights, alive=alive0)
-                parser.reset_batch()
+            def device_batch(batch, n):
+                process_batch(batch, n)
                 if any(s._disabled for s in scanners):
                     # coordinated hand-back: all metric scanners leave
                     # the device together
@@ -720,10 +712,9 @@ class DatasourceFile(object):
                     return False
                 return True
 
-            def submit_batch(ex, n):
-                snap = scan_mt.ParserSnapshot(parser, paths, hints,
+            def submit_batch(ex, batch, n):
+                snap = scan_mt.ParserSnapshot(batch, paths, hints,
                                               dicts)
-                parser.reset_batch()
                 _bump_parse_counters(parser_stage, adapter_stage,
                                      snap.nlines, snap.nbad, n)
                 if auto_mt:
@@ -741,9 +732,7 @@ class DatasourceFile(object):
                     lambda: [DeviceScan(q, self.ds_timefield,
                                         _Pipeline(), ds_filter=None)
                              for q in queries],
-                    lambda snap: NativeColumns(
-                        _RemappedParser(snap, remap) if skinner
-                        else snap),
+                    lambda snap: NativeColumns(_remapped(snap, remap)),
                     lambda snap, n: _batch_weights(skinner, snap, n),
                     # production passes the shared ds-filter mask as a
                     # non-None alive; the replay must match that shape
@@ -756,32 +745,12 @@ class DatasourceFile(object):
                 files, parser, BATCH_SIZE, progress_fn, new_executor,
                 submit_batch,
                 take_over if auto_mt else None,
-                lambda: _RemappedParser(parser, remap) if skinner
-                else parser,
                 device_batch)
         else:
-            # one provider object per build so per-column caches persist
-            src = _RemappedParser(parser, remap) if skinner else parser
-
-            def flush():
-                n = parser.batch_size()
-                if n == 0:
-                    return
-                nlines, nbad = parser.counters()
-                _bump_parse_counters(parser_stage, adapter_stage,
-                                     nlines, nbad, n)
-                provider = NativeColumns(src)
-                weights = _batch_weights(skinner, parser, n)
-                alive0 = None
-                if ds_pred is not None:
-                    alive0 = eval_ds_filter(ds_pred, ds_stage, provider,
-                                            n)
-                if stack is not None:
-                    stack.process(provider, weights, alive0)
-                else:
-                    for s in scanners:
-                        s._process(provider, weights, alive=alive0)
-                parser.reset_batch()
+            def flush(batch):
+                n = batch.batch_size()
+                if n:
+                    process_batch(batch, n)
 
             self._stream_native(files, parser, flush, BATCH_SIZE,
                                 progress=progress_fn)
@@ -797,7 +766,7 @@ class DatasourceFile(object):
 
     def _takeover_stream(self, files, parser, batch_size, progress,
                          new_executor, submit_batch, take_over,
-                         make_device_src, device_batch):
+                         device_batch):
         """The MT-host / device takeover state machine shared by scan
         and build: batches go to the MT executor until take_over()
         (auto mode's escalation decision) fires, then to the device
@@ -807,23 +776,21 @@ class DatasourceFile(object):
         across both transitions: the executor is fully drained before
         any device batch flushes, and the device accumulator is flushed
         before the next executor starts."""
-        state = {'ex': new_executor(), 'src': None}
+        state = {'ex': new_executor()}
 
-        def flush():
-            n = parser.batch_size()
+        def flush(batch):
+            n = batch.batch_size()
             if n == 0:
                 return
             if state['ex'] is not None and take_over is not None and \
                     take_over():
                 state['ex'].finish()
                 state['ex'] = None
-                state['src'] = make_device_src()
             if state['ex'] is None:
-                if not device_batch(state['src'], n):
-                    state['src'] = None
+                if not device_batch(batch, n):
                     state['ex'] = new_executor()
                 return
-            submit_batch(state['ex'], n)
+            submit_batch(state['ex'], batch, n)
 
         try:
             self._stream_native(files, parser, flush, batch_size,
@@ -834,16 +801,27 @@ class DatasourceFile(object):
 
     def _stream_native(self, files, parser, flush, batch_size,
                        progress=None):
-        """Feed the concatenated file bytes to the native parser,
-        flushing a batch whenever enough records accumulate (partial
-        trailing lines join across file boundaries — catstreams
+        """Feed the concatenated file bytes to the parser and hand each
+        batch to flush(batch) whenever enough records accumulate
+        (partial trailing lines join across file boundaries — catstreams
         semantics).  The bulk of each read chunk is parsed in place
         (zero-copy span); only the carry-spanning line is stitched.
 
+        A parser that can give its batch away (NativeParser.detach_batch)
+        parses on a producer thread, one batch ahead: it fills batch
+        N+1 while this thread runs flush on batch N.  One is in flush,
+        one may wait in the queue and one is being filled.  The batch
+        boundaries, their order and the single consumer are the serial
+        loop's, and a batch sees the dictionaries as they stood at its
+        end, so flush stages what it would have staged serially.  Other
+        parsers (the byte lane) keep the serial loop: flush reads the
+        parser itself, which is reset afterwards.
+
         progress(bytes_done, bytes_total), when given, is called before
-        each flush — auto mode's device-switch heuristic estimates
-        remaining work from it (total is 0 when sizes are unknowable,
-        e.g. character devices)."""
+        each flush with the bytes read when the batch ended — auto
+        mode's device-switch heuristic estimates remaining work from it
+        (total is 0 when sizes are unknowable, e.g. character
+        devices)."""
         # larger reads amortize the multithreaded parse's fork/join; the
         # cap bounds how far a batch can overshoot the flush threshold
         # (flush is only checked between reads).  DN_READ_SIZE overrides
@@ -853,77 +831,54 @@ class DatasourceFile(object):
             readsz = int(os.environ.get('DN_READ_SIZE', 0)) or readsz
         except ValueError:
             pass
-        parse_at = getattr(parser, 'parse_at', None)
         total = 0
         for path, st in files:
             sz = getattr(st, 'st_size', 0) if st is not None else 0
             total += sz if sz and sz > 0 else 0
-        state = {'done': 0}
 
-        def counted_chunks():
-            # scan.read: the wait of this thread for the read-ahead
-            # thread's next chunk
-            ahead = _read_ahead(files, readsz)
-            try:
-                while True:
-                    with obs_metrics.leaf_stage('scan.read'):
-                        chunk = next(ahead, None)
-                    if chunk is None:
-                        return
-                    state['done'] += len(chunk)
-                    yield chunk
-            finally:
-                ahead.close()
-
-        def parse(buf, length=None):
-            # scan.parse: the parser (all its threads) as this thread
-            # sees it, over bytes or over (address, length)
-            with obs_metrics.leaf_stage('scan.parse'):
-                nrecords = parser.parse(buf) if length is None \
-                    else parse_at(buf, length)
-            obs_metrics.inc('scan_parse_bytes',
-                            len(buf) if length is None else length)
-            obs_metrics.inc('scan_parse_records', nrecords)
-
-        if parse_at is None:
-            # byte-lane / plain parsers: complete-line buffers from
-            # the shared chunk-boundary joiner (ingest.py — the same
-            # carry discipline as iter_lines/iter_stream_lines)
-            for lbuf in mod_ingest.iter_line_buffers(counted_chunks()):
-                parse(lbuf)
-                if parser.batch_size() >= batch_size:
-                    if progress is not None:
-                        progress(state['done'], total)
-                    flush()
-            if progress is not None:
-                progress(state['done'], total)
-            flush()
+        if getattr(parser, 'detach_batch', None) is None:
+            for done in _parse_batches(files, parser, batch_size,
+                                       readsz):
+                if progress is not None:
+                    progress(done, total)
+                flush(parser)
+                parser.reset_batch()
             return
 
-        carry = b''
-        for chunk in counted_chunks():
-            nl = chunk.rfind(b'\n')
-            if nl == -1:
-                carry += chunk
-                continue
-            start = 0
-            if carry:
-                first = chunk.index(b'\n', 0, nl + 1)
-                parse(carry + chunk[:first + 1])
-                start = first + 1
-            arr = np.frombuffer(chunk, dtype=np.uint8)
-            if nl + 1 > start:
-                parse(arr[start:].ctypes.data, nl + 1 - start)
-            carry = chunk[nl + 1:]
-            if parser.batch_size() >= batch_size:
+        def detached(cancelled):
+            # on the producer thread; the last batch may be empty (the
+            # serial loop's last flush is a no-op then, and its
+            # progress call is not)
+            for done in _parse_batches(files, parser, batch_size,
+                                       readsz, cancelled):
+                yield (parser.detach_batch()
+                       if parser.batch_size() else None), done
+
+        ahead = _RunAhead(detached, name='dn-parse-ahead',
+                          drop=_release_batch, join=True)
+        try:
+            while True:
+                # scan.parse_wait: this thread's wait for the producer's
+                # next batch (none when the parse was wholly hidden)
+                ready = ahead.ready()
+                with obs_metrics.leaf_stage('scan.parse_wait'):
+                    item = next(ahead, None)
+                if item is None:
+                    return
+                batch, done = item
                 if progress is not None:
-                    progress(state['done'], total)
-                flush()
-        if carry:
-            parse(carry)
-        if progress is not None:
-            progress(state['done'], total)
-        flush()
+                    progress(done, total)
+                if batch is None:
+                    continue
+                obs_metrics.inc('scan_batches_handed')
+                if ready:
+                    obs_metrics.inc('scan_batches_ready')
+                try:
+                    flush(batch)
+                finally:
+                    batch.release()
+        finally:
+            ahead.close()
 
     def _index_write(self, metrics, interval, tagged_points):
         """Write tagged aggregated points into interval-chunked index
@@ -1168,50 +1123,192 @@ class DatasourceFile(object):
         return ScanResult(pipeline, points=aggr.points(), query=query)
 
 
-def _read_ahead(files, readsz):
-    """Yield the concatenated chunk stream of `files` with a producer
-    thread reading one chunk ahead (so file IO overlaps parse and
-    engine work while at most ~2 chunks are resident).  Bytes come
-    through ingest.open_byte_source — the pluggable fetcher seam.
-    Producer exceptions (unreadable file mid-stream) re-raise at the
-    consumer."""
-    import queue as mod_queue
-    import threading
+class _RunAhead(object):
+    """Iterate `make_items(cancelled)` on a producer thread of its own,
+    one item ahead of the consumer (a queue of depth 1), under the
+    consumer's request scope, so that the producer's stages and
+    counters land in the request's registry and span tree.  Producer
+    exceptions re-raise at the consumer.
 
-    q = mod_queue.Queue(maxsize=1)
-    stop = threading.Event()
+    close() stops the producer: `cancelled()` turns true for the
+    generator, which is closed on its own thread, and an item that was
+    never taken goes to `drop`.  With `join`, close() also waits for
+    the thread: for a producer whose every wait ends when it is
+    stopped (one that consumes another _RunAhead passes its own
+    `cancelled` down), not for one that may sit in a read."""
 
-    def put(item):
-        while not stop.is_set():
+    _DONE = object()
+
+    def __init__(self, make_items, name, drop=None, join=False,
+                 cancelled=None):
+        from . import vpipe as mod_vpipe
+        self._q = mod_queue.Queue(maxsize=1)
+        self._stop = threading.Event()
+        self._drop = drop
+        self._join = join
+        self._cancelled = cancelled
+        self._thread = threading.Thread(
+            target=self._produce,
+            args=(make_items, mod_vpipe.current_scope()),
+            name=name, daemon=True)
+        self._thread.start()
+
+    def _put(self, item):
+        while not self._stop.is_set():
             try:
-                q.put(item, timeout=0.1)
+                self._q.put(item, timeout=0.1)
                 return True
             except mod_queue.Full:
                 continue
         return False
 
-    def produce():
-        try:
-            for path, st in files:
-                for chunk in mod_ingest.open_byte_source(path, readsz):
-                    if not put(chunk):
+    def _produce(self, make_items, scope):
+        from . import vpipe as mod_vpipe
+        with mod_vpipe.adopt_scope(scope):
+            items = make_items(self._stop.is_set)
+            try:
+                for item in items:
+                    if not self._put(item):
+                        self._dropped(item)
                         return
-            put(None)
-        except BaseException as e:     # re-raised by the consumer
-            put(e)
+                self._put(self._DONE)
+            except BaseException as e:     # re-raised by the consumer
+                self._put(e)
+            finally:
+                items.close()
 
-    t = threading.Thread(target=produce, daemon=True)
-    t.start()
-    try:
-        while True:
-            item = q.get()
-            if item is None:
+    def _dropped(self, item):
+        if self._drop is not None and item is not self._DONE and \
+                not isinstance(item, BaseException):
+            self._drop(item)
+
+    def ready(self):
+        """True when the next item is waiting already."""
+        return not self._q.empty()
+
+    def __iter__(self):
+        return self
+
+    def __next__(self):
+        while not self._stop.is_set():
+            try:
+                item = self._q.get(timeout=0.1)
+            except mod_queue.Empty:
+                if self._cancelled is not None and self._cancelled():
+                    break
+                continue
+            if item is self._DONE:
                 break
             if isinstance(item, BaseException):
+                self.close()
                 raise item
-            yield item
-    finally:
-        stop.set()
+            return item
+        self.close()
+        raise StopIteration
+
+    def close(self):
+        self._stop.set()
+        if self._join:
+            self._thread.join()
+        try:
+            self._dropped(self._q.get_nowait())
+        except mod_queue.Empty:
+            pass
+
+
+def _read_ahead(files, readsz, cancelled=None):
+    """The concatenated chunk stream of `files` with a producer thread
+    reading one chunk ahead (so file IO overlaps parse and engine work
+    while at most ~2 chunks are resident).  Bytes come through
+    ingest.open_byte_source — the pluggable fetcher seam.  Producer
+    exceptions (unreadable file mid-stream) re-raise at the consumer;
+    `cancelled()`, when given, ends the consumer's wait for a chunk."""
+    def chunks(_cancelled):
+        for path, st in files:
+            for chunk in mod_ingest.open_byte_source(path, readsz):
+                yield chunk
+
+    return _RunAhead(chunks, name='dn-read-ahead', cancelled=cancelled)
+
+
+def _parse_batches(files, parser, batch_size, readsz, cancelled=None):
+    """Parse the chunk stream of `files` into `parser`, yielding the
+    bytes read so far each time its batch is due for a flush: after a
+    chunk that took it to `batch_size` records, and once at the end
+    (when the batch may be empty).  The caller takes the batch before
+    it resumes the generator.  `cancelled()`, when given, ends the
+    stream early (the consumer of the batches has stopped)."""
+    parse_at = getattr(parser, 'parse_at', None)
+    done = 0
+
+    def counted_chunks():
+        # scan.read: the wait of this thread for the read-ahead
+        # thread's next chunk
+        chunks = _read_ahead(files, readsz, cancelled)
+        try:
+            while True:
+                with obs_metrics.leaf_stage('scan.read'):
+                    chunk = next(chunks, None)
+                if chunk is None:
+                    return
+                yield chunk
+        finally:
+            chunks.close()
+
+    def parse(buf, length=None):
+        # scan.parse: the parser (all its threads) as this thread
+        # sees it, over bytes or over (address, length)
+        with obs_metrics.leaf_stage('scan.parse'):
+            nrecords = parser.parse(buf) if length is None \
+                else parse_at(buf, length)
+        obs_metrics.inc('scan_parse_bytes',
+                        len(buf) if length is None else length)
+        obs_metrics.inc('scan_parse_records', nrecords)
+
+    if parse_at is None:
+        # byte-lane / plain parsers: complete-line buffers from
+        # the shared chunk-boundary joiner (ingest.py — the same
+        # carry discipline as iter_lines/iter_stream_lines)
+        def counting(chunks):
+            nonlocal done
+            for chunk in chunks:
+                done += len(chunk)
+                yield chunk
+        for lbuf in mod_ingest.iter_line_buffers(
+                counting(counted_chunks())):
+            parse(lbuf)
+            if parser.batch_size() >= batch_size:
+                yield done
+        yield done
+        return
+
+    carry = b''
+    for chunk in counted_chunks():
+        done += len(chunk)
+        nl = chunk.rfind(b'\n')
+        if nl == -1:
+            carry += chunk
+            continue
+        start = 0
+        if carry:
+            first = chunk.index(b'\n', 0, nl + 1)
+            parse(carry + chunk[:first + 1])
+            start = first + 1
+        arr = np.frombuffer(chunk, dtype=np.uint8)
+        if nl + 1 > start:
+            parse(arr[start:].ctypes.data, nl + 1 - start)
+        carry = chunk[nl + 1:]
+        if parser.batch_size() >= batch_size:
+            yield done
+    if carry:
+        parse(carry)
+    yield done
+
+
+def _release_batch(item):
+    batch, _done = item
+    if batch is not None:
+        batch.release()
 
 
 def _bump_parse_counters(parser_stage, adapter_stage, nlines, nbad, n):
@@ -1257,6 +1354,13 @@ def _skinner_weights(tags, nums, strcodes, parser):
     return weights
 
 
+def _remapped(src, remap):
+    """`src` (a parser, batch or snapshot) under the engine's field
+    names: itself, or a _RemappedParser when the projection paths were
+    prefixed (`remap` is None otherwise)."""
+    return src if remap is None else _RemappedParser(src, remap)
+
+
 class _RemappedParser(object):
     """Presents a NativeParser whose projection paths were prefixed
     (json-skinner: fields.*) under the engine's unprefixed names."""
@@ -1264,12 +1368,13 @@ class _RemappedParser(object):
     def __init__(self, parser, remap):
         self.parser = parser
         self.remap = remap
-        # alias the wrapped parser's decoded-array cache (if it has
-        # one) so per-batch wrappers don't defeat it (the engine
-        # caches on the provider's parser attribute)
+        # alias the wrapped source's decoded-array cache so per-batch
+        # wrappers don't defeat it (the engine caches on the
+        # provider's parser attribute)
         cache = getattr(parser, '_array_cache', None)
-        if cache is not None:
-            self._array_cache = cache
+        if cache is None:
+            cache = parser._array_cache = {}
+        self._array_cache = cache
 
     def batch_size(self):
         return self.parser.batch_size()

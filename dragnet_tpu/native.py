@@ -14,6 +14,9 @@ import threading
 
 import numpy as np
 
+from .scan_mt import PinnedList
+from .watchdog import LeakCheck
+
 
 TAG_MISSING = 0
 TAG_NULL = 1
@@ -159,6 +162,11 @@ def get_lib():
             ctypes.c_void_p, ctypes.c_int32, ctypes.c_int32,
             ctypes.POINTER(ctypes.c_int32)]
         lib.dn_parser_reset_batch.argtypes = [ctypes.c_void_p]
+        lib.dn_parser_detach_batch.restype = ctypes.c_void_p
+        lib.dn_parser_detach_batch.argtypes = [ctypes.c_void_p]
+        lib.dn_parser_release_batch.restype = None
+        lib.dn_parser_release_batch.argtypes = [ctypes.c_void_p,
+                                                ctypes.c_void_p]
         _lib = lib
         return lib
 
@@ -175,82 +183,14 @@ def parse_threads():
     return min(16, os.cpu_count() or 1)
 
 
-class NativeParser(object):
-    """One parser per scan: dictionaries persist across batches."""
-
-    def __init__(self, paths, date_hints, need_dicts=None):
-        self.lib = get_lib()
-        assert self.lib is not None
-        self.nthreads = parse_threads()
-        if not hasattr(self.lib, 'dn_parser_parse_mt'):
-            self.nthreads = 1
-        self.paths = list(paths)
-        arr = (ctypes.c_char_p * len(paths))(
-            *[p.encode() for p in paths])
-        hints = (ctypes.c_uint8 * len(paths))(
-            *[1 if h else 0 for h in date_hints])
-        if need_dicts is not None and \
-                hasattr(self.lib, 'dn_parser_create2'):
-            # date-only fields skip string interning entirely (their
-            # dictionaries would hold ~one entry per record)
-            dicts = (ctypes.c_uint8 * len(paths))(
-                *[1 if d else 0 for d in need_dicts])
-            self.h = self.lib.dn_parser_create2(arr, hints, dicts,
-                                                len(paths))
-        else:
-            self.h = self.lib.dn_parser_create(arr, hints, len(paths))
-        self.field_index = {p: i for i, p in enumerate(paths)}
-        # per-field python mirror of the native dictionary
-        self._dicts = [[] for _ in paths]
-
-    def __del__(self):
-        try:
-            if getattr(self, 'h', None):
-                self.lib.dn_parser_destroy(self.h)
-        except Exception:
-            pass
-
-    def parse(self, buf):
-        """Parse a bytes buffer of complete lines; returns the number of
-        records appended to the current batch."""
-        return self.parse_at(buf, len(buf))
-
-    def parse_at(self, buf, length):
-        """parse() from bytes or a raw integer address (the zero-copy
-        entry for parsing a slice of a read buffer without materializing
-        a copy).  With an address, the caller must keep the backing
-        buffer alive for the duration of the call."""
-        if isinstance(buf, int):
-            buf = ctypes.c_char_p(buf)
-        if self.nthreads > 1:
-            return self.lib.dn_parser_parse_mt(self.h, buf, length,
-                                               self.nthreads)
-        return self.lib.dn_parser_parse(self.h, buf, length)
-
-    def counters(self):
-        return (self.lib.dn_parser_nlines(self.h),
-                self.lib.dn_parser_nbad(self.h))
+class _BatchColumns(object):
+    """The accessors of one parsed batch over a native handle `h`: the
+    parser's current batch (NativeParser) or one it gave away
+    (NativeBatch).  The engines read a batch through these and
+    `dictionary` alone."""
 
     def batch_size(self):
         return self.lib.dn_parser_batch_size(self.h)
-
-    def dictionary(self, field):
-        """Python mirror of the native per-field string dictionary."""
-        fi = self.field_index[field]
-        d = self._dicts[fi]
-        size = self.lib.dn_parser_dict_size(self.h, fi)
-        while len(d) < size:
-            ln = ctypes.c_int32()
-            p = self.lib.dn_parser_dict_get(self.h, fi, len(d),
-                                            ctypes.byref(ln))
-            raw = ctypes.string_at(p, ln.value)
-            try:
-                # surrogatepass round-trips lone \uD800-class escapes
-                # exactly like json.loads does
-                d.append(raw.decode('utf-8', 'surrogatepass'))
-            except UnicodeDecodeError:
-                d.append(raw.decode('utf-8', 'surrogateescape'))
-        return d
 
     def _np(self, fn, field, dtype, n):
         fi = self.field_index[field]
@@ -283,9 +223,6 @@ class NativeParser(object):
         return (self._np(self.lib.dn_parser_datesecs, field, np.float64,
                          n),
                 self._np(self.lib.dn_parser_dateerr, field, np.uint8, n))
-
-    def reset_batch(self):
-        self.lib.dn_parser_reset_batch(self.h)
 
     # -- one-pass batch statistics (device-path eligibility) -----------
 
@@ -334,3 +271,138 @@ class NativeParser(object):
         """The date-error column alone (no epoch-seconds copy)."""
         return self._np(self.lib.dn_parser_dateerr, field, np.uint8,
                         self.batch_size())
+
+
+class NativeParser(_BatchColumns):
+    """One parser per scan: dictionaries persist across batches."""
+
+    def __init__(self, paths, date_hints, need_dicts=None):
+        self.lib = get_lib()
+        assert self.lib is not None
+        self.nthreads = parse_threads()
+        if not hasattr(self.lib, 'dn_parser_parse_mt'):
+            self.nthreads = 1
+        self.paths = list(paths)
+        arr = (ctypes.c_char_p * len(paths))(
+            *[p.encode() for p in paths])
+        hints = (ctypes.c_uint8 * len(paths))(
+            *[1 if h else 0 for h in date_hints])
+        if need_dicts is not None and \
+                hasattr(self.lib, 'dn_parser_create2'):
+            # date-only fields skip string interning entirely (their
+            # dictionaries would hold ~one entry per record)
+            dicts = (ctypes.c_uint8 * len(paths))(
+                *[1 if d else 0 for d in need_dicts])
+            self.h = self.lib.dn_parser_create2(arr, hints, dicts,
+                                                len(paths))
+        else:
+            self.h = self.lib.dn_parser_create(arr, hints, len(paths))
+        self.field_index = {p: i for i, p in enumerate(paths)}
+        # per-field python mirror of the native dictionary
+        self._dicts = [[] for _ in paths]
+        # the engine's decoded-array-values cache (keyed by dictionary
+        # length): lives here so that every batch of the scan shares it
+        self._array_cache = {}
+
+    def __del__(self):
+        try:
+            h, self.h = getattr(self, 'h', None), None
+            if h:
+                self.lib.dn_parser_destroy(h)
+        except Exception:
+            pass
+
+    def counters(self):
+        return (self.lib.dn_parser_nlines(self.h),
+                self.lib.dn_parser_nbad(self.h))
+
+    def parse(self, buf):
+        """Parse a bytes buffer of complete lines; returns the number of
+        records appended to the current batch."""
+        return self.parse_at(buf, len(buf))
+
+    def parse_at(self, buf, length):
+        """parse() from bytes or a raw integer address (the zero-copy
+        entry for parsing a slice of a read buffer without materializing
+        a copy).  With an address, the caller must keep the backing
+        buffer alive for the duration of the call."""
+        if isinstance(buf, int):
+            buf = ctypes.c_char_p(buf)
+        if self.nthreads > 1:
+            return self.lib.dn_parser_parse_mt(self.h, buf, length,
+                                               self.nthreads)
+        return self.lib.dn_parser_parse(self.h, buf, length)
+
+    def dictionary(self, field):
+        """Python mirror of the native per-field string dictionary."""
+        fi = self.field_index[field]
+        d = self._dicts[fi]
+        size = self.lib.dn_parser_dict_size(self.h, fi)
+        while len(d) < size:
+            ln = ctypes.c_int32()
+            p = self.lib.dn_parser_dict_get(self.h, fi, len(d),
+                                            ctypes.byref(ln))
+            raw = ctypes.string_at(p, ln.value)
+            try:
+                # surrogatepass round-trips lone \uD800-class escapes
+                # exactly like json.loads does
+                d.append(raw.decode('utf-8', 'surrogatepass'))
+            except UnicodeDecodeError:
+                d.append(raw.decode('utf-8', 'surrogateescape'))
+        return d
+
+    def reset_batch(self):
+        self.lib.dn_parser_reset_batch(self.h)
+
+    def detach_batch(self):
+        """Give the current batch away as a NativeBatch and go on into
+        an empty one: the hand-off that lets another thread read batch
+        N while this one parses N+1.  Call on the parsing thread."""
+        return NativeBatch(self)
+
+
+# a batch that was detached and never released was never consumed
+_BATCH_LEAKS = LeakCheck(
+    'parsed batch(es) never released; results may be incomplete',
+    lambda b: b.h is not None)
+
+
+class NativeBatch(_BatchColumns):
+    """One batch a NativeParser gave away (detach_batch): its columns,
+    moved out of the parser, its counters, and the parser's dictionaries
+    pinned at their lengths at the hand-off.  The mirrors are extended
+    here, on the parser's thread, so that the reader never touches the
+    native dictionaries, which the next parse may be reallocating: it
+    sees what the serial loop saw, the strings up to this batch's end.
+    Reads are safe from any one thread while the parser goes on;
+    release() when done (the columns' memory goes back to the parser)."""
+
+    def __init__(self, parser):
+        self.lib = parser.lib
+        self.parser = parser        # keeps the native parser alive
+        self.field_index = parser.field_index
+        self._array_cache = parser._array_cache
+        self._counters = parser.counters()
+        self._dicts = []
+        for path in parser.paths:
+            d = parser.dictionary(path)
+            self._dicts.append(PinnedList(d, len(d)))
+        self.h = self.lib.dn_parser_detach_batch(parser.h)
+        _BATCH_LEAKS.track(self)
+
+    def counters(self):
+        return self._counters
+
+    def dictionary(self, field):
+        return self._dicts[self.field_index[field]]
+
+    def release(self):
+        h, self.h = self.h, None
+        if h:
+            self.lib.dn_parser_release_batch(self.parser.h, h)
+
+    def __del__(self):
+        try:
+            self.release()
+        except Exception:
+            pass

@@ -24,6 +24,7 @@
 #include <cstdlib>
 #include <cstring>
 #include <cmath>
+#include <mutex>
 #include <string>
 #include <thread>
 #include <unordered_map>
@@ -102,12 +103,31 @@ struct StringDict {
   }
 };
 
-struct FieldOut {
+// one field's columns of one batch: what a detached batch takes with it
+struct Columns {
   std::vector<uint8_t> tags;
   std::vector<double> nums;
   std::vector<int32_t> strcodes;
   std::vector<double> datesecs;   // only filled when date_hint
   std::vector<uint8_t> dateerr;   // only filled when date_hint
+
+  void swap(Columns& o) {
+    tags.swap(o.tags);
+    nums.swap(o.nums);
+    strcodes.swap(o.strcodes);
+    datesecs.swap(o.datesecs);
+    dateerr.swap(o.dateerr);
+  }
+  void clear() {
+    tags.clear();
+    nums.clear();
+    strcodes.clear();
+    datesecs.clear();
+    dateerr.clear();
+  }
+};
+
+struct FieldOut : Columns {
   StringDict dict;
   bool date_hint = false;
   bool want_dict = true;
@@ -198,6 +218,13 @@ struct Parser {
   // persistent worker-code -> owner-code dictionary remaps,
   // [worker][field][worker_code]
   std::vector<std::vector<std::vector<int32_t>>> remaps;
+  // cleared column sets of released batches, kept for their capacity:
+  // detach takes one in place of fresh memory, so a stream of batches
+  // stops allocating (and page-faulting) after its first few.  A batch
+  // is released on its consumer's thread while the owner parses, hence
+  // the lock.
+  std::mutex spare_mu;
+  std::vector<std::vector<Columns>> spares;
 
   ~Parser() {
     for (Parser* w : workers) delete w;
@@ -1220,13 +1247,54 @@ const char* dn_parser_dict_get(void* h, int32_t field, int32_t code,
 void dn_parser_reset_batch(void* h) {
   Parser* pr = static_cast<Parser*>(h);
   pr->batch_records = 0;
-  for (auto& f : pr->fields) {
-    f.tags.clear();
-    f.nums.clear();
-    f.strcodes.clear();
-    f.datesecs.clear();
-    f.dateerr.clear();
+  for (auto& f : pr->fields) f.clear();
+}
+
+// Hand the current batch off: its columns MOVE (swapped, not copied)
+// into a new handle that answers the per-batch accessors above
+// (batch_size, tags/nums/strcodes/datesecs/dateerr, field_stats,
+// nums_i32, date_stats, date_i32), and the parser goes on into empty
+// columns, so another thread can read the batch while this one parses
+// the next.  Counters and dictionaries stay with the parser: the caller
+// reads the former and pins the lengths of the latter before the next
+// parse.  Release the handle with dn_parser_release_batch.
+void* dn_parser_detach_batch(void* h) {
+  Parser* pr = static_cast<Parser*>(h);
+  Parser* b = new Parser();
+  b->batch_records = pr->batch_records;
+  b->fields.resize(pr->fields.size());
+  std::vector<Columns> spare;
+  {
+    std::lock_guard<std::mutex> lock(pr->spare_mu);
+    if (!pr->spares.empty()) {
+      spare.swap(pr->spares.back());
+      pr->spares.pop_back();
+    }
   }
+  for (size_t i = 0; i < pr->fields.size(); i++) {
+    b->fields[i].swap(pr->fields[i]);
+    if (i < spare.size()) pr->fields[i].swap(spare[i]);
+  }
+  pr->batch_records = 0;
+  return b;
+}
+
+// Free a detached batch; its columns' memory goes back to the parser
+// it came from (h, which the caller keeps alive; null: just free).
+void dn_parser_release_batch(void* h, void* batch) {
+  Parser* pr = static_cast<Parser*>(h);
+  Parser* b = static_cast<Parser*>(batch);
+  if (pr != nullptr) {
+    std::vector<Columns> cols(b->fields.size());
+    for (size_t i = 0; i < cols.size(); i++) {
+      cols[i].swap(b->fields[i]);
+      cols[i].clear();
+    }
+    std::lock_guard<std::mutex> lock(pr->spare_mu);
+    // one being read, one queued, one being filled
+    if (pr->spares.size() < 3) pr->spares.emplace_back(std::move(cols));
+  }
+  delete b;
 }
 
 }  // extern "C"

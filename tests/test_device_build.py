@@ -18,6 +18,8 @@ from dragnet_tpu import native as mod_native      # noqa: E402
 from dragnet_tpu import query as mod_query        # noqa: E402
 from dragnet_tpu.datasource_file import DatasourceFile  # noqa: E402
 from dragnet_tpu.ops import get_jax, backend_ready  # noqa: E402
+from helpers.scan_differential import (  # noqa: E402
+    batches_handed, serial_loop, write_layout)
 
 pytestmark = pytest.mark.skipif(
     mod_native.get_lib() is None or get_jax() is None or
@@ -97,7 +99,8 @@ def _metrics():
     return [mod_query.metric_deserialize(m) for m in METRICS]
 
 
-def _build(monkeypatch, datafile, indexdir, engine, batch=None):
+def _build(monkeypatch, datafile, indexdir, engine, batch=None,
+           read_size=None):
     monkeypatch.setenv('DN_ENGINE', engine)
     monkeypatch.setenv('DN_PARSE_THREADS', '1')
     if batch is not None:
@@ -105,7 +108,7 @@ def _build(monkeypatch, datafile, indexdir, engine, batch=None):
         from dragnet_tpu import device_scan as mod_ds
         monkeypatch.setattr(mod_engine, 'BATCH_SIZE', batch)
         monkeypatch.setattr(mod_ds, 'BATCH_SIZE', batch)
-        monkeypatch.setenv('DN_READ_SIZE', str(batch * 64))
+        monkeypatch.setenv('DN_READ_SIZE', str(read_size or batch * 64))
     result = _ds(datafile, indexdir).build(_metrics(), 'day')
     stacked = 0
     for stage in result.pipeline.stages:
@@ -156,6 +159,43 @@ def test_stacked_build_with_fallback_batches(tmp_path, monkeypatch):
     assert host_tree.keys() == dev_tree.keys()
     for rel in host_tree:
         assert host_tree[rel] == dev_tree[rel], rel
+
+
+@pytest.mark.parametrize('layout,read_size', [
+    ('line-spans-files', 100),          # under one line: all span chunks
+    ('empty-file-between', 16384),
+    ('no-final-newline', 1 << 24),      # the production chunk: one batch
+])
+def test_handoff_build_byte_identical(tmp_path, monkeypatch, layout,
+                                      read_size):
+    """The stacked device build behind the parser's thread writes the
+    index the serial loop and the host engine write, shard for shard:
+    over chunk sizes and over files that end in the middle of a line,
+    are empty, or lack the last newline."""
+    plain = tmp_path / 'plain.log'
+    _write_data(plain, 1200, with_edges=True)
+    with open(plain) as f:
+        datapath = write_layout(tmp_path, f.read().splitlines(), layout)
+
+    _build(monkeypatch, datapath, tmp_path / 'ih', 'vector')
+    h0 = batches_handed()
+    _, s_dev = _build(monkeypatch, datapath, tmp_path / 'id', 'jax',
+                      batch=128, read_size=read_size)
+    h1 = batches_handed()
+    with monkeypatch.context() as mp:
+        serial_loop(mp)
+        _, s_ser = _build(mp, datapath, tmp_path / 'is', 'jax',
+                          batch=128, read_size=read_size)
+    assert s_dev == s_ser > 0
+    assert h1 - h0 >= (4 if read_size < (1 << 24) else 1)
+    assert batches_handed() == h1
+
+    host_tree = _tree_bytes(tmp_path / 'ih')
+    for other in ('id', 'is'):
+        tree = _tree_bytes(tmp_path / other)
+        assert host_tree.keys() == tree.keys()
+        for rel in host_tree:
+            assert host_tree[rel] == tree[rel], (other, rel)
 
 
 def test_stacked_index_scan_points_identical(tmp_path, monkeypatch):

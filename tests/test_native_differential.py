@@ -21,6 +21,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 from dragnet_tpu import native as mod_native  # noqa: E402
 from dragnet_tpu import query as mod_query  # noqa: E402
 from dragnet_tpu.datasource_file import DatasourceFile  # noqa: E402
+from helpers.scan_differential import (  # noqa: E402
+    LAYOUTS, batches_handed, serial_loop, write_layout)
 
 pytestmark = pytest.mark.skipif(mod_native.get_lib() is None,
                                 reason='native parser unavailable')
@@ -114,8 +116,13 @@ QUERIES = [
 
 
 def _scan(monkeypatch, datafile, qconf, native, threads='0',
-          parse_threads='1'):
+          parse_threads='1', read_size=None, batch=None):
     monkeypatch.setenv('DN_NATIVE', native)
+    if read_size is not None:
+        monkeypatch.setenv('DN_READ_SIZE', str(read_size))
+    if batch is not None:
+        from dragnet_tpu import engine as mod_engine
+        monkeypatch.setattr(mod_engine, 'BATCH_SIZE', batch)
     monkeypatch.setenv('DN_SCAN_THREADS', threads)
     # pin the parser's threading so both its single-threaded path and
     # the multithreaded deterministic merge are exercised regardless of
@@ -155,3 +162,51 @@ def test_native_matches_python(tmp_path, monkeypatch, qi):
         assert c[('json parser', 'invalid json')] == \
             py_counters[('json parser', 'invalid json')]
     assert nat_counters == mt_counters
+
+
+# -- the batch hand-off (datasource_file._stream_native) ----------------------
+
+# DN_READ_SIZE from less than one line (every line spans chunks) to the
+# production chunk
+READ_SIZES = (48, 4096, 1 << 24)
+
+
+@pytest.mark.parametrize('read_size', READ_SIZES)
+@pytest.mark.parametrize('layout', LAYOUTS)
+def test_handoff_matches_serial_loop_and_python(tmp_path, monkeypatch,
+                                                layout, read_size):
+    """The parser's thread one batch ahead of the engine's gives what
+    the serial loop over the same parser gives, points and counters,
+    and what the Python ingest path gives: over chunk sizes, a last
+    line with no newline, a line that spans two chunks and two files,
+    and an empty file in the stream."""
+    # the edge lines six times over, each round with strings of its
+    # own, so that later batches grow the dictionaries
+    lines = []
+    for r in range(6):
+        lines.extend(LINES)
+        lines.append('{"host":"round%d","req":{"method":"M%d"},'
+                     '"latency":%d,"time":"2014-05-02T10:00:00Z"}'
+                     % (r, r, 3 ** r))
+    datafile = write_layout(tmp_path, lines, layout)
+    qconf = {'breakdowns': [{'name': 'req.method'}, {'name': 'host'},
+                            {'name': 'latency', 'aggr': 'quantize'}],
+             'filter': {'ne': ['host', 'd']}}
+    py_points, py_counters = _scan(monkeypatch, datafile, qconf,
+                                   native='0')
+    for threads, parse_threads in (('0', '1'), ('3', '4')):
+        kw = dict(native='1', threads=threads, read_size=read_size,
+                  parse_threads=parse_threads, batch=8)
+        h0 = batches_handed()
+        ahead = _scan(monkeypatch, datafile, qconf, **kw)
+        h1 = batches_handed()
+        with monkeypatch.context() as mp:
+            serial_loop(mp)
+            serial = _scan(mp, datafile, qconf, **kw)
+        # several batches crossed the hand-off, none in the serial loop
+        assert h1 - h0 >= (4 if read_size <= 4096 else 1)
+        assert batches_handed() == h1
+        assert ahead == serial
+        assert ahead[0] == py_points
+        assert ahead[1][('json parser', 'invalid json')] == \
+            py_counters[('json parser', 'invalid json')]

@@ -26,9 +26,14 @@ from dragnet_tpu import cli                                # noqa: E402
 from dragnet_tpu.obs import metrics as obs_metrics         # noqa: E402
 from dragnet_tpu.serve import server as mod_server         # noqa: E402
 
-SCAN_STAGES = ('scan.read', 'scan.parse', 'scan.stage', 'scan.upload',
-               'scan.dispatch', 'scan.device_wait', 'scan.fetch',
-               'scan.emit')
+# the parser's thread (datasource_file._RunAhead, 'dn-parse-ahead')
+# runs one batch ahead of the request's: its two leaves lie beside the
+# request thread's, not among them
+PRODUCER_STAGES = ('scan.read', 'scan.parse')
+PRODUCER_THREAD = 'dn-parse-ahead'
+SCAN_STAGES = PRODUCER_STAGES + (
+    'scan.parse_wait', 'scan.stage', 'scan.upload', 'scan.dispatch',
+    'scan.device_wait', 'scan.fetch', 'scan.emit')
 FOLD_STAGES = ('index_fold.stage', 'index_fold.dispatch',
                'index_fold.device_wait', 'index_fold.fetch')
 QUERY_STAGES = ('index_query_stack.load',
@@ -159,12 +164,27 @@ def test_index_fold_counts_its_staged_shards(runs):
 
 @pytest.mark.parametrize('op', ['scan', 'build', 'query'])
 def test_leaf_stages_sum_within_the_request(runs, op):
-    """No double counting: no leaf's `stage_ms` holds another leaf's
-    time, so together they fit into the request's root span."""
+    """No double counting: no leaf's `stage_ms` of the request's thread
+    holds another leaf's time, so together (the wait for the parser's
+    thread among them, that thread's own leaves not) they fit into the
+    request's root span."""
     r = runs[op]
     leaves = sum(ms for s, (_n, ms) in r['stages'].items()
-                 if s in ALL_LEAVES)
+                 if s in ALL_LEAVES and s not in PRODUCER_STAGES)
     assert 0 < leaves <= r['doc']['dur_ms']
+    if op != 'query':
+        assert r['stages']['scan.parse_wait'][1] <= leaves
+
+
+@pytest.mark.parametrize('op', ['scan', 'build'])
+def test_batches_handed_equal_the_batches_of_the_scan(runs, op):
+    """Every batch crosses the hand-off once (one upload a batch), and
+    the request's thread waits once a batch and once for the end."""
+    r = runs[op]
+    handed = r['counters']['scan_batches_handed']
+    assert handed == r['stages']['scan.upload'][0] == 6
+    assert 0 <= r['counters'].get('scan_batches_ready', 0) <= handed
+    assert r['stages']['scan.parse_wait'][0] in (handed + 1, handed + 2)
 
 
 def test_nested_leaf_suspends_the_outer_one():
@@ -211,6 +231,24 @@ def test_stages_in_the_span_tree(runs, op):
     _walk(doc['spans'], check)
     assert set(LEAVES[op]) <= names
     assert 'device_scan.d2h' not in names
+
+
+@pytest.mark.parametrize('op', ['scan', 'build'])
+def test_producer_stages_are_tagged_with_their_thread(runs, op):
+    """`scan.read` and `scan.parse` come from the parser's thread and
+    land in the request's tree all the same, each tagged with that
+    thread; the request thread's leaves carry no tag."""
+    threads = {}
+
+    def note(span, parent):
+        if span['name'].startswith('scan.'):
+            threads.setdefault(span['name'], set()).add(
+                span.get('thread'))
+    _walk(runs[op]['doc']['spans'], note)
+    for stage in PRODUCER_STAGES:
+        assert threads[stage] == {PRODUCER_THREAD}
+    for stage in set(SCAN_STAGES) - set(PRODUCER_STAGES):
+        assert threads[stage] == {None}
 
 
 # -- (3) the profiler leg ----------------------------------------------------
@@ -403,7 +441,7 @@ def test_host_engine_scan_never_imports_jax(tmp_path):
     assert doc['records'] == NRECORDS
 
 
-# -- (7) outputs, byte for byte ------------------------------------------------
+# -- (7) outputs, byte for byte -----------------------------------------------
 
 # sha256 of stdout + stderr at the parent commit (d23e433, PR 25), same
 # corpus, same commands, DN_ENGINE=jax and DN_INDEX_DEVICE=1
