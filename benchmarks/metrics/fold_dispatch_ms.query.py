@@ -1,0 +1,12 @@
+"""Host milliseconds dispatching the device fold, a query that
+reached the device: `stage_ms{index_fold.dispatch}` over the window /
+(finished queries - `serve_result_cache_hits_total`)."""
+
+import stages
+
+META = {'layer': 'index query', 'source': 'program_span', 'unit': 'ms', 'better': 'lower',
+        'moves': 'query_completed_per_s'}
+
+
+def read(r):
+    return stages.per_device_query(r, 'index_fold.dispatch')

@@ -396,6 +396,15 @@ def run(ctx):
     for p in problems:
         say('problem: ' + p)
     line['correct'] = not problems
+    # each number compared beside its limit: the line's last key (the
+    # keys are sorted) and the last lines of stderr
+    line['numbers_compared'] = {
+        name: {'value': value, 'limit': limit}
+        for name, value, limit in res['checks']}
+    for name, value, limit in res['checks']:
+        sys.stderr.write('compared %s = %d (limit %d)\n'
+                         % (name, value, limit))
+    sys.stderr.flush()
     want = (u'tpu', ctx.config['chips'])
     have = (device.get('platform'), device.get('count'))
     out = json.dumps(line, sort_keys=True)

@@ -8,9 +8,12 @@
 * BENCHMARK.json against the data files;
 * one 20,000-record rehearsal of each cell end to end (final line's
   keys, non-zero exit off the chip), with a throw-away cell and metric
-  added as files, and one with the timed path broken underneath.
+  added as files, and one with the timed path broken underneath;
+* every per-layer metric that needs no device plane, read in a traced
+  rehearsal of each cell that lists it.
 """
 
+import contextlib
 import glob
 import json
 import os
@@ -305,8 +308,8 @@ exec(compile(open(%(launcher)r).read(), %(launcher)r, 'exec'))
 '''
 
 
-@pytest.fixture
-def throwaway():
+@contextlib.contextmanager
+def _throwaway_files():
     """Files a test adds under benchmarks/ and takes away again."""
     made = []
 
@@ -316,29 +319,46 @@ def throwaway():
             f.write(text)
         made.append(path)
         return path
-    yield add
-    for p in made:
-        os.unlink(p)
+    try:
+        yield add
+    finally:
+        for p in made:
+            os.unlink(p)
 
 
-def _rehearse(cell, extra_env=None, trace=0, seconds=2):
+@pytest.fixture
+def throwaway():
+    with _throwaway_files() as add:
+        yield add
+
+
+def _run_cell(cell, extra_env=None, trace=0, seconds=2):
     env = dict(os.environ, JAX_PLATFORMS='cpu')
     env.pop('XLA_FLAGS', None)
     env.update(extra_env or {})
-    p = subprocess.run(
+    return subprocess.run(
         [sys.executable, os.path.join(BENCH, 'run.py'), '--workload', cell,
          '--seed', '2147483999', '--seconds', str(seconds), '--trace',
          str(trace)], env=env, cwd=ROOT, stdout=subprocess.PIPE,
         stderr=subprocess.PIPE, timeout=600)
+
+
+def _rehearse(cell, extra_env=None, trace=0, seconds=2):
+    p = _run_cell(cell, extra_env, trace, seconds)
     lines = p.stdout.decode().splitlines()
     assert lines, p.stderr.decode()[-3000:]
     return p.returncode, lines
 
 
-def _small_copy(add, cell, launcher=None, per_layer=None):
+def _mesh_env(cfg):
+    return {'XLA_FLAGS': '--xla_force_host_platform_device_count=4'} \
+        if cfg['chips'] == 4 else {}
+
+
+def _small_copy(add, cell, launcher=None, per_layer=None, **changed):
     """The cell with its configuration cut to 20,000 records, as
     throw-away files; returns the copy's name."""
-    wl = _load('workloads', cell)
+    wl = dict(_load('workloads', cell), **changed)
     cfg = _load('configs', wl['config'])
     cfg['name'] = 't-' + cfg['name']
     cfg['corpus']['records'] = 20000
@@ -358,16 +378,27 @@ def test_rehearsal_end_to_end(cell, throwaway):
     answer equal to the reference, the result's keys in place, no result
     line and a non-zero exit because this is not the chip."""
     name, cfg = _small_copy(throwaway, cell)
-    env = {'XLA_FLAGS': '--xla_force_host_platform_device_count=4'} \
-        if cfg['chips'] == 4 else {}
-    rc, lines = _rehearse(name, env)
-    assert rc != 0
+    p = _run_cell(name, _mesh_env(cfg))
+    rc, lines = p.returncode, p.stdout.decode().splitlines()
+    assert rc != 0 and lines, p.stderr.decode()[-3000:]
     assert lines[-1].startswith('rehearsal ')
     with pytest.raises(ValueError):
         json.loads(lines[-1])            # not a result line
     doc = json.loads(lines[-1][len('rehearsal '):])
     assert set(doc) == {'correct', 'attempted', 'failed', 'metrics',
-                        'device'}
+                        'device', 'numbers_compared'}
+    # every number compared beside its limit, last in the line
+    assert lines[-1].rindex('"numbers_compared"') > \
+        lines[-1].rindex('"metrics"')
+    assert set(doc['numbers_compared']) == {
+        'warmup.mismatched_tuples', 'window.mismatched_tuples',
+        'window.count_difference'}
+    assert all(c == {'value': 0, 'limit': 0}
+               for c in doc['numbers_compared'].values())
+    assert p.stderr.decode().splitlines()[-3:] == [
+        'compared warmup.mismatched_tuples = 0 (limit 0)',
+        'compared window.mismatched_tuples = 0 (limit 0)',
+        'compared window.count_difference = 0 (limit 0)']
     assert doc['device']['platform'] == 'cpu'
     assert doc['device']['count'] == cfg['chips']
     assert doc['correct'] is True, lines
@@ -410,6 +441,60 @@ def test_broken_timed_path_is_not_correct(throwaway):
     assert doc['correct'] is False
     assert any('mismatched_tuples' in ln and 'over its limit' in ln
                for ln in lines), lines
+    assert doc['numbers_compared']['window.mismatched_tuples']['value'] > 0
+
+
+@pytest.fixture(scope='module')
+def traced():
+    """One --trace 1 rehearsal of a cell at 20,000 records, made when
+    first asked for and kept for the module: cell -> the line's doc."""
+    docs = {}
+
+    def get(cell):
+        if cell not in docs:
+            with _throwaway_files() as add:
+                name, cfg = _small_copy(add, cell)
+                _, lines = _rehearse(name, _mesh_env(cfg), trace=1)
+            docs[cell] = json.loads(lines[-1][len('rehearsal '):])
+        return docs[cell]
+    return get
+
+
+def _host_side_metrics():
+    """Every (per-layer metric, cell that lists it) of BENCHMARK.json
+    that a CPU can read: all but those taken from the device's trace."""
+    with open(os.path.join(ROOT, 'BENCHMARK.json')) as f:
+        doc = json.load(f)
+    return [pytest.param(m, cell, id='%s-%s' % (m['name'], cell))
+            for m in doc['per_layer'] if m['source'] != 'device_trace'
+            for cell in m['workloads']]
+
+
+@pytest.mark.parametrize('metric,cell', _host_side_metrics())
+def test_per_layer_metric_reads_a_number(metric, cell, traced):
+    """In every cell that lists it the metric's file finds something to
+    read (a line that lacks it is refused on the chip), a share lies
+    between 0 and 100, and nothing is negative."""
+    got = traced(cell)['metrics']
+    assert metric['name'] in got, sorted(got)
+    value = got[metric['name']]
+    assert value['unit'] == metric['unit']
+    assert isinstance(value['value'], float)
+    assert 0.0 <= value['value'] <= (100.0 if metric['unit'] == '%'
+                                     else float('inf'))
+
+
+def test_spare_trees_reads_zero_when_the_trees_run_out(throwaway):
+    """A warm-up tree and one more: the client's loop ends for want of
+    a tree after one build, whatever the window's seconds, and the
+    metric says so; with the committed count trees are left."""
+    cell = 'muskie-30d.build-daily'
+    name, _ = _small_copy(throwaway, cell, build_trees=2)
+    _, lines = _rehearse(name, trace=1, seconds=20)
+    doc = json.loads(lines[-1][len('rehearsal '):])
+    assert doc['attempted'] == 1 and doc['failed'] == 0
+    assert doc['metrics']['spare_trees.build']['value'] == 0.0
+    assert _load('workloads', cell)['build_trees'] >= 100
 
 
 def test_no_program_no_result(tmp_path):
