@@ -4,10 +4,7 @@
 
 PYTHON ?= python3
 
-.PHONY: all native test check bench chip-smoke bench-iq bench-iq-device \
-    bench-build bench-parse \
-    bench-serve bench-cluster bench-follow bench-subscribe \
-    bench-fanin bench-verify \
+.PHONY: all native test check chip-smoke \
     soak-faults soak-cluster soak-follow soak-compact \
     soak-overload soak-rebalance soak-scrub soak-resources \
     soak-subscribe \
@@ -26,11 +23,8 @@ check:
 	    chip_smoke.py __graft_entry__.py tests
 	$(PYTHON) tools/checkstyle dragnet_tpu bin tests \
 	    tools/checkstyle tools/json_streamer tools/pathenum \
-	    tools/validate-schema tools/profile_device tools/mktestdata \
+	    tools/validate-schema tools/mktestdata \
 	    tools/soak_faults.py bench.py chip_smoke.py __graft_entry__.py
-
-bench: native
-	$(PYTHON) bench.py
 
 # the quickest proof that scan, build and query still run on the chip:
 # forced device lanes through bin/dn at 2M records, byte-compared with
@@ -38,34 +32,6 @@ bench: native
 # only on a TPU).  It builds native/ itself.
 chip-smoke:
 	$(PYTHON) chip_smoke.py
-
-# the serving-path legs only: 365-shard index-query execution
-# (stacked DN_IQ_STACK batch vs DN_IQ_THREADS per-shard pool vs
-# sequential, pruning, shard-handle cache)
-bench-iq: native
-	$(PYTHON) bench.py --iq-only
-
-# the device index-query legs only: 365-shard year query host vs
-# forced device lane (DN_INDEX_DEVICE=1, byte identity asserted) plus
-# the residency repeat legs (accumulator pin, pinned shard tensors)
-bench-iq-device: native
-	$(PYTHON) bench.py --iq-device-only
-
-# the build-path legs only: 365-shard index write (columnar blocks,
-# sequential vs DN_BUILD_THREADS shard writer pool)
-bench-build: native
-	$(PYTHON) bench.py --build-only
-
-# the parse-lane legs only: host-record vs native vs vector vs device
-# ingest MB/s + end-to-end scan rec/s per DN_PARSE lane (byteparse)
-bench-parse: native
-	$(PYTHON) bench.py --parse-only
-
-# the serving legs only: cold-CLI-process vs warm `dn serve` daemon
-# index-query p50/p95, end-to-end rec/s through the socket, request
-# coalescing, and /stats (device engagement, cache hit rates)
-bench-serve: native
-	$(PYTHON) bench.py --serve-only
 
 # the chaos soak: mixed scan/query/build under deterministic fault
 # injection (>= 500 faults across every DN_FAULTS site) plus
@@ -81,12 +47,6 @@ soak-faults: native
 # degraded-or-error contract when none does (docs/serving.md)
 soak-cluster: native
 	JAX_PLATFORMS=cpu $(PYTHON) tools/soak_faults.py --cluster
-
-# the cluster serving legs only: scatter-gather p50/p95 vs the
-# single-server path, failover-added latency with one member killed,
-# and hedge fire rate (bench extras JSON)
-bench-cluster: native
-	$(PYTHON) bench.py --cluster-only
 
 # the continuous-ingest drill: an appender races a `dn follow` daemon
 # under armed follow.read/checkpoint/publish faults with mid-publish
@@ -104,17 +64,6 @@ soak-follow: native
 # shard (docs/robustness.md)
 soak-compact: native
 	JAX_PLATFORMS=cpu $(PYTHON) tools/soak_faults.py --compact
-
-# the continuous-ingest legs only: steady-state follow rec/s and
-# append-to-queryable latency p50/p95 (bench extras JSON)
-bench-follow: native
-	$(PYTHON) bench.py --follow-only
-
-# the standing-query legs only: publish-to-push latency p50/p95 and
-# the N-subscriber fan-out vs N pollers — counter-asserts one
-# incremental merge per publish, not N aggregations (extras JSON)
-bench-subscribe: native
-	$(PYTHON) bench.py --subscribe-only
 
 # the overload drill: multi-tenant flood at ~5x capacity against the
 # 3-member cluster with torn-frame/stall/flood faults armed, tenant
@@ -160,17 +109,6 @@ soak-resources: native
 # publisher kill, dead-subscriber shedding, and zero wedges
 soak-subscribe: native
 	JAX_PLATFORMS=cpu $(PYTHON) tools/soak_faults.py --subscribe
-
-# verified-read overhead: warm + cold-open index-query p50/p95 under
-# DN_VERIFY=open vs off (bench extras JSON)
-bench-verify: native
-	$(PYTHON) bench.py --verify-only
-
-# high fan-in: pooled persistent multiplexed connections vs
-# dial-per-request p50/p95 on the cluster partial path + shed-rate
-# extras (bench extras JSON)
-bench-fanin: native
-	$(PYTHON) bench.py --fanin-only
 
 # golden byte-parity under every engine (the strongest single seal:
 # host per-record, vectorized, forced device, auto router), then the

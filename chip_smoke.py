@@ -5,7 +5,7 @@ the chip.
 Drives the system's three core data operations — scan, build, query —
 through `bin/dn`, with the device lanes FORCED, over a seeded corpus of
 muskie-style request logs (default 2,000,000 records spread over 30
-days, bench.py's headline size), and holds every answer byte for byte
+days), and holds every answer byte for byte
 to the vectorized host engine (`DN_ENGINE=vector`), the plain reference
 of the same semantics.
 
@@ -50,7 +50,8 @@ DN = os.path.join(ROOT, 'bin', 'dn')
 MINDATE_MS = 1388534400000
 DAYS = 30
 
-# bench.py's QUERY and PALLAS_QUERY as dn arguments
+# bench.py's QUERY and PALLAS_QUERY as dn arguments (held equal, like
+# METRIC_ARGS below, by tests/test_chip_smoke.py)
 QUERY_ARGS = ['-b', 'host,req.method,operation,latency[aggr=quantize]',
               '-f', '{"ne":["res.statusCode",599]}']
 PALLAS_ARGS = ['-b', 'host,latency[aggr=quantize]']

@@ -461,8 +461,7 @@ class ByteParser(object):
         # force_fallback routes EVERY line through the host parser
         # (json.loads + the fallback converter): the differential
         # baseline that produces the same tagged columns with
-        # per-record work, used by tests and `bench.py --parse-only`
-        # as the host-lane equivalent-work measurement
+        # per-record work, used by tests
         self.force_fallback = bool(force_fallback)
         self._parity = bk.parity_device if device \
             else bk.parity_numpy

@@ -113,7 +113,7 @@ def _warn_device(reason):
 
 
 def _reset_engagement():
-    """Test/bench hook: zero the per-process engagement snapshot."""
+    """Test hook: zero the per-process engagement snapshot."""
     for k in list(_ENGAGE):
         _ENGAGE[k] = None if k == 'last_lane' else 0
 

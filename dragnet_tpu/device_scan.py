@@ -153,9 +153,9 @@ def _rate_field(r):
 
 def probe_deadline_s():
     """Deadline (seconds) for first-contact device operations —
-    DN_DEVICE_PROBE_TIMEOUT, the same knob bench.py's device_alive
-    probe honors.  The default is far above the ~15 s a process takes
-    to reach a directly attached chip, so a slow cold start is never
+    DN_DEVICE_PROBE_TIMEOUT, which every first device op of `dn` runs
+    under.  The default is far above the ~15 s a process takes to
+    reach a directly attached chip, so a slow cold start is never
     misclassified as a backend that will not answer."""
     import os
     try:
@@ -165,8 +165,8 @@ def probe_deadline_s():
 
 
 def run_with_deadline(fn, seconds, what):
-    """bench.py's probe-deadline pattern as a library: run `fn` on a
-    daemon thread and wait at most `seconds`.  Returns ('ok', result),
+    """The probe deadline: run `fn` on a daemon thread and wait at
+    most `seconds`.  Returns ('ok', result),
     ('error', exception), or ('timeout', None).  A wedged device
     plugin hangs the daemon thread, not the caller; the abandoned
     thread is leaked deliberately — there is no way to cancel a stuck
@@ -339,8 +339,8 @@ def _audition_entries_raw():
 
 def audition_cache_entries():
     """(path, fresh entries, fresh wins) of the persisted audition
-    cache — `dn serve --validate`, the serve pre-warm doc, and the
-    bench artifact all report it; (None, 0, 0) when disabled."""
+    cache — `dn serve --validate` and the serve pre-warm doc report
+    it; (None, 0, 0) when disabled."""
     path, data = _audition_entries_raw()
     if path is None:
         return None, 0, 0
@@ -365,8 +365,8 @@ def audition_cache_shape_hint(shape):
     return True if any(verdicts) else False
 
 # jitted scan programs are shared across DeviceScan instances (a CLI
-# `dn scan` and the bench's repeat runs would otherwise re-trace and
-# re-compile identical programs per scan); keyed by the full static
+# `dn scan` and a server's repeat requests would otherwise re-trace
+# and re-compile identical programs per scan); keyed by the full static
 # structure of the program (see _program_key)
 _PROGRAM_CACHE = {}
 _ACC_INIT_CACHE = {}
@@ -521,9 +521,9 @@ class DeviceScan(VectorScan):
         # synth columns, base) coexist in one merged inputs dict while
         # parser-derived columns stay shared across metrics
         self._pfx = ''
-        # when True, _staged_run records (run, inputs, staged) on
-        # self.captured — the kernel-resident benchmark replays the
-        # exact production program over device-resident inputs
+        # when True, _staged_run records the next batch's (run,
+        # inputs, staged, use_pallas) on self.captured: a hook for a
+        # harness that replays the exact production program
         self.capture_next = False
         self.captured = None
         self._records_seen = 0
@@ -1577,9 +1577,9 @@ class DeviceScan(VectorScan):
                          sparse_cap=profile[-1])
         inputs[self._pfx + 'base'] = np.int64(self._acc_batch << 32)
         if self.capture_next:
-            # capture pre-upload: devbench distinguishes the per-batch
-            # host arrays (H2D measurement) from device-resident tables
-            # by type, so it needs the np view of the inputs
+            # capture pre-upload: the np view of the inputs, so that a
+            # reader can tell the per-batch host arrays from the
+            # device-resident tables by type
             self.capture_next = False
             self.captured = (run, dict(inputs), staged, use_pallas)
         return run

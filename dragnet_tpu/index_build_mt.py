@@ -5,8 +5,8 @@ The build path used to end exactly where the paper says not to:
 aggregates were flattened into per-point field dicts tagged with
 __dn_metric, each dict cost one ISO-timestamp format to pick its
 hour/day shard, one sink.write() call, and every interval shard was
-flushed sequentially (BENCH_r05: the 365-shard build leg ran ~275k
-rec/s against a 2M rec/s scan).  This module owns the write side's
+flushed sequentially (a 365-shard build ran at about an eighth of
+the scan's rate).  This module owns the write side's
 three fixes, mirroring what index_query_mt did for the read side:
 
 * Columnar blocks: the Aggregator exports each metric's result as

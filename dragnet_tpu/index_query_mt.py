@@ -199,8 +199,8 @@ def _note_fanout(mode, ms_per_shard):
 
 def fanout_stats():
     """Measured per-shard fan-out costs + the last strategy used —
-    `dn serve` /stats and the bench artifact surface it so a degraded
-    pool is visible, not silent."""
+    `dn serve` /stats surfaces it so a degraded pool is visible, not
+    silent."""
     with _FANOUT_LOCK:
         return {'pool_ms_per_shard': _FANOUT_EMA['pool'],
                 'seq_ms_per_shard': _FANOUT_EMA['seq'],

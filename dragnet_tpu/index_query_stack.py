@@ -52,7 +52,7 @@ dense bucket tensors merged via psum/scatter-add".  The fused group
 ids and weights upload once per query and jax.ops.segment_sum folds
 them in i64 (exact for the integer weights the gate admits, so device
 and host results are bit-equal).  The first device op runs under the
-bench probe deadline (device_scan.run_with_deadline): a hung backend
+probe deadline (device_scan.run_with_deadline): a hung backend
 warns and falls back to the host bincount instead of hanging
 `dn query`.  Under the cluster backend each process stacks its own
 shard partition and the partial aggregates merge across processes via

@@ -251,7 +251,7 @@ class BatchRecorder(object):
 # -- radix-partitioned merge -------------------------------------------------
 
 # merge-phase telemetry accumulated across RadixMerge finalizations
-# (bench reads the scan/merge time split from here; reset per leg)
+# (merge_stats() reports them; reset_merge_stats() zeroes them)
 _MERGE_STATS = {'merge_ms': 0.0, 'partitions': 0, 'rows': 0,
                 'unique': 0, 'engaged': 0}
 _MERGE_LOCK = threading.Lock()

@@ -395,7 +395,7 @@ def run_or_fallback(remote, req):
 
 
 def stats(remote, timeout_s=5.0):
-    """Fetch and parse the server's /stats document (bench + tests).
+    """Fetch and parse the server's /stats document.
     Rides the _exchange_with_retry backoff path: a transient accept
     flap must not read as a dead server."""
     rc, header, out, err = request_bytes(remote, {'op': 'stats'},

@@ -260,7 +260,7 @@ class PooledConn(object):
 
 class ConnectionPool(object):
     """Endpoint -> PooledConn, with v1 downgrade memory and
-    reuse/dial accounting (bench-fanin reads these)."""
+    reuse/dial accounting."""
 
     def __init__(self):
         self._lock = threading.Lock()

@@ -1,5 +1,6 @@
 import os
 import sys
+import tempfile
 
 # Multi-device tests run on a virtual 8-device CPU mesh.  Nothing in
 # this installation imports jax before this conftest runs, so the
@@ -18,6 +19,16 @@ if 'xla_force_host_platform_device_count' not in xla_flags:
 # itself opt back in with DN_AUDITION_CACHE=1 and a tmp
 # JAX_COMPILATION_CACHE_DIR.
 os.environ['DN_AUDITION_CACHE'] = '0'
+
+# Hermeticity: a DnServer's ResourceGovernor reads the machine's disk
+# and under DN_DISK_LOW_PCT free pauses repair pulls and handoff
+# fetches; pin it at 50% free through the sim-file hook (children
+# inherit it, test_resources.py replaces it).
+_disk_sim = os.path.join(tempfile.mkdtemp(prefix='dn_test_disk_'),
+                         'free_pct')
+with open(_disk_sim, 'w') as _f:
+    _f.write('50\n')
+os.environ['DN_DISK_SIM_FILE'] = _disk_sim
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))

@@ -2,16 +2,88 @@
 comparison and its engagement check, and the verdict is `ok: false` for
 the single reason that the platform is not `tpu`; with the native
 parser disabled the forced scans FAIL rather than answer from the
-host."""
+host.  And the hand-kept copies of the queries and the metrics
+(chip_smoke.py's `dn` arguments, the benchmark's JSON files) say what
+bench.py's say."""
 
 import json
 import os
 import subprocess
 import sys
 
+import pytest
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+import bench                                        # noqa: E402
+import chip_smoke                                   # noqa: E402
+from dragnet_tpu import cli                         # noqa: E402
+from dragnet_tpu import query as mod_query          # noqa: E402
+
 PHASES = ('scan-dense', 'scan-pallas', 'scan-sparse', 'build', 'query',
           'auto')
+
+
+def _query(doc):
+    qc = mod_query.query_load(dict(doc))
+    return qc.qc_breakdowns, qc.qc_filter
+
+
+def _scan_args_query(args):
+    """`dn scan ARGS ds`, as cmd_scan loads it."""
+    opts = cli.dn_parse_args(list(args) + ['ds'],
+                             ['before', 'after', 'filter', 'breakdowns'])
+    return _query(cli.dn_query_doc(opts))
+
+
+def _metric(doc):
+    return mod_query.metric_serialize(
+        mod_query.metric_deserialize(doc), skip_datasource=True)
+
+
+def _metric_args_metric(name, args):
+    """`dn metric-add ARGS ds NAME`, as cmd_metric_add stores it."""
+    opts = cli.dn_parse_args(list(args) + ['ds', name],
+                             ['breakdowns', 'filter'])
+    return _metric({'name': name, 'filter': opts.filter or None,
+                    'breakdowns': opts.breakdowns})
+
+
+def _bench_json(*rel):
+    with open(os.path.join(ROOT, 'benchmarks', *rel)) as f:
+        return json.load(f)
+
+
+def _cell_query():
+    (template,) = _bench_json(
+        'workloads', 'muskie-30d.scan-dense.json')['templates']
+    return _query(template['query'])
+
+
+def _config_metrics():
+    return [_metric(m) for m in
+            _bench_json('configs', 'muskie-30d.json')['metrics']]
+
+
+@pytest.mark.parametrize('copy, original', [
+    (lambda: _scan_args_query(chip_smoke.QUERY_ARGS),
+     lambda: _query(bench.QUERY)),
+    (lambda: _scan_args_query(chip_smoke.PALLAS_ARGS),
+     lambda: _query(bench.PALLAS_QUERY)),
+    (lambda: _metric_args_metric(*chip_smoke.METRIC_ARGS[0]),
+     lambda: _metric(bench.METRICS[0])),
+    (lambda: _metric_args_metric(*chip_smoke.METRIC_ARGS[1]),
+     lambda: _metric(bench.METRICS[1])),
+    (lambda: _metric_args_metric(*chip_smoke.METRIC_ARGS[2]),
+     lambda: _metric(bench.METRICS[2])),
+    (_cell_query, lambda: _query(bench.QUERY)),
+    (_config_metrics, lambda: [_metric(m) for m in bench.METRICS]),
+], ids=['smoke-query', 'smoke-pallas-query', 'smoke-m1', 'smoke-m2',
+        'smoke-m3', 'cell-scan-dense-query', 'config-muskie-30d-metrics'])
+def test_the_copies_agree_with_bench(copy, original):
+    assert len(chip_smoke.METRIC_ARGS) == len(bench.METRICS) == 3
+    assert copy() == original()
 
 
 def _run(extra_env):

@@ -100,6 +100,7 @@ QUERIES = [
 ]
 
 
+from helpers.one_batch import one_batch_parser             # noqa: E402
 from helpers.scan_differential import scan_points_counters  # noqa: E402
 
 
@@ -646,7 +647,6 @@ def test_sparse_program_has_no_scatter_or_long_gather(tmp_path,
     element at a time, which made the fold 221 ms a batch)."""
     import re
     import numpy as np
-    from dragnet_tpu import devbench
     from dragnet_tpu import engine as mod_engine
     from dragnet_tpu import device_scan as mod_ds
     from dragnet_tpu.vpipe import Pipeline
@@ -667,7 +667,7 @@ def test_sparse_program_has_no_scatter_or_long_gather(tmp_path,
     scan = mod_ds.DeviceScan(mod_query.query_load(
         {'breakdowns': [{'name': 'host'}, {'name': 'latency'}]}),
         None, Pipeline())
-    parser = devbench._one_batch_parser(datafile, scan, bn)
+    parser = one_batch_parser(datafile, scan, bn)
     assert scan._probe_backend()
     inputs = {}
     staged = scan._stage_device(

@@ -208,7 +208,7 @@ def test_injected_sink_fault_fails_build_cleanly(tmp_path,
 
 def test_injection_counters_in_counters_dump(tmp_path, monkeypatch):
     """DN_COUNTERS_ALL=1 surfaces the per-site injection counters in
-    the --counters dump (bench-gate's observability contract)."""
+    the --counters dump, and faults.stats() reports the same firing."""
     monkeypatch.setenv('DN_FAULTS', 'iq.shard_read:delay:1.0')
     monkeypatch.setenv('DN_FAULT_DELAY_MS', '1')
     mod_faults.reset()
@@ -226,6 +226,8 @@ def test_injection_counters_in_counters_dump(tmp_path, monkeypatch):
     r.pipeline.dump_counters(out)
     assert 'faults injected' in out.getvalue()
     assert 'iq.shard_read:' in out.getvalue()
+    st = mod_faults.stats()['iq.shard_read']
+    assert 0 < st['fired'] <= st['checked']
 
 
 # -- the miniature chaos soak ----------------------------------------------

@@ -551,9 +551,16 @@ def test_cluster_detect_failover_and_self_heal(healing_cluster):
     assert _wait_healed(shard, expected), 'repair never landed'
     # catalog entry survived and the repaired copy verifies
     assert mod_integrity.load_catalog(idx_b)[rel] == expected
-    doc_b = mod_client.stats(healing_cluster['socks']['b'],
-                             timeout_s=10)
-    rep = doc_b['integrity']['repair']
+    # the repairer counts a completion AFTER the rename that
+    # _wait_healed saw, so wait for the counter, not for the file
+    deadline = time.time() + 25.0
+    while True:
+        doc_b = mod_client.stats(healing_cluster['socks']['b'],
+                                 timeout_s=10)
+        rep = doc_b['integrity']['repair']
+        if rep['completed'] >= 1 or time.time() >= deadline:
+            break
+        time.sleep(0.05)
     assert rep['completed'] >= 1 and rep['scheduled'] >= 1
     assert doc_b['integrity']['corrupt_shards'] >= 1
     assert doc_b['recovery']['quarantine_files'] >= 1

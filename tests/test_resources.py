@@ -195,6 +195,24 @@ def test_pressure_error_forces_mode_despite_statvfs(sim, tmp_path):
     assert gov2.mode() == 'low'
 
 
+def test_the_suite_pins_the_disk(tmp_path, monkeypatch):
+    # tests/conftest.py: no test that is not about the governor sees
+    # this machine's disk, and no server a test starts is in `low`
+    assert os.environ.get('DN_DISK_SIM_FILE')
+    for path in (str(tmp_path), '/', os.getcwd()):
+        st = mod_resources.disk_status(path)
+        assert st['simulated'] and st['free_pct'] == 50.0
+    gov = mod_resources.ResourceGovernor(
+        _conf(env={'DN_FD_HEADROOM': '0'}), paths=[str(tmp_path)])
+    assert gov.refresh(force=True) == 'ok'
+    # without the pin the filesystem answers
+    monkeypatch.delenv('DN_DISK_SIM_FILE')
+    st = mod_resources.disk_status(str(tmp_path))
+    real = os.statvfs(str(tmp_path))
+    assert 'simulated' not in st
+    assert st['total_bytes'] == real.f_frsize * real.f_blocks
+
+
 def test_is_pressure_error_classification():
     assert mod_resources.is_pressure_error(
         OSError(errno.ENOSPC, 'x'))

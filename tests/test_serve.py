@@ -541,7 +541,16 @@ def test_remote_dead_after_connect_reports_attempt_count(
                 continue
             except OSError:
                 break
-            conn.close()          # dies before any response header
+            # die before any response header, but only once the
+            # request is on the wire: a conn that drops before the
+            # client has sent was never reached, and the client may
+            # then run locally (nothing can double-run)
+            conn.settimeout(5.0)
+            try:
+                conn.recv(1)
+            except OSError:
+                pass
+            conn.close()
 
     t = threading.Thread(target=close_all, daemon=True)
     t.start()

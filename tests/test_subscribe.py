@@ -92,12 +92,20 @@ def corpus(tmp_path_factory):
             os.environ['DRAGNET_CONFIG'] = prior
 
 
-def _publish(corpus, ds, n=60):
+def _publish(server, corpus, ds, n=60):
     """One `dn follow`-equivalent publish: append + an incremental
     rebuild bounded to the appended records' days (untouched day
     shards keep their idents, like a follow merge-publish).  The
     build's publish fires the in-process index write hook the
-    manager folds."""
+    manager folds.
+
+    The build runs with every standing group's compute lock held: a
+    sweep that wakes while the publish is half landed (shards renamed
+    in, the hooks that mark the group dirty and bump the epoch not
+    yet fired) waits for the whole of it, so the next frame is the
+    publish's one frame whatever the machine's load, not the first of
+    several steps."""
+    import contextlib
     import datetime
     start = corpus['n'][ds]
     _append(corpus['datafile'][ds], n, start)
@@ -108,8 +116,13 @@ def _publish(corpus, ds, n=60):
     after = datetime.datetime.utcfromtimestamp(day0).strftime(fmt)
     before = datetime.datetime.utcfromtimestamp(day9).strftime(fmt)
     os.environ['DN_INDEX_FORMAT'] = corpus['fmt'][ds]
-    rc, out, err = run_cli(['build', '--after', after,
-                            '--before', before, ds])
+    with server.subman._lock:
+        groups = list(server.subman._groups.values())
+    with contextlib.ExitStack() as held:
+        for group in groups:
+            held.enter_context(group.compute_lock)
+        rc, out, err = run_cli(['build', '--after', after,
+                                '--before', before, ds])
     assert rc == 0, err
 
 
@@ -161,7 +174,7 @@ def test_push_byte_identical_to_poll(server, corpus, ds):
         assert seed['kind'] == 'full' and seed['seq'] == 1
         assert seed['payload'] == _poll(corpus, server.socket_path,
                                         ds)
-        _publish(corpus, ds)
+        _publish(server, corpus, ds)
         pushed = next(stream)
         assert pushed['seq'] == 2
         assert pushed['epoch'] > seed['epoch']
@@ -187,7 +200,7 @@ def test_delta_frame_reconstructs_identical_bytes(corpus, tmp_path,
         try:
             seed = next(stream)
             assert seed['kind'] == 'full'
-            _publish(corpus, ds)
+            _publish(srv, corpus, ds)
             pushed = next(stream)
             assert pushed['kind'] == 'delta'
             assert pushed['payload'] == _poll(
@@ -239,7 +252,7 @@ def test_one_recompute_serves_all_subscribers(server, corpus):
         assert len({fr['payload'] for fr in seeds}) == 1
         before = mod_client.stats(
             server.socket_path)['subscriptions']['counters']
-        _publish(corpus, ds)
+        _publish(server, corpus, ds)
         pushed = [next(s) for s in streams]
         assert len({fr['payload'] for fr in pushed}) == 1
         after = mod_client.stats(
@@ -261,7 +274,7 @@ def test_incremental_fold_reuses_unchanged_shards(server, corpus):
         next(stream)
         before = mod_client.stats(
             server.socket_path)['subscriptions']['counters']
-        _publish(corpus, ds)
+        _publish(server, corpus, ds)
         next(stream)
         after = mod_client.stats(
             server.socket_path)['subscriptions']['counters']
@@ -318,7 +331,7 @@ def test_stalled_subscriber_sheds_healthy_delivers(
         healthy = mod_client.subscribe_stream(sock, dict(req))
         try:
             next(healthy)
-            _publish(corpus, ds)
+            _publish(srv, corpus, ds)
             fresh = next(healthy)          # healthy gets the frame...
             assert fresh['seq'] == 2
             deadline = time.monotonic() + 10
@@ -616,7 +629,7 @@ def test_routed_group_reconfirms_and_stays_quiet(corpus, tmp_path,
 
             # a publish pushes once, then its confirm stays quiet too
             before = reconfirms()
-            _publish(corpus, ds)
+            _publish(srv, corpus, ds)
             pushed = next(stream)
             assert pushed['seq'] == 2
             assert pushed['payload'] == _poll(corpus, sock, ds)

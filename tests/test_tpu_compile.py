@@ -31,7 +31,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 import bench                                        # noqa: E402
 from dragnet_tpu import device_index                # noqa: E402
-from dragnet_tpu import devbench                    # noqa: E402
 from dragnet_tpu import engine                      # noqa: E402
 from dragnet_tpu import native as mod_native        # noqa: E402
 from dragnet_tpu import query as mod_query          # noqa: E402
@@ -41,6 +40,7 @@ from dragnet_tpu.ops import kernels                 # noqa: E402
 from dragnet_tpu.ops import pallas_kernels as pk    # noqa: E402
 from dragnet_tpu.parallel import mesh as mod_mesh   # noqa: E402
 from dragnet_tpu.vpipe import Pipeline              # noqa: E402
+from helpers.one_batch import one_batch_parser      # noqa: E402
 
 BATCH = engine.BATCH_SIZE
 # forces the device-resident sparse sort-merge program at any corpus
@@ -152,7 +152,7 @@ def _staged_program(query_conf, datafile, scan_cls=None):
         from dragnet_tpu.device_scan import DeviceScan as scan_cls
     scan = scan_cls(mod_query.query_load(dict(query_conf)), None,
                     Pipeline())
-    parser = devbench._one_batch_parser(datafile, scan, BATCH)
+    parser = one_batch_parser(datafile, scan, BATCH)
     n = parser.batch_size()
     assert n == BATCH
     assert scan._probe_backend()
