@@ -24,7 +24,8 @@ check:
 	$(PYTHON) tools/checkstyle dragnet_tpu bin tests \
 	    tools/checkstyle tools/json_streamer tools/pathenum \
 	    tools/validate-schema tools/mktestdata \
-	    tools/soak_faults.py bench.py chip_smoke.py __graft_entry__.py
+	    tools/soak_faults.py tools/hostmem_count.py bench.py chip_smoke.py \
+	    __graft_entry__.py
 
 # the quickest proof that scan, build and query still run on the chip:
 # forced device lanes through bin/dn at 2M records, byte-compared with

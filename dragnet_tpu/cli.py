@@ -15,6 +15,7 @@ from .errors import DNError
 from . import jsvalues as jsv
 from . import attrs as mod_attrs
 from . import config as mod_config
+from . import hostmem as mod_hostmem
 from . import query as mod_query
 from . import output as mod_output
 from .aggr import Aggregator
@@ -1999,6 +2000,10 @@ def main(argv=None, startup=None):
     require-vs-total timing (bin/dn:80-83,1290-1296)."""
     if argv is None:
         argv = sys.argv[1:]
+
+    # the one call site: every `dn` process, and no process that only
+    # imports the package
+    mod_hostmem.hold_allocator()
 
     track_time = False
     if argv and argv[0] == '-t':

@@ -37,12 +37,14 @@ def stats_section(registry=None, counters=None):
     picture — including the HBM residency gauges
     (device_residency_hit_rate, device_pinned_bytes, and the
     h2d/d2h_saved transport counters) once a serve process has
-    configured serve/residency.py."""
+    configured serve/residency.py — and the process's own page faults,
+    resident bytes and allocator policy (refresh_process_gauges)."""
     if registry is None:
         registry = mod_metrics.global_registry()
     if counters is not None:
         mod_metrics.refresh_device_gauges(counters, registry)
         mod_metrics.refresh_rollup_gauges(counters, registry)
+        mod_metrics.refresh_process_gauges(registry)
     doc = {'version': STATS_METRICS_VERSION,
            'counters': {}, 'gauges': {}, 'histograms': {}}
     for name, labels, m in registry.snapshot():
@@ -126,6 +128,7 @@ def prometheus_text(registry=None, counters=None):
     if counters is not None:
         mod_metrics.refresh_device_gauges(counters, registry)
         mod_metrics.refresh_rollup_gauges(counters, registry)
+        mod_metrics.refresh_process_gauges(registry)
     lines = []
     typed = set()
     for name, labels, m in registry.snapshot():

@@ -29,10 +29,12 @@ contended by /stats snapshots.
 import bisect
 import contextlib
 import os
+import resource
 import sys
 import threading
 import time
 
+from .. import hostmem as mod_hostmem
 from .. import vpipe as mod_vpipe
 
 # Default latency buckets (milliseconds).  Upper bounds, ascending;
@@ -529,3 +531,50 @@ def refresh_rollup_gauges(counters, registry=None):
     reg.set_gauge('rollup_shards_read_total', read)
     reg.set_gauge('rollup_coverage_pct',
                   100.0 * covered / queried if queried else 0.0)
+
+
+# -- the process's memory (read at scrape) ----------------------------------
+
+def _status_bytes():
+    """{'VmRSS': bytes, 'VmHWM': bytes} of this process from the
+    kernel's status file; empty where there is none (not Linux)."""
+    out = {}
+    try:
+        with open('/proc/self/status') as f:
+            for line in f:
+                key, _, rest = line.partition(':')
+                if key in ('VmRSS', 'VmHWM'):
+                    out[key] = int(rest.split()[0]) * 1024
+    except (OSError, ValueError, IndexError):
+        return {}
+    return out
+
+
+def refresh_process_gauges(registry=None):
+    """What the process costs its host, read when a scrape asks:
+
+    * ``process_minor_faults_total`` — page faults served without IO
+      since the process began (`getrusage`): a counter, set to the
+      kernel's figure.  Its growth over a request is the pages the
+      request touched for the first time, so it says whether the
+      allocator policy (hostmem.hold_allocator) engages.
+    * ``process_resident_bytes`` / ``process_peak_resident_bytes`` —
+      `VmRSS` and `VmHWM` of /proc/self/status (Linux; absent
+      elsewhere): what the policy costs.
+    * ``allocator_policy_held{reason}`` — 1 with reason `applied`, 0
+      with the reason it was skipped (`user_env`, `no_mallopt`,
+      `refused`); absent in a process that `dn` did not start.
+    """
+    reg = registry if registry is not None else _GLOBAL
+    faults = resource.getrusage(resource.RUSAGE_SELF).ru_minflt
+    reg.counter('process_minor_faults_total').value = faults
+    status = _status_bytes()
+    if 'VmRSS' in status:
+        reg.set_gauge('process_resident_bytes', status['VmRSS'])
+    if 'VmHWM' in status:
+        reg.set_gauge('process_peak_resident_bytes', status['VmHWM'])
+    state = mod_hostmem.state()
+    if state is not None:
+        held, reason = state
+        reg.set_gauge('allocator_policy_held', 1.0 if held else 0.0,
+                      reason=reason)

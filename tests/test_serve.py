@@ -474,6 +474,38 @@ def test_request_counters_in_response_header(server, corpus):
     assert counters.get('index shards queried', 0) > 0
 
 
+def test_metrics_scrape_carries_the_process_memory(server, corpus):
+    """A scrape reads the process's page faults, resident bytes and
+    allocator policy when it is asked (obs_metrics.
+    refresh_process_gauges); the fault counter only rises."""
+    import mmap
+
+    def scrape():
+        rc, hd, out, err = mod_client.request_bytes(
+            server.socket_path, {'op': 'metrics'})
+        assert rc == 0, err
+        return {ln.rsplit(' ', 1)[0]: float(ln.rsplit(' ', 1)[1])
+                for ln in out.decode().splitlines()
+                if not ln.startswith('#')}
+
+    first = scrape()
+    rc, hd, out, err = mod_client.request_bytes(
+        server.socket_path, _req('ds_dnc', corpus, op='scan'))
+    assert rc == 0, err
+    with mmap.mmap(-1, 1 << 22) as fresh:       # 1,024 pages, touched
+        fresh.write(b'\x01' * (1 << 22))
+        second = scrape()
+    for doc in (first, second):
+        assert doc['dn_process_resident_bytes'] > 0
+        assert doc['dn_process_peak_resident_bytes'] >= \
+            doc['dn_process_resident_bytes']
+        # the module's corpus went through cli.main, the one call site
+        held = [k for k in doc if k.startswith('dn_allocator_policy_held{')]
+        assert len(held) == 1 and doc[held[0]] in (0.0, 1.0)
+    assert second['dn_process_minor_faults_total'] >= \
+        first['dn_process_minor_faults_total'] + 1024
+
+
 def test_request_counters_attribute_across_pool_threads(
         server, corpus, monkeypatch):
     """On the per-shard pool path (DN_IQ_STACK=0, DN_IQ_THREADS>0)
