@@ -38,17 +38,31 @@ def device_doc():
             'count': len(devs), 'memory_peak_bytes': peak}
 
 
+# One host event from the trace's start to its stop, so that the
+# reduction knows how long the traced window was even where nothing
+# else was traced for most of it (a reply formatted for seconds under
+# no span, the device idle): trace/reduce.py's WINDOW_MARK.
+WINDOW_MARK = 'bench.traced_window'
+_mark = []
+
+
 def trace_start(req):
     import jax
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = int(req.get('python_tracer', 0))
     opts.host_tracer_level = int(req.get('host_tracer', 2))
     jax.profiler.start_trace(req['dir'], profiler_options=opts)
+    mark = jax.profiler.TraceAnnotation(WINDOW_MARK)
+    mark.__enter__()
+    _mark.append(mark)
     return {'ok': True}
 
 
 def trace_stop(req):
     import jax
+    # the control thread opened it, and the same thread closes it
+    while _mark:
+        _mark.pop().__exit__(None, None, None)
     jax.profiler.stop_trace()
     return {'ok': True}
 

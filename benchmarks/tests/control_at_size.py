@@ -1,9 +1,14 @@
 #!/usr/bin/env python3
-"""The control at a cell's own size: the plain reference with its sums
-rounded to bfloat16 after every partial, put in the program's place.
+"""The control at a cell's own size: the plain reference computed the
+way the cell's `control` says (`bfloat16`, the default: sums rounded to
+bfloat16 after every partial; `key32`: the bit-packed key folded to its
+low 32 bits), put in the program's place.
 
     python benchmarks/tests/control_at_size.py --workload <cell> \
-        --seeds 11 12 13
+        --seeds 11 12 13 [--control bfloat16]
+
+`--control` reads another control than the cell's own, to show why the
+cell does not name it.
 
 For every seed it generates the cell's corpus at the configuration's
 size, draws the cell's traffic as a run would, and prints for each
@@ -38,9 +43,11 @@ def main():
     ap.add_argument('--workload', required=True)
     ap.add_argument('--seeds', type=int, nargs='+', required=True)
     ap.add_argument('--seconds', type=float, default=30.0)
+    ap.add_argument('--control', default=None)
     args = ap.parse_args()
     with open(os.path.join(BENCH, 'workloads', args.workload + '.json')) as f:
         wl = json.load(f)
+    control = args.control or wl.get('control', 'bfloat16')
     with open(os.path.join(BENCH, 'configs', wl['config'] + '.json')) as f:
         cfg = json.load(f)
     c = cfg['corpus']
@@ -72,16 +79,15 @@ def main():
                     q['timeBefore'] = q['timeAfter'] + r.days * DAY_MS
                 part = t.get('part', 'batch')
                 exact = ref.expected_lines(q, part=part)
-                low = ref.expected_lines(q, part=part,
-                                         accumulate='bfloat16')
-                got = compare(b'\n'.join(low), exact)
+                low = ref.expected_lines(q, part=part, accumulate=control)
+                got = compare(b'\n'.join(low), exact) + (len(exact),)
                 readings.append(got)
                 key = (t['name'], r.days)
                 worst[key] = min(worst.get(key, got), got)
             for key in sorted(worst, key=str):
-                print('seed %d class %s/%s: control mismatched_tuples=%d '
-                      'count_difference=%d (limits 0, 0)'
-                      % ((seed,) + key + worst[key]))
+                print('seed %d class %s/%s: control %s mismatched_tuples=%d '
+                      'count_difference=%d (limits 0, 0) of %d tuples'
+                      % ((seed,) + key + (control,) + worst[key]))
             failing = sum(1 for g in readings if g[0] > 0)
             print('seed %d: %d of %d requests fail under the control; over '
                   'the run the largest reading is mismatched_tuples=%d, and '

@@ -1,20 +1,26 @@
 """The benchmark's own tests (CPU): `python -m pytest benchmarks/tests -q`.
 
 * the plain reference against dragnet_tpu/scan.py's StreamScan, once per
-  query shape;
-* the control: the reference with bfloat16 accumulation must NOT agree;
+  query shape, and against the lines its dense body gave before PR 34
+  made the table sparse;
+* the control each cell names (bfloat16 accumulation, or the key folded
+  to 32 bits) must NOT agree, and `key32` is refused where it could not
+  fail;
 * the trace reduction on a small recorded trace;
 * the generator's determinism per seed;
 * BENCHMARK.json against the data files;
 * one 20,000-record rehearsal of each cell end to end (final line's
   keys, non-zero exit off the chip), with a throw-away cell and metric
-  added as files, and one with the timed path broken underneath;
+  added as files, one with the timed path broken underneath for each
+  fault (a count altered, a reply truncated), and one with the
+  high-cardinality cell answered by another lane than the sparse one;
 * every per-layer metric that needs no device plane, read in a traced
   rehearsal of each cell that lists it.
 """
 
 import contextlib
 import glob
+import hashlib
 import json
 import os
 import subprocess
@@ -62,8 +68,9 @@ def small(tmp_path_factory):
     return path, cols, ref
 
 
-def _shapes():
-    """Every query shape a committed workload sends or verifies with."""
+def _shapes(with_control=False):
+    """Every query shape a committed workload sends or verifies with
+    (and, asked for, the control its cell names)."""
     seen, out = set(), []
     for path in sorted(glob.glob(os.path.join(BENCH, 'workloads', '*.json'))):
         with open(path) as f:
@@ -74,7 +81,9 @@ def _shapes():
             key = json.dumps(t['query'], sort_keys=True)
             if key not in seen:
                 seen.add(key)
-                out.append(pytest.param(t['query'], id=t['name']))
+                args = (t['query'], wl.get('control', 'bfloat16')) \
+                    if with_control else (t['query'],)
+                out.append(pytest.param(*args, id=t['name']))
     return out
 
 
@@ -107,23 +116,86 @@ def test_reference_equals_stream_scan(small, query):
     assert ref.expected_lines(query, part='day') == got
 
 
-@pytest.mark.parametrize('query', _shapes())
-def test_control_bfloat16_fails(query):
-    """At a size where counts pass 256 the bfloat16 control must differ
-    from the exact reference: the comparison can fail."""
+@pytest.fixture(scope='module')
+def medium():
+    """400,000 records over 30 days, where counts pass 256: the
+    reference."""
     lib = corpus.build_library(os.path.join(ROOT, '.cache', 'bench', 'gen'))
     path = os.path.join(ROOT, '.cache', 'bench', 'gen', 'control.log')
     cols, _ = corpus.generate(lib, path, 400000, MINDATE,
                               MINDATE + 30 * DAY, 5)
     os.unlink(path)
-    ref = Reference(cols, {'host': corpus.HOSTS, 'method': corpus.METHODS,
-                           'op': corpus.OPERATIONS})
+    return Reference(cols, {'host': corpus.HOSTS, 'method': corpus.METHODS,
+                            'op': corpus.OPERATIONS})
+
+
+RECORDED_WINDOWS = [None, (0, 7), (3, 30), (10, 11), (40, 50)]
+
+with open(os.path.join(HERE, 'data', 'reference_dense_body.json')) as _f:
+    RECORDED = json.load(_f)
+
+
+def _recorded_cases(ref, query):
+    """{case: lines' digest} of one shape: both parts, exact and
+    bfloat16, whole and by windows of days."""
+    got = {}
     for part in ('batch', 'day'):
-        exact = ref.expected_lines(query, part=part)
-        low = ref.expected_lines(query, part=part, accumulate='bfloat16')
+        for acc in ('exact', 'bfloat16'):
+            for win in RECORDED_WINDOWS if part == 'day' else [None]:
+                q = dict(query)
+                if win:
+                    q['timeAfter'] = MINDATE + win[0] * DAY
+                    q['timeBefore'] = MINDATE + win[1] * DAY
+                lines = ref.expected_lines(q, part=part, accumulate=acc)
+                got['%s/%s/%s' % (part, acc, win)] = '%d:%s' % (
+                    len(lines),
+                    hashlib.sha256(b'\n'.join(lines)).hexdigest()[:16])
+    return got
+
+
+@pytest.mark.parametrize('query', [
+    p for p in _shapes()
+    if json.dumps(p.values[0], sort_keys=True) in RECORDED])
+def test_reference_equals_its_dense_body(small, medium, query):
+    """New against old: `data/reference_dense_body.json` holds what the
+    reference's dense `counts[part, key]` body (until PR 34) answered
+    for every shape it could hold, over the `small` corpus and over
+    the `medium` one, where bfloat16 loses counts; the sparse body
+    gives the same lines byte for byte, the control's too."""
+    want = RECORDED[json.dumps(query, sort_keys=True)]
+    assert _recorded_cases(small[2], query) == want['small']
+    got = _recorded_cases(medium, query)
+    assert got == want['medium']
+    assert got['batch/bfloat16/None'] != got['batch/exact/None']
+
+
+@pytest.mark.parametrize('query,control', _shapes(with_control=True))
+def test_control_fails(medium, query, control):
+    """The control the shape's cell names must differ from the exact
+    reference: the comparison can fail.  bfloat16 at a size where
+    counts pass 256; key32 wherever tuples differ above bit 31, where
+    counts are too small for bfloat16 to lose anything."""
+    for part in ('batch', 'day'):
+        exact = medium.expected_lines(query, part=part)
+        low = medium.expected_lines(query, part=part, accumulate=control)
         ntuples, delta = compare(b'\n'.join(low), exact)
         assert ntuples > 0 and delta > 0
         assert compare(b'\n'.join(exact), exact) == (0, 0)
+        if control != 'bfloat16':
+            # why the cell names another: bfloat16 cannot fail here
+            assert medium.expected_lines(query, part=part,
+                                         accumulate='bfloat16') == exact
+
+
+@pytest.mark.parametrize('query', [
+    pytest.param(p.values[0], id=p.id)
+    for p in _shapes(with_control=True) if p.values[1] != 'key32'])
+def test_key32_refused_where_the_key_fits_32_bits(small, query):
+    """A cell whose bit-packed key fits 32 bits cannot name `key32`:
+    the fold would lose nothing, so the control could not fail."""
+    _, _, ref = small
+    with pytest.raises(ValueError, match='cannot fail'):
+        ref.expected_lines(query, accumulate='key32')
 
 
 def test_compare_counts_differences():
@@ -236,6 +308,23 @@ def test_trace_reduction_arithmetic():
                                                  pytest.approx(200e-9)]
     assert got['breakdown']['idle_gaps'][0] == ['parse (worker)',
                                                 pytest.approx(250e-9)]
+    # the launcher's marker stretches the window to the trace's whole
+    # length and names no gap
+    doc['planes'][1]['lines'].append({'name': 'bench-control', 'events': [
+        ev(trace_reduce.WINDOW_MARK, -100, 1100)]})
+    got = trace_reduce.reduce_events(doc)
+    assert got['window_s'] == pytest.approx(1100e-9)
+    assert got['chips'][0]['busy_s'] == pytest.approx(250e-9)
+    assert got['chips'][0]['idle_share'] == pytest.approx(1 - 250 / 1100)
+    assert got['breakdown']['idle_gaps'][:2] == [
+        ['host: nothing traced', pytest.approx(500e-9)],
+        ['parse (worker)', pytest.approx(250e-9)]]
+    # a span that only touches a gap does not name it
+    doc['planes'][1]['lines'][0]['events'].append(ev('emit', 480, 60))
+    got = trace_reduce.reduce_events(doc)
+    assert got['breakdown']['idle_gaps'][0] == [
+        'host: nothing traced for most of it (emit (worker) covers 8%)',
+        pytest.approx(500e-9)]
 
 
 def test_benchmark_json_matches_the_files():
@@ -277,8 +366,23 @@ def read(r):
     return float(len(r.outcomes))
 '''
 
+# an answer altered where it is produced: its first count one too high,
+# or its last tuple left out (a truncated reply)
+FAULTS = {
+    'count': '''
+    head, sep, tail = text.partition('"value":')
+    if sep:
+        digits = ''
+        while tail and tail[0].isdigit():
+            digits, tail = digits + tail[0], tail[1:]
+        text = head + sep + str(int(digits) + 1) + tail
+''',
+    'truncate': '''
+    text = text[:text.rstrip('\\n').rfind('\\n') + 1]
+'''}
+
 BROKEN_LAUNCHER = '''"""The normal launcher with the timed path broken underneath: every
-answer's first count is one too high where it is produced."""
+answer is altered where it is produced."""
 import os, sys
 sys.path.insert(0, %(root)r)
 from dragnet_tpu import cli
@@ -293,12 +397,7 @@ def _broken(query, opts, result, dsname):
         text = sys.stdout.getvalue()
     finally:
         sys.stdout = out
-    head, sep, tail = text.partition('"value":')
-    if sep:
-        digits = ''
-        while tail and tail[0].isdigit():
-            digits, tail = digits + tail[0], tail[1:]
-        text = head + sep + str(int(digits) + 1) + tail
+%(fault)s
     sys.stdout.write(text)
 
 
@@ -355,13 +454,15 @@ def _mesh_env(cfg):
         if cfg['chips'] == 4 else {}
 
 
-def _small_copy(add, cell, launcher=None, per_layer=None, **changed):
+def _small_copy(add, cell, launcher=None, per_layer=None, environment=None,
+                **changed):
     """The cell with its configuration cut to 20,000 records, as
     throw-away files; returns the copy's name."""
     wl = dict(_load('workloads', cell), **changed)
     cfg = _load('configs', wl['config'])
     cfg['name'] = 't-' + cfg['name']
     cfg['corpus']['records'] = 20000
+    cfg['environment'].update(environment or {})
     wl.update(name='t-' + cell, config=cfg['name'])
     if launcher:
         wl['launcher'] = launcher
@@ -427,14 +528,16 @@ def test_rehearsal_takes_a_new_cell_and_metric_as_files(throwaway):
     assert doc['correct'] is False
 
 
-def test_broken_timed_path_is_not_correct(throwaway):
+@pytest.mark.parametrize('cell,fault', [
+    ('muskie-30d.scan-dense', 'count'),
+    ('muskie-30d-highcard.scan-highcard', 'truncate')])
+def test_broken_timed_path_is_not_correct(cell, fault, throwaway):
     """The rest of a run with an answer altered where it is produced:
     `correct` comes out false, by the comparison and nothing else."""
     launcher = throwaway('tests', 't_broken_launcher.py', BROKEN_LAUNCHER % {
-        'root': ROOT,
+        'root': ROOT, 'fault': FAULTS[fault],
         'launcher': os.path.join(BENCH, 'drivers', 'launch_serve.py')})
-    name, _ = _small_copy(throwaway, 'muskie-30d.scan-dense',
-                          launcher=launcher)
+    name, _ = _small_copy(throwaway, cell, launcher=launcher)
     rc, lines = _rehearse(name)
     assert rc != 0
     doc = json.loads(lines[-1][len('rehearsal '):])
@@ -442,6 +545,22 @@ def test_broken_timed_path_is_not_correct(throwaway):
     assert any('mismatched_tuples' in ln and 'over its limit' in ln
                for ln in lines), lines
     assert doc['numbers_compared']['window.mismatched_tuples']['value'] > 0
+
+
+def test_scan_off_the_sparse_lane_is_not_correct(throwaway):
+    """The high-cardinality cell answered by another lane (here the
+    host's): every answer equals the reference, and `correct` is false
+    all the same, by the lane counter and the kernel records."""
+    name, _ = _small_copy(throwaway, 'muskie-30d-highcard.scan-highcard',
+                          environment={'DN_ENGINE': 'vector'})
+    rc, lines = _rehearse(name)
+    assert rc != 0
+    doc = json.loads(lines[-1][len('rehearsal '):])
+    assert doc['correct'] is False
+    assert all(c['value'] == 0 for c in doc['numbers_compared'].values())
+    assert any('did not engage' in ln for ln in lines), lines
+    assert any('kernel records of the window do not all say' in ln
+               for ln in lines), lines
 
 
 @pytest.fixture(scope='module')

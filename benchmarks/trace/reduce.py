@@ -17,6 +17,12 @@ whose line `XLA Modules` holds one event per program run
 host thread, with the runtime's own spans (`np.asarray(jax.Array)`,
 `PjitFunction(run)`) even when the Python tracer is off.  `short_op`
 cuts an operation's name to `fusion.1 s32[32769] fusion`.
+
+The traced window runs from the first event to the last.  The launcher
+(drivers/launch_serve.py) keeps one host event, `WINDOW_MARK`, open
+from the trace's start to its stop, so the window is the trace's whole
+length also where the device and every span are silent for most of it
+(a reply formatted for seconds under no leaf); it names no gap.
 """
 
 import glob
@@ -29,6 +35,7 @@ DEVICE_PLANE = re.compile(r'^/device:TPU:(\d+)$')
 OPS_LINE = 'XLA Ops'
 MODULES_LINE = 'XLA Modules'
 HOST_PLANE = '/host:CPU'
+WINDOW_MARK = 'bench.traced_window'
 
 # device operations that move data between chips
 COLLECTIVE = re.compile(
@@ -121,7 +128,8 @@ def reduce_events(doc):
     window_s = (t1 - t0) / 1e9
     host = [(ln['name'], e) for p in doc['planes']
             if p['name'] == HOST_PLANE
-            for ln in p['lines'] for e in ln['events']]
+            for ln in p['lines'] for e in ln['events']
+            if e[0] != WINDOW_MARK]
     chips, op_total = [], {}
     for p in doc['planes']:
         if not DEVICE_PLANE.match(p['name']):
@@ -152,7 +160,7 @@ def reduce_events(doc):
     device_ops = sorted(([n, s / nchips] for n, s in op_total.items()),
                         key=lambda x: -x[1])[:TOP]
     # the longest idle gaps of the first chip, each named by the host
-    # event that covers most of it
+    # event that covers most of it, if one covers half of it
     idle = []
     for g0, g1 in sorted(chips[0]['gaps'] if chips else [],
                          key=lambda g: g[0] - g[1])[:TOP]:
@@ -161,6 +169,10 @@ def reduce_events(doc):
             c = min(g1, s + dur) - max(g0, s)
             if c > cover:
                 best, cover = '%s (%s)' % (name, thread), c
+        if 0 < 2 * cover < g1 - g0:
+            # a span at the gap's edge is not what the host was doing
+            best = 'host: nothing traced for most of it (%s covers %d%%)' \
+                % (best[:60], 100 * cover // (g1 - g0))
         idle.append([best[:120], (g1 - g0) / 1e9])
     for c in chips:
         del c['gaps']
