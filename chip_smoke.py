@@ -23,7 +23,8 @@ Contract (what the driver relies on):
   `ok` is true only on platform `tpu` with every phase passed, and
   the exit code is 0 only then.
 * `--chips 4` runs ONLY the mesh path (`--backend=cluster` over the
-  four local chips) and the one-chip scan it is compared with.
+  four local chips: the dense scan and the high-cardinality one) and
+  the one-chip scans they are compared with.
 
 Rehearsal without the chip:
     JAX_PLATFORMS=cpu python chip_smoke.py --records 20000
@@ -408,18 +409,20 @@ class Smoke(object):
             return self._fmt(r)
         return self.phase('auto', run)
 
-    def mesh_phase(self):
-        """--chips 4: the dense scan through the cluster backend on a
-        mesh of the local chips vs the same scan on one chip."""
+    def mesh_phase(self, name, qargs, kernel, merge):
+        """--chips 4: a scan through the cluster backend on a mesh of
+        the local chips vs the same scan on one chip; every device
+        program of the mesh scan must have run `kernel` over the
+        chips and merged them by `merge`."""
         def run():
             want = self.opts.chips
             env = {'DN_ENGINE': 'jax', 'LOG_LEVEL': 'debug'}
             out, err, secs = self.dn(
-                ['scan', '--counters'] + QUERY_ARGS + ['smoke_mesh'],
-                env, 'mesh scan')
+                ['scan', '--counters'] + qargs + ['smoke_mesh'],
+                env, name)
             rout, rerr, rsecs = self.dn(
-                ['scan', '--counters'] + QUERY_ARGS + ['smoke'],
-                env, 'one-chip scan')
+                ['scan', '--counters'] + qargs + ['smoke'],
+                env, name + ' (one chip)')
             counters, warnings, logs = split_stderr(err)
             rcounters, rwarnings, rlogs = split_stderr(rerr)
             if warnings or rwarnings:
@@ -434,32 +437,38 @@ class Smoke(object):
             if lanes['ndevicebatches'] <= 0 or \
                     lane_counts(rcounters)['ndevicebatches'] <= 0:
                 raise PhaseFailed('device did not engage')
-            meshes = [(rec.get('mesh_devices'), rec.get('merge'))
+            meshes = [(rec.get('kernel'), rec.get('mesh_devices'),
+                       rec.get('merge'))
                       for rec in logs
                       if rec.get('msg') == 'device aggregate kernel']
-            if not meshes or any(m != (want, 'psum+pmin')
+            if not meshes or any(m != (kernel, want, merge)
                                  for m in meshes):
                 raise PhaseFailed(
-                    'expected every program sharded over %d devices '
-                    'with a collective merge, the scan logged %r'
-                    % (want, meshes))
-            one = [rec.get('mesh_devices') for rec in rlogs
+                    'expected every program to run %s over %d devices '
+                    'merged by %s, the scan logged %r'
+                    % (kernel, want, merge, meshes))
+            one = [(rec.get('kernel'), rec.get('mesh_devices'))
+                   for rec in rlogs
                    if rec.get('msg') == 'device aggregate kernel']
-            if not one or any(one):
-                raise PhaseFailed('the one-chip scan ran on a mesh: %r'
-                                  % one)
-            return ('mesh %.1fs one-chip %.1fs shards=%d merge=psum+pmin '
-                    'ndevicebatches=%d sha256=%s'
-                    % (secs, rsecs, want, lanes['ndevicebatches'],
-                       digest(out)))
-        return self.phase('mesh-scan', run)
+            if not one or any(o != (kernel, 0) for o in one):
+                raise PhaseFailed(
+                    'the one-chip scan did not run %s on one chip: %r'
+                    % (kernel, one))
+            return ('mesh %.1fs one-chip %.1fs shards=%d kernel=%s '
+                    'merge=%s ndevicebatches=%d sha256=%s'
+                    % (secs, rsecs, want, kernel, merge,
+                       lanes['ndevicebatches'], digest(out)))
+        return self.phase(name, run)
 
     def run(self):
         self.setup()
         if self.opts.chips > 1:
             self.add_datasource('smoke')
             self.add_datasource('smoke_mesh', backend='cluster')
-            self.mesh_phase()
+            self.mesh_phase('mesh-scan', QUERY_ARGS, 'segment-sum',
+                            'psum+pmin')
+            self.mesh_phase('mesh-scan-sparse', SPARSE_ARGS,
+                            'sparse-sort-merge', 'allgather+sparse-fold')
             return
         self.add_datasource('smoke', os.path.join(self.scratch, 'idx'))
         self.add_datasource('smoke_ref',

@@ -693,10 +693,13 @@ class DeviceScan(VectorScan):
         if acc is None:
             return
         try:
+            sparse = bool(meta.get('sparse_cap'))
+            if sparse:
+                acc, live = self._merge_sparse(acc, meta, self._sparse_ub)
             with obs_metrics.leaf_stage('scan.dispatch'):
-                cap = meta.get('sparse_cap')
-                if cap:
-                    k = min(cap, _pow2(max(self._sparse_ub, 1)))
+                if sparse:
+                    cap = int(acc[0].shape[0])
+                    k = min(cap, _pow2(max(live, 1)))
                     out = _sparse_program(cap, k,
                                           tuple(meta['caps']))(acc)
                 elif meta['cols'] and \
@@ -751,6 +754,7 @@ class DeviceScan(VectorScan):
                     raise RuntimeError(
                         'device sparse aggregation overflowed its '
                         'resident set (cap=%d)' % cap)
+                self._count_sparse_set(meta, int(st[0]))
                 if compacted:
                     self.aggr.stage.bump_hidden('ncompactflush', 1)
                 with obs_metrics.leaf_stage('scan.emit'):
@@ -1357,13 +1361,12 @@ class DeviceScan(VectorScan):
             # into a device-resident compacted set (keys/weights/first),
             # so the host only ever sees unique tuples.  The reference's
             # known failure mode was exactly this workload
-            # (README.md:668-681).  Excluded under a mesh (a sparse set
-            # has no psum merge) and when the fused key would overflow.
-            # per-column codes are computed in i32 on device (and
-            # fetched dtype-narrowed), so any single cap beyond 2^31
-            # would wrap — host path instead
-            if self._device_mesh() is not None or ns > (1 << 62) or \
-                    max(new_caps) > (1 << 31):
+            # (README.md:668-681).  Under a mesh every chip keeps a set
+            # of its own, merged at the flush (_merge_sparse).  Excluded
+            # when the fused key would overflow: per-column codes are
+            # computed in i32 on device (and fetched dtype-narrowed), so
+            # any single cap beyond 2^31 would wrap — host path instead
+            if ns > (1 << 62) or max(new_caps) > (1 << 31):
                 self._disabled = True
                 return None
             sparse = True
@@ -1381,7 +1384,9 @@ class DeviceScan(VectorScan):
         # the overflow guard runs AFTER any epoch-flip flush (a flush
         # resets the unique-count bound, which must then re-reserve
         # THIS batch or the bound undercounts by a batch)
-        if sparse and not self._sparse_guard(n):
+        pn = self._padded_rows(n)
+        if sparse and not self._sparse_guard(
+                min(n, pn // self._mesh_shards())):
             return None
 
         # leaf outcome tables (grown host-side, resident on device)
@@ -1407,25 +1412,6 @@ class DeviceScan(VectorScan):
                 self._ctabs[i] = jax.device_put(ctab)
             inputs[pfx + 'ctab_%d' % i] = self._ctabs[i]
 
-        # pad every per-record array to a stable capacity (batches can
-        # overshoot BATCH_SIZE: the streamer only flushes between
-        # reads); the floor is auto-tuned from the measured H2D
-        # bandwidth so small shards stop uploading BATCH_SIZE worth of
-        # zeros per batch; under a mesh, round up so every shard gets
-        # an equal slice
-        pn = self._pad_floor()
-        while pn < n:
-            pn <<= 1
-        # the capacity only grows within a scan: a later, smaller batch
-        # (the tail of a file) reuses the program already compiled
-        # instead of compiling a second variant of everything — on the
-        # chip a variant of the sparse program costs over a minute of
-        # compilation, its dead padding rows next to nothing
-        self._sticky['pn_floor'] = pn
-        mesh_info = self._device_mesh()
-        if mesh_info is not None:
-            nsh = int(mesh_info[0].devices.size)
-            pn = ((pn + nsh - 1) // nsh) * nsh
         if n < pn:
             pad = pn - n
             for k, v in list(inputs.items()):
@@ -1440,6 +1426,25 @@ class DeviceScan(VectorScan):
                    tuple(kvalid_profile), use_dstats,
                    (self._sparse_cap if sparse else 0))
         return (pn, profile, tuple(new_caps), ns, total_w)
+
+    def _padded_rows(self, n):
+        """The capacity a batch of `n` records is padded to: stable
+        (batches can overshoot BATCH_SIZE: the streamer only flushes
+        between reads), from a floor that is auto-tuned from the
+        measured H2D bandwidth so small shards stop uploading
+        BATCH_SIZE worth of zeros per batch; under a mesh, rounded up
+        so every shard gets an equal slice."""
+        pn = self._pad_floor()
+        while pn < n:
+            pn <<= 1
+        # the capacity only grows within a scan: a later, smaller batch
+        # (the tail of a file) reuses the program already compiled
+        # instead of compiling a second variant of everything — on the
+        # chip a variant of the sparse program costs over a minute of
+        # compilation, its dead padding rows next to nothing
+        self._sticky['pn_floor'] = pn
+        nsh = self._mesh_shards()
+        return ((pn + nsh - 1) // nsh) * nsh
 
     def _pad_floor(self):
         """Smallest staged-batch capacity (a power of two, at most
@@ -1497,7 +1502,10 @@ class DeviceScan(VectorScan):
         an upper bound on uniques (exact count at last check + records
         since); when this batch could overflow, sync-fetch the true
         count from the accumulator, and if still at risk flush the
-        (correct-so-far) epoch and grow the capacity.  Returns False
+        (correct-so-far) epoch and grow the capacity.  Under a mesh
+        every chip has a set of its own: `n` is the most rows of the
+        batch one chip can get, the bound is the fullest chip's, and
+        the count read is the largest over the chips.  Returns False
         when the scan must take the host path instead (capacity
         ceiling: device permanently disabled for this scan)."""
         while True:
@@ -1506,8 +1514,9 @@ class DeviceScan(VectorScan):
                 self._sparse_ub += n
                 return True
             if self._acc is not None and len(self._acc) == 5:
+                obs_metrics.inc('device_sparse_guard_syncs')
                 with obs_metrics.leaf_stage('scan.fetch'):
-                    nuniq = int(np.asarray(self._acc[4])[0])
+                    nuniq = int(np.asarray(self._acc[4])[..., 0].max())
                 if nuniq + n <= cap:
                     self._sparse_ub = nuniq + n
                     return True
@@ -1528,6 +1537,7 @@ class DeviceScan(VectorScan):
                 'cols': [(p.kind, p.lo) for p in self._plans],
                 'ns': ns,
                 'sparse_cap': sparse_cap,
+                'shards': self._mesh_shards(),
             }
             self._acc_batch = 0
 
@@ -1558,13 +1568,16 @@ class DeviceScan(VectorScan):
         self._kernels_logged.add((pkey, use_pallas))
         from .ops import pallas_kernels as pk
         mesh = self._device_mesh()
+        merge = None
+        if mesh:
+            merge = 'allgather+sparse-fold' if sparse_cap else 'psum+pmin'
         LOG.debug('device aggregate kernel',
                   kernel='pallas-onehot' if use_pallas else
                   ('sparse-sort-merge' if sparse_cap else 'segment-sum'),
                   interpret=bool(use_pallas and pk.needs_interpret()),
                   segments=ns,
                   mesh_devices=int(mesh[0].devices.size) if mesh else 0,
-                  merge='psum+pmin' if mesh else None)
+                  merge=merge)
 
     def _staged_run(self, staged, inputs):
         """The last of a batch's staging: its jitted program, with the
@@ -1669,6 +1682,11 @@ class DeviceScan(VectorScan):
             return None
         mesh, axis = m
         return (axis, tuple(d.id for d in mesh.devices.flat))
+
+    def _mesh_shards(self):
+        """How many chips a batch's record axis is dealt over."""
+        m = self._device_mesh()
+        return int(m[0].devices.size) if m is not None else 1
 
     def _build_programs(self, caps, n, profile):
         key = self._program_key(caps, n, profile)
@@ -1980,9 +1998,7 @@ class DeviceScan(VectorScan):
         per_record_prefixes = ('tags_', 'str_', 'num_', 'ts_', 'kv_',
                                'kvalid_', 'key_', 'tsf_', 'terr_')
 
-        def run_body(args, use_pallas):
-            if mesh is None:
-                return body(args, use_pallas)
+        def record_specs(args):
             from jax.sharding import PartitionSpec as SP
             specs = {}
             for k in args:
@@ -1993,6 +2009,13 @@ class DeviceScan(VectorScan):
                     specs[k] = SP(maxis)
                 else:
                     specs[k] = SP()   # lookup tables: replicated
+            return specs
+
+        def run_body(args, use_pallas):
+            if mesh is None:
+                return body(args, use_pallas)
+            from jax.sharding import PartitionSpec as SP
+            specs = record_specs(args)
             sargs = {k: args[k] for k in specs}
             return jax.shard_map(
                 lambda a: body(a, use_pallas), mesh=mesh,
@@ -2024,7 +2047,6 @@ class DeviceScan(VectorScan):
             fetch.  Runs past the capacity are dropped; the sticky
             overflow flag makes that loud at flush (the host guard
             prevents it from ever tripping)."""
-            assert mesh is None
             cvec_b, fused, wb, gidx = body(args, False)
             i64 = jnp.int64
             first_b = jnp.where(fused != i64(I64MAX),
@@ -2032,32 +2054,62 @@ class DeviceScan(VectorScan):
                                 i64(I64MAX))
             return sparse_fold(jax, jnp, acc, cvec_b, fused, wb, first_b)
 
+        def fold_sparse_mesh(args, acc):
+            """The same fold on every chip of the mesh: its shard of
+            the batch into its own set (the accumulator's leaves carry
+            the chips on a leading axis), and no collective.  `gidx`
+            is the batch's global row, so a key's `first` is what one
+            chip would have given it; the chips' sets meet at the
+            flush (_merge_sparse)."""
+            from jax.sharding import PartitionSpec as SP
+            specs = record_specs(args)
+            specs[pfx + 'base'] = SP()
+            sargs = {k: args[k] for k in specs}
+
+            def chip(a, acc1):
+                out = fold_sparse(a, tuple(x[0] for x in acc1))
+                return tuple(x[None] for x in out)
+
+            sets = (SP(maxis),) * 5
+            return jax.shard_map(chip, mesh=mesh, in_specs=(specs, sets),
+                                 out_specs=sets)(sargs, acc)
+
         if sparse_cap:
+            fold_one = fold_sparse if mesh is None else fold_sparse_mesh
+
             def run_sparse(args, acc):
-                out = fold_sparse(args, acc)
-                # completion token: a fresh scalar derived from the
-                # output.  Unlike the (donated) accumulator leaves it
-                # never re-enters the fold, so the pipeline can hold it
-                # and block on it after later batches have consumed the
-                # accumulator buffers (see _note_dispatch)
-                return out, jnp.sum(out[4]).astype(jnp.int32)
+                out = fold_one(args, acc)
+                # completion token: a fresh scalar (under a mesh one a
+                # chip, so that no collective joins them) derived from
+                # the output.  Unlike the (donated) accumulator leaves
+                # it never re-enters the fold, so the pipeline can hold
+                # it and block on it after later batches have consumed
+                # the accumulator buffers (see _note_dispatch)
+                return out, jnp.sum(out[4], axis=-1).astype(jnp.int32)
             run_scatter = jax.jit(run_sparse, **_donate_kw())
 
             def fold_u(args, acc, use_pallas):
-                return fold_sparse(args, acc)
+                return fold_one(args, acc)
 
-            init_key = ('sparse', sparse_cap, ncnt)
+            init_key = ('sparse', sparse_cap, ncnt, self._mesh_key())
             acc_init = _ACC_INIT_CACHE.get(init_key)
             if acc_init is None:
-                def make_sparse_init(cap_, ncnt_):
+                def make_sparse_init(cap_, ncnt_, mesh_, maxis_):
                     jx, jn = get_jax()
+                    lead, kw = (), {}
+                    if mesh_ is not None:
+                        from jax.sharding import NamedSharding, \
+                            PartitionSpec
+                        lead = (int(mesh_.devices.size),)
+                        kw['out_shardings'] = NamedSharding(
+                            mesh_, PartitionSpec(maxis_))
                     return jx.jit(lambda: (
-                        jn.full((cap_,), I64MAX, dtype=jn.int64),
-                        jn.zeros((cap_,), dtype=jn.int64),
-                        jn.full((cap_,), I64MAX, dtype=jn.int64),
-                        jn.zeros((ncnt_,), dtype=jn.int64),
-                        jn.zeros((2,), dtype=jn.int64)))
-                acc_init = make_sparse_init(sparse_cap, ncnt)
+                        jn.full(lead + (cap_,), I64MAX, dtype=jn.int64),
+                        jn.zeros(lead + (cap_,), dtype=jn.int64),
+                        jn.full(lead + (cap_,), I64MAX, dtype=jn.int64),
+                        jn.zeros(lead + (ncnt_,), dtype=jn.int64),
+                        jn.zeros(lead + (2,), dtype=jn.int64)), **kw)
+                acc_init = make_sparse_init(sparse_cap, ncnt, mesh, maxis)
                 if len(_ACC_INIT_CACHE) >= 64:
                     _ACC_INIT_CACHE.pop(next(iter(_ACC_INIT_CACHE)))
                 _ACC_INIT_CACHE[init_key] = acc_init
@@ -2162,8 +2214,9 @@ class DeviceScan(VectorScan):
         already compact, so fetch its occupied slots ordered by first
         occurrence (decoded + narrowed on device), sized by the
         epoch's unique-count upper bound."""
-        k0 = _pow2(max(min(sparse_ub, meta['sparse_cap']), 1)) \
-            if sparse_ub else self.COMPACT_K
+        acc, live = self._merge_sparse(
+            acc, meta, min(sparse_ub, meta['sparse_cap']))
+        k0 = _pow2(max(live, 1)) if live else self.COMPACT_K
         with obs_metrics.leaf_stage('scan.fetch'):
             fetched = _sparse_fetch(acc, k0, meta['caps'])
             if fetched is None:
@@ -2179,9 +2232,47 @@ class DeviceScan(VectorScan):
                 'device sparse aggregation overflowed its resident set'
                 ' (cap=%d); results would be incomplete'
                 % meta['sparse_cap'])
+        self._count_sparse_set(meta, int(stats[0]))
         with obs_metrics.leaf_stage('scan.emit'):
             self._emit_counters(cvec)
             self._emit_cols(meta, cols, wsum)
+
+    def _merge_sparse(self, acc, meta, ub):
+        """A mesh epoch's sets, one a chip, merged on the device into
+        one set in the one-chip layout (replicated), which the fetch
+        and the emit then take as they take a single chip's: the
+        reduce phase of upstream's map -> reduce.  Every shape comes
+        from the chips' own counts, read here: each chip's first `k`
+        slots (k the fullest chip's live tuples, a power of two) are
+        all-gathered and folded once more (`mesh.sparse_merge_program`)
+        into an empty set that holds the sum of the counts.  Returns
+        (set, bound on its live tuples): outside a mesh the set as it
+        is and `ub`, the guard's bound, with nothing read."""
+        if meta['shards'] == 1:
+            return acc, ub
+        from .parallel import mesh as mod_mesh
+        mesh, axis = self._device_mesh()
+        with obs_metrics.leaf_stage('scan.sparse_merge'):
+            cap = meta['sparse_cap']
+            live = np.minimum(np.asarray(acc[4])[:, 0], cap)
+            k = min(cap, _pow2(max(int(live.max()), 1)))
+            merged = mod_mesh.sparse_merge_program(
+                mesh, axis, k, _pow2(max(int(live.sum()), 1)))(acc)
+            tuples = int(np.asarray(merged[4])[0])
+        obs_metrics.inc('device_sparse_merge_rows', int(live.sum()))
+        obs_metrics.inc('device_sparse_merge_tuples', tuples)
+        obs_metrics.inc('device_sparse_set_slots', cap)
+        obs_metrics.inc('device_sparse_set_live', int(live.max()))
+        return merged, tuples
+
+    @staticmethod
+    def _count_sparse_set(meta, live):
+        """The set's fill at a flush, for a single chip (a mesh's is
+        counted where its sets are merged)."""
+        if meta['shards'] == 1:
+            obs_metrics.inc('device_sparse_set_slots', meta['sparse_cap'])
+            obs_metrics.inc('device_sparse_set_live',
+                            min(live, meta['sparse_cap']))
 
 
 # jitted flush-compaction programs, keyed by (acc_len, K)

@@ -58,10 +58,14 @@ class MeshDeviceScan(DeviceScan, MeshVectorScan):
     shard_map over the process-local device mesh, with psum merges for
     dense weights/counters and a pmin over global row indices for
     first-occurrence order (identical to host-engine insertion order).
-    Ineligible batches fall back through the MRO to MeshVectorScan,
-    whose dense aggregation is still mesh-sharded — so every batch is
-    distributed one way or the other, and results match the host
-    engine byte-for-byte (differential-tested).
+    A key space past the dense budget runs the sparse program the same
+    way, with no collective per batch: every chip sort-merges its
+    shard into a set of its own, and at the flush the sets are
+    all-gathered and folded once more (mesh.sparse_merge_program) —
+    upstream's map -> reduce.  Batches the device program cannot take
+    (a non-integral key, a cap past 2^31) fall back through the MRO to
+    MeshVectorScan, whose dense aggregation is still mesh-sharded, and
+    results match the host engine byte-for-byte (differential-tested).
 
     This replaces the round-3 design where only the final segment-sum
     was sharded and predicates/bucketize stayed on the host even in

@@ -91,6 +91,52 @@ def sharded_step(mesh, radices, per_device, scatter, integer_weights,
                          out_specs=out_spec, check_vma=not use_pallas)
 
 
+@functools.lru_cache(maxsize=64)
+def sparse_merge_program(mesh, axis, k, cap_out):
+    """Jitted merge of a mesh's sparse sets (device_scan's sparse lane:
+    one set a chip, the chips on the leading axis of every leaf) into
+    one set of `cap_out` slots in the one-chip layout, replicated.
+    Each chip's first `k` slots are all-gathered over `axis` and
+    folded by one more `kernels.sparse_fold` into an empty set: equal
+    keys of different chips form a run of at most as many rows as
+    there are chips, their weights add and the smallest `first` (a
+    global row index) stays, which is the host engine's insertion
+    order.  Counters add by psum, and a chip's overflow flag stays
+    set.  The caller takes `k` and `cap_out` from the chips' own
+    counts, so that no live slot is left behind and every key has a
+    slot.  Its own module in a trace: `jit_sparse_merge`."""
+    jax, jnp = get_jax()
+    from jax.sharding import PartitionSpec as P
+    from ..ops.kernels import I64MAX, sparse_fold
+
+    def chip(acc):
+        keys, wsum, first, cvec, stats = (x[0] for x in acc)
+        i64 = jnp.int64
+        empty = (jnp.full((cap_out,), I64MAX, dtype=i64),
+                 jnp.zeros((cap_out,), dtype=i64),
+                 jnp.full((cap_out,), I64MAX, dtype=i64),
+                 jnp.zeros_like(cvec),
+                 # (the flag summed, not pmax'ed: the TPU lowers only
+                 # the sum of a 64-bit all-reduce)
+                 jnp.stack([i64(0),
+                            jnp.minimum(jax.lax.psum(stats[1], axis), 1)]))
+
+        def gathered(x):
+            return jax.lax.all_gather(x[:k], axis, tiled=True)
+
+        return sparse_fold(jax, jnp, empty, jax.lax.psum(cvec, axis),
+                           gathered(keys), gathered(wsum), gathered(first))
+
+    def sparse_merge(acc):
+        # every chip computes the same set from the same gathered
+        # rows; an all_gather's result is not marked invariant, so the
+        # replication check is off
+        return jax.shard_map(chip, mesh=mesh, in_specs=((P(axis),) * 5,),
+                             out_specs=(P(),) * 5, check_vma=False)(acc)
+
+    return jax.jit(sparse_merge)
+
+
 @functools.lru_cache(maxsize=None)
 def _sharded_aggregate_cached(radices, per_device, ndev, scatter,
                               integer_weights, use_pallas=False):

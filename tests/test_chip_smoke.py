@@ -55,9 +55,8 @@ def _bench_json(*rel):
         return json.load(f)
 
 
-def _cell_query():
-    (template,) = _bench_json(
-        'workloads', 'muskie-30d.scan-dense.json')['templates']
+def _cell_query(cell='muskie-30d.scan-dense'):
+    (template,) = _bench_json('workloads', cell + '.json')['templates']
     return _query(template['query'])
 
 
@@ -79,21 +78,27 @@ def _config_metrics():
      lambda: _metric(bench.METRICS[2])),
     (_cell_query, lambda: _query(bench.QUERY)),
     (_config_metrics, lambda: [_metric(m) for m in bench.METRICS]),
+    # the high-cardinality cells ask what the smoke's sparse phases ask
+    (lambda: _cell_query('muskie-30d-highcard.scan-highcard'),
+     lambda: _scan_args_query(chip_smoke.SPARSE_ARGS)),
+    (lambda: _cell_query('muskie-30d-highcard-mesh4.scan-highcard'),
+     lambda: _scan_args_query(chip_smoke.SPARSE_ARGS)),
 ], ids=['smoke-query', 'smoke-pallas-query', 'smoke-m1', 'smoke-m2',
-        'smoke-m3', 'cell-scan-dense-query', 'config-muskie-30d-metrics'])
+        'smoke-m3', 'cell-scan-dense-query', 'config-muskie-30d-metrics',
+        'cell-scan-highcard-query', 'cell-mesh4-scan-highcard-query'])
 def test_the_copies_agree_with_bench(copy, original):
     assert len(chip_smoke.METRIC_ARGS) == len(bench.METRICS) == 3
     assert copy() == original()
 
 
-def _run(extra_env):
+def _run(extra_env, *args):
     env = dict(os.environ)
     env['JAX_PLATFORMS'] = 'cpu'
     env.pop('XLA_FLAGS', None)      # one CPU device, as on one chip
     env.update(extra_env)
     p = subprocess.run(
         [sys.executable, os.path.join(ROOT, 'chip_smoke.py'),
-         '--records', '5000'],
+         '--records', '5000'] + list(args),
         env=env, cwd=ROOT, stdout=subprocess.PIPE,
         stderr=subprocess.STDOUT, timeout=600)
     lines = p.stdout.decode('utf-8', 'replace').splitlines()
@@ -120,6 +125,27 @@ def test_rehearsal_passes_every_phase_and_refuses_the_cpu():
     assert verdict == {'ok': False, 'device': {
         'platform': 'cpu', 'kind': 'cpu', 'count': 1}}
     assert "not ok: platform is 'cpu', not tpu" in lines
+
+
+def test_rehearsal_of_four_chips_passes_both_mesh_phases():
+    """`--chips 4` on four virtual CPU devices: the dense scan and the
+    high-cardinality one through the cluster backend, each equal to
+    its one-chip scan, each with its kernel and its merge on every
+    record."""
+    rc, lines = _run(
+        {'XLA_FLAGS': '--xla_force_host_platform_device_count=4'},
+        '--chips', '4')
+    phases = _phase_lines(lines)
+    assert tuple(phases) == ('mesh-scan', 'mesh-scan-sparse'), \
+        '\n'.join(lines)
+    for name, rest in phases.items():
+        assert rest.startswith('passed '), '\n'.join(lines)
+    assert 'kernel=segment-sum merge=psum+pmin' in phases['mesh-scan']
+    assert 'kernel=sparse-sort-merge merge=allgather+sparse-fold' in \
+        phases['mesh-scan-sparse']
+    assert rc != 0
+    assert json.loads(lines[-1]) == {'ok': False, 'device': {
+        'platform': 'cpu', 'kind': 'cpu', 'count': 4}}
 
 
 def test_forced_scans_fail_without_the_native_parser():

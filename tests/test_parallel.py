@@ -239,25 +239,75 @@ def test_cluster_dry_run_plan(tmp_path, capsys):
     assert inputs.splitlines() == [str(datadir / 'a.log')]
 
 
-def test_cluster_highcard_falls_back_to_host_sparse(tmp_path,
-                                                    monkeypatch):
-    """Key spaces beyond the dense budget are excluded from the mesh
-    program (a sparse set has no psum merge): the cluster scan must
-    fall back to the host sparse merge with results identical to the
-    host engine — the bounded-memory discipline survives the
-    distributed backend."""
-    import json
-    from dragnet_tpu import query as mod_query
-    from dragnet_tpu import native as mod_native
-    from dragnet_tpu.parallel import cluster
-    import dragnet_tpu.engine as eng
+def _mesh_scan_setup(monkeypatch, read=4096, cap0=4096):
+    """Small batches (128 records), a small dense budget (64 segments)
+    and a small first set for a forced scan on the cluster backend;
+    returns the StringIO that device_scan's debug records (the kernel
+    records) go to."""
+    import io
+    from dragnet_tpu import log as mod_log
     from dragnet_tpu import device_scan
+    import dragnet_tpu.engine as eng
+    monkeypatch.setattr(eng, 'MAX_DENSE_SEGMENTS', 64)
+    monkeypatch.setattr(device_scan, 'MAX_DENSE_SEGMENTS', 64)
+    monkeypatch.setattr(device_scan, 'SPARSE_CAP0', cap0)
+    monkeypatch.setattr(eng, 'BATCH_SIZE', 128)
+    monkeypatch.setattr(device_scan, 'BATCH_SIZE', 128)
+    monkeypatch.setenv('DN_READ_SIZE', str(read))
+    monkeypatch.setenv('DN_SCAN_THREADS', '0')
+    buf = io.StringIO()
+    monkeypatch.setattr(device_scan, 'LOG', mod_log.Logger(
+        'dn', component='device_scan', level=mod_log.DEBUG, stream=buf))
+    return buf
+
+
+def _kernel_records(buf):
+    import json
+    recs = [json.loads(ln) for ln in buf.getvalue().splitlines()]
+    return [(r['kernel'], r['mesh_devices'], r['merge'])
+            for r in recs if r['msg'] == 'device aggregate kernel']
+
+
+def _dsconfig(datadir, fmt='json'):
+    return {'ds_backend': 'file',
+            'ds_backend_config': {'path': str(datadir)},
+            'ds_filter': None, 'ds_format': fmt}
+
+
+def _scan_points(monkeypatch, ds_cls, dsconfig, qconf, engine):
+    from dragnet_tpu import query as mod_query
+    monkeypatch.setenv('DN_ENGINE', engine)
+    return ds_cls(dsconfig).scan(mod_query.query_load(qconf))
+
+
+def _counter(name):
+    from dragnet_tpu.obs import metrics as obs_metrics
+    return obs_metrics.global_registry().counter(name).value
+
+
+def _ndevicebatches(result):
+    return sum(st.counters.get('ndevicebatches', 0)
+               for st in result.pipeline.stages)
+
+
+HIGHCARD_Q = {'breakdowns': [{'name': 'host'}, {'name': 'latency'}]}
+
+
+def test_cluster_highcard_runs_sparse_on_mesh(tmp_path, monkeypatch):
+    """A key space beyond the dense budget runs the sparse program on
+    every chip of the mesh: a set a chip, merged at the flush by
+    all-gather and one more sparse_fold.  Every batch is the device's
+    (`ndevicebatches`), the kernel record says which program and which
+    merge, and the output is byte-identical, order included, to the
+    per-record host engine (dragnet_tpu/scan.py) and to the one-chip
+    device scan."""
+    import json
+    from dragnet_tpu import native as mod_native
+    from dragnet_tpu import datasource_file
+    from dragnet_tpu.parallel import cluster
 
     if mod_native.get_lib() is None:
         pytest.skip('native parser unavailable')
-
-    monkeypatch.setattr(eng, 'MAX_DENSE_SEGMENTS', 64)
-    monkeypatch.setattr(device_scan, 'MAX_DENSE_SEGMENTS', 64)
 
     datadir = tmp_path / 'data'
     datadir.mkdir()
@@ -268,25 +318,280 @@ def test_cluster_highcard_falls_back_to_host_sparse(tmp_path,
                 'host': 'h%d' % rng.randrange(60),
                 'latency': rng.randrange(0, 4000),
             }) + '\n')
+    dsconfig = _dsconfig(datadir)
 
-    dsconfig = {
-        'ds_backend': 'file',
-        'ds_backend_config': {'path': str(datadir)},
-        'ds_filter': None,
-        'ds_format': 'json',
-    }
-    qconf = {'breakdowns': [{'name': 'host'}, {'name': 'latency'}]}
+    expected = _scan_points(monkeypatch, cluster.DatasourceCluster,
+                            dsconfig, HIGHCARD_Q, 'host').points
+    buf = _mesh_scan_setup(monkeypatch)
+    one_chip = _scan_points(monkeypatch, datasource_file.DatasourceFile,
+                            dsconfig, HIGHCARD_Q, 'jax')
+    assert _kernel_records(buf) == [('sparse-sort-merge', 0, None)]
+    buf.seek(0)
+    buf.truncate()
 
-    monkeypatch.setenv('DN_ENGINE', 'host')
-    expected = cluster.DatasourceCluster(dsconfig).scan(
-        mod_query.query_load(qconf)).points
-    monkeypatch.delenv('DN_ENGINE', raising=False)
-
-    monkeypatch.setattr(eng, 'BATCH_SIZE', 256)
-    monkeypatch.setattr(device_scan, 'BATCH_SIZE', 256)
-    monkeypatch.setenv('DN_READ_SIZE', '65536')
-    monkeypatch.setenv('DN_SCAN_THREADS', '0')
-    r = cluster.DatasourceCluster(dsconfig).scan(
-        mod_query.query_load(qconf))
+    r = _scan_points(monkeypatch, cluster.DatasourceCluster, dsconfig,
+                     HIGHCARD_Q, 'jax')
+    assert len(expected) > 64
     assert r.points == expected
-    assert len(r.points) > 64
+    assert r.points == one_chip.points
+    assert _ndevicebatches(r) == _ndevicebatches(one_chip) >= 5
+    assert _kernel_records(buf) == [
+        ('sparse-sort-merge', 8, 'allgather+sparse-fold')]
+
+
+def _write_mesh_case(case, path):
+    """The corpus of one case of test_mesh_sparse_cases; returns the
+    datasource's format."""
+    import json
+    rng = random.Random(hash(case) % 1000 + 23)
+    lines = []
+    if case == 'weights':
+        # `dn scan --points` output as input: tuples with weights
+        for i in range(1200):
+            host, lat = (i % 40, i) if i < 100 else \
+                (rng.randrange(40), rng.randrange(0, 100))
+            lines.append(json.dumps({
+                'fields': {'host': 'h%d' % host, 'latency': lat},
+                'value': rng.randrange(1, 9)}))
+        fmt = 'json-skinner'
+    elif case == 'dense-then-sparse':
+        # the build's m1 pattern: a key space that fits the dense
+        # budget until new values arrive
+        for i in range(600):
+            lines.append(json.dumps({'host': 'h%d' % (i % 4),
+                                     'latency': i % 3}))
+        for i in range(900):
+            host, lat = (i % 50, i) if i < 100 else \
+                (rng.randrange(50), rng.randrange(0, 100))
+            lines.append(json.dumps({'host': 'h%d' % host,
+                                     'latency': lat}))
+        fmt = 'json'
+    else:
+        # 1501 records: no multiple of the 8 chips; `everywhere` is in
+        # every chip's shard of every batch, `once` in one record; the
+        # first batch brings most values of both columns, so the key
+        # space stays as it is (one epoch, one flush)
+        for i in range(1501):
+            if i == 777:
+                rec = {'host': 'once', 'latency': 5}
+            elif i % 4 == 0:
+                rec = {'host': 'everywhere', 'latency': 1}
+            elif i < 110:
+                rec = {'host': 'h%d' % (i % 60), 'latency': i % 100}
+            else:
+                rec = {'host': 'h%d' % rng.randrange(60),
+                       'latency': rng.randrange(0, 100)}
+            lines.append(json.dumps(rec))
+        fmt = 'json'
+    with open(path, 'w') as f:
+        f.write('\n'.join(lines) + '\n')
+    return fmt
+
+
+@pytest.mark.parametrize('case', [
+    'odd-count', 'guard-grows', 'overflow-raises', 'weights',
+    'dense-then-sparse', 'prefetch'])
+def test_mesh_sparse_cases(case, tmp_path, monkeypatch):
+    """The mesh's sparse lane against the per-record host engine, byte
+    for byte and in order, where it has to do more than fold and merge
+    once: a record count that the chips do not divide, with a key on
+    every chip and a key on one; a set so small that the guard syncs,
+    flushes and grows; a set that overflows with the guard taken away
+    (loud, never a short reply); weighted tuples; a dense epoch
+    followed by a sparse one; the late-stream prefetch of the flush."""
+    from dragnet_tpu import native as mod_native
+    from dragnet_tpu import device_scan
+    from dragnet_tpu.parallel import cluster
+
+    if mod_native.get_lib() is None:
+        pytest.skip('native parser unavailable')
+    datadir = tmp_path / 'data'
+    datadir.mkdir()
+    dsconfig = _dsconfig(datadir, _write_mesh_case(case, datadir / 'a.log'))
+    expected = _scan_points(monkeypatch, cluster.DatasourceCluster,
+                            dsconfig, HIGHCARD_Q, 'host').points
+
+    small = case in ('guard-grows', 'overflow-raises')
+    buf = _mesh_scan_setup(monkeypatch, cap0=32 if small else 4096)
+    merges, prefetched, scanners = [], [], []
+    orig_merge = cluster.MeshDeviceScan._merge_sparse
+    orig_prefetch = cluster.MeshDeviceScan._prefetch_flush
+
+    def spy_merge(self, acc, meta, ub):
+        scanners.append(self)
+        merges.append(meta['sparse_cap'])
+        return orig_merge(self, acc, meta, ub)
+
+    def spy_prefetch(self):
+        prefetched.append(self._acc is not None and len(self._acc) == 5)
+        return orig_prefetch(self)
+    monkeypatch.setattr(cluster.MeshDeviceScan, '_merge_sparse', spy_merge)
+    monkeypatch.setattr(cluster.MeshDeviceScan, '_prefetch_flush',
+                        spy_prefetch)
+    if case != 'prefetch':
+        monkeypatch.setenv('DN_PREFETCH', '0')
+    if case == 'overflow-raises':
+        monkeypatch.setattr(cluster.MeshDeviceScan, '_sparse_guard',
+                            lambda self, n: True)
+        with pytest.raises(RuntimeError, match='overflowed its resident'):
+            _scan_points(monkeypatch, cluster.DatasourceCluster, dsconfig,
+                         HIGHCARD_Q, 'jax')
+        return
+
+    syncs0 = _counter('device_sparse_guard_syncs')
+    r = _scan_points(monkeypatch, cluster.DatasourceCluster, dsconfig,
+                     HIGHCARD_Q, 'jax')
+    assert r.points == expected
+    assert len(expected) > 64
+    kernels = _kernel_records(buf)
+    assert ('sparse-sort-merge', 8, 'allgather+sparse-fold') in kernels
+    assert _ndevicebatches(r) >= 5
+    if case == 'guard-grows':
+        # the first set of 32 slots a chip cannot hold the stream: the
+        # guard read the chips' counts, flushed and grew the set
+        assert _counter('device_sparse_guard_syncs') > syncs0
+        assert len(merges) > 1 and merges[0] == 32
+        assert scanners[0]._sparse_cap > 32
+        assert not scanners[0]._disabled
+    elif case == 'dense-then-sparse':
+        assert set(kernels) == {
+            ('segment-sum', 8, 'psum+pmin'),
+            ('sparse-sort-merge', 8, 'allgather+sparse-fold')}
+        assert kernels[0][0] == 'segment-sum'
+        assert len(merges) == 1
+    elif case == 'prefetch':
+        # the set so far was merged, compacted and fetched beside the
+        # rest of the stream, and the rest merged at the end
+        assert prefetched == [True]
+        assert len(merges) == 2
+    else:
+        assert len(merges) == 1
+
+
+def test_mesh_sparse_partials_tie(tmp_path, monkeypatch):
+    """The chips' sets as they stand before the merge, fetched and
+    re-aggregated on the host (weights added, the smallest `first`
+    kept), are the merged set slot for slot and the host engine's
+    answer: what the collective and the extra fold did is exactly the
+    reduce of the chips' partial aggregates."""
+    import numpy as np
+    from dragnet_tpu import native as mod_native
+    from dragnet_tpu.ops.kernels import I64MAX
+    from dragnet_tpu.parallel import cluster
+
+    if mod_native.get_lib() is None:
+        pytest.skip('native parser unavailable')
+    datadir = tmp_path / 'data'
+    datadir.mkdir()
+    dsconfig = _dsconfig(datadir,
+                         _write_mesh_case('odd-count', datadir / 'a.log'))
+    expected = _scan_points(monkeypatch, cluster.DatasourceCluster,
+                            dsconfig, HIGHCARD_Q, 'host').points
+    _mesh_scan_setup(monkeypatch)
+    monkeypatch.setenv('DN_PREFETCH', '0')
+    seen = []
+    orig = cluster.MeshDeviceScan._merge_sparse
+
+    def spy(self, acc, meta, ub):
+        before = [np.asarray(x) for x in acc]
+        merged, tuples = orig(self, acc, meta, ub)
+        seen.append((before, [np.asarray(x) for x in merged], tuples))
+        return merged, tuples
+    monkeypatch.setattr(cluster.MeshDeviceScan, '_merge_sparse', spy)
+    r = _scan_points(monkeypatch, cluster.DatasourceCluster, dsconfig,
+                     HIGHCARD_Q, 'jax')
+    assert r.points == expected
+
+    (before, merged, tuples), = seen
+    keys, wsum, first, cvec, stats = before
+    assert keys.shape == (8, 4096)
+    live = keys != I64MAX
+    assert (live.sum(axis=1) == stats[:, 0]).all()
+    # several chips hold a key of their own copy: `everywhere` is in
+    # every shard, `once` in one
+    per_key = {}
+    for k, w, f in zip(keys[live], wsum[live], first[live]):
+        w0, f0, n0 = per_key.get(int(k), (0, I64MAX, 0))
+        per_key[int(k)] = (w0 + int(w), min(f0, int(f)), n0 + 1)
+    assert max(n for _, _, n in per_key.values()) == 8
+    assert min(n for _, _, n in per_key.values()) == 1
+    assert tuples == len(per_key) == len(expected) == int(merged[4][0])
+    assert int(live.sum()) > tuples
+    mk, mw, mf = merged[0][:tuples], merged[1][:tuples], merged[2][:tuples]
+    assert mk.tolist() == sorted(per_key)
+    assert [(int(w), int(f)) for w, f in zip(mw, mf)] == \
+        [per_key[k][:2] for k in sorted(per_key)]
+    assert (merged[0][tuples:] == I64MAX).all()
+    assert (merged[3] == cvec.sum(axis=0)).all()
+    assert sorted(int(w) for w in mw) == sorted(p[1] for p in expected)
+
+
+def test_cluster_build_equals_file_build(tmp_path, monkeypatch):
+    """A cluster `dn build` of three metrics writes the file backend's
+    tree, file for file, with the metric whose key space passes the
+    dense budget folded by the mesh's sparse program
+    (`device_sparse_fold_batches`, the `allgather+sparse-fold`
+    record)."""
+    import test_device_build as tdb
+    from dragnet_tpu import native as mod_native
+    from dragnet_tpu.parallel import cluster
+
+    if mod_native.get_lib() is None:
+        pytest.skip('native parser unavailable')
+    datafile = tmp_path / 'data.log'
+    tdb._write_data(datafile, 1500)
+    tdb._build(monkeypatch, datafile, tmp_path / 'ifile', 'vector')
+
+    buf = _mesh_scan_setup(monkeypatch, read=16384)
+    monkeypatch.setenv('DN_PARSE_THREADS', '1')
+    folded0 = _counter('device_sparse_fold_batches')
+    ds = cluster.DatasourceCluster({
+        'ds_backend': 'cluster',
+        'ds_backend_config': {'path': str(datafile),
+                              'indexPath': str(tmp_path / 'icluster'),
+                              'timeField': 'time'},
+        'ds_filter': None, 'ds_format': 'json'})
+    monkeypatch.setenv('DN_ENGINE', 'jax')
+    ds.build(tdb._metrics(), 'day')
+    assert _counter('device_sparse_fold_batches') > folded0
+    assert ('sparse-sort-merge', 8, 'allgather+sparse-fold') in \
+        _kernel_records(buf)
+    t_file = tdb._tree_bytes(tmp_path / 'ifile')
+    t_cluster = tdb._tree_bytes(tmp_path / 'icluster')
+    assert t_file.keys() == t_cluster.keys() and len(t_file) >= 3
+    for rel in t_file:
+        assert t_file[rel] == t_cluster[rel], rel
+
+
+def test_sparse_merge_counters_and_leaf_at_a_scrape(tmp_path, monkeypatch):
+    """The merge's leaf stage and the sparse lane's counters are at a
+    Prometheus scrape after a mesh scan, with what the scan did."""
+    from dragnet_tpu import native as mod_native
+    from dragnet_tpu.obs import export as obs_export
+    from dragnet_tpu.obs import metrics as obs_metrics
+    from dragnet_tpu.parallel import cluster
+
+    if mod_native.get_lib() is None:
+        pytest.skip('native parser unavailable')
+    datadir = tmp_path / 'data'
+    datadir.mkdir()
+    dsconfig = _dsconfig(datadir,
+                         _write_mesh_case('odd-count', datadir / 'a.log'))
+    _mesh_scan_setup(monkeypatch)
+    monkeypatch.setenv('DN_PREFETCH', '0')
+    names = ('device_sparse_merge_rows', 'device_sparse_merge_tuples',
+             'device_sparse_set_slots', 'device_sparse_set_live')
+    before = [_counter(n) for n in names]
+    r = _scan_points(monkeypatch, cluster.DatasourceCluster, dsconfig,
+                     HIGHCARD_Q, 'jax')
+    rows, tuples, slots, live = [
+        _counter(n) - b for n, b in zip(names, before)]
+    assert tuples == len(r.points)
+    assert slots == 4096 and 0 < live < tuples
+    # the chips' live slots: a key that several chips hold counts once
+    # a chip (`everywhere` is on all eight)
+    assert tuples + 7 <= rows <= 8 * live
+    text = obs_export.prometheus_text(obs_metrics.global_registry())
+    for n in names:
+        assert '\ndn_%s ' % n in text, n
+    assert 'dn_stage_ms_count{stage="scan.sparse_merge"} ' in text

@@ -237,6 +237,74 @@ def test_mesh_device_scan_program_compiles(mesh4, corpus, monkeypatch):
     assert 'all-reduce' in text
 
 
+COLLECTIVES = ('all-reduce', 'all-gather', 'all-to-all',
+               'collective-permute', 'reduce-scatter')
+
+
+def _sparse_sets(mesh, ndev, cap, ncnt=5):
+    """Shapes of a mesh's sparse accumulator: a set a chip."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    sharded = NamedSharding(mesh, P('d'))
+    return tuple(_sds((ndev, n), np.int64, sharded)
+                 for n in (cap, cap, cap, ncnt, 2))
+
+
+def test_mesh_sparse_fold_has_no_collective(corpus, monkeypatch):
+    """The cluster backend's high-cardinality fold, as the virtual
+    8-device CPU mesh compiles it: the sparse fold under shard_map, a
+    set a chip, with NO collective in the partitioned program (nothing
+    crosses the chips per batch; the sets meet at the flush)."""
+    from dragnet_tpu import device_scan
+    from dragnet_tpu.parallel import cluster
+    monkeypatch.setattr(device_scan, 'SPARSE_CAP0', 1 << 14)
+    monkeypatch.setattr(cluster.MeshDeviceScan, '_mesh_cache', None)
+    run, inputs, acc, use_pallas = _staged_program(
+        SPARSE_QUERY, corpus, scan_cls=cluster.MeshDeviceScan)
+    assert not use_pallas
+    assert [x.shape for x in acc[:3]] == [(8, 1 << 14)] * 3
+    text = run.lower(inputs, acc).compile().as_text()
+    assert 'sort' in text
+    for collective in COLLECTIVES:
+        assert collective not in text, collective
+
+
+def test_mesh_sparse_merge_compiles(mesh4):
+    """The flush's merge of the chips' sets (parallel/mesh.py
+    `sparse_merge_program`) for the four chips of a v5e 2x2, at a
+    small size: its all-gather is there, every all-reduce in it is one
+    the chip can lower (a 64-bit pmax is not: PR 35), and the merged
+    set is replicated."""
+    jax, _ = get_jax()
+    acc = _sparse_sets(mesh4, 4, 1 << 14)
+    merge = mod_mesh.sparse_merge_program(mesh4, 'd', 1 << 10, 1 << 12)
+    assert 'all-gather' in _compile(merge, acc).as_text()
+    merged = jax.eval_shape(merge, acc)
+    assert [x.shape for x in merged] == [(1 << 12,)] * 3 + [(5,), (2,)]
+
+
+@pytest.mark.slow
+def test_mesh_sparse_programs_compile_at_the_cell_size(mesh4, corpus,
+                                                       monkeypatch):
+    """The benchmark cell's shapes (muskie-30d-highcard-mesh4): the
+    fold into a set of 2^20 slots a chip, without a collective, and
+    the merge of 2^17 slots a chip into a set of 2^19.  215 s of
+    compilation here, so not among the quick tests."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from dragnet_tpu.parallel import cluster
+    monkeypatch.setattr(cluster.MeshDeviceScan, '_mesh_cache',
+                        (mesh4, 'd'))
+    run, inputs, acc, use_pallas = _staged_program(
+        SPARSE_QUERY, corpus, scan_cls=cluster.MeshDeviceScan)
+    assert not use_pallas and acc[0].shape == (4, 1 << 20)
+    sets = _sparse_sets(mesh4, 4, 1 << 20, acc[3].shape[1])
+    text = _compile(run, _like(inputs, NamedSharding(mesh4, P())),
+                    sets).as_text()
+    for collective in COLLECTIVES:
+        assert collective not in text, collective
+    merge = mod_mesh.sparse_merge_program(mesh4, 'd', 1 << 17, 1 << 19)
+    assert 'all-gather' in _compile(merge, sets).as_text()
+
+
 @pytest.mark.parametrize('scatter,use_pallas,collective', [
     (False, False, ('all-reduce',)),
     # at this accumulator size the v5e compiler lowers psum_scatter
