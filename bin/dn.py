@@ -23,9 +23,10 @@ import codecs  # noqa: E402
 def _dn_fffd(err):
     # U+FFFD when the stream encoding can take it; '?' otherwise
     # (ASCII/C-locale stdout cannot encode the replacement char itself)
-    rep = '�'
+    # (as bytes: CPython's utf-8 encoder takes no non-ASCII str from
+    # a handler, it raises "surrogates not allowed" all the same)
     try:
-        rep.encode(err.encoding)
+        rep = '�'.encode(err.encoding)
     except Exception:
         rep = '?'
     return (rep * (err.end - err.start), err.end)

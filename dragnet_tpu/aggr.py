@@ -97,6 +97,89 @@ def js_key_order(keys):
     return ints + rest
 
 
+def _object_array(values):
+    """`values` as a 1-d object array: what a code column gathers
+    through, so the exact Python objects (int against float against
+    str) reach the output."""
+    arr = np.empty(len(values), dtype=object)
+    arr[:] = values
+    return arr
+
+
+def _gather(codes, table):
+    """The column `codes` name in `table`, or `codes` themselves where
+    there is no table (rows() carries a bucketized field's ordinals)."""
+    if table is None:
+        return codes.tolist()
+    return _object_array(table)[codes].tolist()
+
+
+class PointBlock(object):
+    """A columnar result in points() emission order, without the
+    per-point dicts: per decomposition an int64 code column and the
+    table of that column's values exactly as points() emits them
+    (bucket minima for bucketized fields, the dictionary's values
+    otherwise; a table may hold values no code names), and the
+    weights as points() carries them.  output.print_points formats a
+    block by column; whoever wants the dicts asks points(), which
+    builds the list points() returned before there were blocks, once."""
+
+    __slots__ = ('names', 'codes', 'tables', 'weights', '_points')
+
+    def __init__(self, names, codes, tables, weights):
+        self.names = names
+        self.codes = codes
+        self.tables = tables
+        self.weights = weights
+        self._points = None
+
+    def __len__(self):
+        return len(self.weights)
+
+    def columns(self):
+        """The decoded key columns (point_rows()'s)."""
+        return [_gather(c, t) for c, t in zip(self.codes, self.tables)]
+
+    def points(self):
+        if self._points is None:
+            self._points = self._make_points()
+        return self._points
+
+    def _make_points(self):
+        cols_out = self.columns()
+        names = self.names
+        # literal dict construction (dict(zip(...)) costs ~2x here),
+        # and tuples built by a second zip pass rather than inside the
+        # comprehension (measured ~3x faster on CPython 3.12 at
+        # hundreds of thousands of tuples)
+        if len(names) == 1:
+            n0, = names
+            fields = [{n0: a} for a in cols_out[0]]
+        elif len(names) == 2:
+            n0, n1 = names
+            fields = [{n0: a, n1: b}
+                      for a, b in zip(cols_out[0], cols_out[1])]
+        elif len(names) == 3:
+            n0, n1, n2 = names
+            fields = [{n0: a, n1: b, n2: c} for a, b, c
+                      in zip(cols_out[0], cols_out[1], cols_out[2])]
+        else:
+            fields = [dict(zip(names, t)) for t in zip(*cols_out)]
+        return list(zip(fields, self.weights))
+
+    def text_size(self):
+        """About the characters print_points writes for this block,
+        from its arrays (the result cache's size estimate)."""
+        # {"fields":{ ... },"value":N}\n and "name":"value", a column
+        size = len(self) * (26 + sum(len(name) + 6
+                                     for name in self.names))
+        for codes, table in zip(self.codes, self.tables):
+            lens = np.fromiter((len(str(v)) for v in table),
+                               dtype=np.int64, count=len(table))
+            size += int(lens[codes].sum())
+        return size
+
+
 class Aggregator(object):
     def __init__(self, query, stage=None):
         self.decomps = [b['name'] for b in query.qc_breakdowns]
@@ -272,31 +355,42 @@ class Aggregator(object):
             seq.append(nn)
         return np.lexsort(tuple(seq))
 
+    def _columnar(self):
+        """True when the result is columnar: an engine handed it code
+        columns (set_columnar), or the flat map has reached
+        FLAT_COLUMNAR_MIN tuples and is converted here."""
+        if self._cols is None and \
+                len(self.flat) >= self.FLAT_COLUMNAR_MIN:
+            self._flat_to_columnar()
+        return self._cols is not None
+
     def _columnar_cols(self, as_rows):
-        """Ordered, decoded output columns + weights (the shared tail
-        of points()/rows()/point_rows()): bucket-min values for
-        bucketized fields unless as_rows (rows carry ordinals)."""
+        """The ordered output columns + weights (the shared tail of
+        points()/rows()/point_rows()/point_block()): per decomposition
+        the ordered code column and the table its codes index —
+        bucket-min values for bucketized fields, and no table (the
+        codes are the ordinals rows carry) when as_rows."""
         order = self._columnar_order()
-        cols_out = []
+        codes_out = []
+        tables = []
         for codes, dec, name in zip(self._cols, self._cdec,
                                     self.decomps):
             cc = codes[order]
-            if dec[0] == 'ord':
-                if as_rows:
-                    # rows carry ordinal form, not bucket-min
-                    cols_out.append(cc.tolist())
-                    continue
-                # bucket-min per unique ordinal (few), gathered through
-                # an object array so the exact Python values bucket_min
-                # returned (int vs float) survive to the output
+            if dec[0] != 'ord':
+                codes_out.append(cc)
+                tables.append(dec[1])
+            elif as_rows:
+                # rows carry ordinal form, not bucket-min
+                codes_out.append(cc)
+                tables.append(None)
+            else:
+                # bucket-min per unique ordinal (few): the exact
+                # Python values bucket_min returned (int vs float)
+                # survive to the output
                 bz = self.bucketizers[name]
                 uniq, inv = np.unique(cc, return_inverse=True)
-                mins = np.empty(len(uniq), dtype=object)
-                mins[:] = [bz.bucket_min(int(o)) for o in uniq]
-                cols_out.append(mins[inv.reshape(-1)].tolist())
-            else:
-                values = np.asarray(dec[1], dtype=object)
-                cols_out.append(values[cc].tolist())
+                codes_out.append(inv.reshape(-1))
+                tables.append([bz.bucket_min(int(o)) for o in uniq])
         if isinstance(self._cweights, list):
             # flat->columnar conversion keeps the exact stored Python
             # numbers (no f64 round trip)
@@ -312,38 +406,29 @@ class Aggregator(object):
             else:
                 weights = [int(w) if w.is_integer() else w
                            for w in wo.tolist()]
-        return cols_out, weights
+        return codes_out, tables, weights
+
+    def point_block(self):
+        """The columnar aggregate as a PointBlock (points() without
+        the per-point dicts), or None when the aggregate is not
+        columnar.  Stage counters bump as points() bumps them."""
+        if not self._columnar():
+            return None
+        codes, tables, weights = self._columnar_cols(False)
+        if self.stage is not None:
+            self.stage.bump('noutputs', len(weights))
+        return PointBlock(self.decomps, codes, tables, weights)
 
     def _columnar_points(self, as_rows):
-        cols_out, weights = self._columnar_cols(as_rows)
-        n = len(weights)
-        if not as_rows and self.stage is not None:
-            # (rows() never bumped noutputs on the flat path either)
-            self.stage.bump('noutputs', n)
-        if as_rows:
-            if not cols_out:
-                return [list(t) for t in zip(weights)]
-            return [list(t) + [w]
-                    for t, w in zip(zip(*cols_out), weights)]
-        names = self.decomps
-        # literal dict construction (dict(zip(...)) costs ~2x here),
-        # and tuples built by a second zip pass rather than inside the
-        # comprehension (measured ~3x faster on CPython 3.12 at
-        # hundreds of thousands of tuples)
-        if len(names) == 1:
-            n0, = names
-            fields = [{n0: a} for a in cols_out[0]]
-        elif len(names) == 2:
-            n0, n1 = names
-            fields = [{n0: a, n1: b}
-                      for a, b in zip(cols_out[0], cols_out[1])]
-        elif len(names) == 3:
-            n0, n1, n2 = names
-            fields = [{n0: a, n1: b, n2: c} for a, b, c
-                      in zip(cols_out[0], cols_out[1], cols_out[2])]
-        else:
-            fields = [dict(zip(names, t)) for t in zip(*cols_out)]
-        return list(zip(fields, weights))
+        if not as_rows:
+            return self.point_block().points()
+        # (rows() never bumped noutputs on the flat path either)
+        codes, tables, weights = self._columnar_cols(True)
+        if not codes:
+            return [list(t) for t in zip(weights)]
+        cols_out = [_gather(c, t) for c, t in zip(codes, tables)]
+        return [list(t) + [w]
+                for t, w in zip(zip(*cols_out), weights)]
 
     def _walk(self):
         """Yield (keys_tuple, weight) in JS property-enumeration order.
@@ -426,14 +511,9 @@ class Aggregator(object):
         directly (index_build_mt.write_index_blocks); stage counters
         bump identically to points() so --counters output is
         unchanged."""
-        if self._cols is None and \
-                len(self.flat) >= self.FLAT_COLUMNAR_MIN:
-            self._flat_to_columnar()
-        if self._cols is not None:
-            cols, weights = self._columnar_cols(False)
-            if self.stage is not None:
-                self.stage.bump('noutputs', len(weights))
-            return cols, weights
+        block = self.point_block()
+        if block is not None:
+            return block.columns(), block.weights
         if not self.decomps:
             if self.stage is not None:
                 self.stage.bump('noutputs')
@@ -454,10 +534,7 @@ class Aggregator(object):
     def points(self):
         """Aggregated points: fields carry bucket-min values for bucketized
         fields (re-ingestable), strings otherwise."""
-        if self._cols is None and \
-                len(self.flat) >= self.FLAT_COLUMNAR_MIN:
-            self._flat_to_columnar()
-        if self._cols is not None:
+        if self._columnar():
             return self._columnar_points(False)
         out = []
         if not self.decomps:
@@ -481,10 +558,7 @@ class Aggregator(object):
         """Flattened result rows in ordinal form: [key..., weight] per row,
         or a bare total when there are no decompositions (what the
         reference's SkinnerFlattener emits with resultsAsPoints:false)."""
-        if self._cols is None and \
-                len(self.flat) >= self.FLAT_COLUMNAR_MIN:
-            self._flat_to_columnar()
-        if self._cols is not None:
+        if self._columnar():
             return self._columnar_points(True)
         if not self.decomps:
             return [self.total]

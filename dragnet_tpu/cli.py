@@ -19,6 +19,7 @@ from . import hostmem as mod_hostmem
 from . import query as mod_query
 from . import output as mod_output
 from .aggr import Aggregator
+from .obs import metrics as obs_metrics
 from . import __init__ as _facade  # noqa
 from . import datasource_for_name, metrics_for_index, index_config
 
@@ -566,24 +567,32 @@ def dn_output(query, opts, result, dsname):
                              % (pp['parse_lane'], pp['reason']))
         return
 
-    points = result.points or []
-    if getattr(opts, 'points', None):
-        mod_output.print_points(points, sys.stdout)
-    else:
-        flattener = pipeline.stage('Flattener')
-        flat = Aggregator(query)
-        for fields, value in points:
-            flattener.bump('ninputs')
-            flat.write(fields, value)
-        rows = flat.rows()
-        flattener.bump('noutputs')
-
-        if getattr(opts, 'raw', None):
-            mod_output.output_raw(rows, sys.stdout)
-        elif getattr(opts, 'gnuplot', None):
-            mod_output.output_gnuplot(query, rows, dsname, sys.stdout)
+    with obs_metrics.leaf_stage('reply.format'):
+        if getattr(opts, 'points', None):
+            # a columnar result is printed by column and never
+            # becomes dicts; every other format asks `.points`
+            points = result.block
+            if points is None:
+                points = result.points or []
+            path = mod_output.print_points(points, sys.stdout)
+            obs_metrics.inc('reply_tuples_total', len(points),
+                            path=path)
         else:
-            mod_output.output_pretty(query, rows, sys.stdout)
+            flattener = pipeline.stage('Flattener')
+            flat = Aggregator(query)
+            for fields, value in result.points or []:
+                flattener.bump('ninputs')
+                flat.write(fields, value)
+            rows = flat.rows()
+            flattener.bump('noutputs')
+
+            if getattr(opts, 'raw', None):
+                mod_output.output_raw(rows, sys.stdout)
+            elif getattr(opts, 'gnuplot', None):
+                mod_output.output_gnuplot(query, rows, dsname,
+                                          sys.stdout)
+            else:
+                mod_output.output_pretty(query, rows, sys.stdout)
 
     if getattr(opts, 'counters', None):
         pipeline.dump_counters(sys.stderr)

@@ -24,7 +24,7 @@ from . import log as mod_log
 from . import query as mod_query
 from . import ingest as mod_ingest
 from . import find as mod_find
-from .aggr import Aggregator
+from .aggr import Aggregator, PointBlock
 from .scan import StreamScan
 from .vpipe import Pipeline
 from .obs import metrics as obs_metrics
@@ -39,15 +39,51 @@ def create_datasource(dsconfig):
     return DatasourceFile(dsconfig)
 
 
+def _emit_points(aggr):
+    """The aggregate's emission (ordering, decoding) as what a
+    ScanResult carries: the columnar result's PointBlock, else the
+    list of points()."""
+    with obs_metrics.leaf_stage('scan.order'):
+        block = aggr.point_block()
+        return block if block is not None else aggr.points()
+
+
 class ScanResult(object):
+    """`points` is given as the list of (fields, value) pairs or as a
+    columnar result's aggr.PointBlock.  Reading `.points` is always
+    the list: a block builds it on first use, so ask `has_points` /
+    `npoints` / `block` where the dicts are not wanted."""
+
     def __init__(self, pipeline, points=None, dry_run_files=None,
                  query=None):
         self.pipeline = pipeline
-        self.points = points
+        self._points = points
         self.dry_run_files = dry_run_files
         self.dry_run_plan = None    # cluster backend: execution plan
         self.parse_plan = None      # scan dry run: DN_PARSE lane info
         self.query = query
+
+    @property
+    def block(self):
+        p = self._points
+        return p if isinstance(p, PointBlock) else None
+
+    @property
+    def points(self):
+        p = self._points
+        return p.points() if isinstance(p, PointBlock) else p
+
+    @points.setter
+    def points(self, points):
+        self._points = points
+
+    @property
+    def has_points(self):
+        return self._points is not None
+
+    @property
+    def npoints(self):
+        return len(self._points or ())
 
     def clone_for_output(self):
         """An output-formatting view of this result with a PRIVATE
@@ -63,7 +99,7 @@ class ScanResult(object):
             stage = pl.stage(s.name)
             stage.counters = dict(s.counters)
             stage.hidden = set(s.hidden)
-        rv = ScanResult(pl, points=self.points,
+        rv = ScanResult(pl, points=self._points,
                         dry_run_files=self.dry_run_files,
                         query=self.query)
         rv.dry_run_plan = self.dry_run_plan
@@ -219,10 +255,11 @@ class DatasourceFile(object):
 
         if hasattr(scanner, 'finish'):
             scanner.finish()   # merge any device-buffered batches
-        points = scanner.aggr.points()
-        LOG.debug('scan done', npoints=len(points),
+        result = ScanResult(pipeline, points=_emit_points(scanner.aggr),
+                            query=query)
+        LOG.debug('scan done', npoints=result.npoints,
                   engine=type(scanner).__name__)
-        return ScanResult(pipeline, points=points, query=query)
+        return result
 
     def _make_parser(self, lane, paths, hints, dicts, parser_stage):
         """Instantiate the selected ingest parser: the byte lane
@@ -1100,7 +1137,7 @@ class DatasourceFile(object):
                 return mod_iqmt._query_shard_cached(path, q)
 
             mod_rollup.execute_plan(plan, query, query_one, merge)
-            return ScanResult(pipeline, points=aggr.points(),
+            return ScanResult(pipeline, points=_emit_points(aggr),
                               query=query)
 
         # Stacked cross-shard execution (index_query_stack, default):
@@ -1120,7 +1157,8 @@ class DatasourceFile(object):
         if not stacked:
             mod_iqmt.run_shard_queries(paths, query, nworkers, merge)
 
-        return ScanResult(pipeline, points=aggr.points(), query=query)
+        return ScanResult(pipeline, points=_emit_points(aggr),
+                          query=query)
 
 
 class _RunAhead(object):

@@ -14,7 +14,12 @@ Byte-compatible with the reference CLI's output layer (bin/dn:924-1274):
 * gnuplot: single-breakdown plots, time-axis aware.
 """
 
+import itertools
+
+import numpy as np
+
 from . import jsvalues as jsv
+from .aggr import PointBlock
 
 
 def js_round(x):
@@ -25,9 +30,68 @@ def js_round(x):
 
 
 def print_points(points, out):
+    """One JSON line a point.  `points` is the list of (fields, value)
+    pairs, or the aggr.PointBlock of a columnar result, which is
+    formatted by column (_block_text) into the same characters.
+    Returns the path that wrote them: 'block' or 'tuple'."""
+    if isinstance(points, PointBlock):
+        text = _block_text(points)
+        if text is not None:
+            out.write(text)
+            return 'block'
+        points = points.points()
     for fields, value in points:
         out.write(jsv.json_stringify({'fields': fields, 'value': value})
                   + '\n')
+    return 'tuple'
+
+
+def _fragments(prefix, table, codes, suffix=''):
+    """Per row of `codes` the text prefix + json_stringify(table[code])
+    + suffix, each distinct value that a code names stringified once;
+    None where a value has no JSON text (UNDEFINED)."""
+    codes = np.asarray(codes, dtype=np.intp)
+    used = np.zeros(len(table), dtype=bool)
+    used[codes] = True
+    frags = np.empty(len(table), dtype=object)
+    for i in np.flatnonzero(used).tolist():
+        text = jsv.json_stringify(table[i])
+        if text is None:
+            return None
+        frags[i] = prefix + text + suffix
+    return frags[codes].tolist()
+
+
+def _block_text(block):
+    """The characters the per-point loop of print_points writes for
+    block.points(), made by column: json_stringify once per distinct
+    value (so every escape and number form is that loop's: the same
+    function on the same object), the constant text around a value
+    joined to it once, the fragments gathered through the code
+    columns, one flat join.  None where this is not the loop's text
+    (no columns, or two of one name, which are one key of the loop's
+    dict; a value the loop would omit with its key): the caller then
+    runs the loop."""
+    if not block.names or len(set(block.names)) < len(block.names):
+        return None
+    if not len(block):
+        return ''
+    columns = []
+    opening = '{"fields":{"'
+    for name, codes, table in zip(block.names, block.codes,
+                                  block.tables):
+        columns.append(_fragments(
+            opening + jsv._json_escape(name) + '":', table, codes))
+        opening = ',"'
+    # weights equal as numbers have one text (number_to_string knows
+    # no int from float), so the first of each stands for the others
+    index = {}
+    wcodes = [index.setdefault(w, len(index)) for w in block.weights]
+    columns.append(_fragments('},"value":', list(index), wcodes,
+                              '}\n'))
+    if any(col is None for col in columns):
+        return None
+    return ''.join(itertools.chain.from_iterable(zip(*columns)))
 
 
 def output_raw(rows, out):

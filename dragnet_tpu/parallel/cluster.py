@@ -185,7 +185,7 @@ class DatasourceCluster(datasource_file.DatasourceFile):
             result.dry_run_plan = self.execution_plan(
                 result.dry_run_files)
             return result
-        if nprocs <= 1 or result.points is None:
+        if nprocs <= 1 or not result.has_points:
             return result
         result.points = _allgather_merge_points(query, result.points)
         return result
@@ -203,7 +203,7 @@ class DatasourceCluster(datasource_file.DatasourceFile):
             metrics, interval, filter=filter, time_after=time_after,
             time_before=time_before, warn_func=warn_func)
         nprocs, pid = mod_dist.maybe_initialize()
-        if nprocs <= 1 or result.points is None:
+        if nprocs <= 1 or not result.has_points:
             return result
         result.points = _allgather_merge_tagged(result.points)
         return result
@@ -232,7 +232,7 @@ class DatasourceCluster(datasource_file.DatasourceFile):
             result.dry_run_plan = self.execution_plan(
                 result.dry_run_files)
             return result
-        if nprocs <= 1 or result.points is None:
+        if nprocs <= 1 or not result.has_points:
             return result
         result.points = _allgather_merge_points(query, result.points)
         return result

@@ -48,7 +48,12 @@ def _estimate_nbytes(result):
     against."""
     n = 256
     try:
-        if result.points is not None:
+        block = getattr(result, 'block', None)
+        if block is not None:
+            # a columnar result is sized from its arrays: the dicts
+            # stay unbuilt until somebody asks for them
+            n += block.text_size()
+        elif result.points is not None:
             n += len(json.dumps(result.points, default=repr))
         if result.dry_run_files is not None:
             n += sum(len(p) + 16 for p in result.dry_run_files)

@@ -94,7 +94,9 @@ _SERVER_LEAKS = LeakCheck(
 # -- output-encoding parity with bin/dn.py ----------------------------------
 
 def _dn_fffd(err):
-    return ('�' * (err.end - err.start), err.end)
+    # as bytes: CPython's utf-8 encoder takes no non-ASCII str from a
+    # handler (it raises "surrogates not allowed" all the same)
+    return (b'\xef\xbf\xbd' * (err.end - err.start), err.end)
 
 
 def output_errors():
@@ -1643,6 +1645,7 @@ class DnServer(object):
             rc = job()
 
         out, err = cap.finish()
+        obs_ctx.registry.inc('reply_bytes_total', len(out))
         if rc != 0:
             self._bump('errors')
         elif op in ('scan', 'query', 'build', 'query_partial'):

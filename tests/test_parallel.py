@@ -595,3 +595,50 @@ def test_sparse_merge_counters_and_leaf_at_a_scrape(tmp_path, monkeypatch):
     for n in names:
         assert '\ndn_%s ' % n in text, n
     assert 'dn_stage_ms_count{stage="scan.sparse_merge"} ' in text
+
+
+MESH4_SERVE = r'''
+import json, os, sys
+sys.path.insert(0, %(root)r)
+sys.path.insert(0, os.path.join(%(root)r, 'tests'))
+import test_serve as t
+from dragnet_tpu.serve import server as mod_server
+work = sys.argv[1]
+os.environ['DRAGNET_CONFIG'] = os.path.join(work, 'rc.json')
+os.environ['DN_ENGINE'] = 'jax'
+ds = t.add_wide_datasource(work, 'ds_mesh', backend='cluster')
+srv = mod_server.DnServer(socket_path=os.path.join(work, 's.sock'),
+                          conf=t._conf()).start()
+try:
+    t.check_wide_reply(srv.socket_path, ds)
+    os.environ['DN_COUNTERS_ALL'] = '1'     # the engine's own counters
+    rc, out, err = t.run_cli(['scan', '--remote', srv.socket_path,
+                              '--points', '--counters', '-b', 'host,seq',
+                              ds])
+finally:
+    srv.stop()
+import jax
+print(json.dumps({'devices': len(jax.devices()), 'lines': out.count(b'\n'),
+                  'counters': err.decode()}))
+'''
+
+
+def test_columnar_reply_on_the_cluster_backend_over_four_devices(tmp_path):
+    """test_serve.check_wide_reply (a scan of 9,000 tuples through `dn
+    serve` equals the local CLI's bytes, goes out by column and builds
+    no dicts) on `--backend=cluster` with the device engine forced,
+    in a process of its own with four host devices: the mesh cell's
+    shape."""
+    import json
+    import subprocess
+    env = dict(os.environ, JAX_PLATFORMS='cpu',
+               XLA_FLAGS='--xla_force_host_platform_device_count=4')
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    p = subprocess.run(
+        [sys.executable, '-c', MESH4_SERVE % {'root': root},
+         str(tmp_path)], env=env, stdout=subprocess.PIPE,
+        stderr=subprocess.PIPE, timeout=300)
+    assert p.returncode == 0, p.stderr.decode()[-4000:]
+    doc = json.loads(p.stdout.decode().splitlines()[-1])
+    assert doc['devices'] == 4 and doc['lines'] == 9000
+    assert 'ndevicebatches' in doc['counters']

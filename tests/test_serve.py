@@ -506,6 +506,79 @@ def test_metrics_scrape_carries_the_process_memory(server, corpus):
         first['dn_process_minor_faults_total'] + 1024
 
 
+# -- a columnar result's reply: formatted by column, never as dicts -----------
+
+WIDE_TUPLES = 9000          # past Aggregator.FLAT_COLUMNAR_MIN (8,192)
+
+
+def add_wide_datasource(root, name='ds_wide', backend=None):
+    """A datasource whose scan by `host,seq` answers WIDE_TUPLES tuples
+    (every record its own, some values in need of escapes), under the
+    DRAGNET_CONFIG of the moment."""
+    datafile = os.path.join(str(root), name + '.log')
+    with open(datafile, 'w') as f:
+        for i in range(WIDE_TUPLES):
+            f.write(json.dumps({
+                'host': 'h%d "q\\' % (i % 90), 'seq': i // 90,
+                'latency': i % 50}, separators=(',', ':')) + '\n')
+    args = ['datasource-add', '--path', datafile]
+    if backend is not None:
+        args.append('--backend=' + backend)
+    rc, out, err = run_cli(args + [name])
+    assert rc == 0, err
+    return name
+
+
+def scrape_counters(sock):
+    """The reply's counters at a scrape (0 where one has not counted)."""
+    rc, hd, out, err = mod_client.request_bytes(sock, {'op': 'metrics'})
+    assert rc == 0, err
+    doc = dict(ln.rsplit(' ', 1) for ln in out.decode().splitlines()
+               if not ln.startswith('#'))
+    return {k: float(doc.get(k, 0)) for k in (
+        'dn_reply_tuples_total{path="block"}',
+        'dn_reply_tuples_total{path="tuple"}', 'dn_reply_bytes_total')}
+
+
+def check_wide_reply(sock, ds):
+    """A scan of WIDE_TUPLES tuples through `dn serve` (in this
+    process) against the local CLI: the same stdout and stderr; the
+    reply went out by column (`reply_tuples_total{path="block"}` grew
+    by its lines, `{path="tuple"}` by none) and the dicts were never
+    built; `--raw` over such a result still builds them."""
+    from dragnet_tpu.aggr import Aggregator
+    args = ['--points', '--counters', '-b', 'host,seq', ds]
+    expected = run_cli(['scan'] + args)
+    assert expected[0] == 0, expected[2]
+    lines = expected[1].count(b'\n')
+    assert lines == WIDE_TUPLES
+    before = scrape_counters(sock)
+
+    def no_dicts(self, as_rows):
+        raise AssertionError('the dicts were asked for')
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(Aggregator, '_columnar_points', no_dicts)
+        mp.setattr('dragnet_tpu.aggr.PointBlock._make_points', no_dicts)
+        remote = run_cli(['scan', '--remote', sock] + args)
+    after = scrape_counters(sock)
+    assert remote == expected
+    grew = {k: after[k] - before[k] for k in before}
+    assert grew == {'dn_reply_tuples_total{path="block"}': lines,
+                    'dn_reply_tuples_total{path="tuple"}': 0,
+                    'dn_reply_bytes_total': len(expected[1])}
+    raw = ['--raw', '-b', 'host,seq', ds]
+    assert run_cli(['scan', '--remote', sock] + raw) == \
+        run_cli(['scan'] + raw)
+    assert scrape_counters(sock)[
+        'dn_reply_tuples_total{path="block"}'] == after[
+        'dn_reply_tuples_total{path="block"}']
+
+
+def test_columnar_reply_goes_out_by_column(server, corpus):
+    check_wide_reply(server.socket_path,
+                     add_wide_datasource(corpus['root']))
+
+
 def test_request_counters_attribute_across_pool_threads(
         server, corpus, monkeypatch):
     """On the per-shard pool path (DN_IQ_STACK=0, DN_IQ_THREADS>0)

@@ -38,10 +38,13 @@ FOLD_STAGES = ('index_fold.stage', 'index_fold.dispatch',
                'index_fold.device_wait', 'index_fold.fetch')
 QUERY_STAGES = ('index_query_stack.load',
                 'index_query_stack.sort') + FOLD_STAGES
+# the request thread's last two: the aggregate's emission (the order,
+# the decode) and the formatting of the reply
+REPLY_STAGES = ('scan.order', 'reply.format')
 # which request must have met which leaves
-LEAVES = {'scan': SCAN_STAGES, 'build': SCAN_STAGES,
-          'query': QUERY_STAGES}
-ALL_LEAVES = set(SCAN_STAGES + QUERY_STAGES)
+LEAVES = {'scan': SCAN_STAGES + REPLY_STAGES, 'build': SCAN_STAGES,
+          'query': QUERY_STAGES + REPLY_STAGES}
+ALL_LEAVES = set(SCAN_STAGES + QUERY_STAGES + REPLY_STAGES)
 
 NRECORDS = 3000
 SMALL_BATCH = 512
@@ -249,6 +252,25 @@ def test_producer_stages_are_tagged_with_their_thread(runs, op):
         assert threads[stage] == {PRODUCER_THREAD}
     for stage in set(SCAN_STAGES) - set(PRODUCER_STAGES):
         assert threads[stage] == {None}
+
+
+@pytest.mark.parametrize('op', ['scan', 'query'])
+@pytest.mark.parametrize('stage', REPLY_STAGES)
+def test_reply_stages_are_the_request_threads(runs, op, stage):
+    """`scan.order` and `reply.format` are once in the request's tree,
+    on its own thread (no tag), inside the root span, the order
+    before the format."""
+    root = runs[op]['doc']['spans']
+    found = []
+    _walk(root, lambda span, parent: found.append(span)
+          if span['name'] in REPLY_STAGES else None)
+    assert [sp['name'] for sp in found] == list(REPLY_STAGES)
+    span = found[REPLY_STAGES.index(stage)]
+    assert span.get('thread') is None
+    assert span['t0_ms'] >= root['t0_ms'] - 0.01
+    assert span['t0_ms'] + span['dur_ms'] <= \
+        root['t0_ms'] + root['dur_ms'] + 0.01
+    assert runs[op]['stages'][stage][0] == 1
 
 
 # -- (3) the profiler leg ----------------------------------------------------
