@@ -90,30 +90,33 @@ def make_corpus(ctx):
         cols, {'host': corpus.HOSTS, 'method': corpus.METHODS,
                'op': corpus.OPERATIONS}))
 
-    dsconf = ctx.config['datasource']
-
-    def datasource(name, with_index):
-        bc = {'path': path, 'timeField': dsconf['timeField']}
-        if with_index:
-            bc['indexPath'] = os.path.join(ctx.run_dir, 'idx', name)
-        return {'name': name, 'backend': dsconf['backend'],
-                'backend_config': bc, 'filter': None,
-                'dataFormat': dsconf['dataFormat']}
-
-    # one datasource the scans and queries name, and as many more as
-    # the window may build: each build goes into a tree of its own
-    ctx.datasource = 'muskie'
-    ctx.build_trees = ['muskie_b%d' % i
-                       for i in range(ctx.workload.get('build_trees', 0))]
-    indexed = dsconf['backend'] == 'file'
-    names = [ctx.datasource] + ctx.build_trees
-    doc = {'vmaj': 0, 'vmin': 0,
-           'datasources': [datasource(n, indexed) for n in names],
-           'metrics': [dict(m, datasource=n) for n in names
-                       for m in ctx.config['metrics']] if indexed else []}
+    doc = run_document(ctx.config, ctx.workload, ctx.run_dir, path)
+    names = [d['name'] for d in doc['datasources']]
+    ctx.datasource, ctx.build_trees = names[0], names[1:]
     ctx.rc_path = os.path.join(ctx.run_dir, 'dragnetrc.json')
     with open(ctx.rc_path, 'w') as f:
         json.dump(doc, f)
+
+
+def run_document(config, workload, run_dir, corpus_path):
+    """The dragnet config of a run: the datasource `muskie`, which the
+    scans and queries name, and one `muskie_b<i>` for each of the
+    workload's `build_trees` (each build goes into a tree of its own),
+    every one with an index path of its own and the configuration's
+    metrics, whatever the backend: a cluster datasource builds too."""
+    dsconf = config['datasource']
+    names = ['muskie'] + ['muskie_b%d' % i
+                          for i in range(workload.get('build_trees', 0))]
+    return {'vmaj': 0, 'vmin': 0,
+            'datasources': [
+                {'name': n, 'backend': dsconf['backend'],
+                 'backend_config': {
+                     'path': corpus_path, 'timeField': dsconf['timeField'],
+                     'indexPath': os.path.join(run_dir, 'idx', n)},
+                 'filter': None, 'dataFormat': dsconf['dataFormat']}
+                for n in names],
+            'metrics': [dict(m, datasource=n) for n in names
+                        for m in config['metrics']]}
 
 
 def prebuild_index(ctx):

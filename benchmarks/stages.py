@@ -13,12 +13,16 @@ request of the family finished.
 
 import readers
 
-# the request thread's leaves.  `scan.parse` and `scan.read` are not
-# among them: since PR 30 they run on a producer thread beside these,
-# so the sum of all leaves may pass the request
+# the request thread's leaves, which do not overlap: the scan's own,
+# on a mesh's sparse lane the merge of the chips' sets (PR 35), and the
+# reply's two (PR 37: the aggregate's emission in output order, the
+# formatting of the lines).  `scan.parse` and `scan.read` are not among
+# them: since PR 30 they run on a producer thread beside these, so the
+# sum of all leaves may pass the request
 SCAN_THREAD = ('scan.parse_wait', 'scan.stage', 'scan.upload',
                'scan.dispatch', 'scan.device_wait', 'scan.fetch',
-               'scan.emit')
+               'scan.emit', 'scan.sparse_merge', 'scan.order',
+               'reply.format')
 BUILD_THREAD = SCAN_THREAD + ('index_build.prepare', 'index_build.commit')
 QUERY_THREAD = ('index_query_stack.load', 'index_query_stack.sort',
                 'index_fold.stage', 'index_fold.dispatch',

@@ -325,6 +325,30 @@ def test_trace_reduction_arithmetic():
     assert got['breakdown']['idle_gaps'][0] == [
         'host: nothing traced for most of it (emit (worker) covers 8%)',
         pytest.approx(500e-9)]
+    # two leaves of a third each (a reply's order and its formatting
+    # between two scans) name the gap together, the larger first; a
+    # third that would not be needed to reach half is not listed
+    lines = doc['planes'][1]['lines']
+    lines[0]['events'] += [ev('scan.order', 520, 180),
+                           ev('scan.order', 980, 5),
+                           ev('reply.format', 705, 195),
+                           ev('socket', 900, 60)]
+    got = trace_reduce.reduce_events(doc)
+    assert got['breakdown']['idle_gaps'][0] == [
+        'reply.format 39% + scan.order 37%', pytest.approx(500e-9)]
+    # without the larger leaf the rest still reach half, the spans at
+    # the gap's edges with them: every one is listed
+    lines[0]['events'] = [e for e in lines[0]['events']
+                          if e[0] != 'reply.format']
+    got = trace_reduce.reduce_events(doc)
+    assert got['breakdown']['idle_gaps'][0] == [
+        'scan.order 37% + socket 12% + emit 8%', pytest.approx(500e-9)]
+    # and where all of them together do not reach half, as before
+    lines[0]['events'] = [e for e in lines[0]['events'] if e[0] != 'socket']
+    got = trace_reduce.reduce_events(doc)
+    assert got['breakdown']['idle_gaps'][0] == [
+        'host: nothing traced for most of it (scan.order (worker) covers '
+        '36%)', pytest.approx(500e-9)]
 
 
 def test_benchmark_json_matches_the_files():

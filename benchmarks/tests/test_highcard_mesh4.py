@@ -87,23 +87,24 @@ def test_the_cell_says_what_its_twin_says():
 
 
 def test_benchmark_json_holds_the_cell():
+    """The cell, its configuration and its four metrics, found by name:
+    a later PR appends to every list."""
     with open(os.path.join(tb.ROOT, 'BENCHMARK.json')) as f:
         doc = json.load(f)
-    cell = doc['workloads'][-1]
-    assert (cell['name'], cell['chips'], cell['traffic']) == \
-        (CELL, 4, 'scan-highcard')
-    assert doc['configs'][-1]['name'] == cell['config']
-    assert doc['configs'][-1]['reduced'] == ['records']
-    assert len(doc['configs'][-1]['source']) <= 200
+    (cell,) = [w for w in doc['workloads'] if w['name'] == CELL]
+    assert (cell['chips'], cell['traffic']) == (4, 'scan-highcard')
+    (config,) = [c for c in doc['configs'] if c['name'] == cell['config']]
+    assert config['reduced'] == ['records']
+    assert len(config['source']) <= 200
     four = [w['name'] for w in doc['workloads'] if w['chips'] == 4]
-    assert len(four) == 2 and 2 * len(four) <= len(doc['workloads'])
-    assert [m['name'] for m in doc['per_layer'][-4:]] == list(NEW_METRICS)
-    for m in doc['per_layer'][-4:]:
-        assert m['workloads'] == [CELL]
-        assert m['moves'] == 'scan_records_per_s'
-    rates = [m for m in doc['end_to_end']
-             if m['name'] == 'scan_records_per_s'][0]
-    assert rates['workloads'][-1] == CELL and rates['bound'] == 0.06
+    assert CELL in four and 2 * len(four) <= len(doc['workloads'])
+    layers = {m['name']: m for m in doc['per_layer']}
+    for name in NEW_METRICS:
+        assert layers[name]['workloads'] == [CELL]
+        assert layers[name]['moves'] == 'scan_records_per_s'
+    (rates,) = [m for m in doc['end_to_end']
+                if m['name'] == 'scan_records_per_s']
+    assert CELL in rates['workloads'] and rates['bound'] == 0.06
 
 
 def test_key32_fails_on_the_cells_query(medium):         # noqa: F811

@@ -86,8 +86,8 @@ def load_events(trace_dir):
     return {'planes': planes}
 
 
-def union_s(intervals):
-    """Seconds covered by a list of (start_ns, end_ns)."""
+def union_ns(intervals):
+    """Nanoseconds covered by a list of (start_ns, end_ns)."""
     total, cur_s, cur_e = 0, None, None
     for s, e in sorted(intervals):
         if cur_e is None or s > cur_e:
@@ -98,7 +98,11 @@ def union_s(intervals):
             cur_e = e
     if cur_e is not None:
         total += cur_e - cur_s
-    return total / 1e9
+    return total
+
+
+def union_s(intervals):
+    return union_ns(intervals) / 1e9
 
 
 def gaps(intervals, t0, t1):
@@ -112,6 +116,36 @@ def gaps(intervals, t0, t1):
     if at < t1:
         out.append((at, t1))
     return [g for g in out if g[1] > g[0]]
+
+
+def name_gap(g0, g1, host):
+    """What the host was doing over the idle gap [g0, g1]: the host
+    event that covers most of it, if it covers half.  Where none does,
+    the events that cover half of it together, by name, largest first,
+    each with its share (a gap between two scans holds two reply leaves
+    of a third each); where those do not reach half either, nothing was
+    traced for most of it: a span at the gap's edge is not what the host
+    was doing.  `host` is [(thread, [name, start_ns, dur_ns])]."""
+    best, cover, by_name = 'host: nothing traced', 0, {}
+    for thread, (name, s, dur) in host:
+        s, e = max(g0, s), min(g1, s + dur)
+        if e <= s:
+            continue
+        by_name.setdefault(name, []).append((s, e))
+        if e - s > cover:
+            best, cover = '%s (%s)' % (name, thread), e - s
+    if not 0 < 2 * cover < g1 - g0:
+        return best
+    together, parts = [], []
+    for covered, name in sorted(((union_ns(spans), name)
+                                 for name, spans in by_name.items()),
+                                key=lambda x: (-x[0], x[1])):
+        together += by_name[name]
+        parts.append('%s %d%%' % (name, 100 * covered // (g1 - g0)))
+        if 2 * union_ns(together) >= g1 - g0:
+            return ' + '.join(parts)
+    return 'host: nothing traced for most of it (%s covers %d%%)' \
+        % (best[:60], 100 * cover // (g1 - g0))
 
 
 def reduce_events(doc):
@@ -159,21 +193,11 @@ def reduce_events(doc):
     nchips = max(1, len(chips))
     device_ops = sorted(([n, s / nchips] for n, s in op_total.items()),
                         key=lambda x: -x[1])[:TOP]
-    # the longest idle gaps of the first chip, each named by the host
-    # event that covers most of it, if one covers half of it
-    idle = []
-    for g0, g1 in sorted(chips[0]['gaps'] if chips else [],
-                         key=lambda g: g[0] - g[1])[:TOP]:
-        best, cover = 'host: nothing traced', 0
-        for thread, (name, s, dur) in host:
-            c = min(g1, s + dur) - max(g0, s)
-            if c > cover:
-                best, cover = '%s (%s)' % (name, thread), c
-        if 0 < 2 * cover < g1 - g0:
-            # a span at the gap's edge is not what the host was doing
-            best = 'host: nothing traced for most of it (%s covers %d%%)' \
-                % (best[:60], 100 * cover // (g1 - g0))
-        idle.append([best[:120], (g1 - g0) / 1e9])
+    # the longest idle gaps of the first chip, each named by what the
+    # host was doing
+    idle = [[name_gap(g0, g1, host)[:120], (g1 - g0) / 1e9]
+            for g0, g1 in sorted(chips[0]['gaps'] if chips else [],
+                                 key=lambda g: g[0] - g[1])[:TOP]]
     for c in chips:
         del c['gaps']
     return {'window_s': window_s,
