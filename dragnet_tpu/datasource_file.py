@@ -650,8 +650,9 @@ class DatasourceFile(object):
 
         # stacked multi-metric device program: all metrics fold in ONE
         # dispatch per batch with shared columns uploaded once (SURVEY
-        # §7.7); None when the scanners don't support it (host engine,
-        # mesh subclass, single metric) — then the per-scan loop runs
+        # §7.7), on the cluster backend's mesh as off it; None when the
+        # scanners don't support it (host engine, single metric) — then
+        # the per-scan loop runs
         from . import device_scan as mod_device_scan
         stack = mod_device_scan.make_stack(scanners) \
             if scan_cls is not VectorScan else None

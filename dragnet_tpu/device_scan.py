@@ -38,6 +38,7 @@ DeviceScan == VectorScan == StreamScan.
 """
 
 import collections
+import re
 import threading
 import time
 
@@ -506,11 +507,6 @@ class DeviceScan(VectorScan):
     # this scanner take the stream over mid-flight (auto mode only;
     # forced mode owns the stream from the first batch)
     AUTO_STREAM = False
-
-    # whether DeviceScanStack may fuse this scan into a combined
-    # multi-metric program (the mesh subclass opts out: its shard_map
-    # spec derivation assumes unprefixed input names)
-    STACKABLE = True
 
     def __init__(self, query, time_field, pipeline, ds_filter=None):
         VectorScan.__init__(self, query, time_field, pipeline,
@@ -1994,28 +1990,11 @@ class DeviceScan(VectorScan):
         ncnt = len(self._counter_spec)
         acc_ns = max(ns, 1)
 
-        per_record_keys = ('alive', 'weights', 'terr')
-        per_record_prefixes = ('tags_', 'str_', 'num_', 'ts_', 'kv_',
-                               'kvalid_', 'key_', 'tsf_', 'terr_')
-
-        def record_specs(args):
-            from jax.sharding import PartitionSpec as SP
-            specs = {}
-            for k in args:
-                if k == pfx + 'base':
-                    continue
-                if k in per_record_keys or \
-                        k.startswith(per_record_prefixes):
-                    specs[k] = SP(maxis)
-                else:
-                    specs[k] = SP()   # lookup tables: replicated
-            return specs
-
         def run_body(args, use_pallas):
             if mesh is None:
                 return body(args, use_pallas)
             from jax.sharding import PartitionSpec as SP
-            specs = record_specs(args)
+            specs = record_specs(args, pfx, maxis)
             sargs = {k: args[k] for k in specs}
             return jax.shard_map(
                 lambda a: body(a, use_pallas), mesh=mesh,
@@ -2062,7 +2041,7 @@ class DeviceScan(VectorScan):
             chip would have given it; the chips' sets meet at the
             flush (_merge_sparse)."""
             from jax.sharding import PartitionSpec as SP
-            specs = record_specs(args)
+            specs = record_specs(args, pfx, maxis)
             specs[pfx + 'base'] = SP()
             sargs = {k: args[k] for k in specs}
 
@@ -2375,6 +2354,43 @@ def _note_h2d(nbytes):
         obs_metrics.inc('device_h2d_bytes', int(nbytes))
 
 
+_PER_RECORD_KEYS = ('alive', 'weights', 'terr')
+_PER_RECORD_PREFIXES = ('tags_', 'str_', 'num_', 'ts_', 'kv_', 'kvalid_',
+                        'key_', 'tsf_', 'terr_')
+_STACK_PFX = re.compile(r'm\d+_')    # DeviceScanStack's 'm<i>_'
+
+
+def record_specs(args, pfx, axis):
+    """The shard_map partition specs of one mesh scan's inputs, by
+    key: the keys of `args` (a batch's staged inputs, under a
+    DeviceScanStack every scan's in one dict) that are this scan's to
+    read, each sharded over `axis` where _stage_device wrote a value a
+    record, replicated where it wrote a lookup table or a scalar.  A
+    scan reads the shared parser columns, which carry no prefix, and
+    the keys under its own `pfx`; a sibling's 'm<j>_...' keys are left
+    out.  What a key holds is told from its name with the scan's own
+    prefix taken off, so a stacked scan's 'm1_key_x' is what a single
+    scan's 'key_x' is.  The batch base (`pfx + 'base'`) is not listed:
+    the dense fold reads it outside the shard_map."""
+    from jax.sharding import PartitionSpec as SP
+    specs = {}
+    for k in args:
+        if pfx and k.startswith(pfx):
+            name = k[len(pfx):]
+        elif pfx and _STACK_PFX.match(k):
+            continue
+        else:
+            name = k
+        if name == 'base':
+            continue
+        if name in _PER_RECORD_KEYS or \
+                name.startswith(_PER_RECORD_PREFIXES):
+            specs[k] = SP(axis)
+        else:
+            specs[k] = SP()   # lookup tables, nvalid: replicated
+    return specs
+
+
 def _upload_batch(inputs, mesh):
     """The scan.upload stage of one batch: its host arrays to the
     device (in place), counted in `device_h2d_bytes`; returns the
@@ -2635,7 +2651,10 @@ class DeviceScanStack(object):
     Scans keep their own accumulators/flush/emission; the stack only
     changes how batches are staged and dispatched, so per-scan results
     (and the index artifacts) are byte-identical to the unstacked
-    path."""
+    path.  On the cluster backend's mesh it is the same stack: each
+    scan's fold is its own shard_map (record_specs picks its keys out
+    of the merged dict), its merges (psum+pmin, or the sparse sets'
+    all-gather at a flush) stay its own."""
 
     def __init__(self, scans):
         self.scans = list(scans)
@@ -2644,7 +2663,6 @@ class DeviceScanStack(object):
         shared = {'w1': True, 'gen_alive': True, 'filter': {},
                   'kvalid': {}, 'dtypes': {}}
         for i, s in enumerate(self.scans):
-            assert getattr(s, 'STACKABLE', False)
             s._pfx = 'm%d_' % i
             s._sticky = shared
         self._nbatch = 0
@@ -2751,12 +2769,21 @@ class DeviceScanStack(object):
             jax, jnp = get_jax()
             folds = [p[0] for p in parts]
             ups = [p[1] for p in parts]
+            on_mesh = scans[0]._device_mesh() is not None
 
             def stacked(args, accs):
                 outs = tuple(f(args, a, u)
                              for f, a, u in zip(folds, accs, ups))
                 # one fresh, non-donated completion token for the
                 # whole stacked batch (see DeviceScan._note_dispatch)
+                if on_mesh:
+                    # a token a scan, and for a sparse set (its leaves
+                    # carry the chips on a leading axis) one a chip:
+                    # summed into one scalar they would be a collective
+                    # a batch, which run_sparse keeps the sets free of
+                    return outs, tuple(
+                        jnp.sum(o[-1], axis=-1).astype(jnp.int32)
+                        for o in outs)
                 tok = jnp.int32(0)
                 for o in outs:
                     tok = tok + jnp.sum(o[-1]).astype(jnp.int32)
@@ -2770,7 +2797,7 @@ class DeviceScanStack(object):
 
 def make_stack(scanners):
     """A DeviceScanStack when the scanner set supports it (>=2 device
-    scans outside a mesh), else None (callers keep the per-scan
+    scans, on a mesh or off it), else None (callers keep the per-scan
     loop).  DN_STACK=0 disables stacking (operational escape hatch:
     per-scan programs still run)."""
     import os
@@ -2778,8 +2805,7 @@ def make_stack(scanners):
         return None
     if len(scanners) < 2:
         return None
-    if not all(isinstance(s, DeviceScan) and
-               getattr(s, 'STACKABLE', False) for s in scanners):
+    if not all(isinstance(s, DeviceScan) for s in scanners):
         return None
     return DeviceScanStack(scanners)
 

@@ -73,7 +73,6 @@ class MeshDeviceScan(DeviceScan, MeshVectorScan):
 
     ESCALATE_RECORDS = 0          # cluster mode is explicitly sharded
     REQUIRE_ACCELERATOR = False   # the CPU test mesh is a valid target
-    STACKABLE = False             # shard_map specs assume unprefixed keys
 
     _mesh_cache = None
 

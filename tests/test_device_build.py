@@ -48,11 +48,11 @@ METRICS = [
 ]
 
 
-def _write_data(path, n, with_edges=False):
+def _write_data(path, n, with_edges=False, days=3):
     rng = random.Random(7)
     lines = []
     for i in range(n):
-        day = 1 + (i * 3 // n)
+        day = 1 + (i * days // n)
         lines.append(json.dumps({
             'time': '2014-05-%02dT%02d:%02d:%02dZ' % (
                 day, rng.randrange(24), rng.randrange(60),

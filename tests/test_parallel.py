@@ -239,7 +239,7 @@ def test_cluster_dry_run_plan(tmp_path, capsys):
     assert inputs.splitlines() == [str(datadir / 'a.log')]
 
 
-def _mesh_scan_setup(monkeypatch, read=4096, cap0=4096):
+def _mesh_scan_setup(monkeypatch, read=4096, cap0=4096, dense=64):
     """Small batches (128 records), a small dense budget (64 segments)
     and a small first set for a forced scan on the cluster backend;
     returns the StringIO that device_scan's debug records (the kernel
@@ -248,8 +248,8 @@ def _mesh_scan_setup(monkeypatch, read=4096, cap0=4096):
     from dragnet_tpu import log as mod_log
     from dragnet_tpu import device_scan
     import dragnet_tpu.engine as eng
-    monkeypatch.setattr(eng, 'MAX_DENSE_SEGMENTS', 64)
-    monkeypatch.setattr(device_scan, 'MAX_DENSE_SEGMENTS', 64)
+    monkeypatch.setattr(eng, 'MAX_DENSE_SEGMENTS', dense)
+    monkeypatch.setattr(device_scan, 'MAX_DENSE_SEGMENTS', dense)
     monkeypatch.setattr(device_scan, 'SPARSE_CAP0', cap0)
     monkeypatch.setattr(eng, 'BATCH_SIZE', 128)
     monkeypatch.setattr(device_scan, 'BATCH_SIZE', 128)
@@ -534,7 +534,6 @@ def test_cluster_build_equals_file_build(tmp_path, monkeypatch):
     record)."""
     import test_device_build as tdb
     from dragnet_tpu import native as mod_native
-    from dragnet_tpu.parallel import cluster
 
     if mod_native.get_lib() is None:
         pytest.skip('native parser unavailable')
@@ -545,22 +544,288 @@ def test_cluster_build_equals_file_build(tmp_path, monkeypatch):
     buf = _mesh_scan_setup(monkeypatch, read=16384)
     monkeypatch.setenv('DN_PARSE_THREADS', '1')
     folded0 = _counter('device_sparse_fold_batches')
-    ds = cluster.DatasourceCluster({
-        'ds_backend': 'cluster',
-        'ds_backend_config': {'path': str(datafile),
-                              'indexPath': str(tmp_path / 'icluster'),
-                              'timeField': 'time'},
-        'ds_filter': None, 'ds_format': 'json'})
     monkeypatch.setenv('DN_ENGINE', 'jax')
-    ds.build(tdb._metrics(), 'day')
+    _cluster_ds(datafile, tmp_path / 'icluster').build(tdb._metrics(), 'day')
     assert _counter('device_sparse_fold_batches') > folded0
     assert ('sparse-sort-merge', 8, 'allgather+sparse-fold') in \
         _kernel_records(buf)
-    t_file = tdb._tree_bytes(tmp_path / 'ifile')
-    t_cluster = tdb._tree_bytes(tmp_path / 'icluster')
-    assert t_file.keys() == t_cluster.keys() and len(t_file) >= 3
-    for rel in t_file:
-        assert t_file[rel] == t_cluster[rel], rel
+    _same_tree(tmp_path, 'ifile', 'icluster')
+
+
+def _cluster_ds(datafile, indexdir=None):
+    """A cluster-backend datasource over test_device_build's corpus."""
+    from dragnet_tpu.parallel import cluster
+    bc = {'path': str(datafile), 'timeField': 'time'}
+    if indexdir is not None:
+        bc['indexPath'] = str(indexdir)
+    return cluster.DatasourceCluster({
+        'ds_backend': 'cluster', 'ds_backend_config': bc,
+        'ds_filter': None, 'ds_format': 'json'})
+
+
+def _cluster_build(monkeypatch, datafile, indexdir):
+    """A forced-device `dn build` of test_device_build's three metrics
+    on the cluster backend, with a dense budget of 4096 segments:
+    byhost (8 x 8 x 32 = 2048 while its days fit a cap of 8) is dense,
+    bymethod (16384) and bylat sparse from the first batch.  Returns
+    (the kernel records, nstackedbatches of each metric, batches handed
+    by the parser, device dispatches, the sparse cap of each scan of
+    each stacked batch)."""
+    import test_device_build as tdb
+    from dragnet_tpu import device_scan
+    from dragnet_tpu.parallel import cluster
+    from helpers.scan_differential import batches_handed
+
+    caps = []
+    orig = device_scan.DeviceScanStack._stacked_program
+
+    def spy(self, staged, inputs):
+        assert all(isinstance(s, cluster.MeshDeviceScan)
+                   for s in self.scans)
+        caps.append(tuple(st[1][-1] for st in staged))
+        return orig(self, staged, inputs)
+    monkeypatch.setattr(device_scan.DeviceScanStack, '_stacked_program',
+                        spy)
+    buf = _mesh_scan_setup(monkeypatch, read=16384, dense=4096)
+    monkeypatch.setenv('DN_PARSE_THREADS', '1')
+    monkeypatch.setenv('DN_ENGINE', 'jax')
+    handed0 = batches_handed()
+    dispatched0 = _counter('device_pipe_dispatches')
+    result = _cluster_ds(datafile, indexdir).build(tdb._metrics(), 'day')
+    stacked = [st.counters['nstackedbatches']
+               for st in result.pipeline.stages
+               if 'nstackedbatches' in st.counters]
+    return (set(_kernel_records(buf)), stacked, batches_handed() - handed0,
+            _counter('device_pipe_dispatches') - dispatched0, caps)
+
+
+def _same_tree(tmp_path, a, b, shards=3):
+    import test_device_build as tdb
+    t_a = tdb._tree_bytes(tmp_path / a)
+    t_b = tdb._tree_bytes(tmp_path / b)
+    assert t_a.keys() == t_b.keys() and len(t_a) >= shards
+    for rel in t_a:
+        assert t_a[rel] == t_b[rel], rel
+
+
+BOTH_MESH_KERNELS = {('segment-sum', 8, 'psum+pmin'),
+                     ('sparse-sort-merge', 8, 'allgather+sparse-fold')}
+
+
+@pytest.mark.parametrize('records,days', [(1536, 3), (1501, 12)],
+                         ids=['even', 'odd-count-and-flip'])
+def test_cluster_build_goes_through_the_stack(records, days, tmp_path,
+                                              monkeypatch):
+    """A cluster `dn build` folds its three metrics in one stacked
+    dispatch a batch: `nstackedbatches` grows on every metric by the
+    batches handed, `device_pipe_dispatches` by exactly as many, both
+    mesh programs ran (the dense epochs' `psum+pmin`, the sparse sets'
+    `allgather+sparse-fold`) and the tree is the file backend's, file
+    for file: also where the chips do not divide the record count and
+    a metric flips from its dense epoch to the sparse lane inside the
+    build."""
+    import test_device_build as tdb
+    from dragnet_tpu import native as mod_native
+
+    if mod_native.get_lib() is None:
+        pytest.skip('native parser unavailable')
+    datafile = tmp_path / 'data.log'
+    tdb._write_data(datafile, records, days=days)
+    tdb._build(monkeypatch, datafile, tmp_path / 'ifile', 'vector')
+
+    kernels, stacked, handed, dispatched, caps = _cluster_build(
+        monkeypatch, datafile, tmp_path / 'icluster')
+    assert handed >= 5
+    assert stacked == [handed] * 3
+    assert dispatched == handed == len(caps)
+    assert kernels == BOTH_MESH_KERNELS
+    _same_tree(tmp_path, 'ifile', 'icluster', shards=days)
+    # byhost begins dense beside two sparse metrics; the ninth day
+    # takes its key space past the budget (16 x 16 x 32), so its flush
+    # and its move to the sparse lane lie between two stacked batches
+    assert [c > 0 for c in caps[0]] == [False, True, True]
+    assert [c > 0 for c in caps[-1]] == [days > 8, True, True]
+
+
+def test_cluster_build_batch_one_scan_cannot_stage(tmp_path, monkeypatch):
+    """A batch that one metric cannot stage (an array for a key, a
+    latency that is no integer) is folded by the per-scan path on the
+    mesh, the others by the stack, and the tree is still the file
+    backend's."""
+    import test_device_build as tdb
+    from dragnet_tpu import device_scan
+    from dragnet_tpu import native as mod_native
+
+    if mod_native.get_lib() is None:
+        pytest.skip('native parser unavailable')
+    datafile = tmp_path / 'data.log'
+    tdb._write_data(datafile, 1500, with_edges=True)
+    tdb._build(monkeypatch, datafile, tmp_path / 'ifile', 'vector')
+
+    took = []
+    orig = device_scan.DeviceScanStack._process_device
+
+    def spy(self, provider, weights, alive):
+        took.append(orig(self, provider, weights, alive))
+        return took[-1]
+    monkeypatch.setattr(device_scan.DeviceScanStack, '_process_device', spy)
+    kernels, stacked, handed, dispatched, caps = _cluster_build(
+        monkeypatch, datafile, tmp_path / 'icluster')
+    assert took.count(False) >= 2 and took.count(True) >= 5
+    assert stacked == [took.count(True)] * 3
+    assert len(took) == handed
+    # a refused batch is dispatched by each scan that can take it
+    assert took.count(True) < dispatched <= \
+        took.count(True) + 3 * took.count(False)
+    _same_tree(tmp_path, 'ifile', 'icluster')
+
+
+def test_cluster_build_stack_disabled_by_env(tmp_path, monkeypatch):
+    """DN_STACK=0 on the cluster backend: the per-scan loop, a dispatch
+    a metric a batch, and the same tree."""
+    import test_device_build as tdb
+    from dragnet_tpu import native as mod_native
+
+    if mod_native.get_lib() is None:
+        pytest.skip('native parser unavailable')
+    datafile = tmp_path / 'data.log'
+    tdb._write_data(datafile, 1500)
+    tdb._build(monkeypatch, datafile, tmp_path / 'ifile', 'vector')
+
+    monkeypatch.setenv('DN_STACK', '0')
+    kernels, stacked, handed, dispatched, caps = _cluster_build(
+        monkeypatch, datafile, tmp_path / 'icluster')
+    assert stacked == [] and caps == []
+    assert dispatched == 3 * handed > 0
+    assert kernels == BOTH_MESH_KERNELS
+    _same_tree(tmp_path, 'ifile', 'icluster')
+
+
+def test_stacked_mesh_fold_of_sparse_sets_has_no_collective(tmp_path,
+                                                            monkeypatch):
+    """The stacked program of a batch whose three metrics all fold into
+    sparse sets, as the virtual 8-device mesh compiles it: nothing
+    crosses the chips, the completion token included (a token a scan,
+    for a set one a chip: summed into one scalar they would be an
+    all-reduce a batch)."""
+    import jax
+    import test_device_build as tdb
+    from dragnet_tpu import device_scan
+    from dragnet_tpu import native as mod_native
+
+    if mod_native.get_lib() is None:
+        pytest.skip('native parser unavailable')
+    datafile = tmp_path / 'data.log'
+    tdb._write_data(datafile, 400)
+    texts, tokens = [], []
+    orig = device_scan.DeviceScanStack._stacked_program
+
+    def spy(self, staged, inputs):
+        run = orig(self, staged, inputs)
+        if not texts:
+            assert all(st[1][-1] for st in staged)
+            accs = tuple(s._acc for s in self.scans)
+            texts.append(run.lower(inputs, accs).compile().as_text())
+            tokens.append(jax.eval_shape(run, inputs, accs)[1])
+        return run
+    monkeypatch.setattr(device_scan.DeviceScanStack, '_stacked_program',
+                        spy)
+    _mesh_scan_setup(monkeypatch, read=16384)
+    monkeypatch.setenv('DN_PARSE_THREADS', '1')
+    monkeypatch.setenv('DN_ENGINE', 'jax')
+    _cluster_ds(datafile, tmp_path / 'icluster').build(tdb._metrics(), 'day')
+    text, = texts
+    assert 'sort' in text
+    for collective in ('all-reduce', 'all-gather', 'all-to-all',
+                       'collective-permute', 'reduce-scatter'):
+        assert collective not in text, collective
+    assert [t.shape for t in tokens[0]] == [(8,)] * 3
+
+
+def _spec_axes(specs):
+    """{key: the mesh axis it is sharded over, or None}."""
+    return {k: (tuple(v)[0] if tuple(v) else None)
+            for k, v in specs.items()}
+
+
+STACKED_ARGS = [
+    'nvalid', 'alive', 'weights', 'tags_x', 'str_x', 'num_x', 'kv_x',
+    'kvalid_x', 'tsf_time', 'terr_time|other',
+    'm0_base', 'm0_key_x', 'm0_tab_0', 'm0_terr',
+    'm1_base', 'm1_key_x', 'm1_ts_x', 'm1_terr', 'm1_tab_0', 'm1_ctab_0',
+    'm1_trans_x',
+    'm2_base', 'm2_ts_x', 'm2_ctab_0', 'm10_key_x']
+
+
+@pytest.mark.parametrize('pfx,sharded,replicated', [
+    ('m1_',
+     ['alive', 'weights', 'tags_x', 'str_x', 'num_x', 'kv_x', 'kvalid_x',
+      'tsf_time', 'terr_time|other', 'm1_key_x', 'm1_ts_x', 'm1_terr'],
+     ['nvalid', 'm1_tab_0', 'm1_ctab_0', 'm1_trans_x']),
+    ('m0_',
+     ['alive', 'weights', 'tags_x', 'str_x', 'num_x', 'kv_x', 'kvalid_x',
+      'tsf_time', 'terr_time|other', 'm0_key_x', 'm0_terr'],
+     ['nvalid', 'm0_tab_0']),
+], ids=['m1', 'm0'])
+def test_record_specs_of_a_stacked_scan(pfx, sharded, replicated):
+    """A stacked scan's shard_map specs: the shared parser columns and
+    the keys under its own prefix, a value a record sharded over the
+    mesh axis, tables and `nvalid` replicated; no sibling's key, and no
+    batch base."""
+    from dragnet_tpu import device_scan
+    specs = _spec_axes(device_scan.record_specs(
+        dict.fromkeys(STACKED_ARGS), pfx, 'd'))
+    assert sorted(k for k, ax in specs.items() if ax == 'd') == \
+        sorted(sharded)
+    assert sorted(k for k, ax in specs.items() if ax is None) == \
+        sorted(replicated)
+    assert not [k for k in specs
+                if k[0] == 'm' and not k.startswith(pfx)]
+
+
+def test_record_specs_of_a_single_mesh_scan_are_pinned(tmp_path,
+                                                       monkeypatch):
+    """With no prefix the specs are what a single mesh scan has always
+    traced, key for key: a filtered scan with a time bound on the
+    cluster backend, its dense program's and its sparse program's."""
+    import test_device_build as tdb
+    from dragnet_tpu import device_scan
+    from dragnet_tpu import native as mod_native
+    from dragnet_tpu import query as mod_query
+
+    if mod_native.get_lib() is None:
+        pytest.skip('native parser unavailable')
+    datafile = tmp_path / 'data.log'
+    tdb._write_data(datafile, 1500)
+    seen = []
+    orig = device_scan.record_specs
+
+    def spy(args, pfx, axis):
+        specs = orig(args, pfx, axis)
+        seen.append((pfx, axis, 'base' in args, _spec_axes(specs)))
+        return specs
+    monkeypatch.setattr(device_scan, 'record_specs', spy)
+    _mesh_scan_setup(monkeypatch, read=16384)
+    query = mod_query.query_load({
+        'breakdowns': [{'name': 'host'},
+                       {'name': 'latency', 'aggr': 'quantize'}],
+        'filter': {'ne': ['req.method', 'PUT']},
+        'timeAfter': '2014-05-01', 'timeBefore': '2014-05-03'})
+    monkeypatch.setenv('DN_ENGINE', 'host')
+    expected = _cluster_ds(datafile).scan(query).points
+    assert not seen
+    monkeypatch.setenv('DN_ENGINE', 'jax')
+    r = _cluster_ds(datafile).scan(query)
+    assert r.points == expected and _ndevicebatches(r) >= 5
+    assert all(s[:3] == ('', 'd', True) for s in seen)
+    pinned = {
+        'nvalid': None, 'tags_req.method': 'd', 'str_req.method': 'd',
+        'tsf_time': 'd', 'terr_time': 'd', 'kv_latency': 'd',
+        'tab_0': None, 'ctab_0': None}
+    host_keys = ({'key_host': 'd'}, {'str_host': 'd', 'trans_host': None})
+    assert seen
+    for s in seen:
+        assert s[3] in [dict(pinned, **hk) for hk in host_keys], s[3]
 
 
 def test_sparse_merge_counters_and_leaf_at_a_scrape(tmp_path, monkeypatch):
