@@ -170,91 +170,102 @@ class DatasourceFile(object):
         """Scan raw data to execute a query.  Returns a ScanResult whose
         points are the aggregated output.  (reference:
         lib/datasource-file.js:72-108)"""
-        pipeline = Pipeline()
-        pipeline.warn_func = warn_func
-        ctx = self._scan_init(query.qc_after, query.qc_before, pipeline)
-        if isinstance(ctx, DNError):
-            raise ctx
-        files, fmt = ctx
+        # scan.init: the request's thread from here to the stream's
+        # first batch (the file list, the lane, the scanner and its
+        # device objects, the parser, the producer's start); ended
+        # where the records begin (end_open: _stream_native, or the
+        # record loops below)
+        with obs_metrics.leaf_stage('scan.init'):
+            pipeline = Pipeline()
+            pipeline.warn_func = warn_func
+            ctx = self._scan_init(query.qc_after, query.qc_before, pipeline)
+            if isinstance(ctx, DNError):
+                raise ctx
+            files, fmt = ctx
 
-        from . import byteparse as mod_byteparse
+            from . import byteparse as mod_byteparse
 
-        if dry_run:
-            result = ScanResult(pipeline,
-                                dry_run_files=[p for p, st in files])
-            from . import native as mod_native
-            lane = mod_byteparse.choose_lane(
-                [query], self.ds_timefield, self.ds_filter, fmt,
-                mod_native.get_lib() is not None)
-            result.parse_plan = {'parse_lane': lane.lane,
-                                 'parse_mode':
-                                     mod_byteparse.parse_mode(),
-                                 'reason': lane.reason}
-            return result
+            if dry_run:
+                result = ScanResult(pipeline,
+                                    dry_run_files=[p for p, st in files])
+                from . import native as mod_native
+                lane = mod_byteparse.choose_lane(
+                    [query], self.ds_timefield, self.ds_filter, fmt,
+                    mod_native.get_lib() is not None)
+                result.parse_plan = {'parse_lane': lane.lane,
+                                     'parse_mode':
+                                         mod_byteparse.parse_mode(),
+                                     'reason': lane.reason}
+                return result
 
-        LOG.debug('scan start', datapath=self.ds_datapath,
-                  nfiles=len(files),
-                  nbytes=sum(getattr(st, 'st_size', 0) or 0
-                             for p, st in files))
+            LOG.debug('scan start', datapath=self.ds_datapath,
+                      nfiles=len(files),
+                      nbytes=sum(getattr(st, 'st_size', 0) or 0
+                                 for p, st in files))
 
-        # The vectorized engine produces identical results; --warnings
-        # needs the per-record host path for ordered warning output.
-        # Within the vectorized path, ingest runs one of the DN_PARSE
-        # lanes: the native C++ parser (host), the vectorized byte
-        # parser (vector/device — byteparse.py), or the Python record
-        # path when neither engages.
-        from .engine import engine_mode
-        use_vector = warn_func is None and engine_mode() != 'host'
-        native_lib = None
-        lane = None
-        if use_vector:
-            from . import native as mod_native
-            native_lib = mod_native.get_lib()
-            lane = mod_byteparse.choose_lane(
-                [query], self.ds_timefield, self.ds_filter, fmt,
-                native_lib is not None)
+            # The vectorized engine produces identical results; --warnings
+            # needs the per-record host path for ordered warning output.
+            # Within the vectorized path, ingest runs one of the DN_PARSE
+            # lanes: the native C++ parser (host), the vectorized byte
+            # parser (vector/device — byteparse.py), or the Python record
+            # path when neither engages.
+            from .engine import engine_mode
+            use_vector = warn_func is None and engine_mode() != 'host'
+            native_lib = None
+            lane = None
+            if use_vector:
+                from . import native as mod_native
+                native_lib = mod_native.get_lib()
+                lane = mod_byteparse.choose_lane(
+                    [query], self.ds_timefield, self.ds_filter, fmt,
+                    native_lib is not None)
 
-        if use_vector and (native_lib is not None or lane.engaged):
-            scanner = self._scan_native(query, files, fmt, pipeline,
-                                        lane)
-        elif use_vector:
-            from .engine import BATCH_SIZE
-            stages = mod_ingest.make_parser_stages(pipeline, fmt)
-            # no native library AND the byte lane could not engage:
-            # the ineligibility counter must still appear
-            mod_byteparse.note_ineligible(stages[0], lane)
-            scanner = self._vector_scan_cls()(
-                query, self.ds_timefield, pipeline,
-                ds_filter=self.ds_filter)
-            records = mod_ingest.iter_records(
-                mod_ingest.iter_lines([p for p, st in files]), fmt,
-                stages=stages)
-            buf_r, buf_w = [], []
-            for fields, value in records:
-                buf_r.append(fields)
-                buf_w.append(value)
-                if len(buf_r) >= BATCH_SIZE:
-                    scanner.write_batch(buf_r, buf_w)
-                    buf_r, buf_w = [], []
-            scanner.write_batch(buf_r, buf_w)
-        else:
-            from .engine import weights_array
-            stages = mod_ingest.make_parser_stages(pipeline, fmt)
-            scanner = StreamScan(query, self.ds_timefield, pipeline,
-                                 ds_filter=self.ds_filter)
-            records = mod_ingest.iter_records(
-                mod_ingest.iter_lines([p for p, st in files]), fmt,
-                stages=stages)
-            for fields, value in records:
-                # weight coercion identical to the vectorized paths
-                # (json-skinner values may be strings/garbage)
-                if not isinstance(value, int):
-                    value = float(weights_array([value])[0])
-                    value = int(value) if value.is_integer() else value
-                scanner.write(fields, value)
+            if use_vector and (native_lib is not None or lane.engaged):
+                scanner = self._scan_native(query, files, fmt, pipeline,
+                                            lane)
+            elif use_vector:
+                from .engine import BATCH_SIZE
+                stages = mod_ingest.make_parser_stages(pipeline, fmt)
+                # no native library AND the byte lane could not engage:
+                # the ineligibility counter must still appear
+                mod_byteparse.note_ineligible(stages[0], lane)
+                scanner = self._vector_scan_cls()(
+                    query, self.ds_timefield, pipeline,
+                    ds_filter=self.ds_filter)
+                records = mod_ingest.iter_records(
+                    mod_ingest.iter_lines([p for p, st in files]), fmt,
+                    stages=stages)
+                obs_metrics.leaf_stage.end_open('scan.init')
+                buf_r, buf_w = [], []
+                for fields, value in records:
+                    buf_r.append(fields)
+                    buf_w.append(value)
+                    if len(buf_r) >= BATCH_SIZE:
+                        scanner.write_batch(buf_r, buf_w)
+                        buf_r, buf_w = [], []
+                scanner.write_batch(buf_r, buf_w)
+            else:
+                from .engine import weights_array
+                stages = mod_ingest.make_parser_stages(pipeline, fmt)
+                scanner = StreamScan(query, self.ds_timefield, pipeline,
+                                     ds_filter=self.ds_filter)
+                records = mod_ingest.iter_records(
+                    mod_ingest.iter_lines([p for p, st in files]), fmt,
+                    stages=stages)
+                obs_metrics.leaf_stage.end_open('scan.init')
+                for fields, value in records:
+                    # weight coercion identical to the vectorized paths
+                    # (json-skinner values may be strings/garbage)
+                    if not isinstance(value, int):
+                        value = float(weights_array([value])[0])
+                        value = int(value) if value.is_integer() else value
+                    scanner.write(fields, value)
 
-        if hasattr(scanner, 'finish'):
-            scanner.finish()   # merge any device-buffered batches
+        # scan.finish: the deferred merge (the leaves it opens inside,
+        # scan.fetch, scan.emit, scan.sparse_merge, suspend it)
+        with obs_metrics.leaf_stage('scan.finish'):
+            if hasattr(scanner, 'finish'):
+                scanner.finish()   # merge any device-buffered batches
         result = ScanResult(pipeline, points=_emit_points(scanner.aggr),
                             query=query)
         LOG.debug('scan done', npoints=result.npoints,
@@ -440,7 +451,8 @@ class DatasourceFile(object):
         # clean retryable disk_full DNError, never a traceback — the
         # two-phase journal already guarantees the tree is left
         # pre-build or post-build, never torn
-        with mod_resources.translate_pressure_errors('index build'):
+        with mod_resources.translate_pressure_errors('index build'), \
+                obs_metrics.leaf_stage('scan.init'):
             return self._index_scan_impl(
                 metrics, interval, self.ds_filter, time_after,
                 time_before, dry_run, sink='index',
@@ -448,14 +460,17 @@ class DatasourceFile(object):
 
     def index_scan(self, metrics, interval, filter=None, time_after=None,
                    time_before=None, warn_func=None):
-        return self._index_scan_impl(
-            metrics, interval, filter, time_after, time_before, False,
-            sink='points', warn_func=warn_func)
+        with obs_metrics.leaf_stage('scan.init'):
+            return self._index_scan_impl(
+                metrics, interval, filter, time_after, time_before,
+                False, sink='points', warn_func=warn_func)
 
     def _index_scan_impl(self, metrics, interval, filter, time_after,
                          time_before, dry_run, sink, warn_func=None):
         """One pass over raw data feeding every metric's scan; output goes
-        to index files (build) or tagged points (index-scan).
+        to index files (build) or tagged points (index-scan).  The
+        caller's open `scan.init` leaf (see scan()) ends where the
+        records begin.
         (reference: lib/datasource-file.js:322-433)"""
         pipeline = Pipeline()
         pipeline.warn_func = warn_func
@@ -530,6 +545,7 @@ class DatasourceFile(object):
                 scanners.append(s)
 
             lines = mod_ingest.iter_lines([p for p, st in files])
+            obs_metrics.leaf_stage.end_open('scan.init')
             for fields, value in mod_ingest.iter_records(lines, fmt,
                                                          stages=stages):
                 if ds_filter_stage is not None and \
@@ -546,22 +562,26 @@ class DatasourceFile(object):
             # by position)
             from . import index_build_mt as mod_ibmt
             blocks = []
-            for s in scanners:
-                if hasattr(s, 'finish'):
-                    s.finish()   # merge any device-buffered batches
-                cols, weights = s.aggr.point_rows()
-                blocks.append((list(s.aggr.decomps), cols, weights))
+            # scan.finish: every scanner's deferred merge (its own
+            # leaves suspend this one) and its rows
+            with obs_metrics.leaf_stage('scan.finish'):
+                for s in scanners:
+                    if hasattr(s, 'finish'):
+                        s.finish()   # merge any device-buffered batches
+                    cols, weights = s.aggr.point_rows()
+                    blocks.append((list(s.aggr.decomps), cols, weights))
             mod_ibmt.write_index_blocks(metrics, interval,
                                         self.ds_indexpath, blocks)
             return ScanResult(pipeline, points=None)
 
         tagged = []
-        for qi, s in enumerate(scanners):
-            if hasattr(s, 'finish'):
-                s.finish()   # merge any device-buffered batches
-            for fields, value in s.aggr.points():
-                fields['__dn_metric'] = qi
-                tagged.append((fields, value))
+        with obs_metrics.leaf_stage('scan.finish'):
+            for qi, s in enumerate(scanners):
+                if hasattr(s, 'finish'):
+                    s.finish()   # merge any device-buffered batches
+                for fields, value in s.aggr.points():
+                    fields['__dn_metric'] = qi
+                    tagged.append((fields, value))
         return ScanResult(pipeline, points=tagged)
 
     def _index_scan_native(self, queries, files, fmt, filter, pipeline,
@@ -859,7 +879,10 @@ class DatasourceFile(object):
         each flush with the bytes read when the batch ended — auto
         mode's device-switch heuristic estimates remaining work from it
         (total is 0 when sizes are unknowable, e.g. character
-        devices)."""
+        devices).
+
+        The request's open `scan.init` leaf ends here, where the
+        first batch is asked for."""
         # larger reads amortize the multithreaded parse's fork/join; the
         # cap bounds how far a batch can overshoot the flush threshold
         # (flush is only checked between reads).  DN_READ_SIZE overrides
@@ -875,6 +898,7 @@ class DatasourceFile(object):
             total += sz if sz and sz > 0 else 0
 
         if getattr(parser, 'detach_batch', None) is None:
+            obs_metrics.leaf_stage.end_open('scan.init')
             for done in _parse_batches(files, parser, batch_size,
                                        readsz):
                 if progress is not None:
@@ -894,6 +918,7 @@ class DatasourceFile(object):
 
         ahead = _RunAhead(detached, name='dn-parse-ahead',
                           drop=_release_batch, join=True)
+        obs_metrics.leaf_stage.end_open('scan.init')
         try:
             while True:
                 # scan.parse_wait: this thread's wait for the producer's
@@ -1051,8 +1076,12 @@ class DatasourceFile(object):
         """Query the indexes.  (reference:
         lib/datasource-file.js:573-691)"""
         pipeline = Pipeline()
-        root, timeformat, files = self.index_query_paths(
-            query, interval, pipeline)
+        # the query's plan, a leaf a part: the shard walk
+        # (index_query.paths); the pruning, the integrity check and
+        # the rollup planner (index_query.prune)
+        with obs_metrics.leaf_stage('index_query.paths'):
+            root, timeformat, files = self.index_query_paths(
+                query, interval, pipeline)
 
         if dry_run:
             return ScanResult(pipeline,
@@ -1068,31 +1097,42 @@ class DatasourceFile(object):
         # sequential loop (the reference's vasync barrier merged the
         # same way, lib/datasource-file.js:629-689).
         from . import index_query_mt as mod_iqmt
-        paths = [p for p, st in files]
-        paths, npruned = mod_iqmt.prune_shards(
-            paths, timeformat, query.qc_after, query.qc_before)
-        # time-bounded finds never enumerate out-of-window shards, so
-        # count the tree's skipped files for the pruned counter (the
-        # found list can only re-prune what enumeration missed)
-        npruned = max(npruned, mod_iqmt.count_pruned_shards(
-            root, timeformat, query.qc_after, query.qc_before))
-        if npruned:
-            index_list.bump_hidden('index shards pruned', npruned)
-        index_list.bump_hidden('index shards queried', len(paths))
-
-        # verified reads (integrity.py): a catalogued shard that is
-        # MISSING from the walk (quarantined after a corrupt detect,
-        # or externally deleted) must degrade explicitly — a clean
-        # retryable error naming the shard — never silently short
-        # result bytes
         from . import integrity as mod_integrity
-        if mod_integrity.verify_mode() != 'off':
-            mod_integrity.check_missing(
-                self.ds_indexpath, paths,
-                subdir=os.path.basename(root)
-                if timeformat is not None else None,
-                timeformat=timeformat, after_ms=query.qc_after,
-                before_ms=query.qc_before)
+        from . import rollup as mod_rollup
+        with obs_metrics.leaf_stage('index_query.prune'):
+            paths = [p for p, st in files]
+            paths, npruned = mod_iqmt.prune_shards(
+                paths, timeformat, query.qc_after, query.qc_before)
+            # time-bounded finds never enumerate out-of-window shards,
+            # so count the tree's skipped files for the pruned counter
+            # (the found list can only re-prune what enumeration
+            # missed)
+            npruned = max(npruned, mod_iqmt.count_pruned_shards(
+                root, timeformat, query.qc_after, query.qc_before))
+            if npruned:
+                index_list.bump_hidden('index shards pruned', npruned)
+            index_list.bump_hidden('index shards queried', len(paths))
+
+            # verified reads (integrity.py): a catalogued shard that is
+            # MISSING from the walk (quarantined after a corrupt
+            # detect, or externally deleted) must degrade explicitly —
+            # a clean retryable error naming the shard — never
+            # silently short result bytes
+            if mod_integrity.verify_mode() != 'off':
+                mod_integrity.check_missing(
+                    self.ds_indexpath, paths,
+                    subdir=os.path.basename(root)
+                    if timeformat is not None else None,
+                    timeformat=timeformat, after_ms=query.qc_after,
+                    before_ms=query.qc_before)
+
+            # Query planner (rollup.py): serve from the coarsest
+            # covering rollup shards and fold follow mini-generations
+            # into their logical base shard.  plan_query returns None
+            # whenever the walk is plain per-file shards — the
+            # stacked/pooled paths below then run completely untouched.
+            plan = mod_rollup.plan_query(self.ds_indexpath,
+                                         interval or 'all', paths, query)
 
         nworkers = mod_iqmt.iq_threads()
         LOG.debug('query start', indexroot=root, nindexes=len(paths),
@@ -1116,14 +1156,6 @@ class DatasourceFile(object):
             aggr_stage.bump('ninputs', npts)
             aggr.merge_key_items(items)
 
-        # Query planner (rollup.py): serve from the coarsest covering
-        # rollup shards and fold follow mini-generations into their
-        # logical base shard.  plan_query returns None whenever the
-        # walk is plain per-file shards — the stacked/pooled paths
-        # below then run completely untouched.
-        from . import rollup as mod_rollup
-        plan = mod_rollup.plan_query(self.ds_indexpath,
-                                     interval or 'all', paths, query)
         if plan is not None:
             # bump_hidden mirrors into the process-global store, so
             # `dn serve` /stats sees the fleet-wide coverage too

@@ -306,15 +306,6 @@ def _note_engagement(ndispatch, nshards, nrows, pinned_hits,
     _ENGAGE['pinned_shard_hits'] += pinned_hits
     _ENGAGE['h2d_bytes'] += h2d_bytes
     _ENGAGE['h2d_saved_bytes'] += h2d_saved
-    obs_metrics.inc('index_device_dispatches', ndispatch)
-    obs_metrics.inc('index_device_shards', nshards)
-    obs_metrics.inc('index_device_rows', nrows)
-    obs_metrics.inc('index_device_pinned_hits', pinned_hits)
-    obs_metrics.inc('index_device_h2d_bytes', h2d_bytes)
-    obs_metrics.inc('index_device_h2d_saved_bytes', h2d_saved)
-    if ndispatch:
-        obs_metrics.set_gauge('index_device_shards_per_dispatch',
-                              nshards / ndispatch)
 
 
 def stats_doc():
@@ -533,7 +524,6 @@ def batched_sums(inv, weights, nuniq, shard_ctx=None, stage=None,
         mod_ds.audition_cache_put(key, won, device_rate=rate_d,
                                   host_rate=rate_h)
         _ENGAGE['auditions'] += 1
-        obs_metrics.inc('index_device_auditions', 1)
         if not equal:
             # never ship an inexact device result — and never trust
             # this lane again this process (exactness gate tripped)

@@ -1617,7 +1617,6 @@ class DeviceScan(VectorScan):
         depth = pipeline_depth()
         q = self._pipe
         obs_metrics.inc('device_pipe_dispatches')
-        obs_metrics.set_gauge('device_pipeline_depth', depth)
         if q and _acc_ready(q[-1]) is False:
             obs_metrics.inc('device_pipe_overlapped')
             obs_metrics.inc('device_h2d_overlapped_bytes', int(nbytes))
@@ -2490,9 +2489,6 @@ def parallel_fetch_enabled():
             _PARALLEL_FETCH.update(
                 enabled=False, source='probe', probe_ms=ms,
                 reason=reason)
-    obs_metrics.set_gauge(
-        'device_parallel_fetch',
-        1 if _PARALLEL_FETCH['enabled'] else 0)
     return _PARALLEL_FETCH['enabled']
 
 

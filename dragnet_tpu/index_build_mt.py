@@ -45,6 +45,7 @@ from .errors import DNError
 from . import jsvalues as jsv
 from .index_sink import (make_index_sink, metric_catalog_rows,
                          point_metric, point_row)
+from .obs import metrics as obs_metrics
 from .watchdog import LeakCheck
 
 # a flush executor that is never drained means some shards may never
@@ -320,10 +321,9 @@ def publish_prepared(journal, sinks, paths, extra_paths=None,
     missing rows."""
     from . import integrity as mod_integrity
     from .index_query_mt import shard_cache_invalidate
-    from .obs import metrics as obs_metrics
     extra_paths = list(extra_paths or [])
-    with obs_metrics.timed_stage('index_build.commit',
-                                 nshards=len(paths)):
+    with obs_metrics.leaf_stage('index_build.commit',
+                                nshards=len(paths)):
         integ = mod_integrity.integrity_entries(
             [os.path.abspath(p) for p in paths],
             tmp_for=journal.tmp_for)
@@ -382,20 +382,23 @@ def _publish_buckets(metrics, indexroot, buckets, catalog, nworkers):
     contract: the earliest bucket-order error re-raises and no tmp
     litter survives."""
     from . import index_journal as mod_journal
-    from .obs import metrics as obs_metrics
     from .obs import trace as obs_trace
 
-    mod_journal.sweep_index_tree(indexroot)
-    mod_journal.cleanup_own_stale(indexroot)
-    journal = mod_journal.BuildJournal(indexroot)
     paths = [p for p, config, parts in buckets]
     sinks = [None] * len(buckets)
-    tasks = [_prepare_task(metrics, path, config, parts, catalog,
-                           journal.tmp_suffix, sinks, i)
-             for i, (path, config, parts) in enumerate(buckets)]
     try:
-        with obs_metrics.timed_stage('index_build.prepare',
-                                     nshards=len(buckets)):
+        # a leaf of the request's thread: the tree's recovery sweep,
+        # this builder's stale intents retired and the build's journal
+        # opened (0.6 ms a build together), then its wait for the flush
+        # pool (the pool's threads work beside it, under no leaf)
+        with obs_metrics.leaf_stage('index_build.prepare',
+                                    nshards=len(buckets)):
+            mod_journal.sweep_index_tree(indexroot)
+            mod_journal.cleanup_own_stale(indexroot)
+            journal = mod_journal.BuildJournal(indexroot)
+            tasks = [_prepare_task(metrics, path, config, parts, catalog,
+                                   journal.tmp_suffix, sinks, i)
+                     for i, (path, config, parts) in enumerate(buckets)]
             run_flush_tasks(tasks, nworkers)
     except BaseException:
         for sink in sinks:
@@ -416,16 +419,22 @@ def write_index_blocks(metrics, interval, indexroot, blocks,
     per-point loop (same files, same bytes, same dn_start config) for
     any worker count; the shard set publishes through the crash-safe
     journal (_publish_buckets)."""
-    catalog = metric_catalog_rows(metrics)
+    # index_build.bucket: the blocks routed into interval buckets
+    with obs_metrics.leaf_stage('index_build.bucket'):
+        catalog = metric_catalog_rows(metrics)
+        ordered = _bucket_blocks(metrics, interval, indexroot, blocks)
+    _publish_buckets(metrics, indexroot, ordered, catalog, nworkers)
+
+
+def _bucket_blocks(metrics, interval, indexroot, blocks):
+    """write_index_blocks' routing: [(indexpath, config, parts)] in
+    bucket order, parts being [(mi, keycols, values)]."""
     if interval == 'all':
         parts = []
         for mi, (names, cols, weights) in enumerate(blocks):
             sel = _breakdown_positions(names, metrics[mi])
             parts.append((mi, [cols[p] for p in sel], weights))
-        allpath = os.path.join(indexroot, 'all')
-        _publish_buckets(metrics, indexroot,
-                         [(allpath, None, parts)], catalog, nworkers)
-        return
+        return [(os.path.join(indexroot, 'all'), None, parts)]
 
     span = interval_span(interval)
     root = os.path.join(indexroot, 'by_' + interval)
@@ -466,7 +475,7 @@ def write_index_blocks(metrics, interval, indexroot, blocks,
             root, bucket_label(bucket_s, interval) + '.sqlite')
         ordered.append((indexpath, {'dn_start': bucket_s},
                         buckets[bucket_s]))
-    _publish_buckets(metrics, indexroot, ordered, catalog, nworkers)
+    return ordered
 
 
 # -- streaming entry: tagged point chunks -> sharded index files -----------

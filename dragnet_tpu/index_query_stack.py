@@ -633,42 +633,46 @@ def run_stacked(paths, query, aggr, index_list):
         aggr.total += total
         return True
 
-    stacks = [_BreakdownStack(query.qc_bucketizers.get(b['name']))
-              for b in bds]
-    for sh in shards:
-        cols = sh[1]
-        for st, col in zip(stacks, cols):
-            if col[0] == 'i64':
-                st.add_i64(col[1])
-            elif col[0] == 'dict':
-                st.add_dict(col[1], col[2], col[3])
-            else:
-                st.add_rows(col[1])
+    # index_query_stack.stack: the shards' columns stacked into one
+    # batch and resolved to sort and aggregate codes
+    with obs_metrics.leaf_stage('index_query_stack.stack',
+                                nshards=nshards):
+        stacks = [_BreakdownStack(query.qc_bucketizers.get(b['name']))
+                  for b in bds]
+        for sh in shards:
+            cols = sh[1]
+            for st, col in zip(stacks, cols):
+                if col[0] == 'i64':
+                    st.add_i64(col[1])
+                elif col[0] == 'dict':
+                    st.add_dict(col[1], col[2], col[3])
+                else:
+                    st.add_rows(col[1])
 
-    nrows = [sh[0] for sh in shards]
-    shard_ids = (np.repeat(np.arange(nshards, dtype=np.int64), nrows)
-                 if nshards else np.zeros(0, dtype=np.int64))
-    values = (np.concatenate(vals_list) if vals_list
-              else np.zeros(0, dtype=np.float64))
+        nrows = [sh[0] for sh in shards]
+        shard_ids = (np.repeat(np.arange(nshards, dtype=np.int64), nrows)
+                     if nshards else np.zeros(0, dtype=np.int64))
+        values = (np.concatenate(vals_list) if vals_list
+                  else np.zeros(0, dtype=np.float64))
 
-    sort_cols = []
-    agg_cols = []
-    decoders = []
-    drop = None
-    for st in stacks:
-        sk, ak, dm = st.resolve()
-        sort_cols.append(sk)
-        agg_cols.append(ak)
-        decoders.append(st.decoder())
-        if dm is not None:
-            drop = dm if drop is None else (drop | dm)
+        sort_cols = []
+        agg_cols = []
+        decoders = []
+        drop = None
+        for st in stacks:
+            sk, ak, dm = st.resolve()
+            sort_cols.append(sk)
+            agg_cols.append(ak)
+            decoders.append(st.decoder())
+            if dm is not None:
+                drop = dm if drop is None else (drop | dm)
 
-    if drop is not None:
-        keep = ~drop
-        shard_ids = shard_ids[keep]
-        values = values[keep]
-        sort_cols = [c[keep] for c in sort_cols]
-        agg_cols = [c[keep] for c in agg_cols]
+        if drop is not None:
+            keep = ~drop
+            shard_ids = shard_ids[keep]
+            values = values[keep]
+            sort_cols = [c[keep] for c in sort_cols]
+            agg_cols = [c[keep] for c in agg_cols]
 
     n = len(values)
     if n == 0:
@@ -696,18 +700,21 @@ def run_stacked(paths, query, aggr, index_list):
         wsum = _aggregate_weights(inv, values[perm], nuniq,
                                   stage=index_list,
                                   shard_ctx=(sid, idents, query))
-    rows = first_idx[order]
-    out_cols = [np.ascontiguousarray(c[rows]) for c in acols]
-    weights = [int(w) for w in wsum[order].tolist()]
+    # index_query_stack.commit: the result's columns in emission
+    # order, the key-item count, the aggregator's columnar install
+    with obs_metrics.leaf_stage('index_query_stack.commit', nuniq=nuniq):
+        rows = first_idx[order]
+        out_cols = [np.ascontiguousarray(c[rows]) for c in acols]
+        weights = [int(w) for w in wsum[order].tolist()]
 
-    # key-item counter parity: the per-shard loop merges one item per
-    # DISTINCT tuple per shard
-    pair = fuse_codes([sid, inv])
-    if pair is not None:
-        npts = len(np.unique(pair))
-    else:
-        npts = len(np.unique(np.stack([sid, inv], axis=1), axis=0))
-    _commit_counters(index_list, aggr, npts)
-    aggr.nrecords += npts
-    aggr.set_columnar(out_cols, weights, decoders)
+        # key-item counter parity: the per-shard loop merges one item per
+        # DISTINCT tuple per shard
+        pair = fuse_codes([sid, inv])
+        if pair is not None:
+            npts = len(np.unique(pair))
+        else:
+            npts = len(np.unique(np.stack([sid, inv], axis=1), axis=0))
+        _commit_counters(index_list, aggr, npts)
+        aggr.nrecords += npts
+        aggr.set_columnar(out_cols, weights, decoders)
     return True

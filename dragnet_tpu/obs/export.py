@@ -43,7 +43,6 @@ def stats_section(registry=None, counters=None):
         registry = mod_metrics.global_registry()
     if counters is not None:
         mod_metrics.refresh_device_gauges(counters, registry)
-        mod_metrics.refresh_rollup_gauges(counters, registry)
         mod_metrics.refresh_process_gauges(registry)
     doc = {'version': STATS_METRICS_VERSION,
            'counters': {}, 'gauges': {}, 'histograms': {}}
@@ -127,7 +126,6 @@ def prometheus_text(registry=None, counters=None):
         registry = mod_metrics.global_registry()
     if counters is not None:
         mod_metrics.refresh_device_gauges(counters, registry)
-        mod_metrics.refresh_rollup_gauges(counters, registry)
         mod_metrics.refresh_process_gauges(registry)
     lines = []
     typed = set()

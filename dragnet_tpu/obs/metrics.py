@@ -308,7 +308,8 @@ def timed_stage(name, metric='stage_ms', labels=None, **span_attrs):
         observe(metric, (time.perf_counter() - t0) * 1000.0, **labels)
 
 
-_LEAF = threading.local()       # .top: this thread's open leaf stage
+# .top: this thread's open leaf stage; .ms: its ended leaves' self time
+_LEAF = threading.local()
 _TRACE_ANNOTATION = None        # jax.profiler.TraceAnnotation, once seen
 
 
@@ -348,6 +349,18 @@ class leaf_stage(object):
     self time.  So leaves never overlap, and their sum is at most the
     request's own time.
 
+    Every leaf's self time is also added to a total of its thread
+    (`thread_ms`): a request reads it at its start and its end, and
+    the difference is what its own thread spent under leaves,
+    whatever their names (`serve_leaf_ms{op}`, serve/server.py).
+
+    A leaf whose end lies in another function than its start (the
+    request's resolution ends at its admission slot, a scan's set-up
+    at the stream's first batch) is opened by `with` where it starts
+    and ended by `leaf_stage.end_open(name)` where it ends; the
+    `with`'s own exit then does nothing, and ends it on an error
+    path.
+
     A scan meets some 200 of these, each after native code has had
     the caches, so the off path is kept short: the request's scope is
     read once, and no span object exists unless tracing is on
@@ -360,6 +373,13 @@ class leaf_stage(object):
         self.name = name
         self.attrs = span_attrs
         self.inner_ms = 0.0
+        self.t0 = None
+
+    @staticmethod
+    def thread_ms():
+        """Self milliseconds of every leaf that has ended on this
+        thread."""
+        return getattr(_LEAF, 'ms', 0.0)
 
     def __enter__(self):
         self.outer = outer = getattr(_LEAF, 'top', None)
@@ -383,14 +403,29 @@ class leaf_stage(object):
             self.span.set(**attrs)
         return self
 
+    @staticmethod
+    def end_open(name):
+        """End this thread's open leaf if it is `name`; nothing where
+        another leaf, or none, is open (the caller runs under no such
+        leaf, or one opened inside it has not ended)."""
+        top = getattr(_LEAF, 'top', None)
+        if top is not None and top.name == name:
+            top.__exit__(None, None, None)
+
     def __exit__(self, *exc):
+        # ended already (end_open), or not this thread's open leaf:
+        # leaves end innermost first, on the thread that opened them
+        if self.t0 is None or getattr(_LEAF, 'top', None) is not self:
+            return False
         ms = (time.perf_counter() - self.t0) * 1000.0
+        self.t0 = None
         if self.ann is not None:
             self.ann.__exit__(*exc)
         if self.span is not None:
             self.span.__exit__(*exc)
-        self.registry.observe('stage_ms', ms - self.inner_ms,
-                              stage=self.name)
+        self_ms = ms - self.inner_ms
+        self.registry.observe('stage_ms', self_ms, stage=self.name)
+        _LEAF.ms = getattr(_LEAF, 'ms', 0.0) + self_ms
         _LEAF.top = outer = self.outer
         if outer is not None:
             outer.inner_ms += ms
@@ -511,26 +546,6 @@ def refresh_device_gauges(counters, registry=None):
                           float(rs.get('h2d_saved_bytes', 0) or 0))
             reg.set_gauge('device_d2h_saved_bytes',
                           float(rs.get('d2h_saved_bytes', 0) or 0))
-
-
-def refresh_rollup_gauges(counters, registry=None):
-    """Rollup-planner engagement from the hidden query counters:
-
-    * ``rollup_covered_shards_total`` / ``rollup_shards_read_total``
-      — fine shards whose answers came from rollups, and the coarse
-      shards actually read for them.
-    * ``rollup_coverage_pct`` — share of all fine-shard reads the
-      planner served from rollups (0 when nothing ran; honest zero,
-      like the device gauges).
-    """
-    reg = registry if registry is not None else _GLOBAL
-    covered = int(counters.get('index shards via rollup', 0) or 0)
-    read = int(counters.get('rollup shards queried', 0) or 0)
-    queried = int(counters.get('index shards queried', 0) or 0)
-    reg.set_gauge('rollup_covered_shards_total', covered)
-    reg.set_gauge('rollup_shards_read_total', read)
-    reg.set_gauge('rollup_coverage_pct',
-                  100.0 * covered / queried if queried else 0.0)
 
 
 # -- the process's memory (read at scrape) ----------------------------------

@@ -444,7 +444,6 @@ class RadixMerge(object):
         the GIL), stitch the partitions back into global
         first-occurrence order, and emit once into the main scanner."""
         import time as mod_time
-        from .obs import metrics as obs_metrics
         if not self.engaged:
             return
         t0 = mod_time.perf_counter()
@@ -491,10 +490,6 @@ class RadixMerge(object):
             _MERGE_STATS['rows'] += self.rows_in
             _MERGE_STATS['unique'] += nuniq
             _MERGE_STATS['engaged'] += 1
-            obs_metrics.set_gauge('scan_merge_partitions',
-                                  self.npartitions)
-            obs_metrics.set_gauge('scan_merge_ms',
-                                  _MERGE_STATS['merge_ms'])
 
 
 def _translate_codes(wcol, mcol, codes):

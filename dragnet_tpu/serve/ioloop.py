@@ -39,6 +39,7 @@ import time
 from collections import deque
 
 from . import protocol as mod_protocol
+from ..obs import metrics as obs_metrics
 
 _RECV_CHUNK = 1 << 16
 
@@ -155,8 +156,13 @@ class IOLoop(object):
         on the peer).  `completes` marks the end of one dispatched
         request (decrements the in-flight count the reaper consults);
         `close_after` closes the connection once the bytes flush
-        (v1's one-shot contract)."""
-        self._enqueue(('send', conn, data, close_after, completes))
+        (v1's one-shot contract).  A reply (`completes`) carries the
+        time of this call to the loop, which observes
+        `serve_reply_drain_ms` when the socket has accepted the
+        reply's last byte."""
+        sent_at = time.monotonic() if completes else None
+        self._enqueue(('send', conn, (data, sent_at), close_after,
+                       completes))
 
     def close_conn(self, conn, completes=False):
         """Close `conn` without a response (fault injection, torn
@@ -282,8 +288,9 @@ class IOLoop(object):
                 self._close(conn)
                 continue
             # send
+            data, sent_at = data
             if data:
-                conn.wbufs.append(memoryview(data))
+                conn.wbufs.append((memoryview(data), sent_at))
                 if conn.write_started is None:
                     conn.write_started = time.monotonic()
             if close_after:
@@ -409,7 +416,7 @@ class IOLoop(object):
 
     def _writable(self, conn):
         while conn.wbufs:
-            buf = conn.wbufs[0]
+            buf, sent_at = conn.wbufs[0]
             try:
                 n = conn.sock.send(buf[conn.wpos:])
             except (BlockingIOError, InterruptedError):
@@ -421,6 +428,12 @@ class IOLoop(object):
             if conn.wpos >= len(buf):
                 conn.wbufs.popleft()
                 conn.wpos = 0
+                if sent_at is not None:
+                    # a queue, on no thread that works for the
+                    # request: the global registry, once a reply
+                    obs_metrics.observe(
+                        'serve_reply_drain_ms',
+                        (time.monotonic() - sent_at) * 1000.0)
             if n == 0:
                 break
         if not conn.wbufs:

@@ -1243,27 +1243,32 @@ class DnServer(object):
                 self._workers.discard(threading.current_thread())
 
     def _send_response(self, conn, proto, rid, rc, out, err, extra):
-        data = mod_protocol.encode_response(rc, out, err, extra,
-                                            proto=proto, rid=rid)
-        try:
-            mod_faults.fire('serve.write')
-        except mod_faults.FaultInjected:
-            # injected write fault: drop the connection — the peer
-            # sees EOF before any header (pre-commit, retry-safe)
-            self.loop.close_conn(conn, completes=True)
-            return
-        if proto == mod_protocol.PROTO_V2:
+        # reply.frame: the frame's encoding and its hand-over to the
+        # I/O loop, on the request's worker thread after the request's
+        # own accounting (finish_obs): in stage_ms and on the profile,
+        # in neither serve_op_latency_ms nor serve_leaf_ms
+        with obs_metrics.leaf_stage('reply.frame'):
+            data = mod_protocol.encode_response(rc, out, err, extra,
+                                                proto=proto, rid=rid)
             try:
-                mod_faults.fire('serve.frame_torn')
+                mod_faults.fire('serve.write')
             except mod_faults.FaultInjected:
-                # a torn frame: half the response then EOF — the
-                # client must classify post-commit vs pre-commit by
-                # whether ITS header arrived, never hang
-                self.loop.send(conn, data[:max(1, len(data) // 2)],
-                               close_after=True, completes=True)
+                # injected write fault: drop the connection — the peer
+                # sees EOF before any header (pre-commit, retry-safe)
+                self.loop.close_conn(conn, completes=True)
                 return
-        self.loop.send(conn, data, close_after=(proto == 1),
-                       completes=True)
+            if proto == mod_protocol.PROTO_V2:
+                try:
+                    mod_faults.fire('serve.frame_torn')
+                except mod_faults.FaultInjected:
+                    # a torn frame: half the response then EOF — the
+                    # client must classify post-commit vs pre-commit by
+                    # whether ITS header arrived, never hang
+                    self.loop.send(conn, data[:max(1, len(data) // 2)],
+                                   close_after=True, completes=True)
+                    return
+            self.loop.send(conn, data, close_after=(proto == 1),
+                           completes=True)
 
     def execute(self, req, tenant=None, deadline_at=None):
         """Execute one request dict; returns (rc, stdout_bytes,
@@ -1486,11 +1491,14 @@ class DnServer(object):
             if want_trace else None
         obs_ctx = obs_trace.ObsContext(
             trace=tctx, registry=obs_metrics.Registry())
+        leaf_ms = [0.0]
 
         def job():
             # may run on the worker thread OR a deadline-armor
             # thread: stdio binding and the counter scope are
-            # thread-local, so both bind in here
+            # thread-local, so both bind in here -- and so is the
+            # leaves' total, read where the leaves end
+            leaf0 = obs_metrics.leaf_stage.thread_ms()
             with bound_stdio(cap), mod_vpipe.request_scope() as sc:
                 sc.obs = obs_ctx
                 try:
@@ -1580,11 +1588,16 @@ class DnServer(object):
                                      % (mod_cli.ARG0, e))
                     rc = 1
                 scope_out.update(sc)
+            leaf_ms[0] = obs_metrics.leaf_stage.thread_ms() - leaf0
             return rc
 
         def finish_obs(rc, extra):
             """Request-end accounting: merge the scoped registry,
-            record the per-op end-to-end latency, and emit/attach the
+            record the per-op end-to-end latency and, beside it, what
+            the request's thread spent under leaf stages
+            (serve_leaf_ms: with serve_queue_wait_ms the covered part
+            of the latency, whatever the leaves are called; 0 for a
+            job abandoned at its deadline), and emit/attach the
             span tree.  The subtree travels in the response header
             only when the CLIENT's trace header asked (its tracer
             grafts it) — /stats and response bytes stay byte-identical
@@ -1594,6 +1607,7 @@ class DnServer(object):
             reg.merge(obs_ctx.registry)
             reg.observe('serve_op_latency_ms', elapsed_ms,
                         op=str(op))
+            reg.observe('serve_leaf_ms', leaf_ms[0], op=str(op))
             if rc != 0:
                 reg.inc('serve_errors_total', op=str(op))
             if tctx is not None:
@@ -1738,7 +1752,17 @@ class DnServer(object):
             finally:
                 flags['slot'].release()
             return 0
+        # serve.resolve: what the request does before it asks for its
+        # execution: the config's load, the datasource, the query, the
+        # result cache's lookup (a hit formats its reply inside, under
+        # reply.format), a build's metrics and gates.  It ends
+        # (end_open) where the request goes to the coalescer or, a
+        # build, to its admission slot
+        with obs_metrics.leaf_stage('serve.resolve'):
+            return self._run_resolved(req, flags)
 
+    def _run_resolved(self, req, flags):
+        op = req['op']
         from .. import datasource_for_name, metrics_for_index
         cfg_path = req.get('config') or None
         if self.cluster is not None and \
@@ -1845,6 +1869,7 @@ class DnServer(object):
                 slot.release()
                 lease.release()
 
+        obs_metrics.leaf_stage.end_open('serve.resolve')
         try:
             result, shared = self.coalescer.run(key, compute,
                                                 lease=flags)
@@ -1907,6 +1932,7 @@ class DnServer(object):
         # with their missing_partitions/retryable attrs intact — the
         # job() handler frames the message and marks the header
         from . import router as mod_router
+        obs_metrics.leaf_stage.end_open('serve.resolve')
         try:
             (result, missing), shared = self.coalescer.run(
                 key, compute, lease=flags)
@@ -1989,6 +2015,7 @@ class DnServer(object):
                 slot.release()
                 lease.release()
 
+        obs_metrics.leaf_stage.end_open('serve.resolve')
         try:
             shards, shared = self.coalescer.run(key, compute,
                                                 lease=flags)
@@ -2032,6 +2059,7 @@ class DnServer(object):
                         for p in pids):
             mod_cli.fatal(DNError(
                 'bad "partitions" in shard_manifest request'))
+        obs_metrics.leaf_stage.end_open('serve.resolve')
         with self._tree_lock(ds, dsname).read(), \
                 obs_trace.span('serve.execute', op='shard_manifest'):
             try:
@@ -2064,6 +2092,7 @@ class DnServer(object):
                   isinstance(length, bool) or length < 1)):
             mod_cli.fatal(DNError(
                 'bad "offset"/"length" in shard_fetch request'))
+        obs_metrics.leaf_stage.end_open('serve.resolve')
         with self._tree_lock(ds, dsname).read(), \
                 obs_trace.span('serve.execute', op='shard_fetch'):
             try:
@@ -2162,6 +2191,7 @@ class DnServer(object):
         if not opts.dry_run:
             self.governor.check_writable('build')
         lease = self._admit_resources('build', ds)
+        obs_metrics.leaf_stage.end_open('serve.resolve')
         try:
             slot = flags['slot'] = self.admission.acquire(
                 tenant=flags.get('tenant'),

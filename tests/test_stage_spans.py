@@ -41,10 +41,28 @@ QUERY_STAGES = ('index_query_stack.load',
 # the request thread's last two: the aggregate's emission (the order,
 # the decode) and the formatting of the reply
 REPLY_STAGES = ('scan.order', 'reply.format')
+# PR 42, the request between its device phases, once a request each:
+# a scan's set-up and its deferred merge, the index write's routing
+# (its two phases were `timed_stage` before), the query's plan and the
+# stack's two ends
+BETWEEN = {
+    'scan': ('scan.init', 'scan.finish'),
+    'build': ('scan.init', 'scan.finish', 'index_build.bucket',
+              'index_build.prepare', 'index_build.commit'),
+    'query': ('index_query.paths', 'index_query.prune',
+              'index_query_stack.stack', 'index_query_stack.commit'),
+}
+# a resident server's own: the request before its execution (on its
+# thread), and the reply's frame (after the request's accounting)
+SERVED = ('serve.resolve', 'reply.frame')
 # which request must have met which leaves
-LEAVES = {'scan': SCAN_STAGES + REPLY_STAGES, 'build': SCAN_STAGES,
-          'query': QUERY_STAGES + REPLY_STAGES}
-ALL_LEAVES = set(SCAN_STAGES + QUERY_STAGES + REPLY_STAGES)
+LEAVES = {'scan': SCAN_STAGES + REPLY_STAGES + BETWEEN['scan'],
+          'build': SCAN_STAGES + BETWEEN['build'],
+          'query': QUERY_STAGES + REPLY_STAGES + BETWEEN['query']}
+ALL_LEAVES = set(SCAN_STAGES + QUERY_STAGES + REPLY_STAGES + SERVED +
+                 sum(BETWEEN.values(), ()))
+# in `stage_ms` and no leaf: they enclose leaves
+ENCLOSING = ('index_query_stack.aggregate', 'device_scan.probe')
 
 NRECORDS = 3000
 SMALL_BATCH = 512
@@ -273,6 +291,173 @@ def test_reply_stages_are_the_request_threads(runs, op, stage):
     assert runs[op]['stages'][stage][0] == 1
 
 
+# -- (2b) a resident server's requests: the leaves between the phases ------
+
+def hist_table():
+    """{(name, labels): (count, sum)} of the global registry's
+    histograms other than `stage_ms`."""
+    return {(name, labels): (m.total, m.sum)
+            for name, labels, m in obs_metrics.global_registry().snapshot()
+            if m.kind == obs_metrics.HISTOGRAM and name != 'stage_ms'}
+
+
+@pytest.fixture(scope='module')
+def served(tmp_path_factory):
+    """{op: {'stages', 'hists'}} of one scan, one build and one index
+    query through a resident server of this process, each the second
+    of its kind (the first device contact runs on a deadline thread of
+    its own, whose leaves are not the request thread's)."""
+    from dragnet_tpu import device_scan as mod_ds
+    from dragnet_tpu import engine as mod_engine
+    from dragnet_tpu.serve import client as mod_client
+    root = str(tmp_path_factory.mktemp('stage_served'))
+    got = {}
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(mod_engine, 'BATCH_SIZE', SMALL_BATCH)
+        mp.setattr(mod_ds, 'BATCH_SIZE', SMALL_BATCH)
+        for k, v in (('DN_READ_SIZE', '16384'), ('DN_ENGINE', 'jax'),
+                     ('DN_INDEX_DEVICE', '1'), ('DN_PARSE_THREADS', '1'),
+                     ('DRAGNET_CONFIG', '')):
+            mp.setenv(k, v)
+        for k in ('DN_TRACE', 'DN_SLOW_MS', 'DN_SERVE_CACHE_MB'):
+            mp.delenv(k, raising=False)
+        add_datasource(root)
+        srv = mod_server.DnServer(
+            socket_path=os.path.join(root, 's.sock'),
+            conf={'max_inflight': 2, 'queue_depth': 4, 'deadline_ms': 0,
+                  'coalesce': True, 'drain_s': 10}).start()
+        base = {'ds': 'stageds', 'config': os.environ['DRAGNET_CONFIG']}
+        by_host = {'breakdowns': [{'name': 'host', 'field': 'host'}]}
+        reqs = {'scan': dict(base, op='scan', queryconfig=by_host,
+                             opts={'points': True}),
+                'build': dict(base, op='build', interval='day', opts={}),
+                'query': dict(base, op='query', interval='day',
+                              queryconfig=by_host, opts={'points': True})}
+        try:
+            for op in ('scan', 'build', 'query'):
+                for attempt in (0, 1):
+                    obs_metrics.reset_global_registry()
+                    rc, _, out, err = mod_client.request_bytes(
+                        srv.socket_path, reqs[op])
+                    assert rc == 0, err
+                    # the reply's frame and its drain are observed
+                    # after the client has its bytes: by the worker,
+                    # by the I/O loop
+                    limit = time.monotonic() + 10.0
+                    while time.monotonic() < limit:
+                        stages, hists = stage_table()[0], hist_table()
+                        if 'reply.frame' in stages and \
+                                ('serve_reply_drain_ms', ()) in hists:
+                            break
+                        time.sleep(0.01)
+                got[op] = {'stages': stages, 'hists': hists, 'out': out}
+        finally:
+            srv.stop()
+        rc, got['cli_query_out'], err = run_cli(
+            ['query', '-b', 'host', '--points', 'stageds'])
+        assert rc == 0, err
+    return got
+
+
+@pytest.mark.parametrize('op,stage', [
+    (op, s) for op in ('scan', 'build', 'query')
+    for s in BETWEEN[op] + SERVED])
+def test_a_served_request_meets_each_new_leaf_once(served, op, stage):
+    assert served[op]['stages'].get(stage, (0, 0.0))[0] == 1
+
+
+@pytest.mark.parametrize('op', ['scan', 'build', 'query'])
+def test_leaf_ms_is_the_request_threads_leaves(served, op):
+    """`serve_leaf_ms{op}` is counted where the leaves end, by no list
+    of names: it equals the `stage_ms` of every leaf but the parser
+    thread's two and the reply's frame (which follows the request's
+    accounting), and fits into the request's latency."""
+    r = served[op]
+    assert not set(r['stages']) - ALL_LEAVES - set(ENCLOSING)
+    own = sum(ms for s, (_n, ms) in r['stages'].items()
+              if s in ALL_LEAVES and s not in PRODUCER_STAGES and
+              s != 'reply.frame')
+    n, leaf_ms = r['hists'][('serve_leaf_ms', (('op', op),))]
+    n_lat, latency = r['hists'][('serve_op_latency_ms', (('op', op),))]
+    assert (n, n_lat) == (1, 1)
+    assert leaf_ms == pytest.approx(own, rel=0.01)
+    assert 0 < own <= latency
+
+
+@pytest.mark.parametrize('op', ['scan', 'build', 'query'])
+def test_the_replys_drain_is_observed_once_a_request(served, op):
+    n, ms = served[op]['hists'][('serve_reply_drain_ms', ())]
+    assert n == 1 and ms >= 0.0
+
+
+def test_a_served_query_answers_as_the_cli_does(served):
+    """The same tree, the same question: the server's reply under its
+    leaves is the CLI's answer, byte for byte."""
+    assert served['query']['out']
+    assert served['query']['out'] == served['cli_query_out']
+
+
+def test_end_open_ends_the_leaf_once_and_counts_once():
+    """A leaf ended where its part ends (`end_open`), inside its own
+    `with`: one observation, the thread's total grows by its self time,
+    and nothing stays open."""
+    obs_metrics.reset_global_registry()
+    before = obs_metrics.leaf_stage.thread_ms()
+    with obs_metrics.leaf_stage('t.early'):
+        time.sleep(0.01)
+        obs_metrics.leaf_stage.end_open('t.early')
+        assert getattr(obs_metrics._LEAF, 'top', None) is None
+        obs_metrics.leaf_stage.end_open('t.early')
+        with obs_metrics.leaf_stage('t.after'):
+            time.sleep(0.01)
+    stages, _ = stage_table()
+    assert stages['t.early'][0] == 1 and stages['t.after'][0] == 1
+    assert 10.0 <= stages['t.early'][1] < 20.0
+    grown = obs_metrics.leaf_stage.thread_ms() - before
+    assert grown == pytest.approx(
+        stages['t.early'][1] + stages['t.after'][1])
+    assert getattr(obs_metrics._LEAF, 'top', None) is None
+
+
+@pytest.mark.parametrize('inner', [None, 't.inner'])
+def test_end_open_ends_only_the_threads_open_leaf(inner):
+    """Asked for another name than the open leaf's, or for a leaf
+    under which another is still open, `end_open` does nothing: the
+    leaves end innermost first, each once, by their `with`."""
+    obs_metrics.reset_global_registry()
+    with obs_metrics.leaf_stage('t.outer') as outer:
+        if inner is None:
+            obs_metrics.leaf_stage.end_open('t.other')
+        else:
+            with obs_metrics.leaf_stage(inner) as leaf:
+                obs_metrics.leaf_stage.end_open('t.outer')
+                assert obs_metrics._LEAF.top is leaf
+                # nor does an exit out of order end the outer leaf
+                outer.__exit__(None, None, None)
+                assert obs_metrics._LEAF.top is leaf
+        assert obs_metrics._LEAF.top is outer
+        assert 't.outer' not in stage_table()[0]
+    stages, _ = stage_table()
+    assert stages['t.outer'][0] == 1
+    assert stages.get(inner, (1,))[0] == 1
+    assert getattr(obs_metrics._LEAF, 'top', None) is None
+
+
+def test_a_leaf_of_another_thread_is_not_ended_here():
+    """A leaf belongs to the thread that opened it: its exit on
+    another thread leaves both threads' open leaves as they were."""
+    import threading
+    obs_metrics.reset_global_registry()
+    with obs_metrics.leaf_stage('t.mine') as leaf:
+        t = threading.Thread(target=leaf.__exit__,
+                             args=(None, None, None))
+        t.start()
+        t.join()
+        assert obs_metrics._LEAF.top is leaf
+        assert 't.mine' not in stage_table()[0]
+    assert stage_table()[0]['t.mine'][0] == 1
+
+
 # -- (3) the profiler leg ----------------------------------------------------
 
 PROFILED = r'''
@@ -305,6 +490,11 @@ try:
                              profiler_options=opts)
     open(os.environ['DN_TRACE'], 'w').close()
     rc, _, out, err = mod_client.request_bytes(srv.socket_path, req)
+    assert rc == 0, err
+    rc, _, out, err = mod_client.request_bytes(
+        srv.socket_path, {'op': 'build', 'ds': 'stageds',
+                          'config': os.environ['DRAGNET_CONFIG'],
+                          'opts': {}})
     jax.profiler.stop_trace()
     assert rc == 0, err
 finally:
@@ -351,9 +541,19 @@ def test_profiler_host_plane_holds_the_leaves_only(tmp_path):
     for stage in ('scan.parse', 'scan.stage', 'scan.fetch'):
         assert stage in events, sorted(events)
     assert 'serve.execute' not in events
-    assert not [n for n in events if n.startswith('serve.')]
-    assert len(doc['trace_ids']) == 1
+    # of the server's own spans only the leaves: the request before its
+    # execution, not the execution, not the wait for a slot
+    assert [n for n in events if n.startswith('serve.')] == \
+        ['serve.resolve']
+    assert len(doc['trace_ids']) == 2      # the scan, the build
     assert events['scan.parse'] == doc['trace_ids']
+    # the index write's two were `timed_stage` until PR 42 and never
+    # reached the profiler; the same names, leaves now
+    for stage in ('index_build.prepare', 'index_build.commit',
+                  'scan.init', 'scan.finish', 'reply.frame'):
+        assert stage in events, sorted(events)
+    assert events['index_build.prepare'] == events['index_build.commit']
+    assert len(events['index_build.commit']) == 1
 
 
 # -- (4) what the benchmark's reducer makes of an annotation ----------------
