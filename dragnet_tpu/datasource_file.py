@@ -1010,14 +1010,17 @@ class DatasourceFile(object):
                     None)
         return DNError('unsupported interval: "%s"' % interval)
 
-    def _cached_index_walk(self, root, pipeline):
-        """The unbounded index-tree walk, memoized on the directory's
-        stat identity (index_query_mt.cached_find_walk) — the cluster
-        backend overrides this to partition the cached listing across
-        processes, the same way its _find override partitions fresh
-        walks."""
-        from . import index_query_mt as mod_iqmt
-        return mod_iqmt.cached_find_walk(root, pipeline)
+    def _cached_index_walk(self, snap, timeformat, after, before,
+                           pipeline):
+        """The index-tree walk answered from the directory's snapshot
+        (index_query_mt.TreeSnapshot): the whole tree for an unbounded
+        query, the window's names for a bounded one, None where the
+        snapshot cannot answer and _find must — the cluster backend
+        overrides this to partition the listing across processes, the
+        same way its _find override partitions fresh walks."""
+        if before is None:
+            return snap.whole_walk(pipeline)
+        return snap.bounded_walk(timeformat, after, before, pipeline)
 
     def index_query_paths(self, query, interval, pipeline):
         """Enumerate the shard files an index query over `query` x
@@ -1030,6 +1033,12 @@ class DatasourceFile(object):
         executor (serve/router.py), so a member's partition-filtered
         shard set is drawn from the IDENTICAL walk a single-process
         query performs."""
+        return self._index_query_walk(query, interval, pipeline)[:3]
+
+    def _index_query_walk(self, query, interval, pipeline):
+        """index_query_paths, and with its triple the directory
+        snapshot that answered the walk (None where _find did):
+        query() reads the pruned count off the same listing."""
         error = self.check_time_args(query.qc_after, query.qc_before)
         if error is None:
             error = self.check_index_args(interval, True, False)
@@ -1048,29 +1057,62 @@ class DatasourceFile(object):
         from . import index_journal as mod_journal
         mod_journal.maybe_sweep(self.ds_indexpath)
 
-        if before is None and pipeline.warn_func is None:
-            # unbounded query over a flat index tree: the whole-tree
-            # walk (one stat per shard) is memoized on the directory's
-            # stat identity — stage counters replay byte-identically
-            files = self._cached_index_walk(root, pipeline)
-        else:
+        # the tree's listing is kept under the directory's stat
+        # identity (one os.stat a query proves it current) and the
+        # walk's stage counters replay byte-identically; warn_func
+        # consumers, and a window the snapshot cannot answer, take the
+        # real walk
+        from . import index_query_mt as mod_iqmt
+        snap = files = None
+        if pipeline.warn_func is None:
+            snap = mod_iqmt.tree_snapshot(root)
+        if snap is not None:
+            files = self._cached_index_walk(snap, timeformat, after,
+                                            before, pipeline)
+        if files is None:
+            snap = None
             files = self._find(root, timeformat, after, before, pipeline)
         if isinstance(files, DNError):
             raise files
         # never open build machinery as a shard: journals, in-flight
         # tmps (a concurrent builder's), and the quarantine directory
-        # stay out of the shard set
-        files = [(p, st) for p, st in files
-                 if not mod_journal.is_index_litter(p)]
+        # stay out of the shard set (the snapshot's bounded walk names
+        # base shards only: its layout left the litter out)
+        if snap is None or before is None:
+            files = [(p, st) for p, st in files
+                     if not mod_journal.is_index_litter(p)]
         if timeformat is not None:
             # follow --append mini-generations: bounded finds
             # enumerate exact in-window filenames and can never name
             # a `<shard>.sqlite-gNNNNNN`; splice existing generations
             # in after their bases (unbounded walks see them
             # naturally)
-            from . import rollup as mod_rollup
-            files = mod_rollup.augment_generation_files(root, files)
-        return root, timeformat, files
+            if snap is not None:
+                files = snap.splice_generations(files)
+            else:
+                from . import rollup as mod_rollup
+                files = mod_rollup.augment_generation_files(root, files)
+        return root, timeformat, files, snap
+
+    def _prune_index_paths(self, root, timeformat, files, snap, query):
+        """(paths, npruned) of a walk's files: the shards whose
+        filename window meets the query's, and how many of the tree's
+        do not."""
+        from . import index_query_mt as mod_iqmt
+        paths = [p for p, st in files]
+        if snap is not None:
+            # the snapshot's walk named the window's shards and no
+            # other: every one is kept
+            return paths, snap.count_pruned(
+                timeformat, query.qc_after, query.qc_before)
+        paths, npruned = mod_iqmt.prune_shards(
+            paths, timeformat, query.qc_after, query.qc_before)
+        # time-bounded finds never enumerate out-of-window shards,
+        # so count the tree's skipped files for the pruned counter
+        # (the found list can only re-prune what enumeration
+        # missed)
+        return paths, max(npruned, mod_iqmt.count_pruned_shards(
+            root, timeformat, query.qc_after, query.qc_before))
 
     def query(self, query, interval, dry_run=False):
         """Query the indexes.  (reference:
@@ -1080,7 +1122,7 @@ class DatasourceFile(object):
         # (index_query.paths); the pruning, the integrity check and
         # the rollup planner (index_query.prune)
         with obs_metrics.leaf_stage('index_query.paths'):
-            root, timeformat, files = self.index_query_paths(
+            root, timeformat, files, snap = self._index_query_walk(
                 query, interval, pipeline)
 
         if dry_run:
@@ -1100,15 +1142,8 @@ class DatasourceFile(object):
         from . import integrity as mod_integrity
         from . import rollup as mod_rollup
         with obs_metrics.leaf_stage('index_query.prune'):
-            paths = [p for p, st in files]
-            paths, npruned = mod_iqmt.prune_shards(
-                paths, timeformat, query.qc_after, query.qc_before)
-            # time-bounded finds never enumerate out-of-window shards,
-            # so count the tree's skipped files for the pruned counter
-            # (the found list can only re-prune what enumeration
-            # missed)
-            npruned = max(npruned, mod_iqmt.count_pruned_shards(
-                root, timeformat, query.qc_after, query.qc_before))
+            paths, npruned = self._prune_index_paths(
+                root, timeformat, files, snap, query)
             if npruned:
                 index_list.bump_hidden('index shards pruned', npruned)
             index_list.bump_hidden('index shards queried', len(paths))

@@ -9,7 +9,7 @@ nothing (index_query_p50_ms 238.7 vs sequential 218.6 over 365 shards)
 because per-query shard *open* cost — footer parse, config/metrics
 parse, dictionary decode — dominates and repeats on every query.
 
-This module owns the three serving-path optimizations:
+This module owns the four serving-path optimizations:
 
 * ShardQueryExecutor: a bounded worker pool that queries shards
   concurrently and merges per-shard point lists IN FIND ORDER on the
@@ -36,10 +36,20 @@ This module owns the three serving-path optimizations:
   worker at a time; index writers invalidate rewritten paths.  A
   watchdog.LeakCheck makes undrained executors and leaked (never
   checked-in) handles fail loudly at exit.
+
+* One snapshot of each index directory (TreeSnapshot): its sorted
+  names, their parsed windows and generations, each name's stat the
+  first time a query asks for it, and the unbounded walk, kept under
+  the directory's (mtime_ns, size, inode).  A query's plan — the walk,
+  the generations spliced in, the kept list and the pruned count — is
+  one os.stat of the directory and two bisections; index writers drop
+  the snapshot with the handles they invalidate.
 """
 
+import bisect
 import os
 import queue
+import stat as mod_stat
 import threading
 import time
 from collections import OrderedDict
@@ -592,7 +602,7 @@ def shard_cache_invalidate(path):
         _INVAL_GEN[path] = _INVAL_GEN.get(path, 0) + 1
         handle = _CACHE.pop(path, None)
     with _FIND_LOCK:
-        _FIND_CACHE.pop(os.path.dirname(path), None)
+        _drop_snapshots([os.path.dirname(path)])
     if handle is not None:
         handle.querier.close()
 
@@ -612,6 +622,8 @@ def shard_cache_clear():
     _fanout_reset()
     with _FIND_LOCK:
         _FIND_CACHE.clear()
+        _FIND_DROPPED.clear()
+        _FIND_STATS.update(hits=0, rebuilds={})
     for handle in handles:
         handle.querier.close()
 
@@ -646,18 +658,21 @@ def invalidate_index_tree(root):
         # reopen each)
         _EPOCH[0] += 1
     with _FIND_LOCK:
-        for d in [d for d in _FIND_CACHE
-                  if os.path.abspath(d) == root or
-                  os.path.abspath(d).startswith(prefix)]:
-            _FIND_CACHE.pop(d)
+        _drop_snapshots([d for d in _FIND_CACHE
+                         if os.path.abspath(d) == root or
+                         os.path.abspath(d).startswith(prefix)])
     for handle in closing:
         handle.querier.close()
 
 
 def find_cache_stats():
-    """Size of the whole-tree find memo (`dn serve` /stats)."""
+    """The directory snapshots kept, how often one answered a query's
+    walk and how often one was read anew, by reason (`dn serve`
+    /stats)."""
     with _FIND_LOCK:
-        return {'size': len(_FIND_CACHE)}
+        return {'size': len(_FIND_CACHE),
+                'snapshot_hits': _FIND_STATS['hits'],
+                'snapshot_rebuilds': dict(_FIND_STATS['rebuilds'])}
 
 
 def cache_epoch():
@@ -670,49 +685,285 @@ def cache_epoch():
         return _EPOCH[0]
 
 
-# -- shard-list (find) cache ----------------------------------------------
+# -- directory snapshot (find) cache ---------------------------------------
 
-# root directory -> (dir statkey, [(path, stat)], stage snapshot).
-# Unbounded queries walk the whole flat index tree — one os.stat per
-# shard, ~25 ms of syscalls on a 365-shard year — to produce a file
-# list the serving path then reads THROUGH the handle cache anyway.
-# The listing is a pure function of the directory, whose own stat
-# identity changes on every add/remove/rename within it (shard
-# rewrites land via tmp+rename), so one directory stat validates the
-# whole cached walk; in-process writers invalidate explicitly via
-# shard_cache_invalidate, same contract as the handle cache.
+# root directory -> TreeSnapshot: ONE listing of an index directory
+# that every part of a query's plan reads — the unbounded walk (one
+# os.stat per shard, ~25 ms of syscalls on a 365-shard year), the
+# bounded walk's in-window names, the follow generations spliced after
+# their bases and the pruned count.  The listing is a pure function of
+# the directory, whose own stat identity changes on every
+# add/remove/rename within it (shard rewrites land via tmp+rename), so
+# one directory stat validates the whole snapshot; in-process writers
+# invalidate explicitly via shard_cache_invalidate, same contract as
+# the handle cache.
 _FIND_LOCK = threading.Lock()
 _FIND_CACHE = {}
+# root -> why its snapshot went ('invalidated' | 'racy'): only the
+# label of the rebuild that follows (find_cache_stats, the obs counter)
+_FIND_DROPPED = {}
+_FIND_STATS = {'hits': 0, 'rebuilds': {}}
+
+# A rename that lands in the same timestamp tick as a snapshot's own
+# listing leaves the directory's identity as the snapshot recorded it.
+# As git does with racily clean index entries: a snapshot whose
+# directory mtime is not older than the moment it was read by more
+# than any filesystem's timestamp granularity (FAT's 2 s is the
+# coarsest) cannot be proved current, so it serves the query that
+# built it and is not kept.
+_RACY_MARGIN_NS = 2500 * 1000 * 1000
+
+def _note_dropped(root, reason):
+    if len(_FIND_DROPPED) >= 64:
+        _FIND_DROPPED.clear()
+    _FIND_DROPPED[root] = reason
 
 
-def cached_find_walk(root, pipeline):
-    """find_walk([root]) memoized on the directory's stat identity,
-    replaying the walk's pipeline stages and counters exactly (the
-    --counters bytes are pinned).  Only for the index-query path: the
-    cached per-file statbufs go stale (the query path never reads
-    them), and warn_func consumers must take the real walk."""
-    from . import find as mod_find
-    statkey = _statkey(root)
-    if statkey is not None:
-        with _FIND_LOCK:
-            cached = _FIND_CACHE.get(root)
-        if cached is not None and cached[0] == statkey:
-            _, files, stages = cached
-            for name, counters, hidden in stages:
-                stage = pipeline.stage(name)
-                stage.counters.update(counters)
-                stage.hidden.update(hidden)
+def _drop_snapshots(roots):
+    """Forget the snapshots of `roots` (caller holds _FIND_LOCK)."""
+    for root in roots:
+        if _FIND_CACHE.pop(root, None) is not None:
+            _note_dropped(root, 'invalidated')
+
+
+class _Layout(object):
+    """A snapshot's names parsed by one strftime layout: the base
+    shards ordered by window (a query's window is two bisections) and
+    every parseable name's start (bases and generations: what
+    count_pruned_shards counts)."""
+
+    __slots__ = ('unit_ms', 'starts', 'pairs', 'paths', 'all_starts')
+
+    def __init__(self, unit_ms, bases, all_starts):
+        self.unit_ms = unit_ms
+        self.starts = [start for start, _ in bases]
+        self.paths = [path for _, path in bases]
+        # (path, statbuf) per base, filled the first time a query
+        # asks for that name
+        self.pairs = [None] * len(bases)
+        self.all_starts = all_starts
+
+
+class TreeSnapshot(object):
+    """One listing of an index directory under the directory's stat
+    identity, filled by what is asked of it: the unbounded walk
+    (find_walk's files and counters, replayed), or the sorted names
+    with their generations and, per layout, their parsed windows and
+    lazily taken stats.  Every method answers None for "ask the
+    filesystem": the caller then takes today's _find and today's
+    functions whole, so warnings and errors keep their bytes."""
+
+    __slots__ = ('root', 'statkey', '_walk', '_names', '_gens',
+                 '_gen_names', '_gen_pairs', '_layouts')
+
+    def __init__(self, root, statkey):
+        self.root = root
+        self.statkey = statkey
+        self._walk = None
+        self._names = None
+        self._gens = None
+        self._gen_names = None
+        self._gen_pairs = {}
+        self._layouts = {}
+
+    def _listing(self):
+        """The directory's names, sorted, and its follow generations
+        ({base path: [generation name]} in generation order) — one
+        listdir a snapshot."""
+        if self._names is None:
+            from . import rollup as mod_rollup
+            try:
+                names = sorted(os.listdir(self.root))
+            except OSError:
+                return None
+            gens = {}
+            gen_names = set()
+            for name in names:
+                base, gen = mod_rollup.split_generation(name)
+                if gen is not None:
+                    gens.setdefault(os.path.join(self.root, base),
+                                    []).append((gen, name))
+                    gen_names.add(name)
+            self._gens = dict((base, [name for _, name in sorted(found)])
+                              for base, found in gens.items())
+            self._gen_names = gen_names
+            self._names = names
+        return self._names
+
+    def _layout(self, timeformat):
+        """The names parsed by `timeformat`, once a snapshot; None
+        for a layout whose names are not one fixed unit each (only
+        '%Y..%m..%d' and '%Y..%m..%d..%H' trees exist) or a directory
+        that cannot be listed."""
+        layout = self._layouts.get(timeformat)
+        if layout is None:
+            layout = self._layouts[timeformat] = \
+                self._parse_layout(timeformat) or False
+        return layout or None
+
+    def _parse_layout(self, timeformat):
+        from . import index_journal as mod_journal
+        if os.path.basename(timeformat) != timeformat:
+            return None
+        entries = _layout_entries(timeformat)
+        if entries is None:
+            return None
+        kinds = [e['kind'] for e in entries if e['kind'] != 'str']
+        if sorted(kinds) not in (['Y', 'd', 'm'], ['H', 'Y', 'd', 'm']):
+            return None
+        names = self._listing()
+        if names is None:
+            return None
+        bases = []
+        all_starts = []
+        for name in names:
+            window = _range_from_entries(name, entries)
+            if window is None:
+                continue
+            all_starts.append(window[0])
+            if name not in self._gen_names and \
+                    not mod_journal.is_index_litter(name):
+                bases.append((window[0], os.path.join(self.root, name)))
+        bases.sort()
+        all_starts.sort()
+        return _Layout(3600000 if 'H' in kinds else 86400000, bases,
+                       all_starts)
+
+    def whole_walk(self, pipeline):
+        """find_walk([root]) once a snapshot, its pipeline stages and
+        counters replayed exactly (the --counters bytes are pinned).
+        Only for the index-query path: the kept per-file statbufs age
+        with the snapshot."""
+        if self._walk is None:
+            nstages = len(pipeline.stages)
+            files = mod_find.find_walk([self.root], pipeline)
+            self._walk = (files,
+                          [(s.name, dict(s.counters), set(s.hidden))
+                           for s in pipeline.stages[nstages:]])
             return list(files)
-    nstages = len(pipeline.stages)
-    files = mod_find.find_walk([root], pipeline)
-    if statkey is not None:
-        stages = [(s.name, dict(s.counters), set(s.hidden))
-                  for s in pipeline.stages[nstages:]]
-        with _FIND_LOCK:
-            if len(_FIND_CACHE) >= 64:
-                _FIND_CACHE.pop(next(iter(_FIND_CACHE)))
-            _FIND_CACHE[root] = (statkey, list(files), stages)
-    return files
+        files, stages = self._walk
+        for name, counters, hidden in stages:
+            stage = pipeline.stage(name)
+            stage.counters.update(counters)
+            stage.hidden.update(hidden)
+        return list(files)
+
+    def bounded_walk(self, timeformat, after_ms, before_ms, pipeline):
+        """What find_walk over create_path_enumerator(root/timeformat,
+        after_ms, before_ms) returns and bumps, from the snapshot: the
+        window's base shards as (path, statbuf) in find order.  None
+        when a name the window enumerates is not a regular file the
+        snapshot holds (find_walk then warns `badstat`, or descends)."""
+        layout = self._layout(timeformat)
+        if layout is None or not 0 <= after_ms < before_ms:
+            return None
+        unit = layout.unit_ms
+        first = after_ms - after_ms % unit
+        n = -((first - before_ms) // unit)
+        starts = layout.starts
+        lo = bisect.bisect_left(starts, first)
+        hi = lo + n
+        # starts are distinct multiples of the unit: n of them from
+        # `first` on, the last where the window's last name starts,
+        # are every name the enumerator would expand
+        if hi > len(starts) or starts[hi - 1] != first + (n - 1) * unit:
+            return None
+        files = layout.pairs[lo:hi]
+        if None in files:
+            for i in range(lo, hi):
+                if layout.pairs[i] is None:
+                    path = layout.paths[i]
+                    try:
+                        st = os.stat(path)
+                    except OSError:
+                        return None
+                    if not mod_stat.S_ISREG(st.st_mode):
+                        return None
+                    layout.pairs[i] = (path, st)
+            files = layout.pairs[lo:hi]
+        # the counters of PathEnumerator.paths and find_walk for n
+        # roots that are all regular files (n + 1 with the EOF signal)
+        pipeline.stage('PathEnumerator').counters['noutputs'] = \
+            n + 1 if n < 20 else n
+        pipeline.stage('FindStart').counters.update(
+            ninputs=n, noutputs=n)
+        pipeline.stage('FindStatter').counters.update(
+            ninputs=n + 1, noutputs=n + 1)
+        pipeline.stage('FindTraverser').counters.update(
+            ninputs=n + 1, noutputs=n + 1)
+        pipeline.stage('FindFeedback').counters.update(
+            ninputs=n + 1, nregfiles=n, noutputs=n)
+        return files
+
+    def splice_generations(self, files):
+        """rollup.augment_generation_files from the snapshot: the
+        follow generations it lists inserted after their bases, each
+        statted the first time a query reaches it (one that vanished
+        since the listing is skipped, as a racing find misses it)."""
+        if self._listing() is None or not self._gens:
+            return files
+        present = set(p for p, _st in files)
+        out = []
+        for p, st in files:
+            out.append((p, st))
+            for name in self._gens.get(p, ()):
+                gp = os.path.join(self.root, name)
+                if gp in present:
+                    continue
+                pair = self._gen_pairs.get(gp)
+                if pair is None:
+                    try:
+                        pair = (gp, os.stat(gp))
+                    except OSError:
+                        continue
+                    self._gen_pairs[gp] = pair
+                out.append(pair)
+        return out
+
+    def count_pruned(self, timeformat, after_ms, before_ms):
+        """count_pruned_shards from the snapshot: the parsed names
+        less those whose window meets [after_ms, before_ms)."""
+        if timeformat is None or before_ms is None or after_ms is None:
+            return 0
+        layout = self._layout(timeformat)
+        starts = layout.all_starts
+        inside = bisect.bisect_left(starts, before_ms) - \
+            bisect.bisect_right(starts, after_ms - layout.unit_ms)
+        return len(starts) - inside
+
+
+def tree_snapshot(root):
+    """The snapshot of index directory (or `all` file) `root`, proved
+    current by one os.stat; None when `root` cannot be statted."""
+    from .obs import metrics as obs_metrics
+    now_ns = time.time_ns()
+    statkey = _statkey(root)
+    if statkey is None:
+        return None
+    reason = None
+    with _FIND_LOCK:
+        snap = _FIND_CACHE.get(root)
+        if snap is not None and snap.statkey == statkey:
+            _FIND_STATS['hits'] += 1
+        else:
+            reason = 'identity' if snap is not None \
+                else _FIND_DROPPED.pop(root, 'cold')
+            snap = TreeSnapshot(root, statkey)
+            if statkey[0] > now_ns - _RACY_MARGIN_NS:
+                _FIND_CACHE.pop(root, None)
+                _note_dropped(root, 'racy')
+            else:
+                if root not in _FIND_CACHE and len(_FIND_CACHE) >= 64:
+                    _FIND_CACHE.pop(next(iter(_FIND_CACHE)))
+                _FIND_CACHE[root] = snap
+            rebuilds = _FIND_STATS['rebuilds']
+            rebuilds[reason] = rebuilds.get(reason, 0) + 1
+    if reason is None:
+        obs_metrics.inc('index_walk_snapshot_hits_total')
+    else:
+        obs_metrics.inc('index_walk_snapshot_rebuilds_total',
+                        reason=reason)
+    return snap
 
 
 # -- query execution ------------------------------------------------------

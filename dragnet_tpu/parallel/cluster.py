@@ -103,12 +103,15 @@ class DatasourceCluster(datasource_file.DatasourceFile):
             files = mod_dist.partition_files(files, nprocs, pid)
         return files
 
-    def _cached_index_walk(self, root, pipeline):
-        """The memoized index-tree walk lists the WHOLE tree; this
-        process keeps only its partition, mirroring the _find
+    def _cached_index_walk(self, snap, timeformat, after, before,
+                           pipeline):
+        """The snapshot's walk lists what a single process would walk;
+        this process keeps only its partition, mirroring the _find
         override."""
         files = super(DatasourceCluster, self)._cached_index_walk(
-            root, pipeline)
+            snap, timeformat, after, before, pipeline)
+        if files is None:
+            return None
         nprocs, pid = mod_dist.maybe_initialize()
         if nprocs > 1:
             files = mod_dist.partition_files(files, nprocs, pid)

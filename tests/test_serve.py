@@ -506,6 +506,52 @@ def test_metrics_scrape_carries_the_process_memory(server, corpus):
         first['dn_process_minor_faults_total'] + 1024
 
 
+# -- the index walk's directory snapshot at a scrape --------------------------
+
+def test_second_query_counts_a_snapshot_hit(server, corpus):
+    """A resident server reads a tree's directory once: the first
+    bounded query builds its snapshot
+    (`index_walk_snapshot_rebuilds_total{reason="cold"}`), the next one
+    of any window finds it current by the directory's stat
+    (`index_walk_snapshot_hits_total`); `/stats` says the same beside
+    `find_memo`."""
+    from dragnet_tpu import index_query_mt as mod_iqmt
+    hits = 'dn_index_walk_snapshot_hits_total'
+    cold = 'dn_index_walk_snapshot_rebuilds_total{reason="cold"}'
+
+    def scrape():
+        rc, hd, out, err = mod_client.request_bytes(
+            server.socket_path, {'op': 'metrics'})
+        assert rc == 0, err
+        doc = dict(ln.rsplit(' ', 1) for ln in out.decode().splitlines()
+                   if not ln.startswith('#'))
+        return {k: float(v) for k, v in doc.items()
+                if k.startswith('dn_index_walk_snapshot_')}
+
+    # a snapshot is kept once its directory is older than the racy
+    # margin: this tree was built moments ago
+    root = os.path.join(str(corpus['root']), 'idx_dnc', 'by_day')
+    old = time.time() - 60
+    os.utime(root, (old, old))
+    mod_iqmt.shard_cache_clear()
+    t0 = 1388534400000
+    first = scrape()
+    for days in (2, 3):
+        req = _req('ds_dnc', corpus)
+        req['queryconfig'].update(timeAfter=t0,
+                                  timeBefore=t0 + days * 86400000)
+        rc, hd, out, err = mod_client.request_bytes(server.socket_path,
+                                                    req)
+        assert rc == 0, err
+        assert out
+    second = scrape()
+    grew = {k: second[k] - first.get(k, 0) for k in second}
+    assert {k: v for k, v in grew.items() if v} == {hits: 1, cold: 1}
+    memo = mod_client.stats(server.socket_path)['caches']['find_memo']
+    assert memo == {'size': 1, 'snapshot_hits': 1,
+                    'snapshot_rebuilds': {'cold': 1}}
+
+
 # -- a columnar result's reply: formatted by column, never as dicts -----------
 
 WIDE_TUPLES = 9000          # past Aggregator.FLAT_COLUMNAR_MIN (8,192)
