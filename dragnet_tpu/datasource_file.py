@@ -1217,13 +1217,8 @@ class DatasourceFile(object):
         # per-shard loop when the query shape or the exactness gate
         # (non-integer weights) demands it, or under DN_IQ_STACK=0.
         from . import index_query_stack as mod_iqs
-        stacked = False
-        if mod_iqs.stack_enabled() and mod_iqs.stack_eligible(query):
-            stacked = mod_iqs.run_stacked(paths, query, aggr,
-                                          index_list)
-
-        if not stacked:
-            mod_iqmt.run_shard_queries(paths, query, nworkers, merge)
+        mod_iqs.run_index_query(paths, query, aggr, index_list,
+                                nworkers, merge)
 
         return ScanResult(pipeline, points=_emit_points(aggr),
                           query=query)

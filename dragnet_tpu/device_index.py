@@ -442,6 +442,10 @@ def batched_sums(inv, weights, nuniq, shard_ctx=None, stage=None,
         return None
     st = _DEVICE_STATE
     if st['ready'] is False:
+        # the verdict is sticky: a forced lane is refused again (an
+        # error a request, never a quiet host answer after the first),
+        # a lane auto chose has warned once
+        _warn_device('found unusable earlier in this process')
         return None
     from .ops import get_jax
     if get_jax() is None:

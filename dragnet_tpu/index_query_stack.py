@@ -575,14 +575,38 @@ def _commit_counters(index_list, aggr, npts):
         aggr.stage.bump('ninputs', npts)
 
 
-def run_stacked(paths, query, aggr, index_list):
+def _export_items(nshards, first_shard, cols, weights, decoders):
+    """The stacked aggregate as one key-item list per shard of
+    `paths`, for a cluster member's partial (serve/router.py): every
+    tuple ONCE, its weight summed over all the shards, under the shard
+    in which it first occurs, in the order the stack enumerated it
+    (`first_shard` is non-decreasing: the perm sorts shard-first).  A
+    replay of the lists in find order inserts the tuples in the order
+    the sequential loop would have, and shards that bring nothing new
+    keep an empty list."""
+    decoded = []
+    for col, (kind, values) in zip(cols, decoders):
+        codes = col.tolist()
+        decoded.append(codes if kind == 'ord'
+                       else [values[c] for c in codes])
+    per_shard = [[] for _ in range(nshards)]
+    for s, keys, w in zip(first_shard.tolist(), zip(*decoded), weights):
+        per_shard[s].append([list(keys), w])
+    return per_shard
+
+
+def run_stacked(paths, query, aggr, index_list, export=None):
     """Execute the index query as ONE stacked aggregation over every
     shard's matching rows.  Returns True when the result (and the
     fan-in counters) were committed into `aggr`, byte-identical to the
     sequential per-shard loop; False when an exactness gate failed —
     the caller falls back to the per-shard path with `aggr` and the
     stage counters untouched.  Shard errors raise the same DNError
-    contract as the sequential loop (first shard in find order)."""
+    contract as the sequential loop (first shard in find order).
+
+    With `export` (a cluster member's partial) nothing is committed
+    and `aggr` is not touched: the aggregate goes to `export` as one
+    key-item list per path (_export_items)."""
     from . import index_query_mt as mod_iqmt
     from .engine import _unique_rows, fuse_codes
 
@@ -628,6 +652,12 @@ def run_stacked(paths, query, aggr, index_list):
         for v in vals_list:
             if len(v):
                 total += int(v.sum())
+        if export is not None:
+            per_shard = [[] for _ in shards]
+            if per_shard:
+                per_shard[0].append([[], total])
+            export(per_shard)
+            return True
         _commit_counters(index_list, aggr, nshards)
         aggr.nrecords += nshards
         aggr.total += total
@@ -680,6 +710,8 @@ def run_stacked(paths, query, aggr, index_list):
         # already emits nothing, without the 'noutputs' counter key a
         # zero-length columnar install would create (the per-shard
         # loop never bumps it on empty results)
+        if export is not None:
+            export([[] for _ in shards])
         return True
 
     # one stable sort over (shard, per-column sort keys) puts rows in
@@ -701,11 +733,19 @@ def run_stacked(paths, query, aggr, index_list):
                                   stage=index_list,
                                   shard_ctx=(sid, idents, query))
     # index_query_stack.commit: the result's columns in emission
-    # order, the key-item count, the aggregator's columnar install
-    with obs_metrics.leaf_stage('index_query_stack.commit', nuniq=nuniq):
+    # order, the key-item count, the aggregator's columnar install;
+    # for a member's partial index_query_stack.export in its place:
+    # the tuples decoded to key items under their first shards
+    with obs_metrics.leaf_stage(
+            'index_query_stack.commit' if export is None
+            else 'index_query_stack.export', nuniq=nuniq):
         rows = first_idx[order]
         out_cols = [np.ascontiguousarray(c[rows]) for c in acols]
         weights = [int(w) for w in wsum[order].tolist()]
+        if export is not None:
+            export(_export_items(nshards, sid[rows], out_cols, weights,
+                                 decoders))
+            return True
 
         # key-item counter parity: the per-shard loop merges one item per
         # DISTINCT tuple per shard
@@ -718,3 +758,30 @@ def run_stacked(paths, query, aggr, index_list):
         aggr.nrecords += npts
         aggr.set_columnar(out_cols, weights, decoders)
     return True
+
+
+DEVICE_SUMS = 'index device sums'
+
+
+def run_index_query(paths, query, aggr, index_list, nworkers, on_items,
+                    export=None):
+    """The one place that chooses an index query's lane over plain
+    per-file shards, for the `query` op (datasource_file.query) and
+    for a cluster member's `query_partial` (serve/router.py) alike:
+    the stacked aggregation where the mode and the query's shape allow
+    it (the device fold inside it by device_index.lane_decision:
+    DN_INDEX_DEVICE, DN_ENGINE), else, or when the exactness gate
+    refuses the shards' weights, the per-shard loop, whose key items
+    go to `on_items` in find order.  The stacked result is committed
+    into `aggr`, or with `export` handed over as per-shard key items
+    (run_stacked).  Returns the lane that answered: 'device' (the
+    stack, summed by the device fold), 'stacked' or 'shard'."""
+    from . import index_query_mt as mod_iqmt
+    if stack_enabled() and stack_eligible(query):
+        sums0 = index_list.counters.get(DEVICE_SUMS, 0)
+        if run_stacked(paths, query, aggr, index_list, export=export):
+            return 'device' \
+                if index_list.counters.get(DEVICE_SUMS, 0) > sums0 \
+                else 'stacked'
+    mod_iqmt.run_shard_queries(paths, query, nworkers, on_items)
+    return 'shard'

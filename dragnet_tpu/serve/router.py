@@ -14,8 +14,11 @@ Byte-identity is structural, not hopeful: final output order depends
 on the FIRST-OCCURRENCE order of string-like group keys across the
 whole shard set (aggr.js_key_order), so partials travel as
 PER-SHARD key-item lists (each member answers for its shards in find
-order) and the router merges every shard — across all partitions —
-in global find order (the path-component sort below).  The merge loop
+order: a shard's own aggregate from the per-shard loop, or, from the
+stacked lanes, each tuple of the member's slice once, under the shard
+of its first occurrence there: partial_query) and the router merges
+every shard — across all partitions — in global find order (the
+path-component sort below).  The merge loop
 is the same write_key replay `datasource_file.query` runs for its own
 shard fan-in.
 
@@ -45,8 +48,10 @@ Failure-first design (the headline of this layer):
 
 Every decision lands in the obs layer: router_* counters and the
 router_partial_ms histogram (which also feeds the hedge delay),
-router.scatter/router.partial/router.merge spans, and the /stats
-`cluster` section (serve/server.py).
+the leaf stages router.scatter (the wait for the partials) and
+router.merge, the router.partial spans, router_partial_items_total /
+router_partial_bytes_total (what the partials carried), and the
+/stats `cluster` section (serve/server.py).
 
 Dynamic topology (serve/coordinator.py): the serving map can change
 while the router runs.  update_topology() swaps the map atomically —
@@ -254,50 +259,68 @@ def partial_query(ds, query, interval, topology, partition_ids):
     """Execute an index query over THIS member's slice of the shard
     set: the identical enumerate/sweep/litter-filter/prune walk a
     single-process query performs (datasource_file.index_query_paths),
-    restricted to the shards `partition_ids` own, each shard's
-    aggregate exported as key items in find order.  Returns
-    [[relpath, [[keys..., ], weight], ...], ...] — the JSON wire shape
-    of the `query_partial` op."""
+    restricted to the shards `partition_ids` own, then the lane a
+    single process's query takes over the same shards
+    (index_query_stack.run_index_query: the stacked aggregation, on
+    the device fold where DN_INDEX_DEVICE / DN_ENGINE engage it, else
+    the per-shard loop).  Returns [[relpath, [[keys...], weight],
+    ...], ...] — the JSON wire shape of the `query_partial` op — with
+    every shard of the slice listed in find order.  The per-shard loop
+    lists each shard's own aggregate; the stack lists every tuple of
+    the slice ONCE, its weight summed over the slice, under the shard
+    in which it first occurs there (index_query_stack._export_items),
+    and the other shards with no items: the router's replay in global
+    find order inserts the same tuples in the same order either way."""
     from .. import index_query_mt as mod_iqmt
+    from .. import index_query_stack as mod_iqs
     from ..vpipe import Pipeline
     pipeline = Pipeline()
-    root, timeformat, files = ds.index_query_paths(query, interval,
-                                                   pipeline)
-    paths = [p for p, st in files]
-    paths, _ = mod_iqmt.prune_shards(paths, timeformat,
-                                     query.qc_after, query.qc_before)
-    want = set(partition_ids)
-    paths = [p for p in paths
-             if topology.partition_of(p, timeformat) in want]
-    mod_vpipe.counter_bump('cluster partial shards', len(paths))
-    # verified reads: a catalogued shard of OUR partitions missing
-    # from the walk (quarantined post-corruption, not yet repaired)
-    # rejects the partial retryably — the router fails over to a
-    # replica that has the bytes, instead of this member silently
-    # merging a short shard set
-    from .. import integrity as mod_integrity
-    if mod_integrity.verify_mode() != 'off':
-        mod_integrity.check_missing(
-            ds.ds_indexpath, paths,
-            subdir=os.path.basename(root)
-            if timeformat is not None else None,
-            timeformat=timeformat, after_ms=query.qc_after,
-            before_ms=query.qc_before,
-            partition_filter=lambda p:
-            topology.partition_of(p, timeformat) in want)
+    with obs_metrics.leaf_stage('index_query.paths'):
+        root, timeformat, files = ds.index_query_paths(query, interval,
+                                                       pipeline)
+    with obs_metrics.leaf_stage('index_query.prune'):
+        paths = [p for p, st in files]
+        paths, _ = mod_iqmt.prune_shards(paths, timeformat,
+                                         query.qc_after,
+                                         query.qc_before)
+        want = set(partition_ids)
+        paths = [p for p in paths
+                 if topology.partition_of(p, timeformat) in want]
+        mod_vpipe.counter_bump('cluster partial shards', len(paths))
+        # verified reads: a catalogued shard of OUR partitions missing
+        # from the walk (quarantined post-corruption, not yet
+        # repaired) rejects the partial retryably — the router fails
+        # over to a replica that has the bytes, instead of this member
+        # silently merging a short shard set
+        from .. import integrity as mod_integrity
+        if mod_integrity.verify_mode() != 'off':
+            mod_integrity.check_missing(
+                ds.ds_indexpath, paths,
+                subdir=os.path.basename(root)
+                if timeformat is not None else None,
+                timeformat=timeformat, after_ms=query.qc_after,
+                before_ms=query.qc_before,
+                partition_filter=lambda p:
+                topology.partition_of(p, timeformat) in want)
     indexroot = ds.ds_indexpath
+    relpaths = [os.path.relpath(p, indexroot) for p in paths]
     shards = []
-    state = {'i': 0}
 
     def on_items(items):
         # run_shard_queries reports once per shard in `paths` order
-        path = paths[state['i']]
-        state['i'] += 1
-        shards.append([os.path.relpath(path, indexroot),
+        shards.append([relpaths[len(shards)],
                        [[list(k), w] for k, w in items]])
 
-    mod_iqmt.run_shard_queries(paths, query, mod_iqmt.iq_threads(),
-                               on_items)
+    def export(per_shard):
+        shards.extend([rel, items]
+                      for rel, items in zip(relpaths, per_shard))
+
+    # a slice with no shard in the window took no lane
+    lane = mod_iqs.run_index_query(
+        paths, query, None, pipeline.stage('Index List'),
+        mod_iqmt.iq_threads(), on_items, export=export) \
+        if paths else 'empty'
+    obs_metrics.inc('cluster_partials_total', lane=lane)
     return shards
 
 
@@ -625,6 +648,7 @@ class Router(object):
             raise DNError('member "%s": malformed partial response'
                           % name, cause=DNError(str(e)))
         self._bump('partials_remote')
+        obs_metrics.inc('router_partial_bytes_total', len(out))
         self._observe_latency((time.monotonic() - t0) * 1000.0)
         return shards
 
@@ -791,7 +815,7 @@ class Router(object):
         member sheds partials it cannot finish in time instead of
         computing past the client's patience."""
         from ..aggr import Aggregator
-        from ..datasource_file import ScanResult
+        from ..datasource_file import ScanResult, _emit_points
         from ..vpipe import Pipeline
 
         self._bump('scatters')
@@ -841,7 +865,10 @@ class Router(object):
                         'partition %d: internal fetch error: %r'
                         % (pid, e))
 
-        with obs_trace.span('router.scatter', partitions=len(pids)):
+        # router.scatter: the wait for the partials (each fetched on
+        # a thread of its own; a local one runs its leaves there)
+        with obs_metrics.leaf_stage('router.scatter',
+                                    partitions=len(pids)):
             for pid in pids:
                 t = threading.Thread(target=fetch, args=(pid,),
                                      daemon=True,
@@ -894,14 +921,16 @@ class Router(object):
         aggr = Aggregator(query,
                           stage=pipeline.stage(
                               'Index Result Aggregator'))
-        all_shards = []
-        for pid in sorted(results):
-            all_shards.extend(results[pid])
-        all_shards.sort(key=lambda s: tuple(s[0].split('/')))
-        with obs_trace.span('router.merge', shards=len(all_shards)):
+        with obs_metrics.leaf_stage('router.merge') as leaf:
+            all_shards = []
+            for pid in sorted(results):
+                all_shards.extend(results[pid])
+            all_shards.sort(key=lambda s: tuple(s[0].split('/')))
+            leaf.set(shards=len(all_shards))
             mod_faults.fire('router.merge')
             seen = set()
             aggr_stage = aggr.stage
+            nitems = 0
             for relpath, items in all_shards:
                 if relpath in seen:
                     # partitions are disjoint by construction; a
@@ -918,9 +947,11 @@ class Router(object):
                 index_list.bump('ninputs', npts)
                 index_list.bump('noutputs', npts)
                 aggr_stage.bump('ninputs', npts)
+                nitems += npts
                 aggr.merge_key_items([(tuple(k), w)
                                       for k, w in items])
+            obs_metrics.inc('router_partial_items_total', nitems)
         index_list.bump_hidden('index shards queried',
                                len(all_shards))
-        return (ScanResult(pipeline, points=aggr.points(),
+        return (ScanResult(pipeline, points=_emit_points(aggr),
                            query=query), missing)

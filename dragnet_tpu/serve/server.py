@@ -2138,6 +2138,11 @@ class DnServer(object):
         deadline_ms = partial_req.get('deadline_ms')
         deadline_at = time.monotonic() + deadline_ms / 1000.0 \
             if deadline_ms and deadline_ms > 0 else None
+        # accounted as the socket-delivered partial is (finish_obs):
+        # its latency, the wait for its slot included, and its
+        # thread's leaves, under the op's own label
+        t0 = time.monotonic()
+        leaf0 = obs_metrics.leaf_stage.thread_ms()
         lease = self._admit_resources('query_partial', ds)
         try:
             slot = self.admission.acquire(
@@ -2166,6 +2171,13 @@ class DnServer(object):
         finally:
             slot.release()
             lease.release()
+            obs_metrics.observe('serve_op_latency_ms',
+                                (time.monotonic() - t0) * 1000.0,
+                                op='query_partial')
+            obs_metrics.observe(
+                'serve_leaf_ms',
+                obs_metrics.leaf_stage.thread_ms() - leaf0,
+                op='query_partial')
 
     def _run_build(self, req, ds, config, dsname, opts,
                    metrics_for_index, flags):

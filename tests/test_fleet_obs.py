@@ -296,6 +296,9 @@ def test_prometheus_exposition_completeness():
                      'integrity_repairs_total',
                      'integrity_corrupt_shards_total',
                      'router_failovers_total', 'serve_shed_total',
+                     'router_partial_items_total',
+                     'router_partial_bytes_total',
+                     'cluster_partials_total',
                      'handoff_shards_streamed_total',
                      'follow_ingest_lag_ms', 'device_residency_pct'):
         assert expected in names, expected

@@ -742,3 +742,395 @@ def test_non_member_rejects_query_partial(corpus, tmp_path):
         assert 'not a cluster member' in err.decode()
     finally:
         srv.stop()
+
+
+# -- a member's partial on every lane ---------------------------------------
+#
+# The tree below makes the order matter: day d's shard brings a host
+# whose name sorts BEFORE every host seen so far, so the reply's order
+# of hosts (first occurrence in find order) is the reverse of any
+# sort, and the days' shards land in different partitions.
+
+LANE_ENV = {
+    'shard': {'DN_IQ_STACK': '0', 'DN_INDEX_DEVICE': '0'},
+    'stacked': {'DN_IQ_STACK': '1', 'DN_INDEX_DEVICE': '0'},
+    'device': {'DN_IQ_STACK': '1', 'DN_INDEX_DEVICE': '1'},
+}
+ORDER_DAYS = 9
+ORDER_QUERY = ['query', '--points', '-b', 'host,latency[aggr=quantize]']
+VALUE_QUERY = ['query', '--points', '-b', 'value,host']
+
+
+def _gen_order_corpus(path):
+    import datetime
+    t0 = 1388534400  # 2014-01-01T00:00:00Z
+    with open(path, 'w') as f:
+        for day in range(ORDER_DAYS):
+            # hosts of this day: a new one that sorts first, and the
+            # two before it (so tuples repeat across shards)
+            hosts = ['h%02d' % (50 - d) for d in range(max(0, day - 2),
+                                                       day + 1)]
+            for i in range(24):
+                ts = datetime.datetime.fromtimestamp(
+                    t0 + day * 86400 + i * 3000,
+                    datetime.timezone.utc).strftime(
+                        '%Y-%m-%dT%H:%M:%S.000Z')
+                f.write(json.dumps({
+                    'time': ts, 'host': hosts[i % len(hosts)],
+                    'value': 'v%d' % ((i + day) % 4),
+                    'latency': (i * 37 + day * 11) % 900,
+                }, separators=(',', ':')) + '\n')
+
+
+@pytest.fixture(scope='module')
+def order_corpus(tmp_path_factory):
+    """Two trees over the same nine days: `ord` (the default format)
+    and `ordf` (SQLite, one stored weight made non-integral
+    afterwards: the exactness gate must refuse it)."""
+    import sqlite3
+    root = tmp_path_factory.mktemp('order_corpus')
+    datafile = str(root / 'data.log')
+    _gen_order_corpus(datafile)
+    rc_path = str(root / 'dragnetrc.json')
+    prior = {k: os.environ.get(k)
+             for k in ('DRAGNET_CONFIG', 'DN_INDEX_FORMAT')}
+    os.environ['DRAGNET_CONFIG'] = rc_path
+    try:
+        for ds, fmt in (('ord', None), ('ordf', 'sqlite')):
+            if fmt is None:
+                os.environ.pop('DN_INDEX_FORMAT', None)
+            else:
+                os.environ['DN_INDEX_FORMAT'] = fmt
+            for args in (
+                    ['datasource-add', '--path', datafile, '--index-path',
+                     str(root / ('idx_' + ds)), '--time-field', 'time', ds],
+                    ['metric-add', '-b',
+                     'timestamp[date,field=time,aggr=lquantize,'
+                     'step=86400],host,latency[aggr=quantize]', ds, 'm1'],
+                    # (SQLite cannot hold a column named `value` twice)
+                    ['metric-add', '-b', 'value,host', ds, 'mv'],
+                    ['build', ds]):
+                if fmt == 'sqlite' and args[-1] == 'mv':
+                    continue
+                rc, out, err = run_cli(args)
+                assert rc == 0, err
+        shard = sorted((root / 'idx_ordf' / 'by_day').iterdir())[3]
+        db = sqlite3.connect(str(shard))
+        table = [r[0] for r in db.execute(
+            "select name from sqlite_master where type='table' and "
+            "name like 'dragnet_index_%'")][0]
+        db.execute('update %s set value = 2.5 where rowid = '
+                   '(select min(rowid) from %s)' % (table, table))
+        db.commit()
+        db.close()
+        yield {'root': root, 'rc_path': rc_path}
+    finally:
+        for k, v in prior.items():
+            if v is None:
+                os.environ.pop(k, None)
+            else:
+                os.environ[k] = v
+
+
+def _order_topo(socks, assign):
+    doc = _topo_doc(socks, assign=assign)
+    if assign == 'time-range':
+        # two windows and a partition that takes the rest by hash
+        doc['partitions'][0].update(after='2014-01-02',
+                                    before='2014-01-04')
+        doc['partitions'][1].update(after='2014-01-06',
+                                    before='2014-01-08')
+    return doc
+
+
+@pytest.fixture
+def lane_cluster(order_corpus, tmp_path, monkeypatch, request):
+    """Three in-process members over the order trees, on the lane and
+    under the assignment rule the test's parameters name."""
+    from dragnet_tpu import device_index as mod_di
+    from dragnet_tpu import index_query_mt as mod_iqmt
+    lane = request.getfixturevalue('lane')
+    assign = request.getfixturevalue('assign')
+    for k, v in LANE_ENV[lane].items():
+        monkeypatch.setenv(k, v)
+    monkeypatch.setenv('DRAGNET_CONFIG', order_corpus['rc_path'])
+    monkeypatch.setenv('DN_ROUTER_PROBE_MS', '60000')
+    monkeypatch.setenv('DN_REMOTE_RETRIES', '0')
+    mod_di._reset_device_state()
+    mod_iqmt.shard_cache_clear()
+    socks = {m: str(tmp_path / ('dn-%s.sock' % m)) for m in 'abc'}
+    topo_path = str(tmp_path / 'topo.json')
+    with open(topo_path, 'w') as f:
+        json.dump(_order_topo(socks, assign), f)
+    servers = {}
+    for m in 'abc':
+        topo = mod_topology.load_topology(topo_path, member=m)
+        servers[m] = mod_server.DnServer(
+            socket_path=socks[m], conf=_conf(), cluster=topo,
+            member=m).start()
+    try:
+        yield {'socks': socks, 'rc_path': order_corpus['rc_path'],
+               'topo': mod_topology.load_topology(topo_path)}
+    finally:
+        for srv in servers.values():
+            srv.stop()
+        mod_di._reset_device_state()
+
+
+def _order_query(case, rc_path):
+    """(datasource, query) of a `dn query` command line."""
+    from dragnet_tpu import config as mod_config
+    from dragnet_tpu import datasource_for_name
+    from dragnet_tpu import query as mod_query
+    err, config = mod_config.ConfigBackendLocal(rc_path).load()
+    assert err is None
+    bds = []
+    specs = case[case.index('-b') + 1].split(',') if '-b' in case else []
+    for spec in specs:
+        name = spec.split('[')[0]
+        b = {'name': name, 'field': name}
+        if 'quantize' in spec:
+            b['aggr'] = 'quantize'
+        bds.append(b)
+    return (datasource_for_name(config, case[-1]),
+            mod_query.query_load({'breakdowns': bds}))
+
+
+def _find_order_paths(ds, query):
+    from dragnet_tpu import index_query_mt as mod_iqmt
+    from dragnet_tpu.vpipe import Pipeline
+    root, timeformat, files = ds.index_query_paths(query, 'day',
+                                                   Pipeline())
+    paths, _ = mod_iqmt.prune_shards([p for p, st in files], timeformat,
+                                     query.qc_after, query.qc_before)
+    return paths, timeformat
+
+
+def _points_of(query, shard_items):
+    """The points of a replay of per-shard key items, in the order
+    given: the straightforward merge."""
+    from dragnet_tpu.aggr import Aggregator
+    aggr = Aggregator(query)
+    for items in shard_items:
+        aggr.merge_key_items([(tuple(k), w) for k, w in items])
+    return aggr.points()
+
+
+def _sequential(ds, query):
+    """(points, {tuple: weight}) of the plain sequential loop: every
+    shard opened, queried and merged in find order."""
+    from dragnet_tpu import index_query_mt as mod_iqmt
+    paths, _ = _find_order_paths(ds, query)
+    per_shard = [mod_iqmt.query_shard_once(p, query) for p in paths]
+    flat = {}
+    for items in per_shard:
+        for k, w in items:
+            flat[tuple(k)] = flat.get(tuple(k), 0) + w
+    return _points_of(query, per_shard), flat
+
+
+def _partials(lane_cluster, case):
+    """{partition: [[relpath, items], ...]} asked of each partition's
+    first replica."""
+    topo = lane_cluster['topo']
+    doc = {'op': 'query_partial', 'ds': case[-1],
+           'config': lane_cluster['rc_path'], 'interval': 'day',
+           'opts': {}, 'epoch': 1, 'queryconfig': {'breakdowns': [
+               dict(b) for b in _order_query(
+                   case, lane_cluster['rc_path'])[1].qc_breakdowns]}}
+    out = {}
+    for pid in topo.partition_ids():
+        rc, header, body, err = mod_client.request_bytes(
+            lane_cluster['socks'][topo.replicas(pid)[0]],
+            dict(doc, partitions=[pid]), timeout_s=60.0)
+        assert rc == 0, err
+        out[pid] = json.loads(body.decode())['shards']
+    return out
+
+
+def _routed(lane_cluster, case):
+    return [run_cli(case[:1] + ['--remote', lane_cluster['socks'][m]] +
+                    case[1:]) for m in 'abc']
+
+
+def _points_text(case, points):
+    """Points as the output layer prints them for this command."""
+    from dragnet_tpu.datasource_file import ScanResult
+    from dragnet_tpu.vpipe import Pipeline
+    opts = mod_server._opts_shim({'opts': {'points': True}})
+    with mod_server.thread_stdio() as cap:
+        cli.dn_output(None, opts, ScanResult(Pipeline(), points=points),
+                      case[-1])
+    return cap.finish()[0]
+
+
+def _check_bytes(lc, lane, assign):
+    case = ORDER_QUERY + ['ord']
+    ds, query = _order_query(case, lc['rc_path'])
+    expected = run_cli(case)
+    assert expected[0] == 0, expected[2]
+    seq_points, _ = _sequential(ds, query)
+    assert _points_text(case, seq_points) == expected[1]
+    from dragnet_tpu import device_index as mod_di
+    folds = mod_di.stats_doc()['dispatches']
+    assert _routed(lc, case) == [expected] * 3
+    # the members' partials reached the device fold on its lane alone
+    assert (mod_di.stats_doc()['dispatches'] > folds) == \
+        (lane == 'device')
+    # the order is not a sort's: hosts come newest first
+    hosts = [f['host'] for f, w in seq_points]
+    assert hosts != sorted(hosts)
+    if assign == 'hash':
+        # and not a partition's either: the partials replayed one
+        # partition after the other put the hosts in another order
+        parts = _partials(lc, case)
+        assert len({pid for pid in parts if parts[pid]}) > 1
+        wrong = _points_of(query, [items for pid in sorted(parts)
+                                   for rel, items in parts[pid]])
+        assert [f['host'] for f, w in wrong] != hosts
+
+
+def _check_once(lc, lane, assign):
+    from dragnet_tpu import index_query_mt as mod_iqmt
+    case = ORDER_QUERY + ['ord']
+    ds, query = _order_query(case, lc['rc_path'])
+    paths, timeformat = _find_order_paths(ds, query)
+    by_rel = {os.path.relpath(p, ds.ds_indexpath): p for p in paths}
+    parts = _partials(lc, case)
+    listed = [rel for pid in sorted(parts) for rel, items in parts[pid]]
+    # every shard of the tree listed once, by the partition that owns it
+    assert sorted(listed) == sorted(by_rel)
+    for pid, shards in parts.items():
+        assert all(lc['topo'].partition_of(by_rel[rel], timeformat) == pid
+                   for rel, items in shards)
+        tuples = [tuple(k) for rel, items in shards for k, w in items]
+        if lane == 'shard':
+            # the per-shard wire: each shard's own aggregate
+            for rel, items in shards:
+                assert [[list(k), w] for k, w in
+                        mod_iqmt.query_shard_once(by_rel[rel], query)] \
+                    == items
+        else:
+            assert len(tuples) == len(set(tuples))
+    if lane != 'shard':
+        # fewer key items than the per-shard wire carries
+        nshard = sum(len(mod_iqmt.query_shard_once(p, query))
+                     for p in paths)
+        assert sum(len(items) for s in parts.values()
+                   for rel, items in s) < nshard
+
+
+def _assert_partials_add_up(lc, case, ds, query):
+    """The partitions' weights for a tuple add up to the whole tree's;
+    returns the tree's {tuple: weight}."""
+    _, flat = _sequential(ds, query)
+    got = {}
+    for shards in _partials(lc, case).values():
+        for rel, items in shards:
+            for k, w in items:
+                got[tuple(k)] = got.get(tuple(k), 0) + w
+    assert got == flat
+    return flat
+
+
+def _check_weights(lc, lane, assign):
+    case = ORDER_QUERY + ['ord']
+    assert _assert_partials_add_up(
+        lc, case, *_order_query(case, lc['rc_path']))
+
+
+def _check_value_breakdown(lc, lane, assign):
+    """A breakdown named `value` is not the stack's: the per-shard
+    loop answers on every lane, with the single process's bytes."""
+    from dragnet_tpu import index_query_mt as mod_iqmt
+    case = VALUE_QUERY + ['ord']
+    ds, query = _order_query(case, lc['rc_path'])
+    expected = run_cli(case)
+    assert expected[0] == 0, expected[2]
+    assert _points_text(case, _sequential(ds, query)[0]) == expected[1]
+    assert _routed(lc, case) == [expected] * 3
+    paths, _ = _find_order_paths(ds, query)
+    by_rel = {os.path.relpath(p, ds.ds_indexpath): p for p in paths}
+    for shards in _partials(lc, case).values():
+        for rel, items in shards:
+            assert [[list(k), w] for k, w in mod_iqmt.query_shard_once(
+                by_rel[rel], query)] == items
+
+
+def _check_float_weight(lc, lane, assign):
+    """A stored weight of 2.5 fails the exactness gate: the per-shard
+    loop answers, and the bytes are the single process's."""
+    case = ORDER_QUERY + ['ordf']
+    ds, query = _order_query(case, lc['rc_path'])
+    expected = run_cli(case)
+    assert expected[0] == 0, expected[2]
+    assert b'.5' in expected[1]
+    assert _points_text(case, _sequential(ds, query)[0]) == expected[1]
+    assert _routed(lc, case) == [expected] * 3
+    _assert_partials_add_up(lc, case, ds, query)
+
+
+def _check_lane_cannot_run(lc, lane, assign, monkeypatch):
+    """No jax in the process: a forced device lane is an error on a
+    member as in a single process, never a quiet host answer; a lane
+    that needs no device answers as ever."""
+    from dragnet_tpu import ops as mod_ops
+    case = ORDER_QUERY + ['ord']
+    monkeypatch.setattr(mod_ops, 'get_jax', lambda: None)
+    expected = run_cli(case)
+    routed = _routed(lc, case)
+    if lane != 'device':
+        assert expected[0] == 0
+        assert routed == [expected] * 3
+        return
+    needle = b'device index-query lane unavailable ('
+    assert expected[0] != 0 and needle in expected[2]
+    assert b'(jax unavailable)' in expected[2]
+    # the verdict sticks to the process (these members share it with
+    # the single process above): every later request is refused too
+    for rc, out, err in routed:
+        assert rc != 0 and out == b'' and needle in err, err
+
+
+def _check_no_breakdown(lc, lane, assign):
+    """The grand total: one key item a partition from the stacked
+    lanes, one a shard from the per-shard loop, the same bytes."""
+    case = ['query', '--points', 'ord']
+    expected = run_cli(case)
+    assert expected[0] == 0, expected[2]
+    assert _routed(lc, case) == [expected] * 3
+    total = 0
+    for shards in _partials(lc, case).values():
+        items = [it for rel, its in shards for it in its]
+        assert all(keys == [] for keys, w in items)
+        assert len(items) == (len(shards) if lane == 'shard'
+                              else min(1, len(shards)))
+        total += sum(w for keys, w in items)
+    assert total == ORDER_DAYS * 24
+    assert ('"value":%d' % total).encode() in expected[1]
+
+
+CHECKS = {'bytes': _check_bytes, 'once': _check_once,
+          'no_breakdown': _check_no_breakdown,
+          'weights': _check_weights,
+          'value_breakdown': _check_value_breakdown,
+          'float_weight': _check_float_weight,
+          'lane_cannot_run': _check_lane_cannot_run}
+
+
+@pytest.mark.parametrize('check', sorted(CHECKS))
+@pytest.mark.parametrize('assign', ['hash', 'time-range'])
+@pytest.mark.parametrize('lane', sorted(LANE_ENV))
+def test_partial_lanes(lane_cluster, lane, assign, check, monkeypatch):
+    """A member's partial on each lane (the per-shard loop, the stack
+    on the host, the stack on the device fold) under both assignment
+    rules: the routed reply is byte-equal to the single process's and
+    to the plain sequential loop's."""
+    if lane == 'device':
+        from dragnet_tpu.ops import get_jax
+        if get_jax() is None:
+            pytest.skip('jax unavailable')
+    if check == 'lane_cannot_run':
+        CHECKS[check](lane_cluster, lane, assign, monkeypatch)
+    else:
+        CHECKS[check](lane_cluster, lane, assign)

@@ -696,3 +696,143 @@ def test_points_equal_the_host_engines(runs, tmp_path, monkeypatch):
         assert rc == 0, err
         outs[engine] = out
     assert outs['jax'] == outs['vector'] and outs['jax']
+
+
+# -- (2c) a routed query: the router's leaves and the member's export ------
+
+ROUTED_LEAVES = ('router.scatter', 'router.merge')
+EXPORT = 'index_query_stack.export'
+NPARTITIONS = 2
+
+
+def counter_table():
+    """{(name, labels): value} of the global registry's counters."""
+    return {(name, labels): m.value
+            for name, labels, m in obs_metrics.global_registry().snapshot()
+            if m.kind == obs_metrics.COUNTER}
+
+
+@pytest.fixture(scope='module')
+def routed(tmp_path_factory):
+    """{'stages', 'hists', 'counters', 'out'} of one index query through
+    a two-member cluster of this process (member a routes: one
+    partition is its own, one is b's), the device lane forced; the
+    second of its kind, as in `served`."""
+    from dragnet_tpu.serve import client as mod_client
+    from dragnet_tpu.serve import topology as mod_topology
+    root = str(tmp_path_factory.mktemp('stage_routed'))
+    got = {}
+    with pytest.MonkeyPatch.context() as mp:
+        for k, v in (('DN_INDEX_DEVICE', '1'), ('DRAGNET_CONFIG', ''),
+                     ('DN_ROUTER_PROBE_MS', '60000')):
+            mp.setenv(k, v)
+        for k in ('DN_TRACE', 'DN_SLOW_MS', 'DN_SERVE_CACHE_MB',
+                  'DN_ENGINE'):
+            mp.delenv(k, raising=False)
+        add_datasource(root)
+        rc, out, err = run_cli(['build', 'stageds'])
+        assert rc == 0, err
+        socks = {m: os.path.join(root, m + '.sock') for m in 'ab'}
+        topo_path = os.path.join(root, 'topo.json')
+        with open(topo_path, 'w') as f:
+            json.dump({'epoch': 1, 'assign': 'time-range',
+                       'members': {m: {'endpoint': socks[m]}
+                                   for m in socks},
+                       'partitions': [
+                           {'id': 0, 'replicas': ['a'],
+                            'after': '2014-01-01', 'before': '2014-01-02'},
+                           {'id': 1, 'replicas': ['b'],
+                            'after': '2014-01-02', 'before': '2014-01-05'},
+                       ]}, f)
+        servers = [mod_server.DnServer(
+            socket_path=socks[m],
+            conf={'max_inflight': 2, 'queue_depth': 4, 'deadline_ms': 0,
+                  'coalesce': True, 'drain_s': 10},
+            cluster=mod_topology.load_topology(topo_path, member=m),
+            member=m).start() for m in 'ab']
+        req = {'ds': 'stageds', 'config': os.environ['DRAGNET_CONFIG'],
+               'op': 'query', 'interval': 'day', 'opts': {'points': True},
+               'queryconfig': {'breakdowns': [
+                   {'name': 'host', 'field': 'host'}]}}
+        try:
+            for attempt in (0, 1):
+                obs_metrics.reset_global_registry()
+                rc, _, out, err = mod_client.request_bytes(socks['a'], req)
+                assert rc == 0, err
+                limit = time.monotonic() + 10.0
+                while time.monotonic() < limit:
+                    stages, hists = stage_table()[0], hist_table()
+                    if stages.get('reply.frame', (0,))[0] == 2 and \
+                            hists.get(('serve_reply_drain_ms', ()),
+                                      (0,))[0] == 2:
+                        break
+                    time.sleep(0.01)
+            got.update(stages=stages, hists=hists, out=out,
+                       counters=counter_table())
+        finally:
+            for srv in servers:
+                srv.stop()
+        rc, got['cli_query_out'], err = run_cli(
+            ['query', '-b', 'host', '--points', 'stageds'])
+        assert rc == 0, err
+    return got
+
+
+@pytest.mark.parametrize('stage,count', [
+    ('router.scatter', 1), ('router.merge', 1), (EXPORT, NPARTITIONS),
+    ('index_query.paths', NPARTITIONS), ('index_query.prune', NPARTITIONS),
+    ('index_query_stack.load', NPARTITIONS), ('index_fold.stage',
+                                              NPARTITIONS)])
+def test_a_routed_query_meets_the_routers_leaves(routed, stage, count):
+    """The router's two leaves once a routed query; the member's export
+    and the stack's and the fold's leaves once a partial."""
+    assert routed['stages'].get(stage, (0, 0.0))[0] == count
+
+
+@pytest.mark.parametrize('series', ['serve_op_latency_ms',
+                                    'serve_leaf_ms'])
+def test_a_partial_is_accounted_under_its_own_op(routed, series):
+    """One observation a partial under `op="query_partial"`, the one
+    that ran in the router's process and the one that came by the
+    socket alike; one under `op="query"` for the routed query."""
+    n, ms = routed['hists'][(series, (('op', 'query_partial'),))]
+    assert n == NPARTITIONS and ms > 0
+    assert routed['hists'][(series, (('op', 'query'),))][0] == 1
+
+
+def test_a_routed_querys_leaf_ms_is_its_own_threads(routed):
+    """`serve_leaf_ms{op="query"}`: the resolution, the wait for the
+    partials, the merge and the reply's two, and none of the partials'
+    leaves (they ran on other threads)."""
+    own = sum(routed['stages'][s][1] for s in
+              ('router.scatter', 'router.merge') + REPLY_STAGES)
+    n, leaf_ms = routed['hists'][('serve_leaf_ms', (('op', 'query'),))]
+    resolve = routed['stages']['serve.resolve'][1]
+    assert own <= leaf_ms <= own + resolve + 0.01
+    _, latency = routed['hists'][('serve_op_latency_ms',
+                                  (('op', 'query'),))]
+    assert leaf_ms <= latency
+
+
+@pytest.mark.parametrize('name,labels,least', [
+    ('router_partial_items_total', (), 5),
+    ('router_partial_bytes_total', (), 40),
+    ('cluster_partials_total', (('lane', 'device'),), NPARTITIONS)])
+def test_a_routed_query_counts_what_its_partials_carried(routed, name,
+                                                         labels, least):
+    """Five hosts over three days, two partitions: each partition's
+    partial lists its hosts once (at most ten key items where the
+    per-shard wire carried fifteen), b's came over the socket, and
+    both took the device lane."""
+    value = routed['counters'][(name, labels)]
+    assert value >= least
+    if name == 'router_partial_items_total':
+        assert value <= 5 * NPARTITIONS
+    if name == 'cluster_partials_total':
+        assert value == NPARTITIONS
+        assert not [k for k in routed['counters']
+                    if k[0] == name and k != (name, labels)]
+
+
+def test_a_routed_query_answers_as_the_cli_does(routed):
+    assert routed['out'] and routed['out'] == routed['cli_query_out']
