@@ -1849,10 +1849,7 @@ def cmd_serve(ctx, argv):
                dev_conf['probe_timeout_s'], apath or 'off',
                entries, wins))
         sys.stdout.write(
-            'index device lane ok: mode=%s batch_rows=%d '
-            'residency_share=%.2f\n'
-            % (iq_conf['mode'], iq_conf['batch_rows'],
-               iq_conf['residency_share']))
+            'index device lane ok: mode=%s\n' % iq_conf['mode'])
         from . import scan_mt as mod_scan_mt
         sys.stdout.write(
             'scan pipeline ok: pipeline_depth=%d batch_floor=%s '

@@ -919,55 +919,23 @@ def device_config(env=None):
 
 
 def index_device_config(env=None):
-    """The resolved index-query device-lane knobs (keys: mode,
-    batch_rows, residency_share), or DNError on the first malformed
-    value — validated up front like device_config; device_index.py
-    and serve/residency.py read the env forgivingly at runtime.
+    """The resolved index-query device-lane knob (key: mode), or
+    DNError on a malformed value — validated up front like
+    device_config; engine.index_device_mode reads the env forgivingly
+    at runtime.
 
     * DN_INDEX_DEVICE: 'auto' (default; DN_ENGINE=jax engages, auto
       escalates on a persisted audition win), '1' (force the device
-      lane), '0' (pin the host bincount).
-    * DN_INDEX_DEVICE_BATCH_ROWS: padded-row budget per slot-packed
-      dispatch (>= 4096; how many shards merge per launch).
-    * DN_INDEX_RESIDENCY_SHARE: fraction [0, 1] of the HBM residency
-      budget pinned shard tensors may occupy (accumulator pins own
-      the rest)."""
+      lane), '0' (pin the host bincount)."""
     if env is None:
         env = os.environ
-    rv = {}
     raw = env.get('DN_INDEX_DEVICE')
     if raw is None or raw == '':
-        rv['mode'] = 'auto'
-    elif raw in ('auto', '0', '1'):
-        rv['mode'] = raw
-    else:
-        return DNError("DN_INDEX_DEVICE: expected 'auto', '0' or "
-                       "'1', got \"%s\"" % raw)
-    raw = env.get('DN_INDEX_DEVICE_BATCH_ROWS')
-    if raw is None or raw == '':
-        rv['batch_rows'] = 1 << 20
-    else:
-        try:
-            value = int(raw)
-        except ValueError:
-            value = -1
-        if value < 4096:
-            return DNError('DN_INDEX_DEVICE_BATCH_ROWS: expected an '
-                           'integer >= 4096, got "%s"' % raw)
-        rv['batch_rows'] = value
-    raw = env.get('DN_INDEX_RESIDENCY_SHARE')
-    if raw is None or raw == '':
-        rv['residency_share'] = 0.5
-    else:
-        try:
-            value = float(raw)
-        except ValueError:
-            value = -1.0
-        if not 0.0 <= value <= 1.0:
-            return DNError('DN_INDEX_RESIDENCY_SHARE: expected a '
-                           'fraction in [0, 1], got "%s"' % raw)
-        rv['residency_share'] = value
-    return rv
+        return {'mode': 'auto'}
+    if raw in ('auto', '0', '1'):
+        return {'mode': raw}
+    return DNError("DN_INDEX_DEVICE: expected 'auto', '0' or "
+                   "'1', got \"%s\"" % raw)
 
 
 # --- observability knobs (DN_TRACE / DN_SLOW_MS / DN_METRICS_BUCKETS) -

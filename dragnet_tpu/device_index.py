@@ -1,48 +1,33 @@
-"""Device-offloaded index query: batched shard tensors, on-device
-scatter-add merge, residency-pinned hot columns.
+"""Device-offloaded index query: the stacked batch folded on the
+device in one packed upload and one dispatch.
 
 This module is the device engine behind the stacked index-query path
 (index_query_stack.run_stacked): once the stacked batch exists, the
 per-tuple weight sums are SURVEY §2.3's "index shards materialized as
-dense bucket tensors merged via psum/scatter-add" — and the cost of
-moving bytes between host and device (not measured on the current
-chip) dictates the rest of the shape:
+dense bucket tensors merged via psum/scatter-add".  run_stacked
+already holds the whole answer's input as two flat arrays, every row's
+query-global segment id and its weight, and the fold takes them as
+they are:
 
-* **Shard-batch staging.**  Rows arrive already perm-ordered by
-  (shard, sort keys...), so each shard occupies one contiguous slice.
-  Per shard we stage two pow2-padded i64 tensors — the LOCAL group
-  code per row (first-occurrence rank of the row's aggregate tuple
-  within the shard) and the integer weight — plus one tiny per-query
-  translation table mapping local codes to the query-global segment
-  ids.  Local codes are a pure function of (query plan, shard
-  content): the slice order is the content-stable sort the stacked
-  path already proves byte-parity for, and aggregate-tuple EQUALITY is
-  content-determined even where global code values are not.  That is
-  what makes the big tensors pinnable across queries whose global code
-  space differs (a sliding year window re-keys every global id, but
-  363 of 365 shard tensors are unchanged).
-* **Slot-packed dispatches.**  Shards group by padded row count R and
-  pack S-at-a-time (pow2 ladder, bounded by DN_INDEX_DEVICE_BATCH_ROWS
-  and _MAX_SLOTS) into one jitted program: gather each slot's local
-  codes through its translation row, then one segment_sum into the
-  shared accumulator.  A 365-shard year query becomes a handful of
-  device launches instead of 365 host group-bys, and the program cache
-  stays O(log^2) on (S, R, T) like the scan path's pow2 ladders.
-* **Device-resident fold, ONE fetch.**  The i64 accumulator rides
-  device-resident through every dispatch as each jit's output fed
-  into the next (psum-shaped fold, mesh-ready: under a sharded mesh
-  the same program body folds partials with psum), so nothing but the
-  final demuxed result ever rides the slow D2H path — np.asarray
-  once, at the end.
+* **One packed pair.**  The segment ids and the i64 weights go into
+  one i64[2, rows] array, padded to a row count from a fixed ladder
+  (pad_rows: 4096, then times four up to 2^18, then times two); pad
+  rows carry the last segment and weight 0.  No per-shard loop, no
+  local codes, no translation tables: nothing is derived that the
+  stack did not already compute.
+* **One upload, one dispatch, one fetch.**  The pair rides into the
+  jitted program (sums_program, keyed (rows, segments)) as its one
+  argument, so the program's call carries the upload; the program is
+  `segment_sum(pair[1], pair[0], num_segments)`; np.asarray fetches
+  the i64[segments] accumulator once.  The ladder decides the
+  compiles: a year of daily shards of a 400-tuple metric spans four
+  row counts, and residency.prewarm compiles those before the first
+  request.
 * **Residency.**  Inside a residency-armed `dn serve`
-  (serve/residency.py) the staged shard tensors pin in HBM keyed by
-  (plan signature, shard integrity identity) — the integrity
-  catalog's (size, crc32) when the tree has one, the handle cache's
-  statkey otherwise — and retire on the same writer-epoch signal as
-  every other pin, so a repeat dashboard query skips the H2D upload
-  entirely.  The folded accumulator additionally pins under its
-  content digest (the PR 17 contract), so an exact repeat skips the
-  dispatches too.
+  (serve/residency.py) the folded accumulator pins under the content
+  digest of its inputs (the PR 17 contract) and retires on the writer
+  epoch, so an exact repeat skips the upload, the dispatch and the
+  fetch.  Nothing else is pinned: a year's upload is at most 4 MB.
 * **Audition-gated auto.**  The persisted audition cache
   (device_scan.dn_auditions.json) grows an `iq:` verdict family:
   under DN_ENGINE=auto the lane escalates to the device when a fresh
@@ -61,8 +46,6 @@ bincount with the stacked path's ordering — `canonical_item_sort`
 order included — untouched.
 """
 
-import os
-
 import numpy as np
 
 from .obs import metrics as obs_metrics
@@ -72,8 +55,9 @@ from .obs import metrics as obs_metrics
 # whichever lane trips it first)
 _DEVICE_STATE = {'ready': None, 'warned': False}
 
-# slot-packed fold programs keyed (nslots, prow, ptab, pu)
-_FOLD_CACHE = {}
+# the fold's programs keyed (rows, segments) — shared with the legacy
+# single-dispatch lane and residency.prewarm
+_SUMS_CACHE = {}
 
 # per-process engagement snapshot for /stats (server.py reads it):
 # dispatches/shards/rows since process start, last auto decision
@@ -81,13 +65,11 @@ _ENGAGE = {
     'dispatches': 0,
     'shards': 0,
     'rows': 0,
-    'pinned_shard_hits': 0,
+    'padded_rows': 0,
     'h2d_bytes': 0,
-    'h2d_saved_bytes': 0,
     'auditions': 0,
     'last_lane': None,
 }
-_MAX_SLOTS = 64
 
 
 def _reset_device_state():
@@ -125,25 +107,46 @@ def _pow2(x, floor=8):
     return p
 
 
-def batch_rows():
-    """DN_INDEX_DEVICE_BATCH_ROWS: padded-row budget per dispatch (how
-    many shards pack into one launch).  Clamped to a sane floor so a
-    misconfigured knob cannot serialize into per-shard dispatches."""
-    try:
-        v = int(os.environ.get('DN_INDEX_DEVICE_BATCH_ROWS',
-                               str(1 << 20)))
-    except ValueError:
-        v = 1 << 20
-    return max(v, 1 << 12)
+# the row ladder: 4096, then times four up to 2^18, then times two.
+# Four programs cover every batch of up to 262,144 rows (a year of
+# daily shards of a 400-tuple metric is 145,000), and past that the
+# padding's bytes matter more than one compile
+ROW_FLOOR = 1 << 12
+ROW_COARSE_TOP = 1 << 18
+SEGMENT_FLOOR = 1 << 9
+
+
+def pad_rows(n):
+    """The ladder's row count for a batch of `n` rows."""
+    p = ROW_FLOOR
+    while p < n:
+        p <<= 2 if p < ROW_COARSE_TOP else 1
+    return p
+
+
+def pad_segments(nuniq):
+    """The accumulator's padded length: a power of two from 512, so
+    that every aggregate of up to 512 tuples shares its row count's
+    one program (the fetch is 8 bytes a segment)."""
+    return _pow2(nuniq, SEGMENT_FLOOR)
+
+
+def ladder():
+    """The ladder's coarse rungs: every row count it holds up to 2^18,
+    the programs residency.prewarm compiles."""
+    rows = [ROW_FLOOR]
+    while rows[-1] < ROW_COARSE_TOP:
+        rows.append(pad_rows(rows[-1] + 1))
+    return rows
 
 
 # -- lane routing -----------------------------------------------------------
 
 def _audition_key(nrows, nuniq):
     """Audition-cache key family for index queries: log2-bucketed
-    (rows, uniques) — the two sizes that decide dispatch count and
-    accumulator shape — plus the backend identity the verdict was
-    measured on (appended by the caller via _backend_id)."""
+    (rows, uniques) — the two sizes that decide the fold's program —
+    plus the backend identity the verdict was measured on (appended
+    by the caller via _backend_id)."""
     lr = _pow2(max(nrows, 1)).bit_length() - 1
     lu = _pow2(max(nuniq, 1)).bit_length() - 1
     return 'iq:r%d:u%d' % (lr, lu)
@@ -186,126 +189,44 @@ def lane_decision(nrows, nuniq):
     return 'host'
 
 
-# -- shard identity ---------------------------------------------------------
-
-_CATALOG_DIR_MEMO = {}
-
-
-def _shard_identity(path, statkey):
-    """Residency identity for one shard file: the integrity catalog's
-    (size, crc32) when the tree publishes one — content identity that
-    survives a byte-identical republish — else the handle cache's
-    (mtime_ns, size, ino) statkey.  None when neither exists (the
-    shard then stages fresh every query, which is always correct)."""
-    from . import integrity as mod_integrity
-    d = os.path.dirname(os.path.abspath(path))
-    for root in (d, os.path.dirname(d)):
-        has = _CATALOG_DIR_MEMO.get(root)
-        if has is None:
-            has = os.path.exists(mod_integrity.catalog_path(root))
-            _CATALOG_DIR_MEMO[root] = has
-        if not has:
-            continue
-        try:
-            cat = mod_integrity.cached_catalog(root)
-        except Exception:
-            break
-        rel = os.path.relpath(os.path.abspath(path), root)
-        ent = cat.get(rel)
-        if ent is not None:
-            return ('crc', rel, int(ent[0]), int(ent[1]))
-    if statkey is not None:
-        return ('stat',) + tuple(statkey)
-    return None
-
-
-def plan_signature(query):
-    """Digest of everything that determines a shard's staged tensors
-    GIVEN its content: the composed filter inputs, the breakdown
-    specs (bucketizer parameters included — they live in the spec
-    dicts), and the time window.  Two queries with equal signatures
-    stage byte-identical (local, weight) tensors from an identical
-    shard."""
-    import hashlib
-    h = hashlib.blake2b(digest_size=12)
-    h.update(repr((query.qc_filter, query.qc_breakdowns,
-                   query.qc_before, query.qc_after)).encode())
-    return h.hexdigest()
-
-
-# -- staging ----------------------------------------------------------------
-
-def _stage_shard(inv_sl):
-    """(local codes i64[n], ttable i64[nlocal], nlocal) for one
-    shard's slice of the perm-ordered batch.  Local code = rank of the
-    row's aggregate tuple in the shard's first-occurrence order —
-    content-stable, so the padded tensor can pin across queries; the
-    ttable maps local -> this query's global segment id."""
-    lu, first, linv = np.unique(inv_sl, return_index=True,
-                                return_inverse=True)
-    order = np.argsort(first, kind='stable')
-    rankmap = np.empty(len(lu), dtype=np.int64)
-    rankmap[order] = np.arange(len(lu), dtype=np.int64)
-    local = rankmap[linv.reshape(-1)]
-    return local, lu[order], len(lu)
-
-
-def _pad_slot(local, w, nlocal, prow):
-    """Pow2-pad one shard's staged pair: pad rows carry the sentinel
-    local code `nlocal`, whose ttable slot points at the accumulator's
-    last segment with weight 0 — the same harmless-pad trick the
-    legacy single-dispatch lane uses."""
-    pl = np.full(prow, nlocal, dtype=np.int64)
-    pl[:len(local)] = local
-    pw = np.zeros(prow, dtype=np.int64)
-    pw[:len(w)] = w
-    return pl, pw
-
-
 # -- the fold program -------------------------------------------------------
 
-def _fold_program(nslots, prow, ptab, pu):
-    """Jitted slot-packed scatter-add fold: `nslots` shard tensors of
-    `prow` rows each gather their global segment ids through per-slot
-    translation rows [ptab] and merge into the i64[pu] accumulator in
-    ONE segment_sum.  The accumulator stays device-resident across
-    dispatches by riding the jit output back into the next call — the
-    psum-shaped fold.  Deliberately NOT donated: donating the
-    accumulator buffer segfaults jaxlib 0.4.36's CPU client under the
-    multi-device test mesh (flaky heap corruption on repeated
-    donate-and-refeed), and the buffer is pu*8 bytes — there is
-    nothing worth donating."""
-    prog = _FOLD_CACHE.get((nslots, prow, ptab, pu))
+def sums_program(rows, segments):
+    """Jitted i64[2, rows] (segment ids over weights) -> i64[segments]
+    sums: the scatter-add that merges every shard's rows into the
+    dense accumulator in one dispatch.  One argument, so a call with
+    the host's packed pair is one upload."""
+    prog = _SUMS_CACHE.get((rows, segments))
     if prog is None:
         from .ops import get_jax
-        jax, jnp = get_jax()
+        jax, _jnp = get_jax()
 
-        def run(locs, ws, ttabs, acc):
-            lmat = jnp.stack(locs)              # [S, prow]
-            wmat = jnp.stack(ws)                # [S, prow]
-            seg = jnp.take_along_axis(ttabs, lmat, axis=1)
-            return acc + jax.ops.segment_sum(
-                wmat.reshape(-1), seg.reshape(-1), num_segments=pu)
+        def run(pair):
+            return jax.ops.segment_sum(pair[1], pair[0],
+                                       num_segments=segments)
         prog = jax.jit(run)
-        if len(_FOLD_CACHE) >= 32:
-            _FOLD_CACHE.pop(next(iter(_FOLD_CACHE)))
-        _FOLD_CACHE[(nslots, prow, ptab, pu)] = prog
+        if len(_SUMS_CACHE) >= 32:
+            _SUMS_CACHE.pop(next(iter(_SUMS_CACHE)))
+        _SUMS_CACHE[(rows, segments)] = prog
     return prog
+
+
+def pack_pair(inv, weights, rows, segments):
+    """The program's one argument: row 0 the segment ids, row 1 the
+    weights as i64 (exact: the stacked gate admits integers only), pad
+    rows on the last segment with weight 0."""
+    n = len(inv)
+    pair = np.empty((2, rows), dtype=np.int64)
+    pair[0, :n] = inv
+    pair[0, n:] = segments - 1
+    pair[1, :n] = weights
+    pair[1, n:] = 0
+    return pair
 
 
 def _residency():
     from .serve import residency as mod_residency
     return mod_residency.active()
-
-
-def _note_engagement(ndispatch, nshards, nrows, pinned_hits,
-                     h2d_bytes, h2d_saved):
-    _ENGAGE['dispatches'] += ndispatch
-    _ENGAGE['shards'] += nshards
-    _ENGAGE['rows'] += nrows
-    _ENGAGE['pinned_shard_hits'] += pinned_hits
-    _ENGAGE['h2d_bytes'] += h2d_bytes
-    _ENGAGE['h2d_saved_bytes'] += h2d_saved
 
 
 def stats_doc():
@@ -319,111 +240,37 @@ def stats_doc():
 
 # -- execution --------------------------------------------------------------
 
-def _device_fold(inv, w64, nuniq, shard_ctx):
-    """The staged, slot-packed, device-resident fold.  Returns the
-    fetched i64[nuniq] accumulator (host ndarray).  Raises on any
-    backend trouble — the caller owns fallback and the sticky state.
-    `shard_ctx` is (sids i64[n] ascending, [(path, statkey)] per
-    shard, query) from the stacked path, or None (single anonymous
-    shard)."""
-    from .ops import get_jax
-    jax, _jnp = get_jax()
-    pu = _pow2(nuniq)
-
-    if shard_ctx is not None:
-        sid, pairs, query = shard_ctx
-    else:
-        sid = np.zeros(len(inv), dtype=np.int64)
-        pairs, query = [(None, None)], None
-    nshards_total = (int(sid[-1]) + 1) if len(sid) else 0
-    bounds = np.searchsorted(sid, np.arange(nshards_total + 1))
-
-    res = _residency()
-    repoch = plan = None
-    if res is not None:
-        from . import index_query_mt as mod_iqmt
-        repoch = mod_iqmt.cache_epoch()
-        if query is not None:
-            plan = plan_signature(query)
-
-    # stage every non-empty shard: pinned device tensors where
-    # residency has them, fresh host arrays (uploaded per dispatch,
-    # then pinned) otherwise.  One stage for the whole loop: a year's
-    # query stages 365 shards
-    staged = []                  # (prow, ttable, dev_local, dev_w)
-    pinned_hits = 0
-    h2d_bytes = 0
-    h2d_saved = 0
+def _device_fold(inv, weights, nuniq, shard_ctx):
+    """The fold: the stacked batch packed once, uploaded and summed
+    by one dispatch, fetched once.  Returns (the fetched i64[nuniq]
+    accumulator as a host ndarray, the device array it came from, the
+    padded row count).  Raises on any backend trouble — the caller
+    owns fallback and the sticky state.  `shard_ctx` is (sids i64[n]
+    ascending, the number of shards loaded) from the stacked path, or
+    None (one anonymous shard)."""
+    n = len(inv)
+    rows, segments = pad_rows(n), pad_segments(nuniq)
     with obs_metrics.leaf_stage('index_fold.stage'):
-        for s in range(nshards_total):
-            lo, hi = int(bounds[s]), int(bounds[s + 1])
-            if lo == hi:
-                continue
-            local, ttable, nlocal = _stage_shard(inv[lo:hi])
-            prow = _pow2(hi - lo)
-            key = None
-            if plan is not None and s < len(pairs):
-                ident = _shard_identity(*pairs[s]) \
-                    if pairs[s][0] is not None else None
-                if ident is not None:
-                    key = ('iq-shard', plan, ident, prow)
-                dev = res.get_device(key, repoch)
-                if dev is not None:
-                    staged.append((prow, ttable, nlocal, dev[0], dev[1]))
-                    pinned_hits += 1
-                    h2d_saved += prow * 16          # two i64 lanes
-                    continue
-            pl, pw = _pad_slot(local, w64[lo:hi], nlocal, prow)
-            dl = jax.device_put(pl)
-            dw = jax.device_put(pw)
-            h2d_bytes += pl.nbytes + pw.nbytes
-            if key is not None:
-                res.put_device(key, repoch, (dl, dw),
-                               nbytes=pl.nbytes + pw.nbytes)
-            staged.append((prow, ttable, nlocal, dl, dw))
-    obs_metrics.inc('index_fold_shards_staged', len(staged))
-
-    if not staged:
-        return np.zeros(nuniq, dtype=np.int64), None, 0, 0, 0, 0
-
-    # pack by padded row count: pow2 slot ladder bounded by the
-    # batch-rows budget, so a year of daily shards folds in a handful
-    # of launches and the program cache stays O(log^2)
-    ndispatch = 0
+        pair = pack_pair(inv, weights, rows, segments)
+        if shard_ctx is None:
+            nstaged = 1
+        else:
+            sid = shard_ctx[0]
+            nstaged = 1 + int(np.count_nonzero(sid[1:] != sid[:-1]))
+    obs_metrics.inc('index_fold_shards_staged', nstaged)
+    obs_metrics.inc('index_fold_rows', n)
+    obs_metrics.inc('index_fold_padded_rows', rows)
+    # the call carries the upload: the pair is its one argument
     with obs_metrics.leaf_stage('index_fold.dispatch'):
-        groups = {}
-        for st in staged:
-            groups.setdefault(st[0], []).append(st)
-        budget = batch_rows()
-        acc = jax.device_put(np.zeros(pu, dtype=np.int64))
-        for prow in sorted(groups):
-            todo = groups[prow]
-            smax = max(1, min(_MAX_SLOTS, budget // prow))
-            i = 0
-            while i < len(todo):
-                s = 1
-                while s * 2 <= min(smax, len(todo) - i):
-                    s <<= 1
-                chunk = todo[i:i + s]
-                i += s
-                ptab = _pow2(max(c[2] + 1 for c in chunk))
-                ttabs = np.full((s, ptab), pu - 1, dtype=np.int64)
-                for j, (_pr, tt, nl, _dl, _dw) in enumerate(chunk):
-                    ttabs[j, :nl] = tt
-                h2d_bytes += ttabs.nbytes
-                prog = _fold_program(s, prow, ptab, pu)
-                acc = prog(tuple(c[3] for c in chunk),
-                           tuple(c[4] for c in chunk), ttabs, acc)
-                ndispatch += 1
+        acc = sums_program(rows, segments)(pair)
     with obs_metrics.leaf_stage('index_fold.device_wait'):
         try:
             acc.block_until_ready()
         except AttributeError:
             pass
-    # ONE fetch: everything upstream stayed on the device
     with obs_metrics.leaf_stage('index_fold.fetch'):
         out = np.asarray(acc)[:nuniq]
-    return out, acc, ndispatch, pinned_hits, h2d_bytes, h2d_saved
+    return out, acc, rows
 
 
 def batched_sums(inv, weights, nuniq, shard_ctx=None, stage=None,
@@ -453,14 +300,14 @@ def batched_sums(inv, weights, nuniq, shard_ctx=None, stage=None,
         _warn_device('jax unavailable')
         return None
 
-    w64 = weights.astype(np.int64)
     res = _residency()
     rkey = repoch = None
     if res is not None:
         from . import index_query_mt as mod_iqmt
         from .serve import residency as mod_residency
-        rkey = mod_residency.content_key('iq-acc', (inv, w64),
-                                         (_pow2(nuniq), nuniq))
+        rkey = mod_residency.content_key(
+            'iq-acc', (inv, weights.astype(np.int64)),
+            (pad_segments(nuniq), nuniq))
         repoch = mod_iqmt.cache_epoch()
         pinned = res.get(rkey, repoch)
         if pinned is not None:
@@ -476,7 +323,7 @@ def batched_sums(inv, weights, nuniq, shard_ctx=None, stage=None,
         from .ops import backend_ready
         if not backend_ready():
             return None
-        return _device_fold(inv, w64, nuniq, shard_ctx)
+        return _device_fold(inv, weights, nuniq, shard_ctx)
 
     if st['ready'] is None:
         from .device_scan import run_with_deadline, probe_deadline_s
@@ -503,13 +350,16 @@ def batched_sums(inv, weights, nuniq, shard_ctx=None, stage=None,
             st['ready'] = False
             _warn_device('backend failed to initialize')
             return None
-    acc, dev_acc, ndispatch, pinned_hits, h2d_bytes, h2d_saved = out
+    acc, dev_acc, rows = out
     device_s = mod_time.monotonic() - t0
     host = acc.astype(np.float64)
 
-    nshards = len(shard_ctx[1]) if shard_ctx is not None else 1
-    _note_engagement(ndispatch, nshards, len(inv), pinned_hits,
-                     h2d_bytes, h2d_saved)
+    h2d_bytes = 16 * rows                       # two i64 lanes
+    _ENGAGE['dispatches'] += 1
+    _ENGAGE['shards'] += shard_ctx[1] if shard_ctx is not None else 1
+    _ENGAGE['rows'] += len(inv)
+    _ENGAGE['padded_rows'] += rows
+    _ENGAGE['h2d_bytes'] += h2d_bytes
     _ENGAGE['last_lane'] = 'device'
     if stage is not None:
         stage.bump_hidden('index device sums', 1)
@@ -535,7 +385,7 @@ def batched_sums(inv, weights, nuniq, shard_ctx=None, stage=None,
             _warn_device('device/host sums mismatch (audition)')
             return None
 
-    if res is not None and dev_acc is not None:
+    if res is not None:
         # pin the final device-side accumulator + its one fetched
         # copy: an exact repeat answers with zero transfer either way
         res.put(rkey, repoch, dev_acc, host, h2d_bytes=h2d_bytes)

@@ -1079,7 +1079,7 @@ def _load_shard_blocks_cached(path, query, memo):
             table, filt, groupby = plan
             blocks = querier.stack_blocks(table, filt, groupby)
         ok = True
-        return blocks, handle.statkey
+        return blocks
     except DNError as e:
         raise DNError('index "%s" query' % path, cause=e)
     finally:
@@ -1239,12 +1239,8 @@ def run_shard_queries(paths, query, nworkers, on_items):
 
 def run_shard_loads(paths, query, on_blocks):
     """Stacked-mode shard fan-out: load every shard's matching column
-    blocks through the handle cache, calling on_blocks(blocks, path,
-    statkey) once per shard in find order — path + statkey are the
-    shard identity the device lane's residency pins key on
-    (device_index._shard_identity upgrades them to the integrity
-    catalog's (size, crc32) when the tree publishes one).  Loads run
-    on the CALLER's thread
+    blocks through the handle cache, calling on_blocks(blocks) once
+    per shard in find order.  Loads run on the CALLER's thread
     deliberately: unlike full per-shard queries (whose per-group
     Python work a pool overlaps), a block load is ~50 us of small-
     array numpy that never releases the GIL, and measured on the
@@ -1256,5 +1252,4 @@ def run_shard_loads(paths, query, on_blocks):
     run_shard_queries: the first failing shard in find order raises."""
     memo = {}
     for path in paths:
-        blocks, statkey = _load_shard_blocks_cached(path, query, memo)
-        on_blocks(blocks, path, statkey)
+        on_blocks(_load_shard_blocks_cached(path, query, memo))

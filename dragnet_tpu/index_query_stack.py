@@ -395,15 +395,14 @@ class _BreakdownStack(object):
 # -- device lane -----------------------------------------------------------
 
 # The batched engine lives in device_index.py; this module keeps the
-# legacy single-dispatch `_device_sums` (the prewarm shapes and the
-# residency accumulator-pin tests exercise it directly) and shares the
-# sticky per-process availability verdict with it — one probe outcome
-# per process, whichever lane trips it first.
+# legacy single-dispatch `_device_sums` (the residency accumulator-pin
+# tests exercise it directly), a second wrapper of the engine's one
+# program, and shares the sticky per-process availability verdict with
+# it — one probe outcome per process, whichever lane trips it first.
 from .device_index import _DEVICE_STATE          # noqa: E402
 from .device_index import _reset_device_state    # noqa: F401,E402
 from .device_index import _warn_device           # noqa: E402
-
-_SUMS_CACHE = {}
+from .device_index import pack_pair, sums_program     # noqa: E402
 
 
 def _pow2(x):
@@ -411,25 +410,6 @@ def _pow2(x):
     while p < x:
         p <<= 1
     return p
-
-
-def _sums_program(pn, pu):
-    """Jitted (segment ids i64[pn], weights i64[pn]) -> i64[pu] sums —
-    the scatter-add that merges every shard's rows into dense bucket
-    tensors in one dispatch.  Shapes are pow2-padded so the program
-    retraces O(log) times as query sizes vary."""
-    prog = _SUMS_CACHE.get((pn, pu))
-    if prog is None:
-        from .ops import get_jax
-        jax, jnp = get_jax()
-
-        def run(seg, w):
-            return jax.ops.segment_sum(w, seg, num_segments=pu)
-        prog = jax.jit(run)
-        if len(_SUMS_CACHE) >= 32:
-            _SUMS_CACHE.pop(next(iter(_SUMS_CACHE)))
-        _SUMS_CACHE[(pn, pu)] = prog
-    return prog
 
 
 def _residency():
@@ -468,17 +448,14 @@ def _device_sums(inv, weights, nuniq):
 
     pn = _pow2(len(inv))
     pu = _pow2(nuniq)
-    seg = np.full(pn, pu - 1, dtype=np.int64)
-    seg[:len(inv)] = inv
-    w = np.zeros(pn, dtype=np.int64)
-    w[:len(inv)] = weights.astype(np.int64)
+    pair = pack_pair(inv, weights, pn, pu)
 
     res = _residency()
     rkey = repoch = None
     if res is not None:
         from . import index_query_mt as mod_iqmt
         from .serve import residency as mod_residency
-        rkey = mod_residency.content_key('iq-sums', (seg, w),
+        rkey = mod_residency.content_key('iq-sums', (pair,),
                                          (pn, pu, nuniq))
         repoch = mod_iqmt.cache_epoch()
         pinned = res.get(rkey, repoch)
@@ -491,7 +468,7 @@ def _device_sums(inv, weights, nuniq):
         from .ops import backend_ready
         if not backend_ready():
             return None
-        dense = _sums_program(pn, pu)(seg, w)
+        dense = sums_program(pn, pu)(pair)
         try:
             dense.block_until_ready()
         except AttributeError:
@@ -528,8 +505,7 @@ def _device_sums(inv, weights, nuniq):
     if res is not None:
         # pin the device-side accumulator + its fetched copy; future
         # hits book the upload and fetch bytes this execution paid
-        res.put(rkey, repoch, dense, host,
-                h2d_bytes=seg.nbytes + w.nbytes)
+        res.put(rkey, repoch, dense, host, h2d_bytes=pair.nbytes)
         return host.copy()
     return host
 
@@ -622,10 +598,9 @@ def run_stacked(paths, query, aggr, index_list, export=None):
     # per-shard path takes over.
     shards = []
     vals_list = []
-    idents = []
     state = {'total_abs': 0.0}
 
-    def on_blocks(sh, path, statkey):
+    def on_blocks(sh):
         v, ok = _shard_values(sh)
         if ok and len(v):
             state['total_abs'] += float(np.abs(v).sum())
@@ -634,7 +609,6 @@ def run_stacked(paths, query, aggr, index_list, export=None):
             raise _GateFailed()
         shards.append(sh)
         vals_list.append(v)
-        idents.append((path, statkey))
 
     from .obs import metrics as obs_metrics
     try:
@@ -724,14 +698,14 @@ def run_stacked(paths, query, aggr, index_list, export=None):
         first_idx, inv, order = _unique_rows(acols)
     nuniq = len(first_idx)
 
-    # rows are now shard-contiguous (the perm sorts shard-first) —
-    # exactly the slices the batched device engine stages per shard
+    # rows are now shard-contiguous (the perm sorts shard-first): the
+    # device fold takes the batch as it is and counts its shards
     sid = shard_ids[perm]
     with obs_metrics.timed_stage('index_query_stack.aggregate',
                                  nuniq=nuniq):
         wsum = _aggregate_weights(inv, values[perm], nuniq,
                                   stage=index_list,
-                                  shard_ctx=(sid, idents, query))
+                                  shard_ctx=(sid, nshards))
     # index_query_stack.commit: the result's columns in emission
     # order, the key-item count, the aggregator's columnar install;
     # for a member's partial index_query_stack.export in its place:

@@ -103,8 +103,6 @@ def _member_row(name, st, latency=None):
         row['index_device_dispatches'] = iq.get('dispatches')
         row['index_device_shards_per_dispatch'] = \
             iq.get('shards_per_dispatch')
-        row['index_device_h2d_saved_bytes'] = \
-            iq.get('h2d_saved_bytes', 0)
     # standing queries: active subscriber count per member (honest
     # absence when the member runs with DN_SUB_MAX=0)
     subs = st.get('subscriptions') or {}
@@ -287,7 +285,7 @@ def merge_fleet(server, names, stats, events, errors, timeout_s=None):
     cache_on = False
     resid_hits = resid_misses = resid_pinned = 0
     resid_on = False
-    iq_dispatches = iq_shards = iq_pin_hits = iq_saved = 0
+    iq_dispatches = iq_shards = 0
     iq_on = False
     roll_covered = roll_queried = 0
     compact_backlog = None
@@ -347,8 +345,6 @@ def merge_fleet(server, names, stats, events, errors, timeout_s=None):
             iq_on = True
             iq_dispatches += iqd.get('dispatches', 0) or 0
             iq_shards += iqd.get('shards', 0) or 0
-            iq_pin_hits += iqd.get('pinned_shard_hits', 0) or 0
-            iq_saved += iqd.get('h2d_saved_bytes', 0) or 0
         roll = st.get('rollup') or {}
         roll_covered += roll.get('covered_shards', 0) or 0
         roll_queried += roll.get('shards_queried', 0) or 0
@@ -431,15 +427,12 @@ def merge_fleet(server, names, stats, events, errors, timeout_s=None):
         (0.0 if resid_on else None),
         'device_pinned_bytes': resid_pinned if resid_on else None,
         # batched index-query offload: SUMMED dispatch/shard counts
-        # and pinned-shard H2D savings (None when no member's device
-        # index lane has engaged — honest absence)
+        # (None when no member's device index lane has engaged —
+        # honest absence)
         'index_device_dispatches': iq_dispatches if iq_on else None,
         'index_device_shards_per_dispatch': round(
             iq_shards / iq_dispatches, 2)
         if iq_on and iq_dispatches else (0.0 if iq_on else None),
-        'index_device_pinned_shard_hits':
-        iq_pin_hits if iq_on else None,
-        'index_device_h2d_saved_bytes': iq_saved if iq_on else None,
         # standing queries: SUMMED active subscribers and lifetime
         # pushes (None when no member enables subscriptions —
         # honest absence)
@@ -533,9 +526,6 @@ def fleet_prometheus_text(doc):
     if agg.get('index_device_dispatches') is not None:
         reg.set_gauge('fleet_index_device_dispatches',
                       agg['index_device_dispatches'])
-    if agg.get('index_device_h2d_saved_bytes') is not None:
-        reg.set_gauge('fleet_index_device_h2d_saved_bytes',
-                      agg['index_device_h2d_saved_bytes'])
     if agg.get('subscriptions') is not None:
         reg.set_gauge('fleet_subscriptions', agg['subscriptions'])
     if agg.get('subscription_pushes') is not None:

@@ -116,20 +116,11 @@ class DeviceResidency(object):
     invalidated by the writer epoch.  Thread-safe — the serve workers
     race on it."""
 
-    def __init__(self, budget_bytes, shard_share=None):
+    def __init__(self, budget_bytes):
         self.budget = int(budget_bytes or 0)
-        if shard_share is None:
-            import os
-            try:
-                shard_share = float(os.environ.get(
-                    'DN_INDEX_RESIDENCY_SHARE', '0.5'))
-            except ValueError:
-                shard_share = 0.5
-        self.shard_share = min(max(float(shard_share), 0.0), 1.0)
         self._lock = threading.Lock()
         self._entries = OrderedDict()
         self._bytes = 0
-        self._shard_bytes = 0
         self._hits = 0
         self._misses = 0
         self._stale = 0
@@ -148,31 +139,13 @@ class DeviceResidency(object):
             return
         del self._entries[key]
         self._bytes -= ent['nbytes']
-        if ent.get('kind') == 'shard':
-            self._shard_bytes -= ent['nbytes']
 
-    def _evict_lru_locked(self, kind=None):
+    def _evict_lru_locked(self):
         for key, ent in self._entries.items():
-            if kind is not None and ent.get('kind') != kind:
-                continue
             self._drop_locked(key, ent)
             self._evictions += 1
             return True
         return False
-
-    def _evict_global_locked(self):
-        """Global-budget eviction prefers the host-side (whole-result)
-        pins: the shard share exists precisely so staged shard columns
-        survive distinct-query churn — whole-result pins only answer
-        exact repeats, so they are the cheaper loss.  Shard pins go
-        only when nothing else is left."""
-        for key, ent in self._entries.items():
-            if ent.get('kind') == 'shard':
-                continue
-            self._drop_locked(key, ent)
-            self._evictions += 1
-            return True
-        return self._evict_lru_locked(kind='shard')
 
     # -- the residency protocol --------------------------------------------
 
@@ -184,8 +157,6 @@ class DeviceResidency(object):
             return None
         with self._lock:
             ent = self._entries.get(key)
-            if ent is not None and ent.get('kind') == 'shard':
-                ent = None       # device-only pin: not this protocol
             if ent is not None and ent['epoch'] != epoch:
                 self._drop_locked(key, ent)
                 self._stale += 1
@@ -227,70 +198,10 @@ class DeviceResidency(object):
             if old is not None:
                 self._drop_locked(key, old)
             while self._bytes + nbytes > self.budget:
-                if not self._evict_global_locked():
+                if not self._evict_lru_locked():
                     break
             self._entries[key] = ent
             self._bytes += nbytes
-        return True
-
-    # -- per-shard device-tensor pins (device_index.py) --------------------
-
-    def get_device(self, key, epoch):
-        """The pinned DEVICE tensors for a staged shard (tuple of
-        jax arrays), or None.  Unlike get(), nothing is fetched — a
-        hit hands the device references straight back into the next
-        dispatch and books only the H2D upload it skipped."""
-        if not self.enabled() or key is None:
-            return None
-        with self._lock:
-            ent = self._entries.get(key)
-            if ent is not None and (ent.get('kind') != 'shard'
-                                    or ent['epoch'] != epoch
-                                    or _device_deleted(ent['device'])):
-                if ent.get('kind') == 'shard':
-                    self._drop_locked(key, ent)
-                    self._stale += 1
-                ent = None
-            if ent is None:
-                self._misses += 1
-                return None
-            self._entries.move_to_end(key)
-            self._hits += 1
-            self._h2d_saved += ent['h2d_bytes']
-            return ent['device']
-
-    def put_device(self, key, epoch, device, nbytes, h2d_bytes=None):
-        """Pin one shard's staged device tensors (no host copy — the
-        host never needs them back).  Bounded twice: by the global HBM
-        budget AND by the shard share (DN_INDEX_RESIDENCY_SHARE of the
-        budget), so shard columns cannot starve the pinned
-        accumulators that answer exact repeats with zero transfer."""
-        if not self.enabled() or key is None:
-            return False
-        nbytes = int(nbytes or 0)
-        cap = int(self.budget * self.shard_share)
-        if nbytes <= 0 or nbytes > cap:
-            with self._lock:
-                self._shed += 1
-            return False
-        ent = {'epoch': epoch, 'device': device, 'host': None,
-               'nbytes': nbytes, 'kind': 'shard',
-               'h2d_bytes': int(h2d_bytes if h2d_bytes is not None
-                                else nbytes),
-               'ts': time.time()}
-        with self._lock:
-            old = self._entries.get(key)
-            if old is not None:
-                self._drop_locked(key, old)
-            while self._shard_bytes + nbytes > cap:
-                if not self._evict_lru_locked(kind='shard'):
-                    break
-            while self._bytes + nbytes > self.budget:
-                if not self._evict_global_locked():
-                    break
-            self._entries[key] = ent
-            self._bytes += nbytes
-            self._shard_bytes += nbytes
         return True
 
     def clear(self):
@@ -300,17 +211,6 @@ class DeviceResidency(object):
             for key, ent in list(self._entries.items()):
                 self._drop_locked(key, ent)
 
-    def drop_host_pins(self):
-        """Drop every whole-result (host-copy) pin, keeping the shard
-        pins — the state distinct-query churn converges to under
-        budget pressure (_evict_global_locked goes host-first).  Bench
-        and tests use this to exercise the pinned-shard repeat path
-        deterministically."""
-        with self._lock:
-            for key, ent in list(self._entries.items()):
-                if ent.get('kind') != 'shard':
-                    self._drop_locked(key, ent)
-
     def stats(self):
         with self._lock:
             hits, misses = self._hits, self._misses
@@ -319,8 +219,6 @@ class DeviceResidency(object):
                 'budget_bytes': self.budget,
                 'bytes': self._bytes,
                 'entries': len(self._entries),
-                'shard_bytes': self._shard_bytes,
-                'shard_share': self.shard_share,
                 'hits': hits,
                 'misses': misses,
                 'stale_drops': self._stale,
@@ -336,20 +234,17 @@ class DeviceResidency(object):
 
 # -- serve-start pre-warm ---------------------------------------------------
 
-# padded (rows, segments) shapes worth compiling before the first
-# request: the pow2 ladder index_query_stack pads real queries into
-_PREWARM_SHAPES = ((1 << 10, 1 << 8), (1 << 14, 1 << 10))
-
-
-def prewarm(shapes=_PREWARM_SHAPES, deadline_s=None):
+def prewarm(shapes=None, deadline_s=None):
     """Serve-start device pre-warm: initialize the backend, compile
     the stacked index-query programs for representative shapes, and
     report the persisted audition cache — all BEFORE the first
-    request pays for any of it.  Runs the whole thing under the probe
-    deadline on the caller's (background) thread: a wedged plugin
-    costs a bounded wait and an honest 'timeout' doc, never a hung
-    server.  Returns {'state', 'backend', 'programs', 'auditions',
-    'audition_path', 'ms'}."""
+    request pays for any of it.  `shapes` defaults to the index
+    fold's row ladder up to 2^18 rows at its smallest accumulator:
+    every program a query over aggregates of up to 512 tuples takes.
+    Runs the whole thing under the probe deadline on the caller's
+    (background) thread: a wedged plugin costs a bounded wait and an
+    honest 'timeout' doc, never a hung server.  Returns {'state',
+    'backend', 'programs', 'auditions', 'audition_path', 'ms'}."""
     from .. import device_scan as mod_ds
     doc = {'state': 'failed', 'backend': None, 'programs': 0,
            'auditions': 0, 'audition_path': None, 'ms': 0.0}
@@ -360,14 +255,14 @@ def prewarm(shapes=_PREWARM_SHAPES, deadline_s=None):
     def warm():
         import numpy as np
         from ..ops import backend_ready
-        from .. import index_query_stack as mod_iqs
+        from .. import device_index as mod_di
         if not backend_ready():
             return None
         compiled = 0
-        for pn, pu in shapes:
-            prog = mod_iqs._sums_program(pn, pu)
-            out = prog(np.zeros(pn, dtype=np.int64),
-                       np.zeros(pn, dtype=np.int64))
+        for pn, pu in shapes or [(rows, mod_di.SEGMENT_FLOOR)
+                                 for rows in mod_di.ladder()]:
+            prog = mod_di.sums_program(pn, pu)
+            out = prog(np.zeros((2, pn), dtype=np.int64))
             np.asarray(out)          # force compile + execute
             compiled += 1
         return compiled

@@ -345,9 +345,8 @@ class DnServer(object):
         if isinstance(dev_conf, DNError):
             raise dev_conf
         self.device_conf = dev_conf
-        # index-query device-lane knobs (device_index.py) validated
-        # with the same fail-fast contract; the residency share caps
-        # how much HBM pinned shard tensors may occupy
+        # the index-query device lane's knob (device_index.py),
+        # validated with the same fail-fast contract
         iq_conf = mod_config.index_device_config()
         if isinstance(iq_conf, DNError):
             raise iq_conf
@@ -935,7 +934,6 @@ class DnServer(object):
         from .. import device_index as mod_di
         doc = mod_di.stats_doc()
         doc['mode'] = self.index_device_conf['mode']
-        doc['batch_rows'] = self.index_device_conf['batch_rows']
         return doc
 
     def _parallel_fetch_doc(self):

@@ -119,12 +119,10 @@ def render_frame(doc, ansi=True):
         # index-query offload column: only once some member's device
         # index lane has dispatched (idle lanes keep the line short)
         if agg.get('index_device_dispatches') is not None:
-            dev += ('  iq disp %s  sh/disp %s  h2d saved %s'
+            dev += ('  iq disp %s  sh/disp %s'
                     % (_fmt(agg.get('index_device_dispatches')),
                        _fmt(agg.get(
-                           'index_device_shards_per_dispatch')),
-                       _fmt_bytes(agg.get(
-                           'index_device_h2d_saved_bytes'))))
+                           'index_device_shards_per_dispatch'))))
         lines.append(dev)
     if doc.get('members_read_only'):
         lines.append('%sDISK: %d member(s) read-only (min free %s%%)'

@@ -3,10 +3,10 @@ differential byte identity against the host path across formats,
 intervals, predicate shapes, and the cardinality sweep
 (dense -> sparse -> overflow -> host fallback); lane routing
 (DN_INDEX_DEVICE off/forced/auto-audition) and the persisted `iq:`
-audition family; residency integration (shard-tensor pins, the
-whole-result accumulator pin, writer-epoch staleness, the shard-share
-eviction contract); the probed DN_PARALLEL_FETCH capability; and
-index_device_config validation.
+audition family; the packed fold (one upload and one dispatch a fold,
+the row ladder that decides the compiles); residency integration (the
+whole-result accumulator pin, writer-epoch staleness); the probed
+DN_PARALLEL_FETCH capability; and index_device_config validation.
 
 Byte identity is the contract under test everywhere: every device
 result (engaged, audited, pinned, or fallen back) must equal the host
@@ -107,7 +107,6 @@ def _fresh_lane(monkeypatch):
     monkeypatch.setenv('DN_IQ_THREADS', 'auto')
     monkeypatch.delenv('DN_ENGINE', raising=False)
     monkeypatch.delenv('DN_INDEX_DEVICE', raising=False)
-    monkeypatch.delenv('DN_INDEX_DEVICE_BATCH_ROWS', raising=False)
     mod_iqmt.shard_cache_clear()
     mod_di._reset_device_state()
     mod_di._reset_engagement()
@@ -175,13 +174,13 @@ def test_cardinality_sweep_dense_sparse_overflow(monkeypatch):
     for nuniq in (8, 1000, 50000):
         n = max(nuniq * 3, 512)
         inv = rng.randint(0, nuniq, size=n).astype(np.int64)
-        # every segment id present at least once: inv from _unique_rows
-        # is surjective by construction, and staging relies on that
+        # every segment id present at least once, as inv from
+        # _unique_rows is by construction
         inv[:nuniq] = np.arange(nuniq)
         w = rng.randint(0, 1000, size=n).astype(np.int64)
         sid = np.sort(rng.randint(0, 37, size=n).astype(np.int64))
-        got = mod_di.aggregate_weights(
-            inv, w, nuniq, shard_ctx=(sid, [(None, None)] * 37, None))
+        got = mod_di.aggregate_weights(inv, w, nuniq,
+                                       shard_ctx=(sid, 37))
         ref = np.bincount(inv, weights=w, minlength=nuniq)
         assert np.array_equal(got, ref), nuniq
     if mod_di._DEVICE_STATE['ready'] is False:
@@ -256,14 +255,157 @@ def test_auto_audition_persists_iq_verdict(tmp_path, monkeypatch):
     assert 'won' in ent and 'device_rate' in ent
 
 
+# -- the packed fold --------------------------------------------------------
+
+def _batch(nshards, nuniq, empty=(), seed=5):
+    """A stacked batch as run_stacked hands it over: `inv` covering
+    every segment, integer weights as f64, shard ids ascending; the
+    shards of `empty` hold no row."""
+    rng = np.random.RandomState(seed)
+    live = [s for s in range(nshards) if s not in empty]
+    per = max(nuniq // len(live), 1) + 3
+    sid = np.repeat(np.array(live, dtype=np.int64), per)
+    n = len(sid)
+    inv = rng.randint(0, nuniq, size=n).astype(np.int64)
+    inv[:min(nuniq, n)] = np.arange(min(nuniq, n))
+    nuniq = int(inv.max()) + 1
+    w = rng.randint(0, 1 << 20, size=n).astype(np.float64)
+    return inv, w, nuniq, sid
+
+
+FOLD_CASES = [(nshards, nuniq, ())
+              for nshards in (1, 7, 57, 365)
+              for nuniq in (35, 400, 70000)] + [
+    (57, 400, (0, 1)), (57, 400, (20, 21, 22)), (57, 400, (55, 56))]
+
+
+@pytest.mark.parametrize('nshards,nuniq,empty', FOLD_CASES)
+def test_packed_fold_equals_bincount(nshards, nuniq, empty,
+                                     monkeypatch):
+    """The packed fold against np.bincount over shard counts, segment
+    counts and empty shards at the start, in the middle and at the
+    end: the same bits, one dispatch, and the non-empty shards
+    counted."""
+    _need_jax()
+    monkeypatch.setenv('DN_INDEX_DEVICE', '1')
+    inv, w, nuniq, sid = _batch(nshards, nuniq, empty)
+    got = mod_di.aggregate_weights(inv, w, nuniq,
+                                   shard_ctx=(sid, nshards))
+    if mod_di._DEVICE_STATE['ready'] is False:
+        pytest.skip('device lane unavailable on this rig')
+    assert got.dtype == np.float64
+    assert np.array_equal(got, np.bincount(inv, weights=w,
+                                           minlength=nuniq))
+    doc = mod_di.stats_doc()
+    assert doc['last_lane'] == 'device' and doc['dispatches'] == 1
+    assert doc['shards'] == nshards and doc['rows'] == len(inv)
+
+
+def test_packed_fold_without_shard_context(monkeypatch):
+    """The anonymous call (shard_ctx=None) takes the same path."""
+    _need_jax()
+    monkeypatch.setenv('DN_INDEX_DEVICE', '1')
+    inv, w, nuniq, _sid = _batch(7, 400)
+    got = mod_di.aggregate_weights(inv, w, nuniq)
+    if mod_di._DEVICE_STATE['ready'] is False:
+        pytest.skip('device lane unavailable on this rig')
+    assert np.array_equal(got, np.bincount(inv, weights=w,
+                                           minlength=nuniq))
+    doc = mod_di.stats_doc()
+    assert doc['dispatches'] == 1 and doc['shards'] == 1
+
+
+@pytest.mark.parametrize('nshards', [1, 7, 57, 365])
+def test_one_dispatch_and_one_upload_a_fold(nshards, monkeypatch):
+    """Whatever the shard count: one dispatch, and 16 bytes a padded
+    row uploaded (the i64 pair), the padded rows from the ladder."""
+    _need_jax()
+    monkeypatch.setenv('DN_INDEX_DEVICE', '1')
+    inv, w, nuniq, sid = _batch(nshards, 400)
+    calls = []
+    real = mod_di.sums_program
+
+    def counting(rows, segments):
+        prog = real(rows, segments)
+
+        def run(pair):
+            calls.append(pair.shape)
+            return prog(pair)
+        return run
+    monkeypatch.setattr(mod_di, 'sums_program', counting)
+    mod_di.aggregate_weights(inv, w, nuniq, shard_ctx=(sid, nshards))
+    if mod_di._DEVICE_STATE['ready'] is False:
+        pytest.skip('device lane unavailable on this rig')
+    rows = mod_di.pad_rows(len(inv))
+    assert calls == [(2, rows)]
+    doc = mod_di.stats_doc()
+    assert doc['dispatches'] == 1
+    assert doc['padded_rows'] == rows
+    assert doc['h2d_bytes'] == 16 * rows
+    assert doc['shards_per_dispatch'] == float(nshards)
+
+
+def test_pack_pair_pads_onto_the_last_segment():
+    inv = np.array([0, 2, 1, 2], dtype=np.int64)
+    w = np.array([5.0, 7.0, 11.0, 13.0])
+    pair = mod_di.pack_pair(inv, w, 8, 4)
+    assert pair.dtype == np.int64 and pair.shape == (2, 8)
+    assert pair[0].tolist() == [0, 2, 1, 2, 3, 3, 3, 3]
+    assert pair[1].tolist() == [5, 7, 11, 13, 0, 0, 0, 0]
+
+
+# the programs the fold may compile for any batch of up to 2^18 rows:
+# the builder's stated bound (device_index.ladder)
+LADDER_PROGRAMS = 4
+
+
+def test_ladder_bounds_the_programs():
+    """Every row count from 1 to 2^18 maps onto one of four padded
+    row counts, never below itself, and past 2^18 the ladder turns to
+    powers of two (at most twice the rows)."""
+    n = np.arange(1, (1 << 18) + 1)
+    rungs = np.array(mod_di.ladder())
+    assert len(rungs) == LADDER_PROGRAMS
+    assert rungs.tolist() == [1 << 12, 1 << 14, 1 << 16, 1 << 18]
+    padded = rungs[np.searchsorted(rungs, n)]
+    # the vectorized reading above is pad_rows': spot-check the edges
+    for k in (1, 4096, 4097, 16384, 16385, 65536, 65537, 1 << 18):
+        assert mod_di.pad_rows(k) == padded[k - 1]
+    assert (padded >= n).all()
+    assert len(np.unique(padded)) == LADDER_PROGRAMS
+    assert mod_di.pad_rows((1 << 18) + 1) == 1 << 19
+    assert mod_di.pad_rows((1 << 20) + 1) == 1 << 21
+    assert [mod_di.pad_segments(u) for u in (1, 35, 400, 512, 513,
+                                             70000)] == \
+        [512, 512, 512, 512, 1024, 131072]
+
+
+# tuples a daily shard holds for the four templates of the query
+# cells (benchmarks/configs/muskie-365d-index.json: the key space
+# bounds them from above, 5.5 k records a day nearly fill them), as
+# (fewest, most) rows a shard brings to the batch
+CLASS_ROWS = {'m1': (370, 400), 'm2': (28, 30), 'm3': (290, 340),
+              'm1-host-latency-get': (100, 120)}
+
+
+@pytest.mark.parametrize('days', [7, 30, 90, 365])
+@pytest.mark.parametrize('template', sorted(CLASS_ROWS))
+def test_class_shape_does_not_move_with_the_start_day(template, days):
+    """A class of the query cell (template x window) takes one program
+    wherever its window starts: its fewest and its most rows pad to
+    the same rung, so the warm-up's one request a class compiles all
+    the window can ask for."""
+    lo, hi = CLASS_ROWS[template]
+    assert mod_di.pad_rows(days * lo) == mod_di.pad_rows(days * hi)
+    assert mod_di.pad_segments(hi) == mod_di.SEGMENT_FLOOR
+
+
 # -- residency integration --------------------------------------------------
 
-def test_acc_pin_and_pinned_shard_repeat(tmp_path, monkeypatch):
+def test_acc_pin_answers_the_repeat(tmp_path, monkeypatch):
     """Residency-armed repeats: an exact repeat answers from the
-    whole-result pin with zero new dispatches; after host-pin churn
-    (drop_host_pins) the repeat re-folds from PINNED shard tensors —
-    hits > 0, H2D bytes measurably skipped — and stays
-    byte-identical."""
+    whole-result pin with no new dispatch and no new upload, byte-
+    identical; a different query folds again."""
     _need_jax()
     ds, _, _ = _built(tmp_path, n=3000)
     conf = FUZZ_QUERIES[0]
@@ -274,46 +416,49 @@ def test_acc_pin_and_pinned_shard_repeat(tmp_path, monkeypatch):
     if mod_di._DEVICE_STATE['ready'] is False:
         pytest.skip('device lane unavailable on this rig')
     assert pts == ref and cnt == cref
-    assert mgr.stats()['shard_bytes'] > 0      # shard tensors pinned
+    assert mgr.stats()['entries'] == 1         # the accumulator pinned
 
-    base = mod_di.stats_doc()['dispatches']
+    base = mod_di.stats_doc()
+    assert base['dispatches'] == 1
     pts, cnt = _run(ds, 'day', conf, '1', monkeypatch)
     assert pts == ref and cnt == cref
-    assert mod_di.stats_doc()['dispatches'] == base   # acc pin hit
-    assert mgr.stats()['d2h_saved_bytes'] > 0
+    again = mod_di.stats_doc()
+    assert again['dispatches'] == base['dispatches']    # acc pin hit
+    assert again['h2d_bytes'] == base['h2d_bytes']
+    st = mgr.stats()
+    assert st['hits'] == 1
+    assert st['d2h_saved_bytes'] > 0 and st['h2d_saved_bytes'] > 0
 
-    mgr.drop_host_pins()
-    mod_di._reset_engagement()
-    pts, cnt = _run(ds, 'day', conf, '1', monkeypatch)
-    assert pts == ref and cnt == cref
-    eng = mod_di.stats_doc()
-    assert eng['dispatches'] > 0               # re-folded on device
-    assert eng['pinned_shard_hits'] > 0        # from HBM, not H2D
-    assert eng['h2d_saved_bytes'] > 0
-    assert eng['pinned_shard_hits'] == eng['shards']
+    other = FUZZ_QUERIES[2]
+    oref, _ = _run(ds, 'day', other, '0', monkeypatch)
+    opts, _ = _run(ds, 'day', other, '1', monkeypatch)
+    assert opts == oref
+    assert mod_di.stats_doc()['dispatches'] == base['dispatches'] + 1
+    assert mgr.stats()['entries'] == 2
 
 
-def test_writer_epoch_retires_pinned_shards(tmp_path, monkeypatch):
-    """The staleness hazard: shard identity is pinned past a content
-    change (monkeypatched to path-only, simulating an in-place rewrite
-    that preserves statkey), the index is rebuilt with different data,
-    and the writer-epoch signal — the serve write hook's contract —
-    must retire the pinned tensors so the next query matches the host
-    path on the NEW content."""
+def test_writer_epoch_retires_the_acc_pin(tmp_path, monkeypatch):
+    """The staleness hazard: the accumulator is pinned, the index is
+    rebuilt with different data at the same paths, and the writer-
+    epoch signal — the serve write hook's contract — retires the pin,
+    so the next query folds the NEW content and matches the host
+    path; a repeat under the old epoch's content cannot be served."""
     _need_jax()
     datafile = str(tmp_path / 'data.log')
     idx = str(tmp_path / 'idx')
     _make_data(datafile, n=2000, seed=1)
     ds = _ds(datafile, idx)
     ds.build([_metric()], 'day')
-    monkeypatch.setattr(mod_di, '_shard_identity',
-                        lambda path, statkey: ('path', path))
     residency.configure(64 << 20)
     conf = FUZZ_QUERIES[0]
     pts1, _ = _run(ds, 'day', conf, '1', monkeypatch)
     if mod_di._DEVICE_STATE['ready'] is False:
         pytest.skip('device lane unavailable on this rig')
-    assert residency.stats()['shard_bytes'] > 0
+    assert residency.stats()['entries'] == 1
+    # the same tree, the same epoch: the pin answers
+    assert _run(ds, 'day', conf, '1', monkeypatch)[0] == pts1
+    assert mod_di.stats_doc()['dispatches'] == 1
+    epoch = mod_iqmt.cache_epoch()
 
     # publish new content at the same paths, then fire the writer
     # invalidation exactly as serve's install_writer_invalidation does
@@ -321,67 +466,21 @@ def test_writer_epoch_retires_pinned_shards(tmp_path, monkeypatch):
     ds2 = _ds(datafile, idx)
     ds2.build([_metric()], 'day')
     mod_iqmt.invalidate_index_tree(idx)
+    assert mod_iqmt.cache_epoch() > epoch
 
     mod_iqmt.shard_cache_clear()
     ref, cref = _run(ds2, 'day', conf, '0', monkeypatch)
     assert ref != pts1                         # the data really moved
     pts2, cnt2 = _run(ds2, 'day', conf, '1', monkeypatch)
     assert pts2 == ref and cnt2 == cref        # never the stale pin
-    assert residency.stats()['stale_drops'] >= 1
-
-
-def test_shard_share_and_eviction_preference():
-    """The budget split: shard pins are capped at the share, a
-    too-big shard pin is shed, get() never leaks a device-only pin,
-    and global-budget pressure evicts whole-result pins BEFORE shard
-    pins (_evict_global_locked)."""
-    mgr = residency.DeviceResidency(200, shard_share=0.5)
-    # share cap: 0.5 * 200 = 100 -> a 120-byte shard pin is shed
-    assert mgr.put_device('s-big', 1, ('d',), nbytes=120) is False
-    assert mgr.stats()['shed'] == 1
-    assert mgr.put_device('s1', 1, ('d1',), nbytes=60)
-    assert mgr.put_device('s2', 1, ('d2',), nbytes=40)
-    # the kind guard: a shard pin never answers the host protocol
-    assert mgr.get('s1', 1) is None
-    assert mgr.get_device('s1', 1) == ('d1',)
-    # a third shard pin overflows the share: the shard LRU (s2 — s1
-    # was just touched) goes, never the host pin added below
-    host = np.zeros(8)                         # 64 bytes
-    assert mgr.put('acc', 1, host, host, h2d_bytes=7)
-    assert mgr.put_device('s3', 1, ('d3',), nbytes=40)
+    assert mod_di.stats_doc()['dispatches'] == 2     # folded anew
+    # the old epoch's entry is dropped where a lookup meets it
+    mgr = residency.active()
+    stale0 = mgr.stats()['stale_drops']
+    for key in list(mgr._entries):
+        mgr.get(key, mod_iqmt.cache_epoch())
     st = mgr.stats()
-    assert st['shard_bytes'] <= 100
-    assert mgr.get('acc', 1) is not None       # host pin survived
-    # global pressure from a host put evicts the OTHER host pin
-    # first, not the shard tensors
-    big = np.zeros(12)                         # 96 bytes
-    assert mgr.put('acc2', 1, big, big, h2d_bytes=0)
-    assert mgr.get('acc', 1) is None           # host pin was the prey
-    assert mgr.get_device('s1', 1) == ('d1',)  # shards survived
-    assert mgr.get_device('s3', 1) == ('d3',)
-
-
-def test_get_device_epoch_and_hit_accounting():
-    mgr = residency.DeviceResidency(1 << 10)
-    assert mgr.put_device('k', 3, ('dev',), nbytes=64, h2d_bytes=640)
-    assert mgr.get_device('k', 4) is None      # epoch moved on
-    assert mgr.stats()['stale_drops'] == 1
-    assert mgr.put_device('k', 4, ('dev',), nbytes=64, h2d_bytes=640)
-    assert mgr.get_device('k', 4) == ('dev',)
-    st = mgr.stats()
-    assert st['h2d_saved_bytes'] == 640        # a hit skips the upload
-    assert st['d2h_saved_bytes'] == 0          # ...but fetches nothing
-
-
-def test_drop_host_pins_keeps_shards():
-    mgr = residency.DeviceResidency(1 << 10)
-    host = np.zeros(8)
-    mgr.put('acc', 1, host, host, h2d_bytes=0)
-    mgr.put_device('s', 1, ('d',), nbytes=64)
-    mgr.drop_host_pins()
-    st = mgr.stats()
-    assert st['entries'] == 1 and st['shard_bytes'] == 64
-    assert mgr.get_device('s', 1) == ('d',)
+    assert st['stale_drops'] == stale0 + 1 and st['entries'] == 1
 
 
 # -- the probed DN_PARALLEL_FETCH capability --------------------------------
@@ -437,12 +536,13 @@ def test_parallel_fetch_probe_failure_disables(monkeypatch,
 # -- config validation ------------------------------------------------------
 
 def test_index_device_config_defaults(monkeypatch):
-    for k in ('DN_INDEX_DEVICE', 'DN_INDEX_DEVICE_BATCH_ROWS',
-              'DN_INDEX_RESIDENCY_SHARE'):
-        monkeypatch.delenv(k, raising=False)
-    conf = mod_config.index_device_config()
-    assert conf == {'mode': 'auto', 'batch_rows': 1 << 20,
-                    'residency_share': 0.5}
+    monkeypatch.delenv('DN_INDEX_DEVICE', raising=False)
+    assert mod_config.index_device_config() == {'mode': 'auto'}
+    # the two knobs of the slot-packed fold went with it: set, they
+    # are not read
+    monkeypatch.setenv('DN_INDEX_DEVICE_BATCH_ROWS', '12')
+    monkeypatch.setenv('DN_INDEX_RESIDENCY_SHARE', '1.5')
+    assert mod_config.index_device_config() == {'mode': 'auto'}
 
 
 def test_index_device_config_rejects_bad_values(monkeypatch):
@@ -450,20 +550,11 @@ def test_index_device_config_rejects_bad_values(monkeypatch):
     err = mod_config.index_device_config()
     assert isinstance(err, DNError)
     assert 'DN_INDEX_DEVICE' in err.message
-    monkeypatch.setenv('DN_INDEX_DEVICE', '1')
-    monkeypatch.setenv('DN_INDEX_DEVICE_BATCH_ROWS', '12')
-    err = mod_config.index_device_config()
-    assert isinstance(err, DNError)
-    assert 'DN_INDEX_DEVICE_BATCH_ROWS' in err.message
-    monkeypatch.setenv('DN_INDEX_DEVICE_BATCH_ROWS', '8192')
-    monkeypatch.setenv('DN_INDEX_RESIDENCY_SHARE', '1.5')
-    err = mod_config.index_device_config()
-    assert isinstance(err, DNError)
-    assert 'DN_INDEX_RESIDENCY_SHARE' in err.message
-    monkeypatch.setenv('DN_INDEX_RESIDENCY_SHARE', '0.25')
-    conf = mod_config.index_device_config()
-    assert conf == {'mode': '1', 'batch_rows': 8192,
-                    'residency_share': 0.25}
+    for mode in ('auto', '0', '1'):
+        monkeypatch.setenv('DN_INDEX_DEVICE', mode)
+        assert mod_config.index_device_config() == {'mode': mode}
+    monkeypatch.setenv('DN_INDEX_DEVICE', '')
+    assert mod_config.index_device_config() == {'mode': 'auto'}
 
 
 def test_stats_doc_shape():
@@ -471,6 +562,6 @@ def test_stats_doc_shape():
     doc = mod_di.stats_doc()
     assert doc['dispatches'] == 0
     assert doc['shards_per_dispatch'] == 0.0
-    assert set(doc) >= {'dispatches', 'shards', 'rows',
-                        'pinned_shard_hits', 'h2d_bytes',
-                        'h2d_saved_bytes', 'auditions', 'last_lane'}
+    assert set(doc) == {'dispatches', 'shards', 'rows', 'padded_rows',
+                        'h2d_bytes', 'auditions', 'last_lane',
+                        'shards_per_dispatch'}

@@ -431,9 +431,8 @@ def test_forced_device_lane_deadline_is_an_error(tmp_path, monkeypatch):
     monkeypatch.setenv('DN_DEVICE_PROBE_TIMEOUT', '0.2')
     from dragnet_tpu import device_index as mod_di
     monkeypatch.setattr(
-        mod_di, '_fold_program',
-        lambda s, r, t, pu:
-        (lambda locs, ws, ttabs, acc: mod_time.sleep(60)))
+        mod_di, 'sums_program',
+        lambda rows, segments: (lambda pair: mod_time.sleep(60)))
     with pytest.raises(DNError) as ei:
         ds.query(_query(QUERIES[0]), 'day')
     assert 'unresponsive' in ei.value.message

@@ -243,4 +243,24 @@ def test_prewarm_compiles_and_reports():
     assert 'auditions' in doc and 'audition_wins' in doc
     # the compiled program is shared state: a real query of that
     # padded shape now skips its compile
-    assert (1 << 6, 1 << 4) in mod_iqs._SUMS_CACHE
+    from dragnet_tpu import device_index as mod_di
+    assert (1 << 6, 1 << 4) in mod_di._SUMS_CACHE
+
+
+def test_prewarm_defaults_to_the_folds_ladder():
+    """With no shapes given the pre-warm compiles what the index fold
+    runs: every rung of its row ladder up to 2^18 at the smallest
+    accumulator, the programs a query then finds compiled."""
+    _need_jax()
+    from dragnet_tpu import device_index as mod_di
+    from dragnet_tpu import index_query_stack as mod_iqs
+    mod_iqs._reset_device_state()
+    mod_di._SUMS_CACHE.clear()
+    doc = residency.prewarm(deadline_s=120)
+    assert doc['state'] == 'ok'
+    assert doc['programs'] == len(mod_di.ladder()) == 4
+    assert sorted(mod_di._SUMS_CACHE) == [
+        (rows, mod_di.SEGMENT_FLOOR) for rows in mod_di.ladder()]
+    # a year of a 400-tuple metric's daily shards takes the last one
+    assert (mod_di.pad_rows(365 * 397), mod_di.pad_segments(400)) \
+        in mod_di._SUMS_CACHE

@@ -977,8 +977,6 @@ def test_serve_validate_ok(monkeypatch):
     monkeypatch.delenv('DN_DEVICE_PIPELINE_DEPTH', raising=False)
     monkeypatch.delenv('DN_DEVICE_BATCH_FLOOR', raising=False)
     monkeypatch.delenv('DN_INDEX_DEVICE', raising=False)
-    monkeypatch.delenv('DN_INDEX_DEVICE_BATCH_ROWS', raising=False)
-    monkeypatch.delenv('DN_INDEX_RESIDENCY_SHARE', raising=False)
     rc, out, err = run_cli(['serve', '--validate', '--socket',
                             '/tmp/never-bound.sock'])
     assert rc == 0
@@ -1013,8 +1011,7 @@ def test_serve_validate_ok(monkeypatch):
                    b'device lane ok: engine=auto backend=host-only '
                    b'residency_mb=0 prewarm=1 probe_timeout_s=420 '
                    b'audition_cache=off entries=0 wins=0\n'
-                   b'index device lane ok: mode=auto '
-                   b'batch_rows=1048576 residency_share=0.50\n'
+                   b'index device lane ok: mode=auto\n'
                    b'scan pipeline ok: pipeline_depth=2 '
                    b'batch_floor=auto partitions=4 scan_threads=2\n')
 

@@ -183,6 +183,17 @@ def test_index_fold_counts_its_staged_shards(runs):
     assert runs['query']['counters']['index_fold_shards_staged'] == 3
 
 
+def test_index_fold_counts_its_rows_and_its_padding(runs):
+    """`index_fold_rows` is the stacked batch's rows (m1's host x
+    latency-bucket tuples of the three shards: the corpus is made by
+    formula), `index_fold_padded_rows` the ladder's count for them:
+    their ratio is what the ladder costs the device."""
+    from dragnet_tpu import device_index
+    c = runs['query']['counters']
+    assert c['index_fold_rows'] == 99
+    assert c['index_fold_padded_rows'] == device_index.pad_rows(99)
+
+
 @pytest.mark.parametrize('op', ['scan', 'build', 'query'])
 def test_leaf_stages_sum_within_the_request(runs, op):
     """No double counting: no leaf's `stage_ms` of the request's thread

@@ -197,17 +197,18 @@ def test_device_scan_pallas_program_compiles(one_chip, corpus,
 
 # -- index query and parse lanes ---------------------------------------------
 
-def test_index_fold_compiles(one_chip):
-    """The slot-packed fold at the default dispatch budget: 64 slots of
-    16384 rows (DN_INDEX_DEVICE_BATCH_ROWS = 1 << 20)."""
-    prow, ptab, pu = 16384, 4096, 65536
-    nslots = device_index.batch_rows() // prow
-    assert nslots == device_index._MAX_SLOTS
-    prog = device_index._fold_program(nslots, prow, ptab, pu)
-    rows = tuple(_sds((prow,), np.int64, one_chip)
-                 for _ in range(nslots))
-    _compile(prog, rows, rows, _sds((nslots, ptab), np.int64, one_chip),
-             _sds((pu,), np.int64, one_chip))
+@pytest.mark.parametrize('rows,segments', [
+    (rows, device_index.SEGMENT_FLOOR)
+    for rows in device_index.ladder()] + [
+    (device_index.ladder()[-1],
+     device_index.pad_segments(engine.MAX_DENSE_SEGMENTS))])
+def test_index_fold_compiles(one_chip, rows, segments):
+    """The packed fold's one program at every rung residency.prewarm
+    compiles, and at the ladder's largest shape: 2^18 rows into the
+    widest accumulator the lane admits (MAX_DENSE_SEGMENTS)."""
+    prog = device_index.sums_program(rows, segments)
+    compiled = _compile(prog, _sds((2, rows), np.int64, one_chip))
+    assert 's64[%d]' % segments in compiled.as_text()
 
 
 def test_byteparse_parity_compiles(one_chip):
