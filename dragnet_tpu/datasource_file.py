@@ -1119,8 +1119,8 @@ class DatasourceFile(object):
         lib/datasource-file.js:573-691)"""
         pipeline = Pipeline()
         # the query's plan, a leaf a part: the shard walk
-        # (index_query.paths); the pruning, the integrity check and
-        # the rollup planner (index_query.prune)
+        # (index_query.paths); the pruning and the integrity check
+        # (index_query.prune); the rollup planner (index_query.plan)
         with obs_metrics.leaf_stage('index_query.paths'):
             root, timeformat, files, snap = self._index_query_walk(
                 query, interval, pipeline)
@@ -1161,11 +1161,13 @@ class DatasourceFile(object):
                     timeformat=timeformat, after_ms=query.qc_after,
                     before_ms=query.qc_before)
 
-            # Query planner (rollup.py): serve from the coarsest
-            # covering rollup shards and fold follow mini-generations
-            # into their logical base shard.  plan_query returns None
-            # whenever the walk is plain per-file shards — the
-            # stacked/pooled paths below then run completely untouched.
+        # Query planner (rollup.py), a leaf of its own
+        # (index_query.plan: a level's manifest read and every fine
+        # source it vouches for re-statted): serve from the coarsest
+        # covering rollup shards and fold follow mini-generations
+        # into their logical base shard.  plan_query returns None
+        # whenever the walk is plain per-file shards.
+        with obs_metrics.leaf_stage('index_query.plan'):
             plan = mod_rollup.plan_query(self.ds_indexpath,
                                          interval or 'all', paths, query)
 
@@ -1199,26 +1201,20 @@ class DatasourceFile(object):
             index_list.bump_hidden('rollup shards queried',
                                    plan['nrollup'])
 
-            def query_one(path, q):
-                if nworkers <= 0:
-                    return mod_iqmt.query_shard_once(path, q)
-                return mod_iqmt._query_shard_cached(path, q)
-
-            mod_rollup.execute_plan(plan, query, query_one, merge)
-            return ScanResult(pipeline, points=_emit_points(aggr),
-                              query=query)
-
         # Stacked cross-shard execution (index_query_stack, default):
         # shard readers only LOAD matching column blocks, and one
         # vectorized filter+group-by over the concatenated batch
         # replaces the per-shard mask -> groupby -> merge loop —
         # byte-identical output (the stacked lexsort reproduces the
-        # sequential insertion order exactly).  Falls back to the
-        # per-shard loop when the query shape or the exactness gate
-        # (non-integer weights) demands it, or under DN_IQ_STACK=0.
+        # sequential insertion order exactly).  A plan's units enter
+        # the same batch (a rollup shard's rows under the shard ids
+        # of the fine buckets they stand for).  Falls back to the
+        # per-shard loop (under a plan: rollup.execute_plan) when the
+        # query shape or the exactness gate (non-integer weights)
+        # demands it, or under DN_IQ_STACK=0.
         from . import index_query_stack as mod_iqs
         mod_iqs.run_index_query(paths, query, aggr, index_list,
-                                nworkers, merge)
+                                nworkers, merge, plan=plan)
 
         return ScanResult(pipeline, points=_emit_points(aggr),
                           query=query)

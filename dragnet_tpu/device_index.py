@@ -21,8 +21,9 @@ they are:
   `segment_sum(pair[1], pair[0], num_segments)`; np.asarray fetches
   the i64[segments] accumulator once.  The ladder decides the
   compiles: a year of daily shards of a 400-tuple metric spans four
-  row counts, and residency.prewarm compiles those before the first
-  request.
+  row counts, a quarter of hourly shards served from rollups reaches
+  2^20, and residency.prewarm compiles the six rungs up to there
+  before the first request.
 * **Residency.**  Inside a residency-armed `dn serve`
   (serve/residency.py) the folded accumulator pins under the content
   digest of its inputs (the PR 17 contract) and retires on the writer
@@ -113,6 +114,11 @@ def _pow2(x, floor=8):
 # padding's bytes matter more than one compile
 ROW_FLOOR = 1 << 12
 ROW_COARSE_TOP = 1 << 18
+# what residency.prewarm compiles up to: a quarter of hourly shards of
+# a 400-tuple metric, served from its rollups, is one batch of some
+# 670,000 rows (a rollup shard holds its fine shards' rows, not their
+# sum), so a server meets the two rungs past 2^18 in its first minute
+ROW_PREWARM_TOP = 1 << 20
 SEGMENT_FLOOR = 1 << 9
 
 
@@ -131,11 +137,12 @@ def pad_segments(nuniq):
     return _pow2(nuniq, SEGMENT_FLOOR)
 
 
-def ladder():
-    """The ladder's coarse rungs: every row count it holds up to 2^18,
-    the programs residency.prewarm compiles."""
+def ladder(top=ROW_COARSE_TOP):
+    """Every row count the ladder holds up to `top`: the four coarse
+    rungs up to 2^18, or with ROW_PREWARM_TOP the six programs
+    residency.prewarm compiles."""
     rows = [ROW_FLOOR]
-    while rows[-1] < ROW_COARSE_TOP:
+    while rows[-1] < top:
         rows.append(pad_rows(rows[-1] + 1))
     return rows
 

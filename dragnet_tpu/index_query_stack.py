@@ -571,7 +571,93 @@ def _export_items(nshards, first_shard, cols, weights, decoders):
     return per_shard
 
 
-def run_stacked(paths, query, aggr, index_list, export=None):
+def _rollup_shard_ids(ts, ts_bz, buckets, sid0):
+    """The shard id of every row of a rollup shard's block: the walk
+    position (`sid0` + j) of the fine bucket `buckets[j]` whose
+    ordinal the row's `__dn_ts` bucketizes to, -1 for a row of a
+    bucket the unit does not list.  A rollup shard stores its rows
+    ts-major, so the column is a few runs of equal values: one
+    bucketize a run."""
+    if len(ts) == 0:
+        return np.zeros(0, dtype=np.int64)
+    want = {ts_bz.bucketize(b): sid0 + j for j, b in enumerate(buckets)}
+    starts = np.concatenate(([0], np.flatnonzero(ts[1:] != ts[:-1]) + 1))
+    tab = np.fromiter((want.get(ts_bz.bucketize(int(u)), -1)
+                       for u in ts[starts]),
+                      dtype=np.int64, count=len(starts))
+    return np.repeat(tab, np.diff(np.append(starts, len(ts))))
+
+
+def _split_rollup_blocks(sh, ts_bz, buckets, sid0):
+    """A rollup shard's block, loaded under rollup.rollup_query, as
+    (the block of the user's own breakdowns, its rows' shard ids):
+    the leading `__dn_ts` column leaves as shard ids.  None when the
+    column is not integers (a foreign writer's file: the per-shard
+    path coerces those) or a row falls in a bucket the unit does not
+    list (execute_plan never emits that slice; a manifest that
+    matched its sources leaves none)."""
+    nrows, cols, values, isint = sh
+    tcol = cols[0]
+    if tcol[0] == 'i64':
+        ts = tcol[1]
+    elif tcol[0] == 'obj' and all(type(v) is int for v in tcol[1]):
+        ts = np.asarray(tcol[1], dtype=np.int64)
+    else:
+        return None
+    sids = _rollup_shard_ids(ts, ts_bz, buckets, sid0)
+    if (sids < 0).any():
+        return None
+    return (nrows, list(cols[1:]), values, isint), sids
+
+
+def _load_units(plan, query, on_blocks):
+    """index_query_mt.run_shard_loads over a rollup plan's units
+    (rollup.plan_query), in walk order: `on_blocks(blocks, sids)` once
+    a file, `sids` the shard id of each of its rows, which is the
+    position of the row's LOGICAL fine shard in the walk.  A `single`
+    unit's rows take the unit's position; a `group` unit's base and
+    generations share one (the batch then holds what the compacted
+    shard would); a `rollup` unit is loaded once under
+    rollup.rollup_query and each row takes the position of the fine
+    bucket its `__dn_ts` names.  Returns
+    False where a unit cannot be stacked byte-exactly (a rollup shard
+    whose `__dn_ts` is not integers or names a bucket the unit does
+    not list; a group whose files store a
+    breakdown in different kinds, so their sort keys share no scale):
+    the caller hands the plan to rollup.execute_plan."""
+    from . import index_query_mt as mod_iqmt
+    from . import rollup as mod_rollup
+    load = mod_iqmt._load_shard_blocks_cached
+    memo, rmemo = {}, {}
+    rquery = ts_bz = None
+    sid = 0
+    for unit in plan['units']:
+        if unit[0] == 'rollup':
+            if rquery is None:
+                rquery = mod_rollup.rollup_query(query,
+                                                 plan['fine_span'])
+                ts_bz = rquery.qc_bucketizers['__dn_ts']
+            split = _split_rollup_blocks(load(unit[1], rquery, rmemo),
+                                         ts_bz, unit[2], sid)
+            if split is None:
+                return False
+            on_blocks(*split)
+            sid += len(unit[2])
+            continue
+        kinds = None
+        for path in ([unit[1]] if unit[0] == 'single' else unit[1]):
+            sh = load(path, query, memo)
+            k = tuple(c[0] for c in sh[1])
+            if kinds is not None and k != kinds:
+                return False
+            kinds = k
+            on_blocks(sh, np.full(sh[0], sid, dtype=np.int64))
+        sid += 1
+    return True
+
+
+def run_stacked(paths, query, aggr, index_list, export=None,
+                plan=None):
     """Execute the index query as ONE stacked aggregation over every
     shard's matching rows.  Returns True when the result (and the
     fan-in counters) were committed into `aggr`, byte-identical to the
@@ -580,12 +666,21 @@ def run_stacked(paths, query, aggr, index_list, export=None):
     stage counters untouched.  Shard errors raise the same DNError
     contract as the sequential loop (first shard in find order).
 
+    With `plan` (rollup.plan_query's, over `paths`) the batch is
+    loaded from the plan's units in walk order and a row's shard id is
+    its LOGICAL fine shard's position in the walk: a rollup shard's
+    rows take theirs from `__dn_ts`, a base and its generations share
+    one (_load_units).  From the sort on nothing differs: the batch is
+    what the fine walk's would be with every generation compacted into
+    its base.
+
     With `export` (a cluster member's partial) nothing is committed
     and `aggr` is not touched: the aggregate goes to `export` as one
     key-item list per path (_export_items)."""
     from . import index_query_mt as mod_iqmt
     from .engine import _unique_rows, fuse_codes
 
+    assert plan is None or export is None
     bds = query.qc_breakdowns
     nb = len(bds)
 
@@ -598,9 +693,10 @@ def run_stacked(paths, query, aggr, index_list, export=None):
     # per-shard path takes over.
     shards = []
     vals_list = []
+    sid_list = []           # under a plan: each block's rows' shard ids
     state = {'total_abs': 0.0}
 
-    def on_blocks(sh):
+    def on_blocks(sh, sids=None):
         v, ok = _shard_values(sh)
         if ok and len(v):
             state['total_abs'] += float(np.abs(v).sum())
@@ -609,15 +705,25 @@ def run_stacked(paths, query, aggr, index_list, export=None):
             raise _GateFailed()
         shards.append(sh)
         vals_list.append(v)
+        sid_list.append(sids)
 
     from .obs import metrics as obs_metrics
     try:
+        units = {} if plan is None else {
+            'nrollup': plan['nrollup'],
+            'nfine': len(paths) - plan['ncovered']}
         with obs_metrics.leaf_stage('index_query_stack.load',
-                                    nshards=len(paths)):
-            mod_iqmt.run_shard_loads(paths, query, on_blocks)
+                                    nshards=len(paths), **units):
+            if plan is None:
+                mod_iqmt.run_shard_loads(paths, query, on_blocks)
+            elif not _load_units(plan, query, on_blocks):
+                return False
     except _GateFailed:
         return False
-    nshards = len(shards)
+    # the shards the fan-in counts: the files loaded, or the plan's
+    # logical fine shards (a rollup file stands for many, a base and
+    # its generations for one)
+    nshards = len(shards) if plan is None else plan['nlogical']
 
     if nb == 0:
         # per-shard: write_key((), int(shard_sum)) — NULL SUM -> 0 for
@@ -653,9 +759,14 @@ def run_stacked(paths, query, aggr, index_list, export=None):
                 else:
                     st.add_rows(col[1])
 
-        nrows = [sh[0] for sh in shards]
-        shard_ids = (np.repeat(np.arange(nshards, dtype=np.int64), nrows)
-                     if nshards else np.zeros(0, dtype=np.int64))
+        if plan is None:
+            nrows = [sh[0] for sh in shards]
+            shard_ids = (np.repeat(np.arange(nshards, dtype=np.int64),
+                                   nrows)
+                         if nshards else np.zeros(0, dtype=np.int64))
+        else:
+            shard_ids = (np.concatenate(sid_list) if sid_list
+                         else np.zeros(0, dtype=np.int64))
         values = (np.concatenate(vals_list) if vals_list
                   else np.zeros(0, dtype=np.float64))
 
@@ -738,24 +849,35 @@ DEVICE_SUMS = 'index device sums'
 
 
 def run_index_query(paths, query, aggr, index_list, nworkers, on_items,
-                    export=None):
-    """The one place that chooses an index query's lane over plain
-    per-file shards, for the `query` op (datasource_file.query) and
-    for a cluster member's `query_partial` (serve/router.py) alike:
-    the stacked aggregation where the mode and the query's shape allow
-    it (the device fold inside it by device_index.lane_decision:
-    DN_INDEX_DEVICE, DN_ENGINE), else, or when the exactness gate
-    refuses the shards' weights, the per-shard loop, whose key items
-    go to `on_items` in find order.  The stacked result is committed
-    into `aggr`, or with `export` handed over as per-shard key items
-    (run_stacked).  Returns the lane that answered: 'device' (the
-    stack, summed by the device fold), 'stacked' or 'shard'."""
+                    export=None, plan=None):
+    """The one place that chooses an index query's lane, for the
+    `query` op (datasource_file.query) and for a cluster member's
+    `query_partial` (serve/router.py) alike, whether the walk is plain
+    per-file shards or the rollup planner substituted units for them
+    (`plan`, rollup.plan_query's): the stacked aggregation where the
+    mode and the query's shape allow it (the device fold inside it by
+    device_index.lane_decision: DN_INDEX_DEVICE, DN_ENGINE), else, or
+    when the exactness gate refuses the shards' weights, the per-shard
+    loop (under a plan rollup.execute_plan, which walks its units),
+    whose key items go to `on_items` once a logical shard in find
+    order.  The stacked result is committed into `aggr`, or with
+    `export` handed over as per-shard key items (run_stacked).
+    Returns the lane that answered: 'device' (the stack, summed by
+    the device fold), 'stacked' or 'shard'."""
     from . import index_query_mt as mod_iqmt
     if stack_enabled() and stack_eligible(query):
         sums0 = index_list.counters.get(DEVICE_SUMS, 0)
-        if run_stacked(paths, query, aggr, index_list, export=export):
+        if run_stacked(paths, query, aggr, index_list, export=export,
+                       plan=plan):
             return 'device' \
                 if index_list.counters.get(DEVICE_SUMS, 0) > sums0 \
                 else 'stacked'
-    mod_iqmt.run_shard_queries(paths, query, nworkers, on_items)
+    if plan is None:
+        mod_iqmt.run_shard_queries(paths, query, nworkers, on_items)
+    else:
+        from . import rollup as mod_rollup
+        mod_rollup.execute_plan(
+            plan, query,
+            mod_iqmt.query_shard_once if nworkers <= 0
+            else mod_iqmt._query_shard_cached, on_items)
     return 'shard'

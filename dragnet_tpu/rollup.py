@@ -51,6 +51,17 @@ mirroring SQL's `SUM() -> NULL -> 0` per-shard emission.  The one
 caveat mirrors the follow publisher's: non-integral weights merged
 across a generation group can differ from the compacted shard in the
 last ulp (float addition order); integral weights are exact.
+
+Who runs a plan: index_query_stack.run_index_query.  Where the mode
+and the query's shape allow the stacked aggregation, the plan's units
+are loaded into its one batch (index_query_stack._load_units): the
+same slicing done on columns, a rollup shard's `__dn_ts` turned into
+the shard ids of the fine buckets it stands for, a base and its
+generations under one id; the sort, the device fold and the commit
+then see the batch the fine walk would have built.  `execute_plan`
+below is the per-shard walk of the units, kept for what the stack
+refuses (DN_IQ_STACK=0, a breakdown that renames its field,
+non-integer weights, a unit that cannot be stacked byte-exactly).
 """
 
 import json
@@ -805,9 +816,11 @@ def rollup_query(query, fine_span):
 
 
 def execute_plan(plan, query, query_one, on_items):
-    """Run a plan: `query_one(path, queryconfig)` must return the
-    shard's key_items (the caller chooses cached vs uncached reads);
-    `on_items(items)` is called once per LOGICAL fine shard, in walk
+    """Run a plan shard by shard (the lane run_index_query keeps for
+    what the stacked aggregation refuses): `query_one(path,
+    queryconfig)` must return the shard's key_items (the caller
+    chooses cached vs uncached reads); `on_items(items)` is called
+    once per LOGICAL fine shard, in walk
     order — the same call pattern, counter arithmetic, and item
     stream as the plain fine walk."""
     bare = not query.qc_breakdowns

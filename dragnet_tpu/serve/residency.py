@@ -239,8 +239,10 @@ def prewarm(shapes=None, deadline_s=None):
     the stacked index-query programs for representative shapes, and
     report the persisted audition cache — all BEFORE the first
     request pays for any of it.  `shapes` defaults to the index
-    fold's row ladder up to 2^18 rows at its smallest accumulator:
-    every program a query over aggregates of up to 512 tuples takes.
+    fold's row ladder up to 2^20 rows (device_index.ROW_PREWARM_TOP)
+    at its smallest accumulator: every program a query over
+    aggregates of up to 512 tuples takes, up to a quarter of hourly
+    shards read from its rollups.
     Runs the whole thing under the probe deadline on the caller's
     (background) thread: a wedged plugin costs a bounded wait and an
     honest 'timeout' doc, never a hung server.  Returns {'state',
@@ -260,7 +262,8 @@ def prewarm(shapes=None, deadline_s=None):
             return None
         compiled = 0
         for pn, pu in shapes or [(rows, mod_di.SEGMENT_FLOOR)
-                                 for rows in mod_di.ladder()]:
+                                 for rows in mod_di.ladder(
+                                     mod_di.ROW_PREWARM_TOP)]:
             prog = mod_di.sums_program(pn, pu)
             out = prog(np.zeros((2, pn), dtype=np.int64))
             np.asarray(out)          # force compile + execute

@@ -249,7 +249,7 @@ def test_prewarm_compiles_and_reports():
 
 def test_prewarm_defaults_to_the_folds_ladder():
     """With no shapes given the pre-warm compiles what the index fold
-    runs: every rung of its row ladder up to 2^18 at the smallest
+    runs: every rung of its row ladder up to 2^20 at the smallest
     accumulator, the programs a query then finds compiled."""
     _need_jax()
     from dragnet_tpu import device_index as mod_di
@@ -258,9 +258,15 @@ def test_prewarm_defaults_to_the_folds_ladder():
     mod_di._SUMS_CACHE.clear()
     doc = residency.prewarm(deadline_s=120)
     assert doc['state'] == 'ok'
-    assert doc['programs'] == len(mod_di.ladder()) == 4
+    rungs = mod_di.ladder(mod_di.ROW_PREWARM_TOP)
+    assert rungs[:4] == mod_di.ladder() and rungs[4:] == [1 << 19,
+                                                           1 << 20]
+    assert doc['programs'] == len(rungs) == 6
     assert sorted(mod_di._SUMS_CACHE) == [
-        (rows, mod_di.SEGMENT_FLOOR) for rows in mod_di.ladder()]
-    # a year of a 400-tuple metric's daily shards takes the last one
+        (rows, mod_di.SEGMENT_FLOOR) for rows in rungs]
+    # a year of a 400-tuple metric's daily shards takes the fourth,
+    # a quarter of its hourly shards (read from rollups) the last
     assert (mod_di.pad_rows(365 * 397), mod_di.pad_segments(400)) \
-        in mod_di._SUMS_CACHE
+        == (rungs[3], mod_di.SEGMENT_FLOOR)
+    assert (mod_di.pad_rows(2160 * 311), mod_di.pad_segments(435)) \
+        == (rungs[5], mod_di.SEGMENT_FLOOR)

@@ -15,6 +15,7 @@ import json
 import os
 import subprocess
 import sys
+import threading
 import time
 
 import pytest
@@ -50,7 +51,8 @@ BETWEEN = {
     'build': ('scan.init', 'scan.finish', 'index_build.bucket',
               'index_build.prepare', 'index_build.commit'),
     'query': ('index_query.paths', 'index_query.prune',
-              'index_query_stack.stack', 'index_query_stack.commit'),
+              'index_query.plan', 'index_query_stack.stack',
+              'index_query_stack.commit'),
 }
 # a resident server's own: the request before its execution (on its
 # thread), and the reply's frame (after the request's accounting)
@@ -608,6 +610,12 @@ def test_xla_compiles_total_counts_real_compiles_once():
         return (x * 3 + 1).sum()
     x = jnp.arange(1237, dtype=jnp.int32)  # its own input: made before
     x.block_until_ready()
+    # a server of this module may still be compiling its pre-warm
+    # ladder (six programs, to 2^20 rows) on its background thread:
+    # its compiles are not this test's
+    for t in threading.enumerate():
+        if t.name == 'dn-prewarm':
+            t.join()
     c0 = _xla_compiles()
     fresh(x).block_until_ready()
     c1 = _xla_compiles()

@@ -199,13 +199,14 @@ def test_device_scan_pallas_program_compiles(one_chip, corpus,
 
 @pytest.mark.parametrize('rows,segments', [
     (rows, device_index.SEGMENT_FLOOR)
-    for rows in device_index.ladder()] + [
+    for rows in device_index.ladder(device_index.ROW_PREWARM_TOP)] + [
     (device_index.ladder()[-1],
      device_index.pad_segments(engine.MAX_DENSE_SEGMENTS))])
 def test_index_fold_compiles(one_chip, rows, segments):
     """The packed fold's one program at every rung residency.prewarm
-    compiles, and at the ladder's largest shape: 2^18 rows into the
-    widest accumulator the lane admits (MAX_DENSE_SEGMENTS)."""
+    compiles (up to 2^20 rows), and at the coarse ladder's largest
+    shape: 2^18 rows into the widest accumulator the lane admits
+    (MAX_DENSE_SEGMENTS)."""
     prog = device_index.sums_program(rows, segments)
     compiled = _compile(prog, _sds((2, rows), np.int64, one_chip))
     assert 's64[%d]' % segments in compiled.as_text()
