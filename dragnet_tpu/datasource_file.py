@@ -1162,14 +1162,18 @@ class DatasourceFile(object):
                     before_ms=query.qc_before)
 
         # Query planner (rollup.py), a leaf of its own
-        # (index_query.plan: a level's manifest read and every fine
-        # source it vouches for re-statted): serve from the coarsest
-        # covering rollup shards and fold follow mini-generations
-        # into their logical base shard.  plan_query returns None
-        # whenever the walk is plain per-file shards.
+        # (index_query.plan): serve from the coarsest covering rollup
+        # shards and fold follow mini-generations into their logical
+        # base shard.  It is handed the snapshot that answered the
+        # walk: under it a level's manifest stays parsed and a
+        # rollup's verdict stays kept for the stat TTL, and with none
+        # (or a cold process) every fine source a rollup vouches for
+        # is re-statted.  plan_query returns None whenever the walk
+        # is plain per-file shards.
         with obs_metrics.leaf_stage('index_query.plan'):
             plan = mod_rollup.plan_query(self.ds_indexpath,
-                                         interval or 'all', paths, query)
+                                         interval or 'all', paths, query,
+                                         snap=snap)
 
         nworkers = mod_iqmt.iq_threads()
         LOG.debug('query start', indexroot=root, nindexes=len(paths),

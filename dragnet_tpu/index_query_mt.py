@@ -624,6 +624,8 @@ def shard_cache_clear():
         _FIND_CACHE.clear()
         _FIND_DROPPED.clear()
         _FIND_STATS.update(hits=0, rebuilds={})
+    from . import rollup as mod_rollup
+    mod_rollup.planner_memo_drop()
     for handle in handles:
         handle.querier.close()
 
@@ -661,6 +663,9 @@ def invalidate_index_tree(root):
         _drop_snapshots([d for d in _FIND_CACHE
                          if os.path.abspath(d) == root or
                          os.path.abspath(d).startswith(prefix)])
+    # the rollup planner's kept manifests and verdicts of the tree
+    from . import rollup as mod_rollup
+    mod_rollup.planner_memo_drop(root)
     for handle in closing:
         handle.querier.close()
 
@@ -964,6 +969,14 @@ def tree_snapshot(root):
         obs_metrics.inc('index_walk_snapshot_rebuilds_total',
                         reason=reason)
     return snap
+
+
+def snapshot_kept(snap):
+    """True while `snap` is the snapshot tree_snapshot keeps for its
+    directory: one that was racy when it was read, or that a write's
+    hook has dropped since, is nobody's proof past its own query."""
+    with _FIND_LOCK:
+        return _FIND_CACHE.get(snap.root) is snap
 
 
 # -- query execution ------------------------------------------------------

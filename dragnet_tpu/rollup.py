@@ -67,13 +67,17 @@ non-integer weights, a unit that cannot be stacked byte-exactly).
 import json
 import os
 import re
+import threading
+import time
 from collections import OrderedDict
 from datetime import datetime, timedelta, timezone
+from itertools import chain
 
 from .errors import DNError
 from . import query as mod_query
 from . import faults as mod_faults
 from . import index_journal as mod_journal
+from . import index_query_mt as mod_iqmt
 from .aggr import Aggregator
 from .vpipe import counter_bump
 from .index_build_mt import (_breakdown_positions, _notify_index_written,
@@ -82,6 +86,7 @@ from .index_build_mt import (_breakdown_positions, _notify_index_written,
 from .index_query import open_index
 from .index_query_stack import canonical_item_sort
 from .index_sink import metric_catalog_rows
+from .obs import metrics as obs_metrics
 
 MANIFEST_VERSION = 1
 
@@ -288,6 +293,43 @@ def _source_statkey(path):
     return [st.st_mtime_ns, st.st_size]
 
 
+# -- what the planner keeps between queries --------------------------------
+
+# The planner's reads, kept under the validators a query has already
+# checked (a resident server plans the same buckets many times a
+# second; a one-shot `dn query` starts cold and checks everything):
+#   leveldir -> (manifest identity, parsed document)
+#   (leveldir, coarse stem) -> the last _sources_match answer and what
+#   it was answered under (_kept_verdict)
+# Bounded by a cap and a clear; dropped with the trees that
+# shard_cache_clear and invalidate_index_tree sweep; the lock is never
+# held across a system call.
+_MEMO_LOCK = threading.Lock()
+_MANIFESTS = {}
+_VERDICTS = {}
+_MANIFEST_CAP = 64
+_VERDICT_CAP = 4096
+
+
+def planner_memo_drop(root=None):
+    """Forget every kept manifest and verdict at or under `root` (all
+    of them for None): index_query_mt's shard_cache_clear and
+    invalidate_index_tree call this for the trees they sweep."""
+    with _MEMO_LOCK:
+        if root is None:
+            _MANIFESTS.clear()
+            _VERDICTS.clear()
+            return
+        root = os.path.abspath(root)
+        prefix = root + os.sep
+        for leveldir in [d for d in _MANIFESTS
+                         if d == root or d.startswith(prefix)]:
+            del _MANIFESTS[leveldir]
+        for key in [k for k in _VERDICTS
+                    if k[0] == root or k[0].startswith(prefix)]:
+            del _VERDICTS[key]
+
+
 # -- the per-level source manifest ----------------------------------------
 
 def manifest_path(leveldir):
@@ -296,17 +338,60 @@ def manifest_path(leveldir):
 
 def load_manifest(leveldir):
     """The level's source manifest, or None when absent/unreadable/
-    wrong-shape (every consumer treats that as 'no valid rollups')."""
+    wrong-shape (every consumer treats that as 'no valid rollups').
+    The parsed document is kept under the manifest file's stat
+    identity, so a steady caller pays one os.stat and no open: treat
+    it as read-only."""
+    return _manifest(leveldir)[0]
+
+
+def _manifest(leveldir):
+    """(document, identity) of a level's manifest.  write_manifest
+    lands by tmp + rename, so the file's (st_mtime_ns, st_size,
+    st_ino) names one generation of it: a kept document answers while
+    one os.stat reads the same identity, and a new manifest is parsed
+    once.  The identity is None (and nothing is kept) for no valid
+    document, and for a manifest younger than the racy margin, which
+    a second rename in the same timestamp tick could replace unseen
+    (index_query_mt._RACY_MARGIN_NS): it serves the query that read
+    it."""
+    path = manifest_path(leveldir)
+    seen = mod_iqmt._statkey(path)
+    with _MEMO_LOCK:
+        kept = _MANIFESTS.get(leveldir) if seen is not None \
+            else _MANIFESTS.pop(leveldir, None)
+    if seen is None:
+        return None, None
+    if kept is not None and kept[0] == seen:
+        obs_metrics.inc('rollup_manifest_loads_total', result='kept')
+        return kept[1], seen
+    now_ns = time.time_ns()
+    doc = ident = None
     try:
-        with open(manifest_path(leveldir)) as f:
+        with open(path) as f:
+            # the identity of the bytes parsed, not of the name a
+            # moment before
+            st = os.fstat(f.fileno())
+            ident = (st.st_mtime_ns, st.st_size, st.st_ino)
             doc = json.load(f)
     except (OSError, ValueError):
-        return None
+        doc = None
+    obs_metrics.inc('rollup_manifest_loads_total', result='parsed')
     if not isinstance(doc, dict) or \
             doc.get('version') != MANIFEST_VERSION or \
             not isinstance(doc.get('shards'), dict):
-        return None
-    return doc
+        doc = None
+    if doc is None or ident[0] > now_ns - mod_iqmt._RACY_MARGIN_NS:
+        ident = None
+    with _MEMO_LOCK:
+        if ident is None:
+            _MANIFESTS.pop(leveldir, None)
+        else:
+            if leveldir not in _MANIFESTS and \
+                    len(_MANIFESTS) >= _MANIFEST_CAP:
+                _MANIFESTS.clear()
+            _MANIFESTS[leveldir] = (ident, doc)
+    return doc, ident
 
 
 def write_manifest(leveldir, fine_span, shards):
@@ -696,7 +781,7 @@ def compact_tree(indexroot, interval, governor=None, min_gens=1,
 
 # -- the query planner -----------------------------------------------------
 
-def plan_query(indexroot, interval, paths, query):
+def plan_query(indexroot, interval, paths, query, snap=None):
     """Map an ordered (pruned, generation-augmented) fine-shard walk
     onto the cheapest equivalent unit sequence:
 
@@ -713,7 +798,15 @@ def plan_query(indexroot, interval, paths, query):
     removed, a partial month at the window edge — composes fine
     shards instead.  Returns None when the plan degenerates to plain
     single-file units: the caller keeps the existing stacked/pooled
-    execution path untouched."""
+    execution path untouched.
+
+    `snap` is the fine directory's TreeSnapshot that answered this
+    query's walk (None where the filesystem did).  With it, what (b)
+    answered for a bucket is kept and used again while every proof it
+    was taken under still holds (_kept_verdict); without it, at
+    DN_IQ_STAT_TTL_MS=0 and in a cold process every bucket is checked
+    by _sources_match, every fine source statted, as before.  A
+    level's manifest is parsed once a generation (_manifest)."""
     if interval not in _STEM_RE:
         return None
     groups = logical_groups(paths)
@@ -725,14 +818,21 @@ def plan_query(indexroot, interval, paths, query):
         ginfo.append((stem, bucket_s))
     covered = [None] * len(groups)
     nrollup = 0
+    verdicts = {'kept': 0, 'checked': 0}
     rollup_root = os.path.join(os.path.abspath(indexroot),
                                mod_journal.ROLLUP_DIR)
     if os.path.isdir(rollup_root):
+        # a verdict outlives its query only under a snapshot that
+        # tree_snapshot keeps (not a racy one, not one an in-process
+        # write has dropped since the walk), and for the stat TTL
+        ttl = 0.0
+        if snap is not None and mod_iqmt.snapshot_kept(snap):
+            ttl = mod_iqmt.stat_ttl_s()
         for levelname, klen, fine_ok in LEVELS:
             if interval not in fine_ok:
                 continue
             leveldir = os.path.join(rollup_root, levelname)
-            man = load_manifest(leveldir)
+            man, man_ident = _manifest(leveldir)
             if man is None or man.get('fine_span') != fine_span:
                 continue
             shards = man['shards']
@@ -752,14 +852,34 @@ def plan_query(indexroot, interval, paths, query):
                         window[1] * 1000 <= query.qc_before):
                     continue
                 rpath = os.path.join(leveldir, cstem + SUFFIX)
-                if _source_statkey(rpath) is None:
+                rkey = mod_iqmt._statkey(rpath)
+                if rkey is None:
                     continue
-                if not _sources_match(ent.get('sources'),
-                                      [groups[i] for i in idxs]):
+                bucket_groups = [groups[i] for i in idxs]
+                proofs = None
+                if ttl > 0 and man_ident is not None:
+                    proofs = (snap, man_ident, rkey, tuple(
+                        chain.from_iterable(bucket_groups)))
+                ok = _kept_verdict((leveldir, cstem), proofs, ttl)
+                if ok is None:
+                    taken = time.monotonic()
+                    ok = _sources_match(ent.get('sources'),
+                                        bucket_groups)
+                    verdicts['checked'] += 1
+                    if proofs is not None:
+                        _keep_verdict((leveldir, cstem), proofs,
+                                      taken, ok)
+                else:
+                    verdicts['kept'] += 1
+                if not ok:
                     continue
                 for i in idxs:
                     covered[i] = rpath
                 nrollup += 1
+    for result, n in verdicts.items():
+        if n:
+            obs_metrics.inc('rollup_plan_verdicts_total', n,
+                            result=result)
     units = []
     for i, g in enumerate(groups):
         rpath = covered[i]
@@ -781,11 +901,48 @@ def plan_query(indexroot, interval, paths, query):
             'nrollup': nrollup}
 
 
+def _kept_verdict(key, proofs, ttl):
+    """What _sources_match last answered for rollup `key` (leveldir,
+    coarse stem), or None when it has to be asked again.  The answer,
+    true or false, stands while its proofs are the ones it was taken
+    under and it is younger than `ttl` seconds (stat_ttl_s, the one
+    bound the process states for a write it did not observe):
+
+      the fine tree's TreeSnapshot OBJECT that answered the walk: any
+        rename into the fine directory, any in-process invalidation
+        and any racy listing make a new object;
+      the manifest's identity (a rebuilt level is a new inode);
+      the rollup shard's own (mtime_ns, size, ino), statted now;
+      the walk's files in the bucket, bases and generations in order.
+
+    What is left for the TTL is a fine shard rewritten in place by a
+    process that fired no hook: the next _sources_match, at most
+    `ttl` later, stats every source again."""
+    if proofs is None:
+        return None
+    with _MEMO_LOCK:
+        kept = _VERDICTS.get(key)
+    # a TreeSnapshot equals only itself
+    if kept is None or kept[0] != proofs or \
+            time.monotonic() - kept[1] >= ttl:
+        return None
+    return kept[2]
+
+
+def _keep_verdict(key, proofs, taken, ok):
+    with _MEMO_LOCK:
+        if key not in _VERDICTS and len(_VERDICTS) >= _VERDICT_CAP:
+            _VERDICTS.clear()
+        _VERDICTS[key] = (proofs, taken, ok)
+
+
 def _sources_match(sources, bucket_groups):
     """The planner's validity test: the manifest's recorded source set
     equals the walk's files for this bucket, byte-for-byte (statkey
     equality re-statted now, not at walk time — a stale substitute is
-    worse than a slow fallback)."""
+    worse than a slow fallback).  plan_query asks it whenever it holds
+    no verdict whose proofs all stand (_kept_verdict), so at most one
+    stat TTL lies between two askings for a bucket in use."""
     if not isinstance(sources, dict):
         return False
     have = {}

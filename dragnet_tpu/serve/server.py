@@ -953,6 +953,7 @@ class DnServer(object):
 
     def stats_doc(self):
         counters = mod_vpipe.global_counters()
+        reg = obs_metrics.global_registry()
         with self._stats_lock:
             requests = dict(self._counters, by_op=dict(self._by_op))
         requests.update(self.coalescer.stats())
@@ -1047,8 +1048,18 @@ class DnServer(object):
             },
             # rollup-planner engagement (rollup.py via the hidden
             # query counters): fine shards answered from rollups vs
-            # every fine-shard read, as a coverage ratio
+            # every fine-shard read, as a coverage ratio; and how
+            # often the planner's kept reads answered (the typed
+            # counters rollup.plan_query writes)
             'rollup': {
+                'plan_verdicts': {
+                    r: reg.counter('rollup_plan_verdicts_total',
+                                   result=r).value
+                    for r in ('kept', 'checked')},
+                'manifest_loads': {
+                    r: reg.counter('rollup_manifest_loads_total',
+                                   result=r).value
+                    for r in ('kept', 'parsed')},
                 'covered_shards':
                 counters.get('index shards via rollup', 0),
                 'rollup_shards_read':

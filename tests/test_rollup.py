@@ -553,7 +553,7 @@ def test_serve_cached_repeat_and_invalidation(cache_corpus):
         # the /stats sections the planner and timer report through
         assert set(doc['rollup']) == {
             'covered_shards', 'rollup_shards_read', 'shards_queried',
-            'coverage_ratio'}
+            'coverage_ratio', 'plan_verdicts', 'manifest_loads'}
         assert doc['maintenance'] is None   # no timer configured
 
         # an index write (append + rebuild) bumps the cache epoch:

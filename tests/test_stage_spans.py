@@ -410,6 +410,114 @@ def test_a_served_query_answers_as_the_cli_does(served):
     assert served['query']['out'] == served['cli_query_out']
 
 
+# -- (2b') the rollup planner's kept reads at a scrape and in /stats -------
+
+VERDICTS = 'dn_rollup_plan_verdicts_total{result="%s"}'
+MANIFESTS = 'dn_rollup_manifest_loads_total{result="%s"}'
+
+
+def test_the_planners_kept_reads_are_counted(tmp_path, monkeypatch):
+    """`rollup_plan_verdicts_total{result}` and
+    `rollup_manifest_loads_total{result}` at a scrape and under
+    `/stats` `rollup`: a query's `kept + checked` grows by its
+    candidate buckets (a rollup shard of the manifest whose window
+    lies inside the query's), `kept + parsed` by the levels of the
+    interval; the first query of a tree checks and parses, the next
+    ones are answered from what it kept."""
+    from dragnet_tpu import index_query_mt as mod_iqmt
+    from dragnet_tpu import rollup as mod_rollup
+    from dragnet_tpu.serve import client as mod_client
+    root = str(tmp_path)
+    for k, v in (('DN_IQ_STAT_TTL_MS', '600000'), ('DN_INDEX_DEVICE', '0'),
+                 ('DRAGNET_CONFIG', '')):
+        monkeypatch.setenv(k, v)
+    for k in ('DN_TRACE', 'DN_SLOW_MS', 'DN_SERVE_CACHE_MB', 'DN_ENGINE'):
+        monkeypatch.delenv(k, raising=False)
+    add_datasource(root)
+    # four days of records over the end of January: two months' shards
+    datafile = os.path.join(root, 'data.log')
+    t0 = 1391040000                     # 2014-01-30T00:00:00Z
+    with open(datafile, 'w') as f:
+        for i in range(400):
+            f.write(json.dumps({
+                'time': time.strftime('%Y-%m-%dT%H:%M:%S.000Z',
+                                      time.gmtime(t0 + i * 860)),
+                'host': 'host%d' % (i % 5), 'req': {'method': 'GET'},
+                'latency': i % 230}, separators=(',', ':')) + '\n')
+    rc, out, err = run_cli(['build', 'stageds'])
+    assert rc == 0, err
+    idx = os.path.join(root, 'idx')
+    assert sorted(os.listdir(os.path.join(idx, 'by_day'))) == [
+        '2014-01-30.sqlite', '2014-01-31.sqlite', '2014-02-01.sqlite',
+        '2014-02-02.sqlite']
+    assert mod_rollup.build_rollups(idx, 'day')['built'] == 2
+    # a tree that was not written a moment ago (the racy margin)
+    old = time.time() - 120
+    leveldir = os.path.join(idx, 'rollup', 'by_month')
+    for path in (mod_rollup.manifest_path(leveldir), leveldir,
+                 os.path.join(idx, 'by_day')):
+        os.utime(path, (old, old))
+    mod_iqmt.shard_cache_clear()
+
+    srv = mod_server.DnServer(
+        socket_path=os.path.join(root, 's.sock'),
+        conf={'max_inflight': 2, 'queue_depth': 4, 'deadline_ms': 0,
+              'coalesce': False, 'drain_s': 10}).start()
+    names = [VERDICTS % 'kept', VERDICTS % 'checked',
+             MANIFESTS % 'kept', MANIFESTS % 'parsed']
+
+    def scrape():
+        rc, hd, out, err = mod_client.request_bytes(
+            srv.socket_path, {'op': 'metrics'})
+        assert rc == 0, err
+        doc = dict(ln.rsplit(' ', 1) for ln in out.decode().splitlines()
+                   if not ln.startswith('#'))
+        return [float(doc.get(k, 0)) for k in names]
+
+    def query(after=None, before=None):
+        """One query; how the four counters grew by it."""
+        first = scrape()
+        qc = {'breakdowns': [{'name': 'host', 'field': 'host'}]}
+        if after is not None:
+            qc.update(timeAfter=after, timeBefore=before)
+        rc, hd, out, err = mod_client.request_bytes(srv.socket_path, {
+            'op': 'query', 'ds': 'stageds', 'interval': 'day',
+            'config': os.environ['DRAGNET_CONFIG'], 'queryconfig': qc,
+            'opts': {'points': True}})
+        assert rc == 0, err
+        # a request's registry is merged after the client has its bytes
+        limit = time.monotonic() + 10.0
+        while time.monotonic() < limit:
+            grew = [b - a for a, b in zip(first, scrape())]
+            if sum(grew[2:]):
+                break
+            time.sleep(0.01)
+        return out, grew
+
+    try:
+        stats0 = mod_client.stats(srv.socket_path)['rollup']
+        cold, grew = query()
+        assert grew == [0, 2, 0, 1]        # both months asked, one level
+        warm, grew = query()
+        assert grew == [2, 0, 1, 0] and warm == cold
+        # February alone, a window whose days the tree does not all
+        # hold: the filesystem answers that walk, not the snapshot, and
+        # with no snapshot the one candidate is checked
+        out, grew = query('2014-02-01', '2014-03-01')
+        assert grew == [0, 1, 1, 0]
+        # a window inside a month has no candidate bucket
+        out, grew = query('2014-01-30', '2014-01-31')
+        assert grew == [0, 0, 1, 0]
+        stats = mod_client.stats(srv.socket_path)['rollup']
+        assert {k: {r: stats[k][r] - stats0[k][r] for r in stats[k]}
+                for k in ('plan_verdicts', 'manifest_loads')} == {
+            'plan_verdicts': {'kept': 2, 'checked': 3},
+            'manifest_loads': {'kept': 3, 'parsed': 1}}
+    finally:
+        srv.stop()
+        mod_iqmt.shard_cache_clear()
+
+
 def test_end_open_ends_the_leaf_once_and_counts_once():
     """A leaf ended where its part ends (`end_open`), inside its own
     `with`: one observation, the thread's total grows by its self time,
