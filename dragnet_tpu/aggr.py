@@ -23,6 +23,7 @@ maps for columnar batches and merge into the same flat structure.
 import numpy as np
 
 from . import jsvalues as jsv
+from .obs import metrics as obs_metrics
 
 
 def _unique_rows_2(a, b):
@@ -61,6 +62,32 @@ def _is_array_index(s):
     if len(s) > 1 and s[0] == '0':
         return False
     return int(s) < 2 ** 32 - 1
+
+
+def _key_ranks(values):
+    """Per entry of a column's dictionary, its key's rank in JS
+    enumeration among the dictionary's numeric-class keys
+    (array-index-like strings and Python ints, ascending by value,
+    equal values sharing a rank) and -1 for every other key; and how
+    many ranks there are.  A table over the dictionary, not over the
+    tuples."""
+    table = np.full(len(values), -1, dtype=np.int64)
+    idx = []
+    vals = []
+    for i, s in enumerate(values):
+        if isinstance(s, str):
+            if _is_array_index(s):
+                idx.append(i)
+                vals.append(int(s))
+        elif isinstance(s, int) and not isinstance(s, bool):
+            idx.append(i)
+            vals.append(s)
+    if not idx:
+        return table, 0
+    uniq, inv = np.unique(np.array(vals, dtype=np.int64),
+                          return_inverse=True)
+    table[idx] = inv.reshape(-1)
+    return table, len(uniq)
 
 
 def coerce_bucket_value(v):
@@ -300,60 +327,68 @@ class Aggregator(object):
         first-occurrence-within-parent) otherwise — exactly the
         js_key_order applied at every node of the nested walk.  The
         within-parent arrival rank is the first occurrence index of
-        the (parent-group, code) pair in arrival order; a stable
-        lexsort over all levels reproduces the nested enumeration."""
+        the (parent-group, code) pair in arrival order.  Each level
+        becomes ONE non-negative rank column (numeric keys by their
+        rank among the dictionary's values, the others after them by
+        arrival), and a stable sort over the levels reproduces the
+        nested enumeration: one argsort of the fused mixed-radix key
+        (engine.fuse_codes), a lexsort over the same columns where
+        their spans' product would overflow it."""
+        from .engine import fuse_codes
         n = len(self._cweights)
-        levels = []   # (numeric-class, sort-value) per level
-        gid = np.zeros(n, dtype=np.int64)
-        ngroups = 1
+        if not n:
+            return np.zeros(0, dtype=np.int64)
+        # per level: the rank column, which tuples hold a non-numeric
+        # key (None: none does) and how many numeric ranks precede them
+        levels = []
+        grouped = -1    # the last level that holds a non-numeric key
         for codes, dec in zip(self._cols, self._cdec):
             if dec[0] == 'ord':
                 # int keys: all numeric-class, ascending by value
-                nn = np.zeros(n, dtype=np.int8)
-                sk = codes
-                span = int(codes.max()) - int(codes.min()) + 1 \
-                    if n else 1
-                pair_code = codes - (int(codes.min()) if n else 0)
+                levels.append((codes, None, 0))
+                continue
+            table, nnum = _key_ranks(dec[1])
+            rank = table[codes]
+            nn = rank < 0 if nnum < len(table) else None
+            if nn is not None and nn.any():
+                grouped = len(levels)
             else:
-                values = dec[1]
-                # per-code classification (one pass over the dict)
-                cn = len(values)
-                knn = np.empty(cn, dtype=np.int8)
-                kval = np.zeros(cn, dtype=np.int64)
-                for i, s in enumerate(values):
-                    if isinstance(s, str) and _is_array_index(s):
-                        knn[i] = 0
-                        kval[i] = int(s)
-                    elif isinstance(s, int) and \
-                            not isinstance(s, bool):
-                        knn[i] = 0
-                        kval[i] = s
-                    else:
-                        knn[i] = 1
-                nn = knn[codes]
-                sk = kval[codes]
-                span = cn
-                pair_code = codes
-            # within-parent arrival rank for non-numeric keys: first
-            # occurrence of the (group, code) pair in arrival order
-            if ngroups * span < 2 ** 62:
-                pair = gid * span + pair_code
-                first_idx, inv = _unique_1d(pair, ngroups * span)
-            else:
-                first_idx, inv, _ = _unique_rows_2(gid, pair_code)
-            sk = np.where(nn == 1, first_idx[inv], sk)
-            levels.append((nn, sk))
-            gid = inv.reshape(-1)
-            ngroups = len(first_idx)
-        if not n:
-            return np.zeros(0, dtype=np.int64)
-        # lexsort: last key is primary -> feed levels deepest-first,
-        # each level's class before its value (value least significant)
-        seq = []
-        for nn, sk in reversed(levels):
-            seq.append(sk)
-            seq.append(nn)
-        return np.lexsort(tuple(seq))
+                nn = None
+            levels.append((rank, nn, nnum))
+        # the (parent group, code) grouping, only as deep as a
+        # non-numeric key reads it: its own level's for its arrival
+        # rank, the levels' above for its parent group
+        keys = []
+        gid = None
+        ngroups = 1
+        for depth, (rank, nn, nnum) in enumerate(levels):
+            if depth <= grouped:
+                codes = self._cols[depth]
+                if self._cdec[depth][0] == 'ord':
+                    lo = int(codes.min())
+                    span = int(codes.max()) - lo + 1
+                    pair_code = codes - lo
+                else:
+                    span = len(self._cdec[depth][1])
+                    pair_code = codes
+                if gid is None:
+                    first_idx, inv = _unique_1d(pair_code, span)
+                elif ngroups * span < 2 ** 62:
+                    first_idx, inv = _unique_1d(gid * span + pair_code,
+                                                ngroups * span)
+                else:
+                    first_idx, inv, _ = _unique_rows_2(gid, pair_code)
+                if nn is not None:
+                    rank = np.where(nn, first_idx[inv] + nnum, rank)
+                gid = inv
+                ngroups = len(first_idx)
+            keys.append(rank)
+        fused = fuse_codes(keys)        # most significant first
+        if fused is None:
+            obs_metrics.inc('aggr_order_total', path='lexsort')
+            return np.lexsort(tuple(reversed(keys)))
+        obs_metrics.inc('aggr_order_total', path='fused')
+        return np.argsort(fused, kind='stable')
 
     def _columnar(self):
         """True when the result is columnar: an engine handed it code

@@ -518,6 +518,61 @@ def test_the_planners_kept_reads_are_counted(tmp_path, monkeypatch):
         mod_iqmt.shard_cache_clear()
 
 
+# -- (2b'') which sort ordered a columnar aggregate ------------------------
+
+ORDER = 'dn_aggr_order_total{path="%s"}'
+
+
+@pytest.mark.parametrize('path,ords', [
+    ('fused', (0, 40)),
+    # three bucketized levels spanning 2^31 ordinals each: the fused
+    # key would pass 2^62, so the lexsort over the same columns
+    ('lexsort', (-2 ** 30, 2 ** 30))])
+def test_a_columnar_order_counts_its_sort(path, ords):
+    """`aggr_order_total{path}` at a scrape: one bump for each
+    emission of a columnar aggregate that holds a tuple, under the
+    sort that ordered it; none for an empty one."""
+    import numpy as np
+    from dragnet_tpu import aggr as mod_aggr
+    from dragnet_tpu import query as mod_query
+    from dragnet_tpu.obs import export as obs_export
+    query = mod_query.query_load({'breakdowns': [
+        {'name': 'host'},
+        {'name': 'a', 'aggr': 'lquantize', 'step': 10},
+        {'name': 'b', 'aggr': 'lquantize', 'step': 10},
+        {'name': 'c', 'aggr': 'lquantize', 'step': 10}]})
+
+    def aggregate(ntuples):
+        rng = np.random.default_rng(ntuples)
+        aggr = mod_aggr.Aggregator(query)
+        lo, hi = ords
+        aggr.set_columnar(
+            [rng.integers(0, 3, ntuples)] +
+            [np.concatenate([[lo, hi], rng.integers(lo, hi, ntuples)]
+                            )[:ntuples] for _ in range(3)],
+            np.ones(ntuples), [('str', ['x', '7', 'y'])] +
+            [('ord', None)] * 3)
+        return aggr
+
+    def scrape():
+        doc = dict(ln.rsplit(' ', 1) for ln in
+                   obs_export.prometheus_text().splitlines()
+                   if not ln.startswith('#'))
+        return {p: float(doc.get(ORDER % p, 0))
+                for p in ('fused', 'lexsort')}
+
+    before = scrape()
+    block = aggregate(50).point_block()
+    assert len(block) == 50
+    assert aggregate(0).point_block().points() == []
+    after = scrape()
+    other = 'lexsort' if path == 'fused' else 'fused'
+    assert after[path] - before[path] == 1
+    assert after[other] == before[other]
+    assert counter_table()[
+        ('aggr_order_total', (('path', path),))] == after[path]
+
+
 def test_end_open_ends_the_leaf_once_and_counts_once():
     """A leaf ended where its part ends (`end_open`), inside its own
     `with`: one observation, the thread's total grows by its self time,
