@@ -501,9 +501,7 @@ class DatasourceFile(object):
         # --warnings needs the per-record host path for ordered
         # warning output (same rule as scan())
         from .engine import engine_mode
-        use_vector = warn_func is None \
-            and os.environ.get('DN_BUILD_ENGINE', 'auto') != 'host' \
-            and engine_mode() != 'host'
+        use_vector = warn_func is None and engine_mode() != 'host'
         native_lib = None
         lane = None
         if use_vector:
@@ -668,14 +666,17 @@ class DatasourceFile(object):
             stage.bump('noutputs', int(alive0.sum()))
             return alive0
 
-        # stacked multi-metric device program: all metrics fold in ONE
-        # dispatch per batch with shared columns uploaded once (SURVEY
-        # §7.7), on the cluster backend's mesh as off it; None when the
-        # scanners don't support it (host engine, single metric) — then
-        # the per-scan loop runs
-        from . import device_scan as mod_device_scan
-        stack = mod_device_scan.make_stack(scanners) \
-            if scan_cls is not VectorScan else None
+        # device scans, one metric or many, fold in ONE dispatch per
+        # batch with shared columns uploaded once (SURVEY §7.7), on the
+        # cluster backend's mesh as off it; the host engine's scans
+        # take the batch one after the other
+        if scan_cls is VectorScan:
+            def process_all(provider, weights, alive0):
+                for s in scanners:
+                    s._process(provider, weights, alive=alive0)
+        else:
+            from .device_scan import DeviceScanStack
+            process_all = DeviceScanStack(scanners).process
 
         def process_batch(batch, n):
             """One batch through every metric's scan on this thread
@@ -688,11 +689,7 @@ class DatasourceFile(object):
             alive0 = None
             if ds_pred is not None:
                 alive0 = eval_ds_filter(ds_pred, ds_stage, provider, n)
-            if stack is not None:
-                stack.process(provider, weights, alive0)
-            else:
-                for s in scanners:
-                    s._process(provider, weights, alive=alive0)
+            process_all(provider, weights, alive0)
 
         nworkers = scan_mt.scan_threads()
         use_mt = nworkers > 0 and scan_cls is VectorScan

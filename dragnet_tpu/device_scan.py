@@ -11,7 +11,7 @@ optionally offloads only the final segment-sum.  DeviceScan moves the
              upload (dtype-narrowed columns + small lookup tables;
              inputs the stats prove constant are synthesized on
              device instead of uploaded — see the sticky upload
-             profile in _try_device)
+             profile in _stage_device)
     device:  predicate table-gathers + numeric compares -> ternary
              and/or fold -> date-error & time-bounds masks -> p2/linear
              bucketize -> mixed-radix key fusion -> segment-sum (or
@@ -365,21 +365,21 @@ def audition_cache_shape_hint(shape):
         return None
     return True if any(verdicts) else False
 
-# jitted scan programs are shared across DeviceScan instances (a CLI
+# traced scan programs are shared across DeviceScan instances (a CLI
 # `dn scan` and a server's repeat requests would otherwise re-trace
-# and re-compile identical programs per scan); keyed by the full static
-# structure of the program (see _program_key)
+# identical programs per scan); keyed by the full static structure of
+# the program (see _program_key)
 _PROGRAM_CACHE = {}
 _ACC_INIT_CACHE = {}
 
-# run_scatter/run_pallas are jitted (args, acc) -> acc callables; fold
-# is the UNJITTED (args, acc, use_pallas) body DeviceScanStack composes
-# into one combined jit across metrics
-_Programs = collections.namedtuple(
-    '_Programs', 'run_scatter run_pallas acc_init fold')
+# what a scan's batch program is: acc_init makes its empty accumulator,
+# fold is the UNJITTED (args, acc, use_pallas) -> acc body that
+# DeviceScanStack composes, one a scan, into the one jit of a batch
+_Programs = collections.namedtuple('_Programs', 'acc_init fold')
 
-# combined multi-metric programs (DeviceScanStack), keyed by the tuple
-# of member program keys + pallas flags
+# the jitted batch programs (DeviceScanStack._stacked_program), keyed
+# by the tuple of member program keys + pallas flags: a lone scan's is
+# a tuple of one
 _STACK_CACHE = {}
 
 
@@ -512,16 +512,13 @@ class DeviceScan(VectorScan):
         VectorScan.__init__(self, query, time_field, pipeline,
                             ds_filter=ds_filter)
         _SCAN_LEAKS.track(self)
-        # input-key namespace: '' standalone; DeviceScanStack assigns
-        # 'm<i>_' so per-scan inputs (leaf tables, translate tables,
-        # synth columns, base) coexist in one merged inputs dict while
-        # parser-derived columns stay shared across metrics
+        # input-key namespace: '' standalone; a DeviceScanStack of
+        # several scans assigns 'm<i>_' so per-scan inputs (leaf
+        # tables, translate tables, synth columns, base) coexist in
+        # one merged inputs dict while parser-derived columns stay
+        # shared across metrics
         self._pfx = ''
-        # when True, _staged_run records the next batch's (run,
-        # inputs, staged, use_pallas) on self.captured: a hook for a
-        # harness that replays the exact production program
-        self.capture_next = False
-        self.captured = None
+        self._alone = None        # the stack of this scan alone
         self._records_seen = 0
         self._backend_ok = None
         self._host_records = 0
@@ -536,7 +533,7 @@ class DeviceScan(VectorScan):
         self._progress = None     # (bytes_done, bytes_total) from stream
         self._shadow_ctx = None   # set by enable_shadow (MT path)
         self._shadow = None
-        self._sticky = None       # upload-profile state (see _try_device)
+        self._sticky = None       # upload-profile state (_stage_device)
         self._sparse_cap = SPARSE_CAP0
         self._sparse_ub = 0       # unique-count upper bound this epoch
         self._pending_flush = []  # async-prefetched epochs (see
@@ -644,16 +641,15 @@ class DeviceScan(VectorScan):
     # -- per-batch entry ---------------------------------------------------
 
     def _process(self, provider, weights, alive=None):
-        if self._t0 is None:
-            self._t0 = time.monotonic()
+        """A lone scan is a stack of one: the batch goes to the device
+        as a build's does, and a batch the device declines to the host
+        engine after a flush, so that insertion order survives."""
+        if self._alone is None:
+            self._alone = DeviceScanStack([self])
+        if self._alone.try_device(provider, weights, alive):
+            return
         n = provider.n
         self._records_seen += n
-        if not self._disabled and \
-                self._records_seen > self._escalate_records() and \
-                self._engage_device():
-            if self._try_device(provider, weights, alive):
-                self._after_device_batch(n)
-                return
         self._flush()
         self._host_records += n
         VectorScan._process(self, provider, weights, alive=alive)
@@ -668,16 +664,13 @@ class DeviceScan(VectorScan):
         """Stream-progress hook (the file datasource reports bytes
         consumed vs total): lets auto mode estimate remaining work
         before committing to a device switch, and triggers the one-time
-        async flush prefetch late in the stream (DN_PREFETCH=0
-        disables — operational escape hatch)."""
+        async flush prefetch late in the stream."""
         self._progress = (bytes_done, bytes_total)
         if not self._prefetched and self._acc is not None and \
                 bytes_total > 0 and \
                 bytes_done >= self.PREFETCH_PROGRESS * bytes_total:
             self._prefetched = True
-            import os
-            if os.environ.get('DN_PREFETCH', '1') != '0':
-                self._prefetch_flush()
+            self._prefetch_flush()
 
     def _prefetch_flush(self):
         """Compact the current epoch on device and issue its fetch
@@ -953,34 +946,12 @@ class DeviceScan(VectorScan):
         sp = getattr(self, '_shadow', None)
         if sp is not None:
             sp.close()          # end of stream: release audition state
+        self._alone = None      # it holds this scan: let both go
         self._flush()
         self._defer_final()
         return self.aggr
 
     # -- eligibility + input assembly --------------------------------------
-
-    def _try_device(self, provider, weights, alive):
-        """Assemble device inputs for this batch; True when submitted.
-        Any exactness precondition failure returns False (host path)."""
-        if not isinstance(provider, NativeColumns):
-            if engine_mode() == 'jax':
-                raise DNError(
-                    'DN_ENGINE=jax: the device scan needs the native '
-                    'column parser and this batch came through the '
-                    'Python record path (native/build/libdnparse.so '
-                    'missing or DN_NATIVE=0; build it with '
-                    '"make -C native")')
-            return False
-        if self._backend_ok is None and not self._probe_backend():
-            return False
-        inputs = {}
-        with obs_metrics.leaf_stage('scan.stage'):
-            staged = self._stage_device(provider, weights, alive, inputs)
-            if staged is None:
-                return False
-            run = self._staged_run(staged, inputs)
-        self._dispatch_staged(run, inputs)
-        return True
 
     def _stage_device(self, provider, weights, alive, inputs):
         """Eligibility checks + device-input assembly for one batch,
@@ -1538,8 +1509,8 @@ class DeviceScan(VectorScan):
             self._acc_batch = 0
 
     def _staged_programs(self, staged):
-        """(progs, use_pallas) for a staged batch — the program lookup
-        shared by the standalone path and DeviceScanStack."""
+        """(progs, use_pallas) for a staged batch: this scan's part of
+        the program DeviceScanStack composes."""
         pn, profile, caps, ns, total_w = staged
         pkey = (pn, profile)
         progs = self._programs.get(pkey) if self._programs else None
@@ -1549,8 +1520,7 @@ class DeviceScan(VectorScan):
                 self._programs = {}
             self._programs[pkey] = progs
         from .ops import pallas_kernels as pk
-        use_pallas = progs.run_pallas is not None and \
-            pk.should_use(ns, total_w)
+        use_pallas = not profile[-1] and pk.should_use(ns, total_w)
         self._log_kernel(pkey, use_pallas, profile[-1], ns)
         return progs, use_pallas
 
@@ -1574,38 +1544,6 @@ class DeviceScan(VectorScan):
                   segments=ns,
                   mesh_devices=int(mesh[0].devices.size) if mesh else 0,
                   merge=merge)
-
-    def _staged_run(self, staged, inputs):
-        """The last of a batch's staging: its jitted program, with the
-        accumulator made ready and the batch base written into
-        `inputs`."""
-        pn, profile, caps, ns, total_w = staged
-        progs, use_pallas = self._staged_programs(staged)
-        run = progs.run_pallas if use_pallas else progs.run_scatter
-        self._ensure_acc(progs.acc_init, caps, ns,
-                         sparse_cap=profile[-1])
-        inputs[self._pfx + 'base'] = np.int64(self._acc_batch << 32)
-        if self.capture_next:
-            # capture pre-upload: the np view of the inputs, so that a
-            # reader can tell the per-batch host arrays from the
-            # device-resident tables by type
-            self.capture_next = False
-            self.captured = (run, dict(inputs), staged, use_pallas)
-        return run
-
-    def _dispatch_staged(self, run, inputs):
-        nbytes = _upload_batch(inputs, self._device_mesh())
-        with obs_metrics.leaf_stage('scan.dispatch'):
-            self._acc, token = run(inputs, self._acc)
-        self._acc_batch += 1
-        if self._acc_meta['sparse_cap']:
-            obs_metrics.inc('device_sparse_fold_batches')
-        self._note_dispatch(token, nbytes)
-        if self._acc_batch % SYNC_EVERY_BATCHES == 0:
-            # periodic dispatch barrier (no fetch): hard backstop on
-            # how far the host can race ahead of the device beyond the
-            # pipeline window
-            self._sync_device()
 
     def _note_dispatch(self, token, nbytes):
         """Pipeline bookkeeping for one dispatched batch: record
@@ -1698,6 +1636,9 @@ class DeviceScan(VectorScan):
         return progs
 
     def _trace_programs(self, caps, n, profile):
+        """What a fold of this scan is (dense, sparse, sparse on a
+        mesh) and its empty accumulator: nothing here is jitted but
+        the accumulator's constructor."""
         jax, jnp = get_jax()
         from . import native as mod_native
         mn = mod_native
@@ -2016,9 +1957,11 @@ class DeviceScan(VectorScan):
                     jnp.minimum(acc[1], bfirst),
                     acc[2] + cvec.astype(i64))
 
-        def fold_sparse(args, acc):
-            """Sparse fold: sort-merge the batch's fused i64 keys into
-            the device-resident compacted set (kernels.sparse_fold).
+        def fold_sparse(args, acc, use_pallas):
+            """Sparse fold (`use_pallas` is the folds' signature: this
+            lane has no one-hot kernel): sort-merge the batch's fused
+            i64 keys into the device-resident compacted set
+            (kernels.sparse_fold).
             keys/first take the per-key min (first-occurrence order
             preserved exactly), weights sum, and the unique count rides
             along so the host pressure guard can read it without a full
@@ -2032,7 +1975,7 @@ class DeviceScan(VectorScan):
                                 i64(I64MAX))
             return sparse_fold(jax, jnp, acc, cvec_b, fused, wb, first_b)
 
-        def fold_sparse_mesh(args, acc):
+        def fold_sparse_mesh(args, acc, use_pallas):
             """The same fold on every chip of the mesh: its shard of
             the batch into its own set (the accumulator's leaves carry
             the chips on a leading axis), and no collective.  `gidx`
@@ -2045,7 +1988,7 @@ class DeviceScan(VectorScan):
             sargs = {k: args[k] for k in specs}
 
             def chip(a, acc1):
-                out = fold_sparse(a, tuple(x[0] for x in acc1))
+                out = fold_sparse(a, tuple(x[0] for x in acc1), False)
                 return tuple(x[None] for x in out)
 
             sets = (SP(maxis),) * 5
@@ -2053,22 +1996,6 @@ class DeviceScan(VectorScan):
                                  out_specs=sets)(sargs, acc)
 
         if sparse_cap:
-            fold_one = fold_sparse if mesh is None else fold_sparse_mesh
-
-            def run_sparse(args, acc):
-                out = fold_one(args, acc)
-                # completion token: a fresh scalar (under a mesh one a
-                # chip, so that no collective joins them) derived from
-                # the output.  Unlike the (donated) accumulator leaves
-                # it never re-enters the fold, so the pipeline can hold
-                # it and block on it after later batches have consumed
-                # the accumulator buffers (see _note_dispatch)
-                return out, jnp.sum(out[4], axis=-1).astype(jnp.int32)
-            run_scatter = jax.jit(run_sparse, **_donate_kw())
-
-            def fold_u(args, acc, use_pallas):
-                return fold_one(args, acc)
-
             init_key = ('sparse', sparse_cap, ncnt, self._mesh_key())
             acc_init = _ACC_INIT_CACHE.get(init_key)
             if acc_init is None:
@@ -2091,19 +2018,8 @@ class DeviceScan(VectorScan):
                 if len(_ACC_INIT_CACHE) >= 64:
                     _ACC_INIT_CACHE.pop(next(iter(_ACC_INIT_CACHE)))
                 _ACC_INIT_CACHE[init_key] = acc_init
-            return _Programs(run_scatter, None, acc_init, fold_u)
-
-        def _tokenized(up):
-            def run(args, acc):
-                out = fold(args, acc, up)
-                # fresh non-donated completion token (see run_sparse)
-                return out, jnp.sum(out[2]).astype(jnp.int32)
-            return run
-
-        run_scatter = jax.jit(_tokenized(False), **_donate_kw())
-        run_pallas = None
-        if pk.pallas_ok(ns) and pk.available():
-            run_pallas = jax.jit(_tokenized(True), **_donate_kw())
+            return _Programs(acc_init, fold_sparse if mesh is None
+                             else fold_sparse_mesh)
 
         init_key = (acc_ns, ncnt)
         acc_init = _ACC_INIT_CACHE.get(init_key)
@@ -2118,7 +2034,7 @@ class DeviceScan(VectorScan):
             if len(_ACC_INIT_CACHE) >= 64:
                 _ACC_INIT_CACHE.pop(next(iter(_ACC_INIT_CACHE)))
             _ACC_INIT_CACHE[init_key] = acc_init
-        return _Programs(run_scatter, run_pallas, acc_init, fold)
+        return _Programs(acc_init, fold)
 
     # -- flush: fetch + ordered merge ---------------------------------------
 
@@ -2628,7 +2544,9 @@ def _compact_fetch(acc, k0):
 
 
 class DeviceScanStack(object):
-    """One device program per batch for an N-metric build.
+    """One device program per batch for the scans of one parse stream:
+    the N metrics of a build, or a lone scan, which is a stack of one.
+    The only place that stages, composes, uploads and dispatches.
 
     The reference's build fed one parse stream into N per-metric
     scanners (lib/datasource-file.js:403-427); the round-4 device build
@@ -2645,22 +2563,26 @@ class DeviceScanStack(object):
     one pass, stacked metric programs).
 
     Scans keep their own accumulators/flush/emission; the stack only
-    changes how batches are staged and dispatched, so per-scan results
-    (and the index artifacts) are byte-identical to the unstacked
-    path.  On the cluster backend's mesh it is the same stack: each
-    scan's fold is its own shard_map (record_specs picks its keys out
-    of the merged dict), its merges (psum+pmin, or the sparse sets'
-    all-gather at a flush) stay its own."""
+    stages and dispatches, so per-scan results (and the index
+    artifacts) are byte-identical to the host engine's.  On the
+    cluster backend's mesh it is the same stack: each scan's fold is
+    its own shard_map (record_specs picks its keys out of the merged
+    dict), its merges (psum+pmin, or the sparse sets' all-gather at a
+    flush) stay its own."""
 
     def __init__(self, scans):
         self.scans = list(scans)
-        # shared sticky upload-profile state: widening decisions apply
-        # to the shared physical inputs, so all scans must agree
-        shared = {'w1': True, 'gen_alive': True, 'filter': {},
-                  'kvalid': {}, 'dtypes': {}}
-        for i, s in enumerate(self.scans):
-            s._pfx = 'm%d_' % i
-            s._sticky = shared
+        if len(self.scans) > 1:
+            # shared sticky upload-profile state: widening decisions
+            # apply to the shared physical inputs, so all scans must
+            # agree.  (A stack of one leaves its scan's prefix and
+            # sticky state as they are: the scan may be a larger
+            # stack's, handed a batch that a sibling declined.)
+            shared = {'w1': True, 'gen_alive': True, 'filter': {},
+                      'kvalid': {}, 'dtypes': {}}
+            for i, s in enumerate(self.scans):
+                s._pfx = 'm%d_' % i
+                s._sticky = shared
         self._nbatch = 0
         # (scan_idx, pn, profile) -> full program key: _program_key
         # json-stringifies predicate ASTs, too costly per batch
@@ -2671,26 +2593,35 @@ class DeviceScanStack(object):
         program when every scan stages successfully, else the per-scan
         paths (each of which may still use its own device program or
         the host engine).  Exactly one of these runs per batch, so
-        insertion order and results match the unstacked path."""
+        insertion order and results match the host engine's."""
+        if not self.try_device(provider, weights, alive):
+            for s in self.scans:
+                s._process(provider, weights, alive=alive)
+
+    def try_device(self, provider, weights, alive):
+        """One batch to the device for every scan; False, with nothing
+        folded or counted, when a gate or a scan's staging declines
+        it."""
         n = provider.n
         for s in self.scans:
             if s._t0 is None:
                 s._t0 = time.monotonic()
-        if self._device_eligible(provider, n) and \
-                self._process_device(provider, weights, alive):
-            for s in self.scans:
-                s._records_seen += n
-                s._after_device_batch(n)
-            return
-        for s in self.scans:
-            s._process(provider, weights, alive=alive)
-
-    def _device_eligible(self, provider, n):
-        if not isinstance(provider, NativeColumns):
+        if not (self._device_eligible(provider, n) and
+                self._process_device(provider, weights, alive)):
             return False
         for s in self.scans:
-            # mirror DeviceScan._process's escalation compare, which
-            # tests records_seen AFTER counting this batch
+            s._records_seen += n
+            s._after_device_batch(n)
+        return True
+
+    def _device_eligible(self, provider, n):
+        """The gates in front of staging: every scan past its
+        escalation threshold with a backend that answers (a forced
+        scan probes it here, once, under the probe deadline), and a
+        batch of native columns."""
+        for s in self.scans:
+            # the escalation compare tests records_seen AFTER counting
+            # this batch
             s._records_seen += n
             try:
                 ok = (not s._disabled and
@@ -2700,9 +2631,21 @@ class DeviceScanStack(object):
                 s._records_seen -= n
             if not ok:
                 return False
+        if not isinstance(provider, NativeColumns):
+            if engine_mode() == 'jax':
+                raise DNError(
+                    'DN_ENGINE=jax: the device scan needs the native '
+                    'column parser and this batch came through the '
+                    'Python record path (native/build/libdnparse.so '
+                    'missing or DN_NATIVE=0; build it with '
+                    '"make -C native")')
+            return False
         return True
 
     def _process_device(self, provider, weights, alive):
+        """Stage, upload and dispatch one batch; False when a scan
+        cannot stage it (any exactness precondition: the host path
+        then computes the same results)."""
         scans = self.scans
         inputs = {}
         with obs_metrics.leaf_stage('scan.stage'):
@@ -2721,18 +2664,23 @@ class DeviceScanStack(object):
             s._acc_batch += 1
             if s._acc_meta['sparse_cap']:
                 obs_metrics.inc('device_sparse_fold_batches')
-            # telemetry: this batch went through the combined program
-            # (kept out of --counters for golden byte parity)
-            s.aggr.stage.bump_hidden('nstackedbatches', 1)
+            if len(scans) > 1:
+                # telemetry: this batch went through a program that
+                # several scans share (kept out of --counters for
+                # golden byte parity)
+                s.aggr.stage.bump_hidden('nstackedbatches', 1)
         self._nbatch += 1
         scans[0]._note_dispatch(token, nbytes)
         if self._nbatch % SYNC_EVERY_BATCHES == 0:
+            # periodic dispatch barrier (no fetch): hard backstop on
+            # how far the host can race ahead of the device beyond the
+            # pipeline window
             scans[0]._sync_device()
         return True
 
     def _stacked_program(self, staged, inputs):
-        """The combined jitted program of one staged batch (every
-        scan's accumulator made ready and its batch base written into
+        """The jitted program of one staged batch (every scan's
+        accumulator made ready and its batch base written into
         `inputs` on the way)."""
         scans = self.scans
         pns = set(st[0] for st in staged)
@@ -2756,9 +2704,9 @@ class DeviceScanStack(object):
                 self._pkey_memo[mkey] = pkey
             key_parts.append((pkey, use_pallas))
 
-        # combined programs cache globally (like _PROGRAM_CACHE): every
-        # `dn build` constructs a fresh stack, and re-tracing the
-        # N-metric program per build costs seconds
+        # jitted programs cache globally (like _PROGRAM_CACHE's traced
+        # folds): every request constructs a fresh stack, and
+        # re-tracing an N-metric program per build costs seconds
         ckey = tuple(key_parts)
         run = _STACK_CACHE.get(ckey)
         if run is None:
@@ -2771,12 +2719,17 @@ class DeviceScanStack(object):
                 outs = tuple(f(args, a, u)
                              for f, a, u in zip(folds, accs, ups))
                 # one fresh, non-donated completion token for the
-                # whole stacked batch (see DeviceScan._note_dispatch)
+                # whole batch, derived from the outputs.  Unlike the
+                # (donated) accumulator leaves it never re-enters the
+                # fold, so the pipeline can hold it and block on it
+                # after later batches have consumed the accumulator
+                # buffers (see DeviceScan._note_dispatch)
                 if on_mesh:
                     # a token a scan, and for a sparse set (its leaves
                     # carry the chips on a leading axis) one a chip:
                     # summed into one scalar they would be a collective
-                    # a batch, which run_sparse keeps the sets free of
+                    # a batch, which the sparse fold keeps the sets
+                    # free of
                     return outs, tuple(
                         jnp.sum(o[-1], axis=-1).astype(jnp.int32)
                         for o in outs)
@@ -2785,25 +2738,12 @@ class DeviceScanStack(object):
                     tok = tok + jnp.sum(o[-1]).astype(jnp.int32)
                 return outs, tok
             run = jax.jit(stacked, **_donate_kw())
-            if len(_STACK_CACHE) >= 32:
+            if len(_STACK_CACHE) >= 64:
+                # bounded: evict oldest (dict preserves insertion
+                # order); a server's distinct lone scans land here too
                 _STACK_CACHE.pop(next(iter(_STACK_CACHE)))
             _STACK_CACHE[ckey] = run
         return run
-
-
-def make_stack(scanners):
-    """A DeviceScanStack when the scanner set supports it (>=2 device
-    scans, on a mesh or off it), else None (callers keep the per-scan
-    loop).  DN_STACK=0 disables stacking (operational escape hatch:
-    per-scan programs still run)."""
-    import os
-    if os.environ.get('DN_STACK', '1') == '0':
-        return None
-    if len(scanners) < 2:
-        return None
-    if not all(isinstance(s, DeviceScan) for s in scanners):
-        return None
-    return DeviceScanStack(scanners)
 
 
 class _ShadowProbe(object):
@@ -2866,24 +2806,16 @@ class _ShadowProbe(object):
                 # scratch scans: their results are discarded by design,
                 # so an unflushed accumulator here is not lost work
                 _SCAN_LEAKS.untrack(s)
-            # multi-metric auditions replay through the combined
-            # program — the thing production runs after a build
-            # takeover — so the measured rate reflects the stack and
-            # the prewarmed _STACK_CACHE, not N per-scan programs the
-            # takeover would never execute
-            stack = make_stack(scans)
+            # the audition replays through the stack — the thing
+            # production runs after a takeover, a scan's as a build's —
+            # so the measured rate reflects it and the prewarmed
+            # _STACK_CACHE
+            stack = DeviceScanStack(scans)
 
             def run_one(snap, n):
-                provider = self.make_provider(snap)
-                weights = self.make_weights(snap, n)
-                alive = self.make_alive(n)
-                if stack is not None:
-                    return stack._process_device(provider, weights,
-                                                 alive)
-                for s in scans:
-                    if not s._try_device(provider, weights, alive):
-                        return False
-                return True
+                return stack._process_device(
+                    self.make_provider(snap), self.make_weights(snap, n),
+                    self.make_alive(n))
 
             if not run_one(*items[0]):       # warmup: trace + compile
                 self.failed = True

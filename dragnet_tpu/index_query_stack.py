@@ -392,124 +392,6 @@ class _BreakdownStack(object):
         return ('str', self.sdict.values)
 
 
-# -- device lane -----------------------------------------------------------
-
-# The batched engine lives in device_index.py; this module keeps the
-# legacy single-dispatch `_device_sums` (the residency accumulator-pin
-# tests exercise it directly), a second wrapper of the engine's one
-# program, and shares the sticky per-process availability verdict with
-# it — one probe outcome per process, whichever lane trips it first.
-from .device_index import _DEVICE_STATE          # noqa: E402
-from .device_index import _reset_device_state    # noqa: F401,E402
-from .device_index import _warn_device           # noqa: E402
-from .device_index import pack_pair, sums_program     # noqa: E402
-
-
-def _pow2(x):
-    p = 8
-    while p < x:
-        p <<= 1
-    return p
-
-
-def _residency():
-    """The serve-installed device residency manager, or None (bare
-    CLI processes never configure one — the lazy import is the whole
-    cost of asking)."""
-    from .serve import residency as mod_residency
-    return mod_residency.active()
-
-
-def _device_sums(inv, weights, nuniq):
-    """Per-tuple weight sums on the device, or None for the host
-    bincount.  Sums run in i64 (x64 mode), so for the integer weights
-    the stacked gate admits the result is bit-equal to the host path
-    — the same exactness contract as device_scan.py.  The first
-    device op runs under the probe deadline: a wedged backend warns
-    and falls back instead of hanging `dn query`.
-
-    Inside a residency-armed `dn serve` (serve/residency.py), the
-    folded accumulator stays pinned in device memory keyed by the
-    content of the staged columns: a request over the same stacked
-    rows skips the H2D upload, the dispatch, AND the slow D2H fetch —
-    it answers with the exact host array the first execution fetched,
-    while the writer epoch retires pins on any index write."""
-    from .engine import MAX_DENSE_SEGMENTS
-    if nuniq > MAX_DENSE_SEGMENTS or len(inv) == 0:
-        return None
-    st = _DEVICE_STATE
-    if st['ready'] is False:
-        return None
-    from .ops import get_jax
-    if get_jax() is None:
-        st['ready'] = False
-        _warn_device('jax unavailable')
-        return None
-
-    pn = _pow2(len(inv))
-    pu = _pow2(nuniq)
-    pair = pack_pair(inv, weights, pn, pu)
-
-    res = _residency()
-    rkey = repoch = None
-    if res is not None:
-        from . import index_query_mt as mod_iqmt
-        from .serve import residency as mod_residency
-        rkey = mod_residency.content_key('iq-sums', (pair,),
-                                         (pn, pu, nuniq))
-        repoch = mod_iqmt.cache_epoch()
-        pinned = res.get(rkey, repoch)
-        if pinned is not None:
-            # the pinned copy is shared across requests; hand out a
-            # private clone (downstream aggregation may scale it)
-            return pinned.copy()
-
-    def compute():
-        from .ops import backend_ready
-        if not backend_ready():
-            return None
-        dense = sums_program(pn, pu)(pair)
-        try:
-            dense.block_until_ready()
-        except AttributeError:
-            pass
-        return dense
-
-    if st['ready'] is None:
-        from .device_scan import run_with_deadline, probe_deadline_s
-        status, out = run_with_deadline(compute, probe_deadline_s(),
-                                        'iq-device-lane')
-        if status == 'timeout':
-            st['ready'] = False
-            _warn_device('backend unresponsive past the %.0fs probe '
-                         'deadline' % probe_deadline_s())
-            return None
-        if status == 'error' or out is None:
-            st['ready'] = False
-            _warn_device('backend failed to initialize')
-            return None
-        st['ready'] = True
-        dense = out
-    else:
-        try:
-            dense = compute()
-        except Exception as e:
-            st['ready'] = False
-            _warn_device(repr(e))
-            return None
-        if dense is None:
-            st['ready'] = False
-            _warn_device('backend failed to initialize')
-            return None
-    host = np.asarray(dense)[:nuniq].astype(np.float64)
-    if res is not None:
-        # pin the device-side accumulator + its fetched copy; future
-        # hits book the upload and fetch bytes this execution paid
-        res.put(rkey, repoch, dense, host, h2d_bytes=pair.nbytes)
-        return host.copy()
-    return host
-
-
 def _aggregate_weights(inv, weights, nuniq, stage=None,
                        shard_ctx=None):
     """The aggregation seam: the batched device engine

@@ -1,5 +1,5 @@
-"""jit-compiled scan kernels: fused group-by segment-sum (+ mask fold,
-bucketize helpers).
+"""jit-compiled scan kernels: fused group-by segment-sum, the sparse
+fold, a p2 bucketize.
 
 Device kernels are 32-bit native: TPU has no native 64-bit integer path
 (XLA's x64 rewrite rejects the s64 bitcasts that e.g. jnp.frexp emits),
@@ -13,9 +13,8 @@ exists for fully-on-device pipelines.
 Semantics contract (pinned by differential tests against aggr.py):
 
 * p2: v < 1 -> 0; v >= 1 -> floor(log2 v) + 1   (DTrace quantize)
-* linear: floor(v / step)
-* predicate outcomes are ternary (FALSE/TRUE/ERROR) folding with JS
-  short-circuit rules: `and` -> first non-true, `or` -> first non-false
+* predicate outcomes are ternary (FALSE/TRUE/ERROR); device_scan's
+  program folds them with JS short-circuit rules
 * fuse + segment-sum: mixed-radix composite key into a dense
   accumulator; partials merge by addition (psum across a mesh)
 * sparse fold: a batch of fused i64 keys merged into a sorted,
@@ -43,26 +42,6 @@ def p2_bucketize(jnp, v):
     e = jnp.where(pow_e > v, e - 1, e)
     e = jnp.where(pow_e * 2.0 <= v, e + 1, e)
     return jnp.where(v < 1, 0, e + 1).astype('int32')
-
-
-def linear_bucketize(jnp, v, step):
-    return jnp.floor(v / step).astype('int32')
-
-
-def fold_and(jnp, outcomes):
-    """outcomes: list of i8 arrays; first non-TRUE operand wins."""
-    state = outcomes[0]
-    for o in outcomes[1:]:
-        state = jnp.where(state == TRUE, o, state)
-    return state
-
-
-def fold_or(jnp, outcomes):
-    """first non-FALSE operand wins."""
-    state = outcomes[0]
-    for o in outcomes[1:]:
-        state = jnp.where(state == FALSE, o, state)
-    return state
 
 
 @functools.lru_cache(maxsize=None)

@@ -77,8 +77,6 @@ class MeshDeviceScan(DeviceScan, MeshVectorScan):
     _mesh_cache = None
 
     def _device_mesh(self):
-        if os.environ.get('DN_MESH_PIPELINE', '1') == '0':
-            return None
         m = MeshDeviceScan._mesh_cache
         if m is None:
             from ..ops import backend_ready

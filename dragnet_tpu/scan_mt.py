@@ -251,16 +251,10 @@ class BatchRecorder(object):
 # -- radix-partitioned merge -------------------------------------------------
 
 # merge-phase telemetry accumulated across RadixMerge finalizations
-# (merge_stats() reports them; reset_merge_stats() zeroes them)
+# (merge_stats() reports them)
 _MERGE_STATS = {'merge_ms': 0.0, 'partitions': 0, 'rows': 0,
                 'unique': 0, 'engaged': 0}
 _MERGE_LOCK = threading.Lock()
-
-
-def reset_merge_stats():
-    with _MERGE_LOCK:
-        _MERGE_STATS.update(merge_ms=0.0, partitions=0, rows=0,
-                            unique=0, engaged=0)
 
 
 def merge_stats():

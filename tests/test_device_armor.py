@@ -209,12 +209,18 @@ def test_forced_scan_without_native_columns_is_an_error(monkeypatch):
     from dragnet_tpu.errors import DNError
     q = mod_query.query_load({'breakdowns': [{'name': 'host'}]})
     s = device_scan.DeviceScan(q, None, Pipeline())
+    s._backend_ok = True
+
+    class _Records(object):     # a batch that is no NativeColumns
+        n = 1
+    stack = device_scan.DeviceScanStack([s])
     monkeypatch.setenv('DN_ENGINE', 'jax')
     with pytest.raises(DNError) as ei:
-        s._try_device(object(), [1], None)
+        stack.try_device(_Records(), [1], None)
     assert 'native column parser' in ei.value.message
     monkeypatch.setenv('DN_ENGINE', 'auto')
-    assert s._try_device(object(), [1], None) is False
+    assert stack.try_device(_Records(), [1], None) is False
+    assert s._records_seen == 0 and s._acc is None
 
 
 def test_auto_probe_deadline_disables(monkeypatch):

@@ -100,7 +100,7 @@ def _metrics():
 
 
 def _build(monkeypatch, datafile, indexdir, engine, batch=None,
-           read_size=None):
+           read_size=None, metrics=None):
     monkeypatch.setenv('DN_ENGINE', engine)
     monkeypatch.setenv('DN_PARSE_THREADS', '1')
     if batch is not None:
@@ -109,7 +109,7 @@ def _build(monkeypatch, datafile, indexdir, engine, batch=None,
         monkeypatch.setattr(mod_engine, 'BATCH_SIZE', batch)
         monkeypatch.setattr(mod_ds, 'BATCH_SIZE', batch)
         monkeypatch.setenv('DN_READ_SIZE', str(read_size or batch * 64))
-    result = _ds(datafile, indexdir).build(_metrics(), 'day')
+    result = _ds(datafile, indexdir).build(metrics or _metrics(), 'day')
     stacked = 0
     for stage in result.pipeline.stages:
         stacked += stage.counters.get('nstackedbatches', 0)
@@ -214,24 +214,88 @@ def test_stacked_index_scan_points_identical(tmp_path, monkeypatch):
         [(f, v) for f, v in dev.points]
 
 
-def test_stack_disable_env(tmp_path, monkeypatch):
-    """DN_STACK=0 keeps the per-scan device programs (results
-    identical) — the operational escape hatch for plugins that
-    misbehave under the combined program."""
+def _hidden(result, name):
+    return sum(st.counters.get(name, 0) for st in result.pipeline.stages)
+
+
+def _dispatches():
+    from dragnet_tpu.obs import metrics as obs_metrics
+    return obs_metrics.global_registry().counter(
+        'device_pipe_dispatches').value
+
+
+@pytest.mark.parametrize('mi', range(len(METRICS)),
+                         ids=[m['name'] for m in METRICS])
+def test_one_metric_build_byte_identical(tmp_path, monkeypatch, mi):
+    """A forced-device build of ONE metric is a stack of one: every
+    batch is the device's, one dispatch a batch, no `nstackedbatches`
+    (no two scans share the program), and the tree is the vector
+    engine's byte for byte."""
     datafile = tmp_path / 'data.log'
-    _write_data(datafile, 1200)
+    _write_data(datafile, 1500)
+    metric = [_metrics()[mi]]
 
-    _, s_on = _build(monkeypatch, datafile, tmp_path / 'i1', 'jax')
-    assert s_on > 0
-    monkeypatch.setenv('DN_STACK', '0')
-    _, s_off = _build(monkeypatch, datafile, tmp_path / 'i2', 'jax')
-    assert s_off == 0
+    _build(monkeypatch, datafile, tmp_path / 'ih', 'vector',
+           metrics=metric)
+    h0, d0 = batches_handed(), _dispatches()
+    result, stacked = _build(monkeypatch, datafile, tmp_path / 'id', 'jax',
+                             batch=256, metrics=metric)
+    handed = batches_handed() - h0
+    assert handed >= 5 and _dispatches() - d0 == handed
+    assert _hidden(result, 'ndevicebatches') == handed and stacked == 0
 
-    t1 = _tree_bytes(tmp_path / 'i1')
-    t2 = _tree_bytes(tmp_path / 'i2')
-    assert t1.keys() == t2.keys()
-    for rel in t1:
-        assert t1[rel] == t2[rel], rel
+    host_tree = _tree_bytes(tmp_path / 'ih')
+    dev_tree = _tree_bytes(tmp_path / 'id')
+    assert host_tree.keys() == dev_tree.keys() and len(host_tree) >= 3
+    for rel in host_tree:
+        assert host_tree[rel] == dev_tree[rel], rel
+
+
+def test_lone_scan_and_one_metric_build_share_one_path(tmp_path,
+                                                       monkeypatch):
+    """A forced-device lone scan and a one-metric index-scan of the
+    same query go through DeviceScanStack._process_device as stacks of
+    one, a dispatch a batch handed, and through the same cached jitted
+    programs: the second of them adds no entry (no second jit of what
+    the first compiled).  Equal points, no `nstackedbatches`."""
+    from dragnet_tpu import engine as mod_engine
+    from dragnet_tpu import device_scan as mod_ds
+    datafile = tmp_path / 'data.log'
+    _write_data(datafile, 1500)
+    metric = _metrics()[1]          # two key columns and a filter
+    query = mod_query.metric_query(metric, None, None, 'all', 'time')
+
+    took = []
+    orig = mod_ds.DeviceScanStack._process_device
+
+    def spy(self, provider, weights, alive):
+        took.append((len(self.scans), orig(self, provider, weights, alive)))
+        return took[-1][1]
+    monkeypatch.setattr(mod_ds.DeviceScanStack, '_process_device', spy)
+    monkeypatch.setattr(mod_ds, '_STACK_CACHE', {})
+    monkeypatch.setattr(mod_engine, 'BATCH_SIZE', 256)
+    monkeypatch.setattr(mod_ds, 'BATCH_SIZE', 256)
+    monkeypatch.setenv('DN_READ_SIZE', str(256 * 64))
+    monkeypatch.setenv('DN_PARSE_THREADS', '1')
+    monkeypatch.setenv('DN_ENGINE', 'jax')
+
+    runs = []
+    for run in (lambda ds: ds.index_scan([metric], 'all'),
+                lambda ds: ds.scan(query)):
+        h0, d0 = batches_handed(), _dispatches()
+        result = run(_ds(datafile, tmp_path / 'idx'))
+        handed = batches_handed() - h0
+        assert handed >= 5 and _dispatches() - d0 == handed
+        assert took == [(1, True)] * handed
+        assert _hidden(result, 'ndevicebatches') == handed
+        assert _hidden(result, 'nstackedbatches') == 0
+        runs.append((result, sorted(mod_ds._STACK_CACHE, key=repr)))
+        del took[:]
+    (built, programs), (scanned, programs_after) = runs
+    assert programs and all(len(key) == 1 for key in programs)
+    assert programs_after == programs
+    assert [(dict(f, __dn_metric=0), v) for f, v in scanned.points] == \
+        [(f, v) for f, v in built.points]
 
 
 def test_sparse_fold_batches_counter(tmp_path, monkeypatch):

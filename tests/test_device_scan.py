@@ -136,7 +136,7 @@ def test_device_differential_clean(tmp_path, monkeypatch, qi):
     vacuous pass via fallback), results byte-identical to host."""
     from dragnet_tpu import device_scan as mod_ds
     ran = []
-    orig = mod_ds.DeviceScan._try_device
+    orig = mod_ds.DeviceScanStack._process_device
 
     def spy(self, provider, weights, alive):
         rv = orig(self, provider, weights, alive)
@@ -151,7 +151,7 @@ def test_device_differential_clean(tmp_path, monkeypatch, qi):
     qconf = QUERIES[qi]
     host_points, host_counters = _scan(monkeypatch, datafile, qconf,
                                        engine='auto')
-    monkeypatch.setattr(mod_ds.DeviceScan, '_try_device', spy)
+    monkeypatch.setattr(mod_ds.DeviceScanStack, '_process_device', spy)
     dev_points, dev_counters = _scan(monkeypatch, datafile, qconf,
                                      engine='jax', batch=128)
     assert host_points == dev_points, qconf
@@ -164,13 +164,13 @@ def test_device_batches_actually_ran(tmp_path, monkeypatch):
     device path processed batches."""
     from dragnet_tpu import device_scan as mod_ds
     ran = []
-    orig = mod_ds.DeviceScan._try_device
+    orig = mod_ds.DeviceScanStack._process_device
 
     def spy(self, provider, weights, alive):
         rv = orig(self, provider, weights, alive)
         ran.append(rv)
         return rv
-    monkeypatch.setattr(mod_ds.DeviceScan, '_try_device', spy)
+    monkeypatch.setattr(mod_ds.DeviceScanStack, '_process_device', spy)
     rng = random.Random(5)
     datafile = str(tmp_path / 'data.log')
     with open(datafile, 'w') as f:
@@ -675,10 +675,9 @@ def test_sparse_program_has_no_scatter_or_long_gather(tmp_path,
         np.ones(parser.batch_size(), dtype=np.float64), None, inputs)
     assert staged is not None and staged[0] == bn
     assert staged[1][-1] == cap             # the sparse lane, at cap
-    progs, _ = scan._staged_programs(staged)
-    inputs[scan._pfx + 'base'] = np.int64(0)
-    text = progs.run_scatter.lower(
-        inputs, jax.eval_shape(progs.acc_init)).as_text()
+    # the one jitted program of a stack of one
+    run = mod_ds.DeviceScanStack([scan])._stacked_program([staged], inputs)
+    text = run.lower(inputs, (scan._acc,)).as_text()
 
     long_axis = 'tensor<%dx' % (cap + bn)
     assert long_axis + 'i64>' in text       # the concatenation is there

@@ -20,6 +20,7 @@ import pytest
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
+from dragnet_tpu import device_index as mod_di  # noqa: E402
 from dragnet_tpu import query as mod_query  # noqa: E402
 from dragnet_tpu import index_query_mt as mod_iqmt  # noqa: E402
 from dragnet_tpu import index_query_stack as mod_iqs  # noqa: E402
@@ -369,10 +370,10 @@ def test_device_lane_differential(tmp_path, monkeypatch):
     monkeypatch.setenv('DN_IQ_THREADS', '0')
     host = ds.query(_query(QUERIES[0]), 'day').points
 
-    mod_iqs._reset_device_state()
+    mod_di._reset_device_state()
     monkeypatch.setenv('DN_ENGINE', 'jax')
     dev = ds.query(_query(QUERIES[0]), 'day').points
-    assert mod_iqs._DEVICE_STATE['ready'] is True
+    assert mod_di._DEVICE_STATE['ready'] is True
     assert dev == host
 
 
@@ -389,7 +390,7 @@ def test_forced_device_lane_without_jax_is_an_error(tmp_path,
     monkeypatch.setenv('DN_IQ_STACK', '1')
 
     from dragnet_tpu import ops
-    mod_iqs._reset_device_state()
+    mod_di._reset_device_state()
     monkeypatch.setenv('DN_ENGINE', 'jax')
     monkeypatch.setattr(ops, 'get_jax', lambda: None)
     with pytest.raises(DNError) as ei:
@@ -401,15 +402,14 @@ def test_forced_device_lane_without_jax_is_an_error(tmp_path,
 def test_auto_device_lane_clean_fallback(monkeypatch, capsys):
     """A lane auto mode chose (not forced) still warns once and leaves
     the answer to the host path."""
-    from dragnet_tpu import device_index as mod_di
-    mod_iqs._reset_device_state()
+    mod_di._reset_device_state()
     monkeypatch.delenv('DN_ENGINE', raising=False)
     monkeypatch.delenv('DN_INDEX_DEVICE', raising=False)
     mod_di._warn_device('backend failed to initialize')
     mod_di._warn_device('backend failed to initialize')
     err = capsys.readouterr().err
     assert err.count('device index-query lane unavailable') == 1
-    mod_iqs._reset_device_state()
+    mod_di._reset_device_state()
 
 
 def test_forced_device_lane_deadline_is_an_error(tmp_path, monkeypatch):
@@ -426,18 +426,17 @@ def test_forced_device_lane_deadline_is_an_error(tmp_path, monkeypatch):
     ds.build([_metric()], 'day')
     monkeypatch.setenv('DN_IQ_STACK', '1')
 
-    mod_iqs._reset_device_state()
+    mod_di._reset_device_state()
     monkeypatch.setenv('DN_ENGINE', 'jax')
     monkeypatch.setenv('DN_DEVICE_PROBE_TIMEOUT', '0.2')
-    from dragnet_tpu import device_index as mod_di
     monkeypatch.setattr(
         mod_di, 'sums_program',
         lambda rows, segments: (lambda pair: mod_time.sleep(60)))
     with pytest.raises(DNError) as ei:
         ds.query(_query(QUERIES[0]), 'day')
     assert 'unresponsive' in ei.value.message
-    assert mod_iqs._DEVICE_STATE['ready'] is False
-    mod_iqs._reset_device_state()
+    assert mod_di._DEVICE_STATE['ready'] is False
+    mod_di._reset_device_state()
 
 
 # -- CLI + cluster plan ----------------------------------------------------

@@ -261,6 +261,13 @@ def _mesh_scan_setup(monkeypatch, read=4096, cap0=4096, dense=64):
     return buf
 
 
+def _no_prefetch(monkeypatch):
+    """Keep the late-stream prefetch of the flush out of the way: the
+    stream never gets as far as it asks."""
+    from dragnet_tpu import device_scan
+    monkeypatch.setattr(device_scan.DeviceScan, 'PREFETCH_PROGRESS', 2.0)
+
+
 def _kernel_records(buf):
     import json
     recs = [json.loads(ln) for ln in buf.getvalue().splitlines()]
@@ -429,7 +436,7 @@ def test_mesh_sparse_cases(case, tmp_path, monkeypatch):
     monkeypatch.setattr(cluster.MeshDeviceScan, '_prefetch_flush',
                         spy_prefetch)
     if case != 'prefetch':
-        monkeypatch.setenv('DN_PREFETCH', '0')
+        _no_prefetch(monkeypatch)
     if case == 'overflow-raises':
         monkeypatch.setattr(cluster.MeshDeviceScan, '_sparse_guard',
                             lambda self, n: True)
@@ -488,7 +495,7 @@ def test_mesh_sparse_partials_tie(tmp_path, monkeypatch):
     expected = _scan_points(monkeypatch, cluster.DatasourceCluster,
                             dsconfig, HIGHCARD_Q, 'host').points
     _mesh_scan_setup(monkeypatch)
-    monkeypatch.setenv('DN_PREFETCH', '0')
+    _no_prefetch(monkeypatch)
     seen = []
     orig = cluster.MeshDeviceScan._merge_sparse
 
@@ -666,8 +673,10 @@ def test_cluster_build_batch_one_scan_cannot_stage(tmp_path, monkeypatch):
     orig = device_scan.DeviceScanStack._process_device
 
     def spy(self, provider, weights, alive):
-        took.append(orig(self, provider, weights, alive))
-        return took[-1]
+        rv = orig(self, provider, weights, alive)
+        if len(self.scans) == 3:     # not a declined batch's stacks of one
+            took.append(rv)
+        return rv
     monkeypatch.setattr(device_scan.DeviceScanStack, '_process_device', spy)
     kernels, stacked, handed, dispatched, caps = _cluster_build(
         monkeypatch, datafile, tmp_path / 'icluster')
@@ -677,27 +686,6 @@ def test_cluster_build_batch_one_scan_cannot_stage(tmp_path, monkeypatch):
     # a refused batch is dispatched by each scan that can take it
     assert took.count(True) < dispatched <= \
         took.count(True) + 3 * took.count(False)
-    _same_tree(tmp_path, 'ifile', 'icluster')
-
-
-def test_cluster_build_stack_disabled_by_env(tmp_path, monkeypatch):
-    """DN_STACK=0 on the cluster backend: the per-scan loop, a dispatch
-    a metric a batch, and the same tree."""
-    import test_device_build as tdb
-    from dragnet_tpu import native as mod_native
-
-    if mod_native.get_lib() is None:
-        pytest.skip('native parser unavailable')
-    datafile = tmp_path / 'data.log'
-    tdb._write_data(datafile, 1500)
-    tdb._build(monkeypatch, datafile, tmp_path / 'ifile', 'vector')
-
-    monkeypatch.setenv('DN_STACK', '0')
-    kernels, stacked, handed, dispatched, caps = _cluster_build(
-        monkeypatch, datafile, tmp_path / 'icluster')
-    assert stacked == [] and caps == []
-    assert dispatched == 3 * handed > 0
-    assert kernels == BOTH_MESH_KERNELS
     _same_tree(tmp_path, 'ifile', 'icluster')
 
 
@@ -740,6 +728,49 @@ def test_stacked_mesh_fold_of_sparse_sets_has_no_collective(tmp_path,
                        'collective-permute', 'reduce-scatter'):
         assert collective not in text, collective
     assert [t.shape for t in tokens[0]] == [(8,)] * 3
+
+
+def test_lone_mesh_scan_of_a_sparse_set_has_no_collective(tmp_path,
+                                                          monkeypatch):
+    """The lone form of the test above: a high-cardinality scan on the
+    cluster backend is a stack of one, and its program, as the virtual
+    8-device mesh compiles it, crosses no chips either, the completion
+    token (one a chip) included."""
+    import jax
+    from dragnet_tpu import device_scan
+    from dragnet_tpu import native as mod_native
+    from dragnet_tpu.parallel import cluster
+
+    if mod_native.get_lib() is None:
+        pytest.skip('native parser unavailable')
+    datadir = tmp_path / 'data'
+    datadir.mkdir()
+    dsconfig = _dsconfig(datadir,
+                         _write_mesh_case('odd-count', datadir / 'a.log'))
+    texts, tokens = [], []
+    orig = device_scan.DeviceScanStack._stacked_program
+
+    def spy(self, staged, inputs):
+        run = orig(self, staged, inputs)
+        if not texts:
+            (scan,), (st,) = self.scans, staged
+            assert isinstance(scan, cluster.MeshDeviceScan) and st[1][-1]
+            texts.append(run.lower(inputs, (scan._acc,)).compile()
+                         .as_text())
+            tokens.append(jax.eval_shape(run, inputs, (scan._acc,))[1])
+        return run
+    monkeypatch.setattr(device_scan.DeviceScanStack, '_stacked_program',
+                        spy)
+    _mesh_scan_setup(monkeypatch)
+    r = _scan_points(monkeypatch, cluster.DatasourceCluster, dsconfig,
+                     HIGHCARD_Q, 'jax')
+    assert _ndevicebatches(r) >= 5
+    text, = texts
+    assert 'sort' in text
+    for collective in ('all-reduce', 'all-gather', 'all-to-all',
+                       'collective-permute', 'reduce-scatter'):
+        assert collective not in text, collective
+    assert [t.shape for t in tokens[0]] == [(8,)]
 
 
 def _spec_axes(specs):
@@ -843,7 +874,7 @@ def test_sparse_merge_counters_and_leaf_at_a_scrape(tmp_path, monkeypatch):
     dsconfig = _dsconfig(datadir,
                          _write_mesh_case('odd-count', datadir / 'a.log'))
     _mesh_scan_setup(monkeypatch)
-    monkeypatch.setenv('DN_PREFETCH', '0')
+    _no_prefetch(monkeypatch)
     names = ('device_sparse_merge_rows', 'device_sparse_merge_tuples',
              'device_sparse_set_slots', 'device_sparse_set_live')
     before = [_counter(n) for n in names]

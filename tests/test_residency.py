@@ -1,6 +1,6 @@
 """Device-memory residency (serve/residency.py): the LRU pin/evict/
 invalidate mechanics, the module singleton the index-query device lane
-reads, the serve-start pre-warm, and the _device_sums integration —
+reads, the serve-start pre-warm, and the device fold's integration —
 byte-identity against the recompute pinned throughout (a hit returns
 the SAME bytes the first execution produced, by construction)."""
 
@@ -182,18 +182,18 @@ def _need_jax():
         pytest.skip('jax unavailable')
 
 
-def test_device_sums_pins_and_serves_repeats():
+def test_batched_sums_pins_and_serves_repeats():
     _need_jax()
-    from dragnet_tpu import index_query_stack as mod_iqs
+    from dragnet_tpu import device_index as mod_di
     from dragnet_tpu import index_query_mt as mod_iqmt
-    mod_iqs._reset_device_state()
+    mod_di._reset_device_state()
     residency.configure(64 << 20)
     seg = np.array([0, 1, 1, 2, 2, 2], dtype=np.int64)
     w = np.array([1, 2, 3, 4, 5, 6], dtype=np.int64)
-    first = mod_iqs._device_sums(seg, w, 3)
+    first = mod_di.batched_sums(seg, w, 3)
     if first is None:
         pytest.skip('device lane unavailable on this rig')
-    again = mod_iqs._device_sums(seg, w, 3)
+    again = mod_di.batched_sums(seg, w, 3)
     assert np.array_equal(first, again)      # byte identity on a hit
     assert again.dtype == np.float64
     st = residency.stats()
@@ -202,28 +202,28 @@ def test_device_sums_pins_and_serves_repeats():
     # a returned hit is a private copy: mutating it must not poison
     # the pinned accumulator
     again[0] = 12345.0
-    third = mod_iqs._device_sums(seg, w, 3)
+    third = mod_di.batched_sums(seg, w, 3)
     assert np.array_equal(first, third)
     # an index write (epoch bump) retires the pin; recompute matches
     mod_iqmt.invalidate_index_tree('/nonexistent/tree')
-    fourth = mod_iqs._device_sums(seg, w, 3)
+    fourth = mod_di.batched_sums(seg, w, 3)
     assert np.array_equal(first, fourth)
     assert residency.stats()['stale_drops'] >= 1
 
 
-def test_device_sums_identical_with_and_without_residency():
+def test_batched_sums_identical_with_and_without_residency():
     _need_jax()
-    from dragnet_tpu import index_query_stack as mod_iqs
-    mod_iqs._reset_device_state()
+    from dragnet_tpu import device_index as mod_di
+    mod_di._reset_device_state()
     rng = np.random.RandomState(7)
     seg = rng.randint(0, 50, size=777).astype(np.int64)
     w = rng.randint(0, 1000, size=777).astype(np.int64)
-    bare = mod_iqs._device_sums(seg, w, 50)
+    bare = mod_di.batched_sums(seg, w, 50)
     if bare is None:
         pytest.skip('device lane unavailable on this rig')
     residency.configure(64 << 20)
-    pinned_miss = mod_iqs._device_sums(seg, w, 50)
-    pinned_hit = mod_iqs._device_sums(seg, w, 50)
+    pinned_miss = mod_di.batched_sums(seg, w, 50)
+    pinned_hit = mod_di.batched_sums(seg, w, 50)
     assert np.array_equal(bare, pinned_miss)
     assert np.array_equal(bare, pinned_hit)
     host = np.bincount(seg, weights=w.astype(np.float64),
@@ -233,8 +233,8 @@ def test_device_sums_identical_with_and_without_residency():
 
 def test_prewarm_compiles_and_reports():
     _need_jax()
-    from dragnet_tpu import index_query_stack as mod_iqs
-    mod_iqs._reset_device_state()
+    from dragnet_tpu import device_index as mod_di
+    mod_di._reset_device_state()
     doc = residency.prewarm(shapes=((1 << 6, 1 << 4),), deadline_s=120)
     assert doc['state'] == 'ok'
     assert doc['programs'] == 1
@@ -243,7 +243,6 @@ def test_prewarm_compiles_and_reports():
     assert 'auditions' in doc and 'audition_wins' in doc
     # the compiled program is shared state: a real query of that
     # padded shape now skips its compile
-    from dragnet_tpu import device_index as mod_di
     assert (1 << 6, 1 << 4) in mod_di._SUMS_CACHE
 
 
@@ -253,8 +252,7 @@ def test_prewarm_defaults_to_the_folds_ladder():
     accumulator, the programs a query then finds compiled."""
     _need_jax()
     from dragnet_tpu import device_index as mod_di
-    from dragnet_tpu import index_query_stack as mod_iqs
-    mod_iqs._reset_device_state()
+    mod_di._reset_device_state()
     mod_di._SUMS_CACHE.clear()
     doc = residency.prewarm(deadline_s=120)
     assert doc['state'] == 'ok'

@@ -17,7 +17,8 @@ same reason, and none of them starts a child process.
 Not covered: DeviceScan asks `jax.default_backend()` for buffer
 donation (device_scan._donate_kw) and sees the CPU here, so the
 programs compile WITHOUT `donate_argnums`; and DeviceScanStack's
-combined multi-metric jit is only compiled through its member folds.
+jit is compiled for a stack of one, a multi-metric build's only
+through its member folds.
 """
 
 import os
@@ -31,6 +32,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 import bench                                        # noqa: E402
 from dragnet_tpu import device_index                # noqa: E402
+from dragnet_tpu.device_scan import (               # noqa: E402
+    DeviceScan, DeviceScanStack)
 from dragnet_tpu import engine                      # noqa: E402
 from dragnet_tpu import native as mod_native        # noqa: E402
 from dragnet_tpu import query as mod_query          # noqa: E402
@@ -143,13 +146,14 @@ def test_segment_sum_aggregate_compiles(one_chip, radices):
 # -- the DeviceScan programs --------------------------------------------------
 
 def _staged_program(query_conf, datafile, scan_cls=None):
-    """(jitted fold, example inputs, accumulator shapes, use_pallas) of
-    the program DeviceScan builds for one real batch of `datafile`:
-    staged on the CPU backend exactly as a scan stages it, but not
-    run."""
+    """(jitted program, example inputs, accumulator shapes, use_pallas)
+    of the program a lone DeviceScan, a stack of one, builds for one
+    real batch of `datafile`: staged on the CPU backend exactly as a
+    scan stages it, but not run.  The program takes the stack's
+    accumulators: (accumulator,)."""
     jax, _ = get_jax()
     if scan_cls is None:
-        from dragnet_tpu.device_scan import DeviceScan as scan_cls
+        scan_cls = DeviceScan
     scan = scan_cls(mod_query.query_load(dict(query_conf)), None,
                     Pipeline())
     parser = one_batch_parser(datafile, scan, BATCH)
@@ -162,9 +166,12 @@ def _staged_program(query_conf, datafile, scan_cls=None):
                                 inputs)
     assert staged is not None, 'batch was not eligible for the device'
     progs, use_pallas = scan._staged_programs(staged)
-    inputs[scan._pfx + 'base'] = np.int64(0)
-    run = progs.run_pallas if use_pallas else progs.run_scatter
-    return run, inputs, jax.eval_shape(progs.acc_init), use_pallas
+    # the one jitted program of a stack of one; the accumulator's
+    # shapes stand in for it (none can be made on a described mesh)
+    acc = scan._acc = jax.eval_shape(progs.acc_init)
+    run = DeviceScanStack([scan])._stacked_program([staged], inputs)
+    scan._acc = None
+    return run, inputs, acc, use_pallas
 
 
 @pytest.mark.parametrize('name,query_conf,sparse', [
@@ -177,7 +184,7 @@ def test_device_scan_program_compiles(one_chip, corpus, name,
     run, inputs, acc, use_pallas = _staged_program(query_conf, corpus)
     assert not use_pallas
     assert (len(acc) == 5) == sparse     # the sparse set's five leaves
-    _compile(run, _like(inputs, one_chip), _like(acc, one_chip))
+    _compile(run, _like(inputs, one_chip), (_like(acc, one_chip),))
 
 
 def test_device_scan_pallas_program_compiles(one_chip, corpus,
@@ -191,7 +198,7 @@ def test_device_scan_pallas_program_compiles(one_chip, corpus,
                                                    corpus)
     assert use_pallas
     compiled = _compile(run, _like(inputs, one_chip),
-                        _like(acc, one_chip))
+                        (_like(acc, one_chip),))
     assert 'tpu_custom_call' in compiled.as_text()
 
 
@@ -234,7 +241,7 @@ def test_mesh_device_scan_program_compiles(mesh4, corpus, monkeypatch):
     assert not use_pallas
     replicated = NamedSharding(mesh4, P())
     compiled = _compile(run, _like(inputs, replicated),
-                        _like(acc, replicated))
+                        (_like(acc, replicated),))
     text = compiled.as_text()
     assert 'all-reduce' in text
 
@@ -264,7 +271,7 @@ def test_mesh_sparse_fold_has_no_collective(corpus, monkeypatch):
         SPARSE_QUERY, corpus, scan_cls=cluster.MeshDeviceScan)
     assert not use_pallas
     assert [x.shape for x in acc[:3]] == [(8, 1 << 14)] * 3
-    text = run.lower(inputs, acc).compile().as_text()
+    text = run.lower(inputs, (acc,)).compile().as_text()
     assert 'sort' in text
     for collective in COLLECTIVES:
         assert collective not in text, collective
@@ -300,7 +307,7 @@ def test_mesh_sparse_programs_compile_at_the_cell_size(mesh4, corpus,
     assert not use_pallas and acc[0].shape == (4, 1 << 20)
     sets = _sparse_sets(mesh4, 4, 1 << 20, acc[3].shape[1])
     text = _compile(run, _like(inputs, NamedSharding(mesh4, P())),
-                    sets).as_text()
+                    (sets,)).as_text()
     for collective in COLLECTIVES:
         assert collective not in text, collective
     merge = mod_mesh.sparse_merge_program(mesh4, 'd', 1 << 17, 1 << 19)
