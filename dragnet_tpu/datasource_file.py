@@ -126,10 +126,11 @@ class DatasourceFile(object):
 
     # -- input enumeration ------------------------------------------------
 
-    def _find(self, root, timeformat, start_ms, end_ms, pipeline):
+    def _find(self, root, timeformat, start_ms, end_ms, pipeline,
+              skip=None):
         """Returns list of (path, stat) or DNError."""
         if end_ms is None:
-            return mod_find.find_walk([root], pipeline)
+            return mod_find.find_walk([root], pipeline, skip=skip)
         assert start_ms is not None
         pathenum = mod_find.create_path_enumerator(
             os.path.join(root, timeformat), start_ms, end_ms)
@@ -1068,7 +1069,8 @@ class DatasourceFile(object):
                                             before, pipeline)
         if files is None:
             snap = None
-            files = self._find(root, timeformat, after, before, pipeline)
+            files = self._find(root, timeformat, after, before, pipeline,
+                               skip=mod_journal.is_index_litter)
         if isinstance(files, DNError):
             raise files
         # never open build machinery as a shard: journals, in-flight

@@ -457,6 +457,48 @@ def numeric_leaf_plan(op, const):
     return (mode, t)
 
 
+def _time_shape(bounds):
+    """A scan's time bounds as its device program takes them:
+    ((lo_mode, hi_mode), (lo, hi)).  The modes are the program's static
+    structure (and its cache key's part); the values are ARGUMENTS of
+    the program ('tb_lo' / 'tb_hi' under the scan's prefix), so a
+    resident server that builds one window after another (`dn build
+    --after D --before D+1`, day after day) runs one compiled program,
+    not one a window.
+
+    A mode is None (no test), 'arg' (compare with the argument) or
+    'never' (nothing passes).  Bounds may lie outside int32 (a
+    far-future timeBefore as "unbounded" is a plausible idiom;
+    jnp.int32(2208988800) raises on numpy>=2).  Uploaded ts values are
+    exact-i32 (the eligibility check falls back otherwise), so an
+    out-of-range bound resolves statically: vacuous or
+    nothing-passes."""
+    lo, hi = bounds
+    lo_mode = hi_mode = None
+    if lo is not None:
+        lo = int(lo)
+        lo_mode = 'never' if lo > I32MAX else \
+            'arg' if lo > I32MIN else None
+    if hi is not None:
+        hi = int(hi)
+        hi_mode = 'never' if hi <= I32MIN else \
+            'arg' if hi <= I32MAX else None
+    return (lo_mode, hi_mode), (lo if lo_mode == 'arg' else 0,
+                                hi if hi_mode == 'arg' else 0)
+
+
+def _clamp_to_bounds(minmax, time_args):
+    """(min, max) of a timestamp column cut to the bounds that are the
+    program's arguments; None where nothing is left."""
+    (lo_mode, hi_mode), (lo, hi) = time_args
+    mn, mx = minmax
+    if lo_mode == 'arg':
+        mn = max(mn, lo)
+    if hi_mode == 'arg':
+        mx = min(mx, hi - 1)
+    return (mn, mx) if mn <= mx else None
+
+
 class _KeyPlan(object):
     """Per-breakdown device plan + its growing window/capacity state."""
 
@@ -518,6 +560,9 @@ class DeviceScan(VectorScan):
         # one merged inputs dict while parser-derived columns stay
         # shared across metrics
         self._pfx = ''
+        # the time bounds as the program takes them: (modes, values)
+        self._time_args = _time_shape(self.time_bounds) \
+            if self.time_bounds is not None else None
         self._alone = None        # the stack of this scan alone
         self._records_seen = 0
         self._backend_ok = None
@@ -1161,6 +1206,11 @@ class DeviceScan(VectorScan):
             need = set()
             if self.time_bounds is not None:
                 need.add('dn_ts')
+                for mode, name, v in zip(self._time_args[0],
+                                         ('tb_lo', 'tb_hi'),
+                                         self._time_args[1]):
+                    if mode == 'arg':
+                        inputs[pfx + name] = np.int32(v)
             for p in self._plans:
                 if p.field.startswith('\0synth:'):
                     need.add(p.field[len('\0synth:'):])
@@ -1245,6 +1295,15 @@ class DeviceScan(VectorScan):
                     sel = synth_vals[sname][ok]
                     minmax = (int(sel.min()), int(sel.max())) \
                         if len(sel) else None
+                    if minmax is not None and self._time_args and \
+                            sfield[sname] == sfield['dn_ts']:
+                        # a column of the time filter's own field: a
+                        # row outside the bounds is dead before its
+                        # code is used, so the window need not hold it
+                        # (a one-day build of a year's file keeps a
+                        # day's segments, not the year's)
+                        minmax = _clamp_to_bounds(minmax,
+                                                  self._time_args)
                 else:
                     st = _stats(p.name)
                     if st is not None and st[0] == 0 and st[5] == 0:
@@ -1347,6 +1406,12 @@ class DeviceScan(VectorScan):
             self._flush()
             self._epoch_sig = sig
             self._programs = None
+        if self._time_args:
+            # a bounded scan's window origins move with its bounds:
+            # arguments of its program like them, not constants of it
+            for p in self._plans:
+                if p.kind == 'lin':
+                    inputs[pfx + 'lo_' + p.name] = np.int32(p.lo)
 
         # the overflow guard runs AFTER any epoch-flip flush (a flush
         # resets the unique-count bound, which must then re-reserve
@@ -1575,8 +1640,12 @@ class DeviceScan(VectorScan):
         (which inputs are synthesized on device instead of uploaded);
         batches with different profiles use different cached
         variants."""
-        plans = tuple((p.kind, p.name, p.field, p.step, p.lo,
-                       p.host_translate) for p in self._plans)
+        # a bounded scan's linear windows start where its bounds do:
+        # their origins are arguments ('lo_<name>'), not the key's
+        plans = tuple((p.kind, p.name, p.field, p.step,
+                       None if self._time_args and p.kind == 'lin'
+                       else p.lo, p.host_translate)
+                      for p in self._plans)
         leaves = tuple(
             (key, self._num_plans[i])
             for i, (key, _) in enumerate(self._leaf_list))
@@ -1586,7 +1655,9 @@ class DeviceScan(VectorScan):
             if self.ds_pred is not None else None,
             jsv.json_stringify(self.user_pred.ast)
             if self.user_pred is not None else None,
-            self.time_bounds,
+            # the bounds' static shape alone: their values are the
+            # program's arguments (_time_shape)
+            self._time_args[0] if self._time_args else None,
             # ordered (name, field) pairs: the traced body bakes in
             # field-derived input keys ('tsf_<field>') and an
             # order-dependent error chain ('terr_<f1|f2>'), so neither
@@ -1682,7 +1753,7 @@ class DeviceScan(VectorScan):
             def ts_key(name):
                 return pfx + 'ts_' + name
         num_plans = self._num_plans
-        time_bounds = self.time_bounds
+        time_modes = self._time_args[0] if self._time_args else None
         has_synth = bool(self.synthetic)
         ds_ast = self.ds_pred.ast if self.ds_pred is not None else None
         user_ast = self.user_pred.ast if self.user_pred is not None \
@@ -1814,30 +1885,17 @@ class DeviceScan(VectorScan):
                 alive = alive & (terr == 0)
                 counters.append(isum(alive))
 
-            if time_bounds is not None:
+            if time_modes is not None:
                 counters.append(isum(alive))
                 ts = args[ts_key('dn_ts')]
-                lo, hi = time_bounds
+                lo_mode, hi_mode = time_modes
                 ok = jnp.ones((bn,), dtype=bool)
-                # Bounds are Python ints baked at trace time and may lie
-                # outside int32 (a far-future timeBefore as "unbounded"
-                # is a plausible idiom; jnp.int32(2208988800) raises on
-                # numpy>=2).  Uploaded ts values are exact-i32 (the
-                # eligibility check falls back otherwise), so an
-                # out-of-range bound resolves statically: vacuous or
-                # nothing-passes.
-                if lo is not None:
-                    lo = int(lo)
-                    if lo > I32MAX:
-                        ok = ok & False
-                    elif lo > I32MIN:
-                        ok = ok & (ts >= i32(lo))
-                if hi is not None:
-                    hi = int(hi)
-                    if hi <= I32MIN:
-                        ok = ok & False
-                    elif hi <= I32MAX:
-                        ok = ok & (ts < i32(hi))
+                if 'never' in time_modes:
+                    ok = ok & False
+                if lo_mode == 'arg':
+                    ok = ok & (ts >= args[pfx + 'tb_lo'])
+                if hi_mode == 'arg':
+                    ok = ok & (ts < args[pfx + 'tb_hi'])
                 counters.append(isum(alive & ~ok))
                 alive = alive & ok
                 counters.append(isum(alive))
@@ -1865,8 +1923,10 @@ class DeviceScan(VectorScan):
                 if p.kind == 'p2':
                     codes.append(p2_int(v))
                 else:
-                    codes.append(jnp.floor_divide(v, i32(p.step)) -
-                                 i32(p.lo))
+                    codes.append(
+                        jnp.floor_divide(v, i32(p.step)) -
+                        (i32(p.lo) if time_modes is None
+                         else args[pfx + 'lo_' + p.name]))
             counters.append(nnon)
             counters.append(isum(alive) if sparse_cap
                             else jnp.int32(0))   # nspillrecords

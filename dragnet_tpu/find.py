@@ -161,11 +161,15 @@ class _Eof(object):
         self.gen = gen
 
 
-def find_walk(roots, pipeline, pathenum=None):
+def find_walk(roots, pipeline, pathenum=None, skip=None):
     """Walk `roots` recursively, returning [(path, statbuf)] for every
     regular file and character device, in the reference's emission order
     (FIFO/BFS with lexicographic dirents).  Registers the pipeline stages
-    and counters that `dn --counters` reports.
+    and counters that `dn --counters` reports.  Directory entries whose
+    name `skip` accepts are not there for the walk (an index walk
+    passes index_journal.is_index_litter: a builder's tmps and journals
+    are no part of the tree before their commit, in the counters as in
+    the answer).
     """
     if pathenum is not None:
         pe_stage = pipeline.stage('PathEnumerator')
@@ -216,6 +220,8 @@ def find_walk(roots, pipeline, pathenum=None):
             except OSError as e:
                 traverser.warn(e, 'badreaddir')
                 continue
+            if skip is not None:
+                dirents = [d for d in dirents if not skip(d)]
             traverser.bump('noutputs')
             feedback.bump('ninputs')
             feedback.bump('ndirectories')

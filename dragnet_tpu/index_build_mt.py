@@ -35,6 +35,7 @@ stdin point stream of `dn index-read`: points arrive in bounded chunks
 bulk write path, and flush on the same pool.
 """
 
+import contextlib
 import os
 import threading
 from collections import OrderedDict
@@ -369,6 +370,53 @@ def publish_prepared(journal, sinks, paths, extra_paths=None,
                 'deletes': list(deletes or []),
                 'integrity_remove': dict(integrity_remove or {})})
         journal.retire()
+    obs_metrics.inc('index_publishes_total')
+    obs_metrics.inc('index_publish_shards_total', len(paths))
+
+
+# the guard of this thread's builds: see commit_guard
+_GUARD = threading.local()
+
+
+@contextlib.contextmanager
+def commit_guard(factory):
+    """For the builds this thread runs inside the block: their commit
+    (commit_prepared) is held inside `factory()`, a context-manager
+    factory.  `dn serve` gives the write side of the tree's lock
+    (admission.TreeLock.write), so a query sees a multi-shard publish
+    whole or not at all while the scan, the bucketing and the prepare
+    before it ran beside the queries.  The CLI's one-shot `dn build`,
+    `dn index-read`, `dn follow` and the compactor set none.  The
+    guard is the thread's and no argument of the writers, whose
+    signatures callers outside this package hold (write_index_blocks
+    is called with its four arguments)."""
+    prev = getattr(_GUARD, 'factory', None)
+    _GUARD.factory = factory
+    try:
+        yield
+    finally:
+        _GUARD.factory = prev
+
+
+def commit_prepared(indexroot, journal, sinks, paths):
+    """A build's commit, the one part of it that excludes the tree's
+    readers: this builder's stale intents retired, publish_prepared,
+    and the write hooks (so a resident server's caches are retired
+    before the first reader re-enters), all inside the thread's
+    commit_guard, where one is set.  Both publishers end here, on the
+    thread that asked for the build.
+
+    cleanup_own_stale runs here, under the guard, and not with the
+    recovery sweep at the prepare's start: it acts on THIS pid's
+    journals, and in a resident server that pid also owns the
+    compactor's, whose commit (its record landed, its renames under
+    way) holds the same write side."""
+    from . import index_journal as mod_journal
+    factory = getattr(_GUARD, 'factory', None)
+    with factory() if factory is not None else contextlib.nullcontext():
+        mod_journal.cleanup_own_stale(indexroot)
+        publish_prepared(journal, sinks, paths)
+        _notify_index_written(indexroot, paths)
 
 
 def _publish_buckets(metrics, indexroot, buckets, catalog, nworkers):
@@ -380,21 +428,21 @@ def _publish_buckets(metrics, indexroot, buckets, catalog, nworkers):
     quarantined) or exactly post-build (commit record: renames
     finished) — never a mix.  Prepare-phase errors keep the seed
     contract: the earliest bucket-order error re-raises and no tmp
-    litter survives."""
+    litter survives.  Only phase 2 runs inside the thread's
+    commit_guard (commit_prepared)."""
     from . import index_journal as mod_journal
     from .obs import trace as obs_trace
 
     paths = [p for p, config, parts in buckets]
     sinks = [None] * len(buckets)
     try:
-        # a leaf of the request's thread: the tree's recovery sweep,
-        # this builder's stale intents retired and the build's journal
-        # opened (0.6 ms a build together), then its wait for the flush
-        # pool (the pool's threads work beside it, under no leaf)
+        # a leaf of the request's thread: the tree's recovery sweep
+        # (dead owners' litter only) and the build's journal opened,
+        # then its wait for the flush pool (the pool's threads work
+        # beside it, under no leaf)
         with obs_metrics.leaf_stage('index_build.prepare',
                                     nshards=len(buckets)):
             mod_journal.sweep_index_tree(indexroot)
-            mod_journal.cleanup_own_stale(indexroot)
             journal = mod_journal.BuildJournal(indexroot)
             tasks = [_prepare_task(metrics, path, config, parts, catalog,
                                    journal.tmp_suffix, sinks, i)
@@ -406,8 +454,7 @@ def _publish_buckets(metrics, indexroot, buckets, catalog, nworkers):
                 sink.abort()
         raise
     with obs_trace.span('index_build.publish', nshards=len(paths)):
-        publish_prepared(journal, sinks, paths)
-        _notify_index_written(indexroot, paths)
+        commit_prepared(indexroot, journal, sinks, paths)
 
 
 def write_index_blocks(metrics, interval, indexroot, blocks,
@@ -501,7 +548,6 @@ class StreamingIndexWriter(object):
         # every sink writes tmps under this build's id; finish()
         # publishes the whole set through the commit journal
         mod_journal.sweep_index_tree(indexroot)
-        mod_journal.cleanup_own_stale(indexroot)
         self._journal = mod_journal.BuildJournal(indexroot)
         self._catalog = metric_catalog_rows(metrics)
         self._names = [[b['b_name'] for b in m.m_breakdowns]
@@ -603,6 +649,5 @@ class StreamingIndexWriter(object):
                     sink.abort()
             raise
         paths = [self.sinkpaths[key] for key, sink in entries]
-        publish_prepared(self._journal, [s for k, s in entries],
-                         paths)
-        _notify_index_written(self.indexroot, paths)
+        commit_prepared(self.indexroot, self._journal,
+                        [s for k, s in entries], paths)

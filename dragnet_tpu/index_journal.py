@@ -435,9 +435,12 @@ def cleanup_own_stale(indexroot):
     supersedes that intent, and must retire it BEFORE publishing:
     otherwise, after this process dies, the sweep would roll the
     STALE journal forward over the newer shards.  Callers are the
-    publishers themselves, at publish start (one publish per tree at
-    a time — the serve layer's TreeLock serializes; the CLI is one
-    build per process)."""
+    publishers themselves, at their commit's start
+    (index_build_mt.commit_prepared), one publish per tree at a time:
+    in `dn serve` under the write side of the tree's TreeLock, which
+    the compactor's commit holds too (this pid's journals are its
+    journals), and with the tree's build mutex keeping two builds
+    apart; the CLI is one build per process."""
     indexroot = os.path.abspath(indexroot)
     try:
         names = sorted(os.listdir(indexroot))

@@ -668,6 +668,10 @@ def invalidate_index_tree(root):
     mod_rollup.planner_memo_drop(root)
     for handle in closing:
         handle.querier.close()
+    if closing:
+        from .obs import metrics as obs_metrics
+        obs_metrics.inc('index_shard_handles_retired_total',
+                        len(closing))
 
 
 def find_cache_stats():
@@ -841,7 +845,9 @@ class TreeSnapshot(object):
         with the snapshot."""
         if self._walk is None:
             nstages = len(pipeline.stages)
-            files = mod_find.find_walk([self.root], pipeline)
+            from . import index_journal as mod_journal
+            files = mod_find.find_walk(
+                [self.root], pipeline, skip=mod_journal.is_index_litter)
             self._walk = (files,
                           [(s.name, dict(s.counters), set(s.hidden))
                            for s in pipeline.stages[nstages:]])

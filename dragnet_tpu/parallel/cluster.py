@@ -91,9 +91,10 @@ class DatasourceCluster(datasource_file.DatasourceFile):
     """File-layout datasource executed over the device mesh / process
     set."""
 
-    def _find(self, root, timeformat, start_ms, end_ms, pipeline):
+    def _find(self, root, timeformat, start_ms, end_ms, pipeline,
+              skip=None):
         files = super(DatasourceCluster, self)._find(
-            root, timeformat, start_ms, end_ms, pipeline)
+            root, timeformat, start_ms, end_ms, pipeline, skip=skip)
         if isinstance(files, DNError):
             return files
         nprocs, pid = mod_dist.maybe_initialize()

@@ -463,25 +463,67 @@ class Admission(object):
 
 
 class TreeLock(object):
-    """Writer-priority reader/writer lock, one per index tree: index
-    queries hold the read side while they execute, builds hold the
-    write side — so a query never enumerates a tree mid-rewrite (the
-    writer's tmp+rename discipline makes each SHARD atomic, but the
-    tree as a whole grows tmp litter and partial shard sets while a
-    build runs, and a resident server overlaps those freely).  Writer
-    priority keeps a build from starving under a steady query load."""
+    """Writer-priority reader/writer lock, one per index tree, and the
+    tree's build mutex.
+
+    What the two sides guard is a publish's COMMIT, not a build: index
+    queries hold the read side while they execute; a build holds the
+    write side only around its commit (the journal's commit record,
+    the renames, the writer-invalidation hooks:
+    index_build_mt's `commit_guard`), so a query sees a multi-shard
+    publish whole or not at all, and the first query after a build's
+    reply finds the caches already retired.  The build's raw scan, its
+    bucketing and its prepared tmps run beside the readers: every
+    shard lands by one atomic rename, and readers filter journal, tmp
+    and quarantine names (index_journal.is_index_litter), exactly as
+    they do under a `dn follow` publishing from another process.
+    Writer priority keeps a commit from starving under a steady query
+    load: from the moment it asks, new readers wait, and it enters
+    when the readers in flight have left.
+
+    `building()` is the per-tree build mutex, held for the whole of a
+    build and never taken by a query: two builds of one tree must not
+    overlap (one process is one pid, and
+    index_journal.cleanup_own_stale retires this pid's earlier
+    journals at a commit's start).
+
+    A side that has to wait to enter observes the wait
+    (`serve_tree_lock_wait_ms{side}`, under the leaf `serve.tree_lock`;
+    the uncontended path observes nothing, so the histogram counts
+    waits, not entries), the write side also how long it was held
+    (`serve_tree_lock_held_ms{side="write"}`)."""
 
     def __init__(self):
         self._cond = threading.Condition()
         self._readers = 0
         self._writer = False
         self._writers_waiting = 0
+        self._build = threading.Lock()
+        # a scrape shows the series from the tree's first request on,
+        # at 0 until somebody waits
+        for side in ('read', 'write'):
+            obs_metrics.global_registry().histogram(
+                'serve_tree_lock_wait_ms', side=side)
+
+    def _wait(self, side, blocked):
+        """Wait (self._cond held) until `blocked()` is false."""
+        if not blocked():
+            return
+        t0 = time.monotonic()
+        try:
+            with obs_metrics.leaf_stage('serve.tree_lock', side=side):
+                while blocked():
+                    self._cond.wait()
+        finally:
+            obs_metrics.observe('serve_tree_lock_wait_ms',
+                                (time.monotonic() - t0) * 1000.0,
+                                side=side)
 
     @contextmanager
     def read(self):
         with self._cond:
-            while self._writer or self._writers_waiting:
-                self._cond.wait()
+            self._wait('read', lambda: self._writer or
+                       self._writers_waiting)
             self._readers += 1
         try:
             yield
@@ -495,17 +537,26 @@ class TreeLock(object):
         with self._cond:
             self._writers_waiting += 1
             try:
-                while self._writer or self._readers:
-                    self._cond.wait()
+                self._wait('write', lambda: self._writer or
+                           self._readers)
             finally:
                 self._writers_waiting -= 1
             self._writer = True
+        t0 = time.monotonic()
         try:
             yield
         finally:
             with self._cond:
                 self._writer = False
                 self._cond.notify_all()
+            obs_metrics.observe('serve_tree_lock_held_ms',
+                                (time.monotonic() - t0) * 1000.0,
+                                side='write')
+
+    @contextmanager
+    def building(self):
+        with self._build:
+            yield
 
 
 class _Execution(object):

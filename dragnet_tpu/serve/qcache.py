@@ -14,8 +14,11 @@ staleness signals all agree:
   lifecycle.install_writer_invalidation hook fires on EVERY completed
   in-process index write (build, follow publish, compaction, rollup
   build).  Any write anywhere retires every entry: conservative,
-  O(1), and exactly the invalidation contract the issue's write-hook
-  machinery provides.
+  O(1) at the write, and exactly the invalidation contract the
+  issue's write-hook machinery provides.  The first lookup or insert
+  that brings the newer epoch drops every entry of an older one at
+  once (`serve_result_cache_retired_total`), so what a write cost
+  the cache is a number and its bytes are free again.
 
 * **Validators**: stat identities of the queried tree's shard-bearing
   directories, re-checked on every hit.  A CROSS-process writer (a
@@ -39,6 +42,7 @@ import threading
 from collections import OrderedDict
 
 from .. import integrity as mod_integrity
+from ..obs import metrics as obs_metrics
 
 
 def _estimate_nbytes(result):
@@ -123,11 +127,30 @@ class ResultCache(object):
         self._stale = 0
         self._evictions = 0
         self._shed = 0
+        self._epoch = -1
 
     def enabled(self):
         return self.budget > 0
 
     # -- internals (call with self._lock held) ----------------------------
+
+    def _retire_older_locked(self, epoch):
+        """The first caller to bring a newer epoch drops every entry
+        stamped with an older one (`serve_result_cache_retired_total`):
+        what an epoch bump cost the cache is a number, and the bytes
+        are free at once, not when each stale key is next asked
+        for."""
+        if epoch <= self._epoch:
+            return
+        self._epoch = epoch
+        stale = [(k, e) for k, e in self._entries.items()
+                 if e['epoch'] < epoch]
+        for key, ent in stale:
+            self._drop_locked(key, ent)
+        if stale:
+            self._stale += len(stale)
+            obs_metrics.inc('serve_result_cache_retired_total',
+                            len(stale))
 
     def _drop_locked(self, key, ent):
         # identity-checked: between a reader's two lock windows a put
@@ -157,6 +180,7 @@ class ResultCache(object):
         if not self.enabled() or key is None:
             return None
         with self._lock:
+            self._retire_older_locked(epoch)
             ent = self._entries.get(key)
             if ent is not None and ent['epoch'] == epoch:
                 self._entries.move_to_end(key)
@@ -196,6 +220,7 @@ class ResultCache(object):
         ent = {'epoch': epoch, 'validators': validators,
                'result': result, 'nbytes': nbytes}
         with self._lock:
+            self._retire_older_locked(epoch)
             old = self._entries.get(key)
             if old is not None:
                 self._drop_locked(key, old)

@@ -128,6 +128,7 @@ class DeviceResidency(object):
         self._shed = 0
         self._h2d_saved = 0
         self._d2h_saved = 0
+        self._epoch = -1
 
     def enabled(self):
         return self.budget > 0
@@ -147,6 +148,24 @@ class DeviceResidency(object):
             return True
         return False
 
+    def _retire_older_locked(self, epoch):
+        """As the result cache's (serve/qcache.py): the first caller
+        to bring a newer epoch drops every pin of an older one
+        (`device_residency_retired_total`), so the HBM is free at
+        once."""
+        if epoch <= self._epoch:
+            return
+        self._epoch = epoch
+        stale = [(k, e) for k, e in self._entries.items()
+                 if e['epoch'] < epoch]
+        for key, ent in stale:
+            self._drop_locked(key, ent)
+        if stale:
+            self._stale += len(stale)
+            from ..obs import metrics as obs_metrics
+            obs_metrics.inc('device_residency_retired_total',
+                            len(stale))
+
     # -- the residency protocol --------------------------------------------
 
     def get(self, key, epoch):
@@ -156,6 +175,7 @@ class DeviceResidency(object):
         if not self.enabled() or key is None:
             return None
         with self._lock:
+            self._retire_older_locked(epoch)
             ent = self._entries.get(key)
             if ent is not None and ent['epoch'] != epoch:
                 self._drop_locked(key, ent)
@@ -194,6 +214,7 @@ class DeviceResidency(object):
                'nbytes': nbytes, 'h2d_bytes': int(h2d_bytes or 0),
                'ts': time.time()}
         with self._lock:
+            self._retire_older_locked(epoch)
             old = self._entries.get(key)
             if old is not None:
                 self._drop_locked(key, old)

@@ -65,6 +65,7 @@ from .. import faults as mod_faults
 from .. import integrity as mod_integrity
 from .. import resources as mod_resources
 from .. import vpipe as mod_vpipe
+from .. import index_build_mt as mod_ibmt
 from .. import index_query_mt as mod_iqmt
 from .. import log as mod_log
 from ..errors import DNError
@@ -390,11 +391,11 @@ class DnServer(object):
         self._started_wall = time.time()
         self._hook = None
         self._thread = None
-        # per-index-tree reader/writer locks (admission.TreeLock):
-        # index queries read-lock, builds write-lock — concurrent
-        # builds over one tree would race on the writer's per-PID tmp
-        # names (one process = one pid), and a query walking a tree
-        # mid-rewrite would see tmp litter and partial shard sets
+        # per-index-tree locks (admission.TreeLock): index queries
+        # read-lock, a build write-locks for its commit alone (a
+        # query sees a multi-shard publish whole or not at all) and
+        # holds the tree's build mutex throughout — concurrent builds
+        # over one tree would race on this pid's journals
         self._tree_locks = {}
         self._tree_locks_lock = threading.Lock()
 
@@ -1646,9 +1647,11 @@ class DnServer(object):
                 # releasing again later is a no-op) and retire its
                 # coalescer registration so identical new requests
                 # recompute instead of attaching to a dead execution.
-                # A TreeLock held by an abandoned BUILD stays held on
-                # purpose — the tree is mid-rewrite and must not be
-                # served until the write actually finishes.
+                # An abandoned BUILD keeps its tree's build mutex
+                # (and, once it reaches its commit, takes the write
+                # side) on purpose — its tmps are mid-prepare, and no
+                # second build of the tree may start until the write
+                # actually finishes.
                 slot = flags.get('slot')
                 if slot is not None:
                     slot.release()
@@ -2221,8 +2224,19 @@ class DnServer(object):
             lease.release()
             raise
         flags['exec_t0'] = time.monotonic()
+        # The tree's readers are excluded for the build's COMMIT, not
+        # for the build: the raw scan, the bucketing and the prepared
+        # tmps run beside the queries (readers filter tmp and journal
+        # names; a shard lands by one rename), and the write side of
+        # the tree's lock is this thread's index_build_mt.commit_guard,
+        # entered by commit_prepared around the commit record, the
+        # renames and the invalidation hooks.  What is held for the
+        # whole build is the tree's build mutex, which no query takes:
+        # two builds of one tree never overlap.
+        tree = self._tree_lock(ds, dsname)
         try:
-            with self._tree_lock(ds, dsname).write(), \
+            with tree.building(), \
+                    mod_ibmt.commit_guard(tree.write), \
                     obs_trace.span('serve.execute', op='build'):
                 result = ds.build(metrics, interval,
                                   time_after=after,

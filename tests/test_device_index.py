@@ -471,16 +471,18 @@ def test_writer_epoch_retires_the_acc_pin(tmp_path, monkeypatch):
     mod_iqmt.shard_cache_clear()
     ref, cref = _run(ds2, 'day', conf, '0', monkeypatch)
     assert ref != pts1                         # the data really moved
+    mgr = residency.active()
+    stale0 = mgr.stats()['stale_drops']
     pts2, cnt2 = _run(ds2, 'day', conf, '1', monkeypatch)
     assert pts2 == ref and cnt2 == cref        # never the stale pin
     assert mod_di.stats_doc()['dispatches'] == 2     # folded anew
-    # the old epoch's entry is dropped where a lookup meets it
-    mgr = residency.active()
-    stale0 = mgr.stats()['stale_drops']
-    for key in list(mgr._entries):
-        mgr.get(key, mod_iqmt.cache_epoch())
+    # the old epoch's entry went where the new epoch was first met
+    # (the first lookup under it), and only the new pin is left
     st = mgr.stats()
     assert st['stale_drops'] == stale0 + 1 and st['entries'] == 1
+    for key in list(mgr._entries):
+        assert mgr.get(key, mod_iqmt.cache_epoch()) is not None
+    assert mgr.stats()['stale_drops'] == stale0 + 1
 
 
 # -- the probed DN_PARALLEL_FETCH capability --------------------------------

@@ -163,7 +163,11 @@ def _reference_plan(ds, interval, query):
     pipeline = Pipeline()
     root, timeformat, after, before = ds.index_find_params(
         interval, query.qc_after, query.qc_before)
-    files = ds._find(root, timeformat, after, before, pipeline)
+    # a builder's tmps and journals are no part of the tree for the
+    # walk, in its counters as in its files (a resident server's
+    # builds prepare beside its queries)
+    files = ds._find(root, timeformat, after, before, pipeline,
+                     skip=mod_journal.is_index_litter)
     files = [(p, st) for p, st in files
              if not mod_journal.is_index_litter(p)]
     files = mod_rollup.augment_generation_files(root, files)

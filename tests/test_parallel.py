@@ -852,7 +852,10 @@ def test_record_specs_of_a_single_mesh_scan_are_pinned(tmp_path,
     pinned = {
         'nvalid': None, 'tags_req.method': 'd', 'str_req.method': 'd',
         'tsf_time': 'd', 'terr_time': 'd', 'kv_latency': 'd',
-        'tab_0': None, 'ctab_0': None}
+        'tab_0': None, 'ctab_0': None,
+        # the time bounds: two scalars among the program's arguments
+        # since PR 50, on every chip alike
+        'tb_lo': None, 'tb_hi': None}
     host_keys = ({'key_host': 'd'}, {'str_host': 'd', 'trans_host': None})
     assert seen
     for s in seen:

@@ -61,8 +61,10 @@ SERVED = ('serve.resolve', 'reply.frame')
 LEAVES = {'scan': SCAN_STAGES + REPLY_STAGES + BETWEEN['scan'],
           'build': SCAN_STAGES + BETWEEN['build'],
           'query': QUERY_STAGES + REPLY_STAGES + BETWEEN['query']}
+# `serve.tree_lock` is a leaf only where a request waited for its
+# tree's lock (PR 50: tests/test_live_publish.py); none here does
 ALL_LEAVES = set(SCAN_STAGES + QUERY_STAGES + REPLY_STAGES + SERVED +
-                 sum(BETWEEN.values(), ()))
+                 sum(BETWEEN.values(), ())) | {'serve.tree_lock'}
 # in `stage_ms` and no leaf: they enclose leaves
 ENCLOSING = ('index_query_stack.aggregate', 'device_scan.probe')
 

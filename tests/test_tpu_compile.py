@@ -145,7 +145,7 @@ def test_segment_sum_aggregate_compiles(one_chip, radices):
 
 # -- the DeviceScan programs --------------------------------------------------
 
-def _staged_program(query_conf, datafile, scan_cls=None):
+def _staged_program(query_conf, datafile, scan_cls=None, time_field=None):
     """(jitted program, example inputs, accumulator shapes, use_pallas)
     of the program a lone DeviceScan, a stack of one, builds for one
     real batch of `datafile`: staged on the CPU backend exactly as a
@@ -154,7 +154,7 @@ def _staged_program(query_conf, datafile, scan_cls=None):
     jax, _ = get_jax()
     if scan_cls is None:
         scan_cls = DeviceScan
-    scan = scan_cls(mod_query.query_load(dict(query_conf)), None,
+    scan = scan_cls(mod_query.query_load(dict(query_conf)), time_field,
                     Pipeline())
     parser = one_batch_parser(datafile, scan, BATCH)
     n = parser.batch_size()
@@ -184,6 +184,35 @@ def test_device_scan_program_compiles(one_chip, corpus, name,
     run, inputs, acc, use_pallas = _staged_program(query_conf, corpus)
     assert not use_pallas
     assert (len(acc) == 5) == sparse     # the sparse set's five leaves
+    _compile(run, _like(inputs, one_chip), (_like(acc, one_chip),))
+
+
+def test_bounded_scan_program_takes_its_bounds_as_arguments(one_chip,
+                                                            corpus):
+    """A scan with time bounds (a build of one day's window): the
+    bounds are two int32 scalars among the program's arguments, the
+    program of another day is the same cached one, and the v5e's
+    compiler takes it."""
+    hour = 3600000
+    # the corpus's own window: three hours of one evening
+    start = int(bench._mktestdata().MINDATE.timestamp() * 1000)
+    assert start % hour == 0
+    runs = []
+    by_hour = {'name': 'timestamp', 'field': 'time', 'date': '',
+               'aggr': 'lquantize', 'step': 3600}
+    for after in (start, start + 2 * hour):
+        conf = dict(bench.QUERY, timeAfter=after, timeBefore=after + hour,
+                    breakdowns=[by_hour] + bench.QUERY['breakdowns'])
+        run, inputs, acc, _ = _staged_program(conf, corpus,
+                                              time_field='time')
+        assert inputs['tb_lo'] == after // 1000
+        assert inputs['tb_hi'] == (after + hour) // 1000
+        assert inputs['tb_lo'].dtype == np.int32
+        # the hour column's window starts at the bounds' hour, as an
+        # argument too
+        assert inputs['lo_timestamp'] == after // hour
+        runs.append(run)
+    assert runs[0] is runs[1]
     _compile(run, _like(inputs, one_chip), (_like(acc, one_chip),))
 
 
