@@ -36,6 +36,7 @@ from . import batch as mod_batch
 from . import log as mod_log
 from . import query as mod_query
 from .aggr import Aggregator
+from .obs import metrics as obs_metrics
 from .ops.kernels import FALSE, TRUE, ERROR
 
 BATCH_SIZE = 65536
@@ -51,6 +52,12 @@ LOG = mod_log.get('engine')
 # memory stays bounded by unique tuples.
 DEFER_UNIQUE = 4096
 DEFER_COMPACT_ROWS = 1 << 21
+
+# A key column's numbers are translated through a table kept on the
+# engine column (_native_num_codes): one int64 slot a non-negative
+# integral value below this bound, at most 8 MiB a column.
+NUM_TRANS_BOUND = 1 << 20
+_NO_NUM_TRANS = np.zeros(0, dtype=np.int64)
 
 
 def engine_mode():
@@ -82,6 +89,71 @@ def _native_str_trans(column, parser_dict):
         cache = np.concatenate([cache, new])
         column._native_trans = cache
     return cache
+
+
+def _number_codes_by_value(column, vals):
+    """The f64 numbers `vals` coded in the engine dictionary, one
+    String(v) a distinct value, new values in ascending order: the
+    miss path of _native_num_codes.  Returns np.unique's (uniq, inv)
+    with the codes of uniq between them: vals' codes are ucodes[inv]."""
+    code = column.dict.code
+    uniq, inv = np.unique(vals, return_inverse=True)
+    # TAG_INT means integral |v| <= 2^53: prints without a dot
+    table = np.array([
+        code(s, s) for s in
+        (jsv.number_to_string(int(u) if float(u).is_integer()
+                              and abs(u) <= 2 ** 53 else u)
+         for u in uniq)], dtype=np.int64)
+    return uniq, table, inv
+
+
+def _table_slots(vals, size):
+    """(iv, held): vals as int64 and which of them are a slot of a
+    table of `size`, i.e. integral and in [0, size) (the unsigned
+    compare tests both ends; a NaN or a value past int64 is not)."""
+    with np.errstate(invalid='ignore'):
+        iv = vals.astype(np.int64)
+    held = iv == vals
+    held &= iv.view(np.uint64) < np.uint64(size)
+    return iv, held
+
+
+def _native_num_codes(column, vals):
+    """Engine-dictionary codes of a native column's f64 numbers,
+    answered from a value -> code table kept on the engine column
+    across batches (`_native_num_trans`: the slot of an integral value
+    in [0, NUM_TRANS_BOUND), -1 until the value has been coded).  Only
+    values the table does not hold (unseen, non-integral, negative,
+    past the bound) go through _number_codes_by_value, so the
+    dictionary numbers its values as that path alone would.  The
+    column is shared across scan_mt worker threads: a grown table is a
+    fresh array, a published one is never written."""
+    table = getattr(column, '_native_num_trans', _NO_NUM_TRANS)
+    iv, held = _table_slots(vals, len(table))
+    if held.all():
+        codes = table[iv]
+    else:
+        codes = np.full(len(vals), -1, dtype=np.int64)
+        codes[held] = table[iv[held]]
+    miss = np.flatnonzero(codes < 0)
+    if not len(miss):
+        obs_metrics.inc('scan_key_translate_total', path='table')
+        return codes
+    obs_metrics.inc('scan_key_translate_total', path='values')
+    uniq, ucodes, inv = _number_codes_by_value(column, vals[miss])
+    codes[miss] = ucodes[inv]
+    uiv, keep = _table_slots(uniq, NUM_TRANS_BOUND)
+    if keep.any():
+        uiv = uiv[keep]
+        # onto the table as it stands now: another thread may have
+        # published since this call read it
+        table = getattr(column, '_native_num_trans', table)
+        size = min(1 << int(uiv.max()).bit_length(), NUM_TRANS_BOUND)
+        grown = np.full(max(size, len(table)), -1, dtype=np.int64)
+        grown[:len(table)] = table
+        grown[uiv] = ucodes[keep]
+        column._native_num_trans = grown
+    return codes
 
 
 def fuse_codes(cols):
@@ -329,14 +401,22 @@ class NativeColumns(object):
             trans = _native_str_trans(column,
                                       self.parser.dictionary(path))
             return trans[strcodes]
-        out = np.empty(self.n, dtype=np.int64)
         code = column.dict.code
-        out[tags == mn.TAG_MISSING] = code('undefined', 'undefined')
-        out[tags == mn.TAG_NULL] = code('null', 'null')
-        out[tags == mn.TAG_TRUE] = code('true', 'true')
-        out[tags == mn.TAG_FALSE] = code('false', 'false')
-        out[tags == mn.TAG_OBJECT] = code('[object Object]',
-                                          '[object Object]')
+        # the constant tags' codes first and in this order, rows or
+        # none: the dictionary numbers its values by first call
+        consts = ((mn.TAG_MISSING, code('undefined', 'undefined')),
+                  (mn.TAG_NULL, code('null', 'null')),
+                  (mn.TAG_TRUE, code('true', 'true')),
+                  (mn.TAG_FALSE, code('false', 'false')),
+                  (mn.TAG_OBJECT, code('[object Object]',
+                                       '[object Object]')))
+        isnum = (tags == mn.TAG_INT) | (tags == mn.TAG_NUMBER)
+        if isnum.all():
+            # all-numbers column: no other tag has a row to code
+            return _native_num_codes(column, nums)
+        out = np.empty(self.n, dtype=np.int64)
+        for tag, c in consts:
+            out[tags == tag] = c
         m = tags == mn.TAG_ARRAY
         if m.any():
             out[m] = -1  # sentinel: every array row must be covered
@@ -350,17 +430,8 @@ class NativeColumns(object):
                 raise RuntimeError(
                     'native parser: array-tagged row with unparseable '
                     'dictionary entry (field %r)' % path)
-        m = (tags == mn.TAG_INT) | (tags == mn.TAG_NUMBER)
-        if m.any():
-            tagm = tags[m]
-            uniq, inv = np.unique(nums[m], return_inverse=True)
-            # TAG_INT means integral |v| <= 2^53: prints without a dot
-            table = np.array([
-                code(s, s) for s in
-                (jsv.number_to_string(int(u) if float(u).is_integer()
-                                      and abs(u) <= 2 ** 53 else u)
-                 for u in uniq)], dtype=np.int64)
-            out[m] = table[inv]
+        if isnum.any():
+            out[isnum] = _native_num_codes(column, nums[isnum])
         m = tags == mn.TAG_STRING
         if m.any():
             d = self.parser.dictionary(path)

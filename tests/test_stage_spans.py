@@ -525,6 +525,16 @@ def test_the_planners_kept_reads_are_counted(tmp_path, monkeypatch):
 ORDER = 'dn_aggr_order_total{path="%s"}'
 
 
+def scrape_paths(series, paths):
+    """{path: value} of a `{path}`-labelled counter at a scrape, 0
+    where the series has not been bumped yet."""
+    from dragnet_tpu.obs import export as obs_export
+    doc = dict(ln.rsplit(' ', 1) for ln in
+               obs_export.prometheus_text().splitlines()
+               if not ln.startswith('#'))
+    return {p: float(doc.get(series % p, 0)) for p in paths}
+
+
 @pytest.mark.parametrize('path,ords', [
     ('fused', (0, 40)),
     # three bucketized levels spanning 2^31 ordinals each: the fused
@@ -537,7 +547,6 @@ def test_a_columnar_order_counts_its_sort(path, ords):
     import numpy as np
     from dragnet_tpu import aggr as mod_aggr
     from dragnet_tpu import query as mod_query
-    from dragnet_tpu.obs import export as obs_export
     query = mod_query.query_load({'breakdowns': [
         {'name': 'host'},
         {'name': 'a', 'aggr': 'lquantize', 'step': 10},
@@ -557,11 +566,7 @@ def test_a_columnar_order_counts_its_sort(path, ords):
         return aggr
 
     def scrape():
-        doc = dict(ln.rsplit(' ', 1) for ln in
-                   obs_export.prometheus_text().splitlines()
-                   if not ln.startswith('#'))
-        return {p: float(doc.get(ORDER % p, 0))
-                for p in ('fused', 'lexsort')}
+        return scrape_paths(ORDER, ('fused', 'lexsort'))
 
     before = scrape()
     block = aggregate(50).point_block()
@@ -573,6 +578,53 @@ def test_a_columnar_order_counts_its_sort(path, ords):
     assert after[other] == before[other]
     assert counter_table()[
         ('aggr_order_total', (('path', path),))] == after[path]
+
+
+# -- (2b''') which path translated a numeric key column --------------------
+
+TRANSLATE = 'dn_scan_key_translate_total{path="%s"}'
+
+
+@pytest.mark.parametrize('batches,want', [
+    # every value new, then every value seen
+    ([[4, 9, 4], [9, 4]], [('values', 1), ('table', 1)]),
+    # one value the table has not seen is the whole call's `values`
+    ([[4, 9], [9, 5], [5, 4, 9]],
+     [('values', 1), ('values', 1), ('table', 1)]),
+    # outside the table's domain every time; strings count nothing
+    ([[2.5, 1], [2.5, 1], ['a', 'b'], [1, 'a']],
+     [('values', 1), ('values', 1), None, ('table', 1)])])
+def test_a_numeric_translation_counts_its_path(batches, want):
+    """`scan_key_translate_total{path}` at a scrape: exactly one bump
+    for each `NativeColumns.string_codes` call that had numbers to
+    translate, `table` when the kept table answered every one of
+    them, `values` when at least one went through the per-value path;
+    a call over no number bumps neither."""
+    from dragnet_tpu import batch as mod_batch
+    from dragnet_tpu import engine as mod_engine
+    from dragnet_tpu import native as mod_native
+    if mod_native.get_lib() is None:
+        pytest.skip('native parser not built')
+
+    def scrape():
+        return scrape_paths(TRANSLATE, ('table', 'values'))
+
+    parser = mod_native.NativeParser(['k'], [False])
+    column = mod_batch.StringColumn()
+    for values, grew in zip(batches, want):
+        parser.parse(''.join(json.dumps({'k': v}) + '\n'
+                             for v in values).encode())
+        before = scrape()
+        codes = mod_engine.NativeColumns(parser).string_codes('k', column)
+        parser.reset_batch()
+        after = scrape()
+        assert [column.dict.values[c] for c in codes] == [
+            v if isinstance(v, str) else '%g' % v for v in values]
+        assert {p: after[p] - before[p] for p in after} == {
+            p: (1 if grew and grew[0] == p else 0) for p in after}
+    assert counter_table()[
+        ('scan_key_translate_total', (('path', 'table'),))] == \
+        scrape()['table']
 
 
 def test_end_open_ends_the_leaf_once_and_counts_once():
